@@ -80,13 +80,19 @@ def set_device(device: str):
     idx = int(idx) if idx else 0
     if name in ("gpu", "cuda", "tpu", "xpu", "npu"):
         kind = _accelerator_kind()
+        if kind == "cpu":
+            raise RuntimeError(
+                f"set_device({device!r}): JAX's default backend is 'cpu' "
+                "— no accelerator is attached to this process")
     elif name == "cpu":
         kind = "cpu"
     else:
         raise ValueError(f"unknown device {device!r}")
     devs = jax.devices("cpu" if kind == "cpu" else None)
     if idx >= len(devs):
-        idx = 0
+        raise ValueError(
+            f"set_device({device!r}): index {idx} out of range, "
+            f"{len(devs)} {kind} device(s) visible")
     if kind != "cpu":
         jax.config.update("jax_default_device", devs[idx])
     _current = Place(kind, idx)
@@ -120,10 +126,7 @@ def is_compiled_with_custom_device(name: str = "tpu"):
 
 def synchronize(device=None):
     """Block until all dispatched work completes (stream sync analog)."""
-    try:
-        (jax.device_put(0) + 0).block_until_ready()
-    except Exception:
-        pass
+    (jax.device_put(0) + 0).block_until_ready()
 
 
 def get_available_device():

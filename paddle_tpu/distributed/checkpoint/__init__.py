@@ -136,7 +136,7 @@ def save_state_dict(state_dict, path, process_index=None,
     flat = _flatten(state_dict)
     proc = process_index
     if proc is None:
-        proc = getattr(jax, "process_index", lambda: 0)()
+        proc = jax.process_index()
     os.makedirs(path, exist_ok=True)
 
     # snapshot the array refs now (immutability makes this a consistent
@@ -157,7 +157,7 @@ def save_state_dict(state_dict, path, process_index=None,
                 meta_pkl[name] = leaf
 
     try:
-        n_procs = getattr(jax, "process_count", lambda: 1)()
+        n_procs = jax.process_count()
     except Exception:
         n_procs = 1
 

@@ -491,7 +491,7 @@ def batch_isend_irecv(p2p_op_list):
 
 
 def barrier(group=None):
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     try:
         (jnp.zeros(()) + 0).block_until_ready()
     except Exception:

@@ -221,20 +221,6 @@ def collective_matmul_dispatch(kind, x, w, bias=None, group=None,
         if m is None or ax not in m.axis_names:
             cm.record_dispatch(kind, False, "no_mesh")
             return None
-        # jax<0.5 legacy shard_map cannot lower ring collectives in a
-        # PARTIAL-manual region under an outer SPMD partition when any
-        # other mesh axis is live (XLA rejects the axis_index/ppermute
-        # lowering with PartitionId / manual-subgroup check failures —
-        # verified in-container; the sep-axis ring attention has the
-        # same latent limit). Decompose only when the ring axis is the
-        # sole >1-degree axis; newer jax keeps the multi-axis path.
-        if getattr(jax, "shard_map", None) is None:
-            from ....mesh import active_axis_info
-
-            degrees = active_axis_info()["degrees"]
-            if any(d > 1 for name, d in degrees.items() if name != ax):
-                cm.record_dispatch(kind, False, "legacy_multi_axis")
-                return None
 
     rows = _rows(x)
     n_out = int(w.shape[-1])

@@ -53,28 +53,33 @@ def in_manual_context(names) -> bool:
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-portable shard_map: ``jax.shard_map`` only exists from
-    jax 0.5/0.6; older installs (this image ships 0.4.37) carry it at
-    jax.experimental.shard_map with ``auto=`` (the complement of the
-    newer ``axis_names=``) and a ``check_rep`` flag whose replication
-    checker rejects some valid collectives — so it is disabled on the
-    legacy path, matching the new API's default behavior."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    kwargs = {"check_rep": False}
+    """``jax.shard_map`` manual over ``axis_names`` (all mesh axes when
+    None). ``check_vma=False``: the replication checker cannot infer the
+    replication the ring collectives and custom_vjp bodies produce."""
+    kwargs = {}
     if axis_names is not None:
-        auto = set(mesh.axis_names) - set(axis_names)
-        if auto:
-            kwargs["auto"] = frozenset(auto)
-    return _legacy(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, **kwargs)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kwargs)
+
+
+def dividing_axis(name, *dims):
+    """``name`` when the global mesh splits every one of ``dims`` evenly
+    over that axis (degree > 1), else None (replicate)."""
+    d = axis_degree(name)
+    return name if d > 1 and all(x % d == 0 for x in dims) else None
+
+
+def kernel_shard_map(f, in_specs, out_specs):
+    """Run a Pallas TPU kernel body per shard of the global mesh. Mosaic
+    kernels cannot be partitioned automatically: under a multi-device jit
+    the TPU lowering refuses them ("wrap the call in a shard_map") unless
+    every mesh axis is manual. Returns ``f`` itself where there is nothing
+    to wrap — no mesh, one device, or already fully manual."""
+    m = global_mesh()
+    if m is None or m.size == 1 or in_manual_context(m.axis_names):
+        return f
+    return shard_map(f, mesh=m, in_specs=in_specs, out_specs=out_specs)
 
 
 class GlobalMesh:

@@ -852,25 +852,11 @@ class Binomial(Distribution):
 
         def f(p):
             out_shape = shape + tuple(p.shape)
-            if hasattr(jax.random, "binomial"):
-                # O(shape) native sampler (upstream uses a dedicated
-                # binomial kernel); the bernoulli-sum fallback is
-                # O(total_count) memory and only safe for small n
-                return jax.random.binomial(
-                    k, n, p, shape=out_shape
-                ).astype(jnp.float32)
-            if n > 4096:
-                # normal approximation keeps memory bounded
-                mean = n * p
-                std = jnp.sqrt(n * p * (1.0 - p))
-                g = jax.random.normal(k, out_shape, jnp.float32)
-                return jnp.clip(jnp.round(mean + std * g), 0.0, n)
-            return jnp.sum(
-                jax.random.bernoulli(
-                    k, p, (n,) + out_shape
-                ).astype(jnp.float32),
-                axis=0,
-            )
+            # O(shape) native sampler (upstream uses a dedicated
+            # binomial kernel)
+            return jax.random.binomial(
+                k, n, p, shape=out_shape
+            ).astype(jnp.float32)
 
         return apply_op("binomial_sample", f, self.probs,
                         differentiable=False)

@@ -97,7 +97,9 @@ define_flag("dy2static_convert_control_flow", True,
             "predicate dispatch (upstream: jit/dy2static transformers)")
 define_flag("compilation_cache_dir", "",
             "persistent XLA compilation-cache directory (empty -> "
-            "~/.cache/paddle_tpu/xla_cache; 'off' disables). Analog of "
+            "<checkout>/.jax_cache; 'off' disables; where "
+            "JAX_COMPILATION_CACHE_DIR is set JAX reads that and this "
+            "flag sets nothing). Analog of "
             "the reference persisting optimized inference programs "
             "(paddle/fluid/inference/api/analysis_predictor.cc)")
 define_flag("jit_lint", "warn",

@@ -1576,7 +1576,7 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "moe_a2a for the expert all-to-all overlap)"),
     ("collective.declined.<reason>", "counter",
      "dispatch declines, by reason (off/degree/indivisible/"
-     "below_threshold/shape/no_mesh/legacy_multi_axis)"),
+     "below_threshold/shape/no_mesh)"),
     ("collective.ring_chunks", "counter",
      "total ring hops dispatched (overlap coverage)"),
     ("collective.quantized.<kind>", "counter",

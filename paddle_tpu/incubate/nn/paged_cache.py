@@ -568,6 +568,10 @@ class PagedKVCacheManager:
     def __init__(self, num_pages, page_size, kv_heads, head_dim,
                  dtype=jnp.bfloat16, kv_dtype=None, sanitizer=None,
                  mp_size=1, mp_rank=0):
+        # the serving path's jax.jit programs persist like to_static's
+        from ...jit.api import ensure_compilation_cache
+
+        ensure_compilation_cache()
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         # mp-mesh KV-head sharding (disaggregated serving / tensor

@@ -24,6 +24,7 @@ inside the traced function.
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -48,44 +49,39 @@ def live_static_functions():
     return list(_LIVE_STATICS)
 
 
+# the one default cache directory: fixed inside the checkout (the path
+# is part of the cache key — a directory that moves never hits)
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def ensure_compilation_cache():
-    """Enable JAX's persistent compilation cache (idempotent; called
-    before every framework-path compile: to_static, jit.load/Predictor,
-    bench). Plays the role of the reference's serialized optimized
-    programs (analysis_predictor warm start): a cold headline compile
-    is tens of seconds (54s measured in round 3); a warm start is a
-    disk hit. Controlled by FLAGS_compilation_cache_dir ('' -> default
-    ~/.cache/paddle_tpu/xla_cache, 'off' -> disabled); an explicit
-    JAX_COMPILATION_CACHE_DIR env (e.g. from bench.py) wins."""
+    """Enable JAX's persistent compilation cache (idempotent; wired at
+    the first to_static compile, jit.load/Predictor, and the first
+    PagedKVCacheManager — every BatchScheduler serves from those pools,
+    so the serving path's jax.jit programs land in it too). Plays the role of the reference's
+    serialized optimized programs (analysis_predictor warm start).
+    Where JAX_COMPILATION_CACHE_DIR is set JAX reads it and no
+    directory is set here; otherwise FLAGS_compilation_cache_dir
+    ('' -> <checkout>/.jax_cache, 'off' -> not wired)."""
     global _CACHE_WIRED
     if _CACHE_WIRED:
         return
     _CACHE_WIRED = True
-    from ..framework.flags import flag
-
     conf = flag("compilation_cache_dir")
     if conf == "off":
         return
-    import os
-
-    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or conf
-            or os.path.expanduser("~/.cache/paddle_tpu/xla_cache"))
-    try:
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = conf or _DEFAULT_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # default threshold is 1s of compile time: big programs (the
-        # ones worth persisting) qualify, trivia stays out of the dir
-        if os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS") \
-                is None:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # cache is an optimization, never fatal
-        import logging
-
-        logging.getLogger("paddle_tpu").warning(
-            "persistent compilation cache unavailable (%s); compiles "
-            "will be cold every process", e)
+    # default threshold is 1s of compile time: big programs (the ones
+    # worth persisting) qualify, trivia stays out of the dir
+    if os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS") is None:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def _tree_flatten(obj):

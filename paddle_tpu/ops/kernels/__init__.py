@@ -15,14 +15,23 @@ from ...framework.flags import flag
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas() -> bool:
     return on_tpu() and flag("use_pallas_kernels")
+
+
+def _per_shard(f, in_specs, out_specs):
+    """``f`` for a Pallas kernel body that may meet a multi-device mesh:
+    on the chip it must sit in a fully-manual shard_map there
+    (distributed/mesh.kernel_shard_map); off the chip (interpret mode,
+    XLA reference) the partitioner handles it and ``f`` runs as is."""
+    if not use_pallas():
+        return f
+    from ...distributed.mesh import kernel_shard_map
+
+    return kernel_shard_map(f, in_specs, out_specs)
 
 
 def interpret_mode() -> bool:

@@ -623,16 +623,29 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     0 <= q_pos - k_pos < window with out-of-band blocks skipped."""
     if window and not causal:
         raise ValueError("flash_attention: window requires causal=True")
-    b, sq, h, d = q.shape
-    hkv = k.shape[2]
-    sk = k.shape[1]
+    d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    q3 = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    k3 = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    v3 = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    out = _flash_core(q3, k3, v3, bool(causal), float(scale),
-                      int(block_q), int(block_k), int(window or 0))
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+    def local(q, k, v):
+        b, sq, h, _ = q.shape
+        hkv = k.shape[2]
+        sk = k.shape[1]
+        q3 = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        k3 = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
+        v3 = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
+        out = _flash_core(q3, k3, v3, bool(causal), float(scale),
+                          int(block_q), int(block_k), int(window or 0))
+        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+
+    # on a mesh: batch over dp, heads over mp, where they divide
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed.mesh import dividing_axis
+    from . import _per_shard
+
+    spec = P(dividing_axis("dp", q.shape[0]), None,
+             dividing_axis("mp", q.shape[2], k.shape[2]), None)
+    return _per_shard(local, (spec,) * 3, spec)(q, k, v)
 
 
 def flash_attention_with_lse(q, k, v, causal=False, sm_scale=None,

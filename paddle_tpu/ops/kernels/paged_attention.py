@@ -58,9 +58,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...framework.flags import flag
+from . import on_tpu  # defined before the package imports its kernels
 from .rope import apply_rotary_emb
 
 NEG_INF = -1e30
+
+
+def _scale_index(phys_page, q_head, group):
+    """Flat index of (page, kv head) into the scale sidecars. They ride
+    scalar prefetch FLATTENED: a 2-D SMEM operand pads its last dim to
+    128 words (2048 pages x 8 heads would take 1 MiB each — all of
+    SMEM), a 1-D one only rounds up its length."""
+    return phys_page * (pl.num_programs(1) // group) + q_head // group
 
 
 def _decode_kernel(scale, page_size, kvh_per_q, max_pages, window,
@@ -83,8 +92,9 @@ def _decode_kernel(scale, page_size, kvh_per_q, max_pages, window,
 
     @pl.when(p == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        # m/l live in SMEM: the TPU lowering stores scalars there only
+        m_ref[0, 0] = NEG_INF
+        l_ref[0, 0] = 0.0
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     seq_len = lens_ref[b]
@@ -102,11 +112,10 @@ def _decode_kernel(scale, page_size, kvh_per_q, max_pages, window,
         k = k_ref[0, 0]                   # (page_size, D)
         v = v_ref[0, 0]
         if quant:
-            phys = page_tbl_ref[b, p]
-            kvh = hq // kvh_per_q
+            si = _scale_index(page_tbl_ref[b, p], hq, kvh_per_q)
             q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * k_scale_ref[phys, kvh]
-            v = v.astype(jnp.float32) * v_scale_ref[phys, kvh]
+            k = k.astype(jnp.float32) * k_scale_ref[si]
+            v = v.astype(jnp.float32) * v_scale_ref[si]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -243,13 +252,13 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
             "neither")
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
 
     scalar_args = [page_table.astype(jnp.int32),
                    seq_lens.astype(jnp.int32)]
     if quant:
-        scalar_args += [k_scales.astype(jnp.float32),
-                        v_scales.astype(jnp.float32)]
+        scalar_args += [k_scales.astype(jnp.float32).reshape(-1),
+                        v_scales.astype(jnp.float32).reshape(-1)]
     cfg = (b, h, d, npages, page_size, kvh, max_pages, float(scale),
            int(window or 0), quant, bool(interpret))
     args = (q, k_pages, v_pages, *scalar_args)
@@ -394,11 +403,10 @@ def _ragged_kernel(scale, page_size, group, max_pages, t, window,
         k = k_ref[0, 0]                   # (page_size, D)
         v = v_ref[0, 0]
         if quant:
-            phys = page_tbl_ref[b, p]
-            kvh = hq // group
+            si = _scale_index(page_tbl_ref[b, p], hq, group)
             q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * k_scale_ref[phys, kvh]
-            v = v.astype(jnp.float32) * v_scale_ref[phys, kvh]
+            k = k.astype(jnp.float32) * k_scale_ref[si]
+            v = v.astype(jnp.float32) * v_scale_ref[si]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -472,7 +480,7 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
             "or neither")
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
 
     ragged = q_lens is not None
     scalar_args = [page_table.astype(jnp.int32),
@@ -480,8 +488,8 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
     if ragged:
         scalar_args.append(jnp.asarray(q_lens).astype(jnp.int32))
     if quant:
-        scalar_args += [k_scales.astype(jnp.float32),
-                        v_scales.astype(jnp.float32)]
+        scalar_args += [k_scales.astype(jnp.float32).reshape(-1),
+                        v_scales.astype(jnp.float32).reshape(-1)]
     cfg = (b, t, h, d, npages, page_size, kvh, max_pages,
            float(scale), int(window or 0), quant, ragged,
            bool(interpret))
@@ -721,7 +729,7 @@ def paged_ragged_fused_step(x, wq, wk, wv, wo, biases, cos, sin, pos,
     max_pages = page_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     has_bias = biases is not None
     cfg = (n_pad, e, nh, kvh, hd, npages, page_size,
            b_pad, t_pad, max_pages, float(scale), int(window or 0),

@@ -21,12 +21,39 @@ except ImportError:  # pragma: no cover
 
 
 def _choose_block_rows(n_rows, hidden, itemsize):
-    # keep block ≲ 2 MB VMEM; at least the fp32 sublane tile (8)
+    """Row block of a (block_rows, hidden) VMEM tile, ≲ 2 MB: the whole
+    array when it is that small (a block equal to the array is always
+    legal), else a multiple of the sublane tile (8) — rows that do not
+    divide are padded by ``_row_tiled_call``, never cut to one-row
+    blocks (the TPU lowering refuses a block whose last two dims are
+    neither (8, 128)-divisible nor the array's)."""
     target = (2 * 1024 * 1024) // max(hidden * itemsize, 1)
     br = max(8, min(256, target))
+    if n_rows <= br:
+        return n_rows
     while n_rows % br and br > 8:
         br //= 2
-    return br if n_rows % br == 0 else 1
+    return br
+
+
+def _row_tiled_call(kernel, x2d, vecs, interpret):
+    """Run a row-wise ``kernel(x_ref, *vec_refs, o_ref)`` over (n, h)
+    in row blocks; ``vecs`` are (h,) operands every block sees whole."""
+    n, h = x2d.shape
+    br = _choose_block_rows(n, h, x2d.dtype.itemsize)
+    n_pad = -(-n // br) * br
+    if n_pad != n:
+        x2d = jnp.pad(x2d, ((0, n_pad - n), (0, 0)))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n_pad, h), x2d.dtype),
+        grid=(n_pad // br,),
+        in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0))]
+        + [pl.BlockSpec((h,), lambda i: (0,))] * len(vecs),
+        out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
+        interpret=interpret,
+    )(x2d, *vecs)
+    return out[:n] if n_pad != n else out
 
 
 def _rms_kernel(eps, has_w, x_ref, *refs):
@@ -43,24 +70,22 @@ def _rms_kernel(eps, has_w, x_ref, *refs):
 
 
 def _rms_pallas(x2d, w, eps, interpret=False):
-    n, h = x2d.shape
-    br = _choose_block_rows(n, h, x2d.dtype.itemsize)
-    grid = (n // br,) if n % br == 0 else (n,)
-    if n % br != 0:
-        br = 1
-    in_specs = [pl.BlockSpec((br, h), lambda i: (i, 0))]
-    args = [x2d]
-    if w is not None:
-        in_specs.append(pl.BlockSpec((h,), lambda i: (0,)))
-        args.append(w)
-    return pl.pallas_call(
-        functools.partial(_rms_kernel, eps, w is not None),
-        out_shape=jax.ShapeDtypeStruct((n, h), x2d.dtype),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
-        interpret=interpret,
-    )(*args)
+    return _row_tiled_call(
+        functools.partial(_rms_kernel, eps, w is not None), x2d,
+        [] if w is None else [w], interpret)
+
+
+def _rows_per_shard(local, x, vecs):
+    """Run the row-wise ``local(x, *vecs)``; on a mesh, batch over dp
+    where it divides, everything else replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed.mesh import dividing_axis
+    from . import _per_shard
+
+    dp = dividing_axis("dp", x.shape[0]) if x.ndim > 1 else None
+    xs = P(dp, *[None] * (x.ndim - 1))
+    return _per_shard(local, (xs,) + (P(None),) * len(vecs), xs)(x, *vecs)
 
 
 def _rms_ref(x, w, eps):
@@ -79,10 +104,13 @@ def _rms_norm_core(x, w, eps):
     ok = (use_pallas() or interpret_mode()) and x.shape[-1] % 128 == 0
     record_dispatch("rms_norm", ok)
     if ok:
-        shape = x.shape
-        out = _rms_pallas(x.reshape(-1, shape[-1]), w, eps,
-                          interpret=interpret_mode())
-        return out.reshape(shape)
+        def local(x, *w):
+            out = _rms_pallas(x.reshape(-1, x.shape[-1]),
+                              w[0] if w else None, eps,
+                              interpret=interpret_mode())
+            return out.reshape(x.shape)
+
+        return _rows_per_shard(local, x, [] if w is None else [w])
     return _rms_ref(x, w, eps)
 
 
@@ -165,29 +193,15 @@ def layer_norm_fused(x, weight=None, bias=None, eps=1e-5):
     if not ok:
         return _ln_ref(x, weight, bias, eps)
 
-    shape = x.shape
-    x2d = x.reshape(-1, h)
-    n = x2d.shape[0]
-    br = _choose_block_rows(n, h, x2d.dtype.itemsize)
-    if n % br != 0:
-        br = 1
-    in_specs = [pl.BlockSpec((br, h), lambda i: (i, 0))]
-    args = [x2d]
-    if weight is not None:
-        in_specs.append(pl.BlockSpec((h,), lambda i: (0,)))
-        args.append(weight)
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((h,), lambda i: (0,)))
-        args.append(bias)
-    out = pl.pallas_call(
-        functools.partial(_ln_kernel, eps, weight is not None, bias is not None),
-        out_shape=jax.ShapeDtypeStruct((n, h), x2d.dtype),
-        grid=(n // br,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
-        interpret=interpret_mode(),
-    )(*args)
-    return out.reshape(shape)
+    def local(x, *vecs):
+        out = _row_tiled_call(
+            functools.partial(_ln_kernel, eps, weight is not None,
+                              bias is not None),
+            x.reshape(-1, h), vecs, interpret_mode())
+        return out.reshape(x.shape)
+
+    return _rows_per_shard(local, x,
+                           [a for a in (weight, bias) if a is not None])
 
 
 def _ln_fwd(x, weight, bias, eps):

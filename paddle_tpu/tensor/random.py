@@ -166,16 +166,9 @@ def binomial(count, prob, name=None):
     k = next_key()
 
     def f(n, p):
-        if hasattr(jax.random, "binomial"):
-            return jax.random.binomial(
-                k, n.astype(jnp.float32), p
-            ).astype(jnp.int64)
-        mean = n * p
-        std = jnp.sqrt(n * p * (1 - p))
-        g = jax.random.normal(k, jnp.broadcast_shapes(n.shape, p.shape))
-        return jnp.clip(jnp.round(mean + std * g), 0, n).astype(
-            jnp.int64
-        )
+        return jax.random.binomial(
+            k, n.astype(jnp.float32), p
+        ).astype(jnp.int64)
 
     return apply_op("binomial", f, count, prob, differentiable=False)
 

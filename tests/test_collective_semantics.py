@@ -39,7 +39,6 @@ def _run_manual(fn, *arrs):
             out = fn(*[Tensor(a) for a in local])
         return out._data if isinstance(out, Tensor) else out
 
-    # version-portable wrapper (jax.shard_map only exists from 0.5+)
     from paddle_tpu.distributed.mesh import shard_map
 
     return shard_map(

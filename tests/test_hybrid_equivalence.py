@@ -139,9 +139,8 @@ class TestHybridEquivalence:
     def test_mp4_collective_matmul_on(self):
         # ISSUE-4: the ring-decomposed collective matmul engaged on
         # every TP linear (FLAGS_collective_matmul=on forces
-        # decomposition; pure-TP grid — on jax<0.5 the dispatcher
-        # declines when another mesh axis is live, see mp_ops) must
-        # reproduce the plain-chain trajectory step for step.
+        # decomposition; pure-TP grid) must reproduce the plain-chain
+        # trajectory step for step.
         _grid(mp_degree=4)
         try:
             paddle.set_flags({"FLAGS_collective_matmul": "off"})
@@ -193,10 +192,8 @@ class TestHybridEquivalence:
         assert got == base, (got, base)
 
     def test_dp2_mp4_collective_matmul_on_grid_safe(self):
-        # multi-axis grid with the flag forced on: on jax<0.5 the
-        # legacy-shard_map gate must keep the lowering identical to
-        # plain (decline, not crash); on newer jax the decomposition
-        # itself must hold the match
+        # multi-axis grid with the flag forced on: the decomposition
+        # itself must hold the match with plain
         _grid(dp_degree=2, mp_degree=4)
         try:
             paddle.set_flags({"FLAGS_collective_matmul": "off"})

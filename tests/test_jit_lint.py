@@ -151,7 +151,7 @@ def _mp_mesh():
 
 class TestCollectiveHazards:
     def test_psum_over_missing_axis_is_critical(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -170,7 +170,7 @@ class TestCollectiveHazards:
         assert "collective-axis" not in _rules(rep_ok)
 
     def test_collective_in_one_cond_branch_is_critical(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -180,7 +180,7 @@ class TestCollectiveHazards:
                 p, lambda v: jax.lax.psum(v, "mp"), lambda v: v * 1.0, x)
 
         g = shard_map(body, mesh=mesh, in_specs=(P(), P("mp")),
-                      out_specs=P("mp"), check_rep=False)
+                      out_specs=P("mp"))
         closed = jax.make_jaxpr(g)(jnp.asarray(True), jnp.ones((2, 4)))
         rep = analysis.analyze_jaxpr(closed, mesh_axes={"mp"})
         crit = [f for f in rep.findings
@@ -189,7 +189,7 @@ class TestCollectiveHazards:
         assert "deadlock" in crit[0].message
 
     def test_collective_in_all_branches_clean(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -200,7 +200,7 @@ class TestCollectiveHazards:
                 lambda v: jax.lax.psum(v, "mp"), x)
 
         g = shard_map(body, mesh=mesh, in_specs=(P(), P("mp")),
-                      out_specs=P(), check_rep=False)
+                      out_specs=P())
         closed = jax.make_jaxpr(g)(jnp.asarray(True), jnp.ones((2, 4)))
         rep = analysis.analyze_jaxpr(closed, mesh_axes={"mp"})
         assert "collective-branch" not in _rules(rep)
@@ -544,7 +544,7 @@ class TestOverlapMiss:
     decompose — the linter must point at it."""
 
     def _ag_dot_jaxpr(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -555,7 +555,7 @@ class TestOverlapMiss:
 
         f = shard_map(local, mesh=mesh,
                       in_specs=(P("mp", None), P(None, None)),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None))
         return jax.make_jaxpr(f)(
             jnp.ones((8, 16), jnp.float32),
             jnp.ones((16, 8), jnp.float32))
@@ -577,7 +577,7 @@ class TestOverlapMiss:
     def test_decomposed_ring_clean(self):
         # the ring replacement (ppermute chunks, no blocking gather)
         # must NOT fire the rule
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.ops.kernels import collective_matmul as cm
@@ -590,7 +590,7 @@ class TestOverlapMiss:
 
         f = shard_map(local, mesh=mesh,
                       in_specs=(P("mp", None), P(None, None)),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(
             jnp.ones((8, 16), jnp.float32),
             jnp.ones((16, 8), jnp.float32))
@@ -601,7 +601,7 @@ class TestOverlapMiss:
     def test_gather_with_second_consumer_clean(self):
         # the gathered value escaping to a second consumer is not the
         # pure dependent pair (decomposition would change live ranges)
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -612,7 +612,7 @@ class TestOverlapMiss:
 
         f = shard_map(local, mesh=mesh,
                       in_specs=(P("mp", None), P(None, None)),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(
             jnp.ones((8, 16), jnp.float32),
             jnp.ones((16, 8), jnp.float32))

@@ -154,13 +154,13 @@ class TestLifetimeGolden:
 
 class TestCommGolden:
     def _shmapped(self, body, n_in=1, shape=(8, 8)):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         f = shard_map(body, mesh=mesh,
                       in_specs=tuple([P("mp", None)] * n_in),
-                      out_specs=P("mp", None), check_rep=False)
+                      out_specs=P("mp", None))
         return jax.make_jaxpr(f)(
             *[jnp.ones(shape, jnp.float32)] * n_in)
 
@@ -200,13 +200,13 @@ class TestCommGolden:
             return cm.all_gather_matmul(
                 x, w, axis_name="mp", axis_size=ws, gather_axis=0)
 
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh(ws)
         f = shard_map(body, mesh=mesh,
                       in_specs=(P("mp", None), P(None, None)),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(
             jnp.ones((rows, k), jnp.float32),
             jnp.ones((k, n), jnp.float32))
@@ -222,13 +222,12 @@ class TestCommGolden:
         # leave a zero-byte entry behind (which would make
         # comm_bytes_by_axis truthy with a None flops/comm ratio —
         # print(plan) and the artifact rows crashed on exactly this)
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()[:1]).reshape(1), ("mp",))
         f = shard_map(lambda x: jax.lax.psum(x, "mp"), mesh=mesh,
-                      in_specs=P("mp", None), out_specs=P(None, None),
-                      check_rep=False)
+                      in_specs=P("mp", None), out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
         plan, _ = planner.plan_jaxpr(closed, name="deg1",
                                      mesh_axis_sizes={"mp": 1})
@@ -253,7 +252,7 @@ class TestCommGolden:
         assert plan.comm_bytes_by_axis == {"mp": 5 * 128}
 
     def test_flops_per_comm_byte(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -264,7 +263,7 @@ class TestCommGolden:
 
         f = shard_map(body, mesh=mesh,
                       in_specs=(P("mp", None), P(None, None)),
-                      out_specs=P("mp", None), check_rep=False)
+                      out_specs=P("mp", None))
         closed = jax.make_jaxpr(f)(
             jnp.ones((8, 8), jnp.float32),
             jnp.ones((8, 4), jnp.float32))
@@ -319,13 +318,12 @@ class TestPlannerRules:
             sf(_x32((64, 64)))  # suppressed: compiles
 
     def test_comm_over_budget(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         f = shard_map(lambda x: jax.lax.psum(x, "mp"), mesh=mesh,
-                      in_specs=P("mp", None), out_specs=P(None, None),
-                      check_rep=False)
+                      in_specs=P("mp", None), out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
         with flags(jit_budget_comm=16):
             _, rep = planner.plan_jaxpr(closed, name="comm",
@@ -339,14 +337,13 @@ class TestPlannerRules:
                 planner.emit_plan_report(rep, "strict")
 
     def test_comm_bound_program_fires_on_fp32_collectives(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         # pure communication, no flops: ratio 0 < any threshold
         f = shard_map(lambda x: jax.lax.psum(x, "mp"), mesh=mesh,
-                      in_specs=P("mp", None), out_specs=P(None, None),
-                      check_rep=False)
+                      in_specs=P("mp", None), out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
         with flags(jit_plan_comm_bound_ratio=8.0):
             _, rep = planner.plan_jaxpr(closed, name="bound",
@@ -357,13 +354,12 @@ class TestPlannerRules:
         assert "quantized" in f.message
 
     def test_comm_bound_quiet_on_bf16_wire(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         f = shard_map(lambda x: jax.lax.psum(x, "mp"), mesh=mesh,
-                      in_specs=P("mp", None), out_specs=P(None, None),
-                      check_rep=False)
+                      in_specs=P("mp", None), out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.bfloat16))
         with flags(jit_plan_comm_bound_ratio=8.0):
             _, rep = planner.plan_jaxpr(closed, name="bf16",
@@ -371,13 +367,12 @@ class TestPlannerRules:
         assert "comm-bound-program" not in _rules(rep)
 
     def test_comm_bound_threshold_zero_disables(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         f = shard_map(lambda x: jax.lax.psum(x, "mp"), mesh=mesh,
-                      in_specs=P("mp", None), out_specs=P(None, None),
-                      check_rep=False)
+                      in_specs=P("mp", None), out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
         with flags(jit_plan_comm_bound_ratio=0.0):
             _, rep = planner.plan_jaxpr(closed, name="off",
@@ -385,7 +380,7 @@ class TestPlannerRules:
         assert "comm-bound-program" not in _rules(rep)
 
     def _dead_psum_jaxpr(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
@@ -395,7 +390,7 @@ class TestPlannerRules:
             return x * 2.0
 
         f = shard_map(body, mesh=mesh, in_specs=P("mp", None),
-                      out_specs=P("mp", None), check_rep=False)
+                      out_specs=P("mp", None))
         return jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
 
     def test_dead_collective_detected(self):
@@ -418,13 +413,13 @@ class TestPlannerRules:
         planner.emit_plan_report(rep, "strict")  # nothing blocking
 
     def test_consumed_collective_clean(self):
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh()
         f = shard_map(lambda x: jax.lax.psum(x, "mp") * 2.0,
                       mesh=mesh, in_specs=P("mp", None),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None))
         closed = jax.make_jaxpr(f)(jnp.ones((8, 8), jnp.float32))
         plan, rep = planner.plan_jaxpr(closed, name="live",
                                        mesh_axis_sizes={"mp": 2})
@@ -455,7 +450,7 @@ class TestQuantizedWirePlanning:
         import functools
 
         from paddle_tpu.ops.kernels import collective_matmul as cm
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.distributed.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = _mp_mesh(n)
@@ -463,7 +458,7 @@ class TestQuantizedWirePlanning:
             functools.partial(cm.ring_all_reduce, axis_name="mp",
                               axis_size=n, wire=wire),
             mesh=mesh, in_specs=P("mp", None),
-            out_specs=P("mp", None), check_rep=False)
+            out_specs=P("mp", None))
         return jax.make_jaxpr(f)(jnp.ones(shape, jnp.float32))
 
     def _plan(self, wire, **kw):

@@ -1,0 +1,241 @@
+"""Compile the main path's kernels for a DESCRIBED v5e (no chip attached).
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (guides: on-chip-measurement, section 2): what
+it refuses — a block the (8, 128) tiling cannot hold, more SMEM/VMEM
+than a kernel may use — it would refuse on the chip, and interpret mode
+never sees it. These are the kernels ``chip_smoke.py`` dispatches, at
+Mistral-7B widths (H32 / KV8 / D128, hidden 4096, pages of 16), about
+two seconds each. Nothing runs: a compile that passes is not a chip run.
+
+This is the ONLY file that describes the chip, and it does so inside a
+fixture: one process at a time may load libtpu, xdist workers each
+import every test file, and a topology call at import would give the
+workers different tests to collect (the whole suite then counts 0).
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, KVH, D, E, PAGE = 32, 8, 128, 4096, 16
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *specs):
+    """Lower ``fn`` over (shape, dtype) specs placed on the described
+    chip and compile it; returns the compiled program's text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in specs]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _ragged_specs(b, t, npages, max_pages, kv_dtype):
+    specs = [((b, t, H, D), BF16),
+             ((npages, PAGE, KVH, D), kv_dtype),
+             ((npages, PAGE, KVH, D), kv_dtype),
+             ((b, max_pages), jnp.int32), ((b,), jnp.int32),
+             ((b,), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        specs += [((npages, KVH), jnp.float32)] * 2
+    return specs
+
+
+@pytest.mark.parametrize("b,t,max_pages,window", [
+    (8, 1, 128, 0), (8, 1, 128, 4096), (8, 16, 128, 4096),
+    (8, 64, 128, 0), (8, 64, 128, 4096), (1, 64, 16, 4096),
+    (64, 1, 256, 4096),
+])
+def test_ragged_bf16(one_chip, b, t, max_pages, window):
+    from paddle_tpu.ops.kernels.paged_attention import \
+        paged_ragged_attention
+
+    npages = 4096 if b == 64 else 2048
+
+    def f(q, kp, vp, tbl, lens, ql):
+        return paged_ragged_attention(q, kp, vp, tbl, lens, q_lens=ql,
+                                      window=window, interpret=False)
+
+    text = _compile(f, one_chip,
+                    *_ragged_specs(b, t, npages, max_pages, BF16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("t", [1, 16])
+def test_ragged_int8_kv_2048_pages(one_chip, t):
+    """The per-page scale sidecars ride scalar prefetch flattened: as
+    2-D (2048, 8) SMEM operands they padded to 1 MiB each and the
+    compiler refused the kernel (2.01M of 1.00M SMEM)."""
+    from paddle_tpu.ops.kernels.paged_attention import \
+        paged_ragged_attention
+
+    def f(q, kp, vp, tbl, lens, ql, ks, vs):
+        return paged_ragged_attention(
+            q, kp, vp, tbl, lens, q_lens=ql, window=4096,
+            k_scales=ks, v_scales=vs, interpret=False)
+
+    text = _compile(f, one_chip,
+                    *_ragged_specs(8, t, 2048, 128, jnp.int8))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_legacy_decode_kernel(one_chip, kv_dtype):
+    """FLAGS_ragged_attention=off lowering (the dedicated decode
+    kernel), built directly so no flag is touched."""
+    from paddle_tpu.ops.kernels.paged_attention import _build_decode_call
+
+    quant = kv_dtype == jnp.int8
+    run = _build_decode_call(8, H, D, 2048, PAGE, KVH, 128,
+                             D ** -0.5, 4096, quant, False)
+    specs = [((8, H, D), BF16), ((2048, PAGE, KVH, D), kv_dtype),
+             ((2048, PAGE, KVH, D), kv_dtype),
+             ((8, 128), jnp.int32), ((8,), jnp.int32)]
+    if quant:
+        specs += [((2048 * KVH,), jnp.float32)] * 2
+    assert "tpu_custom_call" in _compile(run, one_chip, *specs)
+
+
+def test_fused_ragged_step(one_chip):
+    """qkv + RoPE + page scatter + ragged attend + o_proj as ONE program
+    at one serving bucket (128 packed tokens, 8 rows x 64)."""
+    from paddle_tpu.ops.kernels.paged_attention import _build_fused_call
+
+    n_pad, b_pad, t_pad, mp = 128, 8, 64, 128
+    run = _build_fused_call(n_pad, E, H, KVH, D, 2048, PAGE, b_pad,
+                            t_pad, mp, D ** -0.5, 4096, False, False)
+    i32 = jnp.int32
+    specs = [((n_pad, E), BF16), ((E, H * D), BF16),
+             ((E, KVH * D), BF16), ((E, KVH * D), BF16),
+             ((H * D, E), BF16),
+             ((32768, D), jnp.float32), ((32768, D), jnp.float32),
+             ((n_pad,), i32), ((n_pad,), i32), ((n_pad,), i32),
+             ((b_pad, t_pad), i32), ((n_pad,), i32), ((n_pad,), i32),
+             ((n_pad,), i32),
+             ((2048, PAGE, KVH, D), BF16), ((2048, PAGE, KVH, D), BF16),
+             ((b_pad, mp), i32), ((b_pad,), i32), ((b_pad,), i32)]
+    assert "tpu_custom_call" in _compile(run, one_chip, *specs)
+
+
+@pytest.mark.parametrize("window", [0, 1024])
+def test_flash_fwd_s4096(one_chip, window):
+    from paddle_tpu.ops.kernels.flash_attention import _flash_fwd_pallas
+
+    f = functools.partial(_flash_fwd_pallas, causal=True,
+                          scale=D ** -0.5, block_q=512, block_k=512,
+                          interpret=False, window=window)
+    specs = [((H, 4096, D), BF16), ((KVH, 4096, D), BF16),
+             ((KVH, 4096, D), BF16)]
+    assert "tpu_custom_call" in _compile(f, one_chip, *specs)
+
+
+@pytest.mark.parametrize("window", [0, 1024])
+def test_flash_bwd_s4096(one_chip, window):
+    from paddle_tpu.ops.kernels.flash_attention import _flash_bwd_pallas
+
+    f = functools.partial(_flash_bwd_pallas, causal=True,
+                          scale=D ** -0.5, block_q=512, block_k=512,
+                          interpret=False, window=window)
+    specs = [((H, 4096, D), BF16), ((KVH, 4096, D), BF16),
+             ((KVH, 4096, D), BF16), ((H, 4096, D), BF16),
+             ((H, 4096), jnp.float32), ((H, 4096, D), BF16)]
+    assert "tpu_custom_call" in _compile(f, one_chip, *specs)
+
+
+def test_flash_varlen_fwd(one_chip):
+    from paddle_tpu.ops.kernels.flash_varlen import _varlen_fwd_pallas
+
+    f = functools.partial(_varlen_fwd_pallas, causal=True,
+                          scale=D ** -0.5, block_q=512, block_k=512,
+                          interpret=False)
+    t = 4096
+    specs = [((H, t, D), BF16), ((KVH, t, D), BF16), ((KVH, t, D), BF16)]
+    specs += [((t,), jnp.int32)] * 4
+    assert "tpu_custom_call" in _compile(f, one_chip, *specs)
+
+
+@pytest.mark.parametrize("rows", [3, 8, 13, 260, 4096])
+def test_rms_norm_rows(one_chip, rows):
+    """Rows that are not a multiple of 8 used to fall to one-row
+    blocks, which the TPU lowering refuses (dense generate() at batch
+    2-7, odd packed lengths)."""
+    from paddle_tpu.ops.kernels.rms_norm import _rms_pallas
+
+    f = functools.partial(_rms_pallas, eps=1e-5, interpret=False)
+    text = _compile(f, one_chip, ((rows, E), BF16), ((E,), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_rope(one_chip):
+    from paddle_tpu.ops.kernels.rope import apply_rotary_emb
+
+    def f(x, cos, sin, pos):
+        return apply_rotary_emb(x, cos, sin, position_ids=pos)
+
+    _compile(f, one_chip, ((1, 128, H, D), BF16),
+             ((32768, D), jnp.float32), ((32768, D), jnp.float32),
+             ((1, 128), jnp.int32))
+
+
+def test_kernels_under_mp4_mesh(topo, monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically: under a
+    4-device mesh rms_norm and flash attention (forward and backward)
+    must reach the compiler inside a shard_map (heads over mp). The CPU
+    backend is steered to the chip's dispatch here, in the test."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import paddle_tpu.ops.kernels as K
+    from paddle_tpu.distributed import mesh as M
+
+    monkeypatch.setattr(K, "on_tpu", lambda: True)
+    mesh = M.build_global_mesh(("dp", "mp"), (1, 4),
+                               devices=np.array(topo.devices))
+    try:
+        def loss(x, w, q, k, v):
+            y = K.rms_norm(x, w).astype(jnp.float32).sum()
+            o = K.flash_attention(q, k, v, causal=True)
+            return y + o.astype(jnp.float32).sum()
+
+        def spec(shape, *names):
+            return jax.ShapeDtypeStruct(
+                shape, BF16, sharding=NamedSharding(mesh, P(*names)))
+
+        heads = (None, None, "mp", None)
+        text = jax.jit(jax.grad(loss, argnums=(0, 2, 3))).lower(
+            spec((1, 2048, E)), spec((E,)),
+            spec((1, 2048, H, D), *heads), spec((1, 2048, KVH, D), *heads),
+            spec((1, 2048, KVH, D), *heads)).compile().as_text()
+    finally:
+        M.reset_mesh()
+    assert text.count("tpu_custom_call") >= 3
