@@ -303,12 +303,19 @@ define_flag("telemetry", "off",
             "'trace' additionally records nested wall-clock spans "
             "(admit/prefill-chunk/decode/retire, jit.compile) into a "
             "bounded ring exportable as Chrome trace JSON. The mode "
-            "is read when a scheduler/pool/cache is CONSTRUCTED")
-define_flag("telemetry_ring", 8192,
+            "is read when a scheduler/pool/cache is CONSTRUCTED; "
+            "spans alone are decided at call time and are also live "
+            "while a jax.profiler session or a Profiler RECORD window "
+            "collects")
+define_flag("telemetry_ring", 262144,
             "span ring-buffer capacity for the telemetry tracer: the "
             "newest this-many finished spans are retained (rollover "
-            "drops the oldest; exports stay valid Chrome JSON "
-            "regardless of how long the process ran)")
+            "drops the oldest and counts it in Tracer.dropped; "
+            "exports stay valid Chrome JSON regardless of how long "
+            "the process ran). Sized to hold a 40 s profiler session "
+            "of the serving path whole: ~250 spans a step at ~1 step/s "
+            "today, ~45 a step at 100 steps/s once a step is one "
+            "program; the ring allocates per span, ~0.4 KB each")
 define_flag("telemetry_samples", 4096,
             "per-histogram raw-sample reservoir for the telemetry "
             "registry: percentile readout (p50/p90/p99) is EXACT "

@@ -8,7 +8,9 @@ instrument ONCE at the framework layer so every workload — serving,
 bench, the future async engine — reports from the same counters
 instead of growing ad-hoc per-step dicts.
 
-Two surfaces, both behind ``FLAGS_telemetry=off|metrics|trace``:
+Two surfaces, both behind ``FLAGS_telemetry=off|metrics|trace``
+(spans are also live inside a profiler RECORD window or a
+``jax.profiler`` session, decided at each :func:`span` call):
 
 * :class:`MetricsRegistry` — named counters, gauges, and log2-bucketed
   histograms with EXACT p50/p90/p99 readout (a bounded raw-sample
@@ -29,8 +31,10 @@ Two surfaces, both behind ``FLAGS_telemetry=off|metrics|trace``:
 
 Zero-cost off mode (the ``FLAGS_page_sanitizer=off`` discipline):
 ``registry()``/``tracer()`` return ``None`` when the flag is off and
-this module allocates NOTHING — instrumented call sites cache the
-handle at construction and pay one ``is None`` check per event.
+this module allocates NOTHING — registry call sites cache the handle
+at construction and pay one ``is None`` check per event; span sites
+call :func:`span`, which returns the shared :data:`NULL_SPAN` after
+one probe.
 ``bench.py --serving`` gates off mode at literally zero tracemalloc
 blocks attributed to this file.
 
@@ -112,6 +116,7 @@ import itertools
 import json
 import math
 import os
+import sys
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -127,6 +132,7 @@ __all__ = [
     "telemetry_mode", "metrics_on", "tracing_on", "registry", "tracer",
     "request_traces", "clock", "reset", "arm_tracer", "disarm_tracer",
     "current_trace_context", "use_trace_context", "span_in",
+    "span", "add_complete", "peek_tracer", "install_session_probe",
     "export_chrome", "chrome_payload", "prometheus_text",
     "write_prometheus", "atomic_write_text", "summarize_jsonl",
     "chrome_from_jsonl", "summarize_incident",
@@ -174,7 +180,36 @@ def metrics_on() -> bool:
 
 
 def tracing_on() -> bool:
-    return telemetry_mode() == "trace" or _ARMED > 0
+    """Spans are live: a profiler RECORD window armed the tracer, a
+    ``jax.profiler`` session is collecting (the probe that
+    paddle_tpu/profiler installs — this module stays jax-free), or
+    ``FLAGS_telemetry=trace``. Decided at CALL time, cheapest test
+    first: the off path is one integer compare, one probe call and
+    one dict lookup."""
+    if _ARMED > 0:
+        return True
+    probe = _SESSION_PROBE
+    if probe is not None and probe():
+        return True
+    return flag("telemetry") != "off" and telemetry_mode() == "trace"
+
+
+# installed by paddle_tpu/profiler/__init__.py, which imports both jax
+# and this module: ``_SESSION_PROBE()`` is true while a jax.profiler
+# session collects (jax.profiler.TraceAnnotation.is_enabled) and
+# ``_ANNOTATION(name, **attrs)`` is the context manager that writes a
+# range into that session's trace, on the device trace's own clock
+# (written once, when paddle_tpu.profiler is imported)
+_SESSION_PROBE = None  # concurrency: single-writer
+_ANNOTATION = None  # concurrency: single-writer
+
+
+def install_session_probe(probe, annotation) -> None:
+    """Hand this jax-free module the device profiler's session probe
+    and its annotation class (None, None uninstalls)."""
+    global _SESSION_PROBE, _ANNOTATION
+    _SESSION_PROBE = probe
+    _ANNOTATION = annotation
 
 
 # ---------------------------------------------------------------------------
@@ -1041,14 +1076,20 @@ def _chrome_doc(span_recs, request_recs) -> dict:
 
 
 class _SpanCtx:
-    __slots__ = ("_tr", "_span")
+    __slots__ = ("_tr", "_span", "_ann")
 
     def __init__(self, tr, span):
         self._tr = tr
         self._span = span
+        self._ann = None
 
     def __enter__(self) -> Span:
         s = self._span
+        if _ANNOTATION is not None and _SESSION_PROBE():
+            # the same range in the device profiler's own trace
+            # (.xplane.pb), on its clock, beside the device's ops
+            self._ann = _ANNOTATION(s.name, **s.attrs)
+            self._ann.__enter__()
         var = self._tr._stack_var
         stack = var.get()
         # the thread DOING the work owns the span — an executor
@@ -1057,7 +1098,9 @@ class _SpanCtx:
         s.depth = len(stack)
         parent = stack[-1] if stack else None
         if parent is not None:
-            s.path = parent.path + "/" + s.name
+            # interned: a few dozen distinct paths, one string each,
+            # however many spans the ring holds
+            s.path = sys.intern(parent.path + "/" + s.name)
         s._stamp_identity(parent)
         var.set(stack + (s,))
         s.t0 = clock()
@@ -1066,6 +1109,9 @@ class _SpanCtx:
     def __exit__(self, *exc):
         s = self._span
         s.dur = clock() - s.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         var = self._tr._stack_var
         stack = var.get()
         if stack and stack[-1] is s:
@@ -1143,6 +1189,9 @@ class Tracer:
         s.t0 = float(t0)
         s.dur = float(dur)
         stack = self._stack_var.get()
+        if stack:
+            s.depth = len(stack)
+            s.path = sys.intern(stack[-1].path + "/" + s.name)
         s._stamp_identity(stack[-1] if stack else None)
         self._commit(s)
         return s
@@ -1276,6 +1325,43 @@ def request_traces() -> Optional[RequestTraceBook]:
             if _TRACES is None:
                 _TRACES = RequestTraceBook()
     return _TRACES
+
+
+def peek_tracer() -> Optional[Tracer]:
+    """The tracer singleton whatever the mode is NOW (None if no span
+    was ever live): how a reader collects the ring after the session
+    or RECORD window that filled it has closed."""
+    return _TRACER
+
+
+def span(name: str, cat: str = "app", **attrs):
+    """``with telemetry.span("pool.table", rows=32) as sp:`` — THE
+    span entry point of instrumented code. Decides at call time
+    (:func:`tracing_on`), so a profiler session or RECORD window that
+    starts after the caller was built is honoured. Live: the span
+    commits to the tracer ring and, under a device-profiler session,
+    is also written into that session's trace. Dead:
+    :data:`NULL_SPAN` (``sp`` is None), nothing allocated here."""
+    tr = tracer()
+    if tr is None:
+        return NULL_SPAN
+    return _SpanCtx(tr, Span(name, cat, attrs))
+
+
+def add_complete(name: str, t0: float, dur: float, cat: str = "event",
+                 **attrs) -> Optional[Span]:
+    """Record an already-timed range (``t0`` from :func:`clock`) under
+    whatever span is open in the calling context; None when no span
+    is live. Ring only: the device profiler takes no range after the
+    fact, so under a session a zero-length mark named ``name`` with
+    the duration as ``dur_us`` is left at the range's end."""
+    tr = tracer()
+    if tr is None:
+        return None
+    if _ANNOTATION is not None and _SESSION_PROBE():
+        with _ANNOTATION(name, dur_us=int(dur * 1e6), **attrs):
+            pass
+    return tr.add_complete(name, t0, dur, cat=cat, attrs=attrs)
 
 
 def arm_tracer() -> Tracer:
@@ -1700,6 +1786,49 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "serialization (req/shards attrs)"),
     ("span:jit.compile", "span",
      "one to_static trace (program/variant/n_eqns/lint attrs)"),
+    ("span:jit.call", "span", "the to_static entry call"),
+    ("span:serving.pack", "span",
+     "_chunk_feeds and the bucket choice (rows/packed/pad_to attrs)"),
+    ("span:serving.logits_pull", "span",
+     "np.asarray(logits): the wait for the device and the "
+     "device->host copy"),
+    ("span:engine.idle", "span", "pump parked in its wake event"),
+    ("span:engine.ops", "span",
+     "pump between steps: inbox ops, deadline expiry, retire, "
+     "drain check, gate"),
+    ("span:engine.flush", "span",
+     "token marshalling after a step (streams attr)"),
+    ("span:engine.stream_lag", "span",
+     "a flushed batch's first token: committed on the pump thread "
+     "-> returned by TokenStream.__anext__ (req/n attrs)"),
+    ("span:model.plan", "span",
+     "host prep of prefill_chunk: lengths, positions, right-align "
+     "plan, uploads (rows/packed/pad_to/bytes attrs)"),
+    ("span:model.embed", "span", "the embedding gather"),
+    ("span:model.layer", "span", "one decoder layer (li attr)"),
+    ("span:model.norm", "span", "an eager rms_norm call of a layer"),
+    ("span:model.mlp", "span", "a layer's MLP and its residual add"),
+    ("span:model.head", "span", "final norm, row gather, lm head"),
+    ("span:pool.fused_step", "span",
+     "fused_ragged_step / append_ragged / attend_ragged, whole "
+     "(op attr)"),
+    ("span:pool.book", "span",
+     "_ragged_slots: capacity check, COW forks, slot plan "
+     "(slots/pages attrs)"),
+    ("span:pool.table", "span",
+     "page table / scatter plan built in numpy and uploaded "
+     "(rows/bytes attrs)"),
+    ("span:kernel.ragged", "span",
+     "the jitted ragged call: LRU lookup and dispatch "
+     "(rows/t/max_pages attrs)"),
+    ("span:xla.trace", "span",
+     "jax.monitoring jaxpr_trace_duration under the open span "
+     "(fun attr)"),
+    ("span:xla.lower", "span",
+     "jax.monitoring jaxpr_to_mlir_module_duration (fun attr)"),
+    ("span:xla.build", "span",
+     "jax.monitoring backend_compile_duration; a persistent-cache "
+     "load lands here (fun attr)"),
 )
 
 

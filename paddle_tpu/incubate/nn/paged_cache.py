@@ -1299,25 +1299,28 @@ class PagedKVCacheManager:
         device scatter belongs to the caller — :meth:`append_ragged`,
         or the fused program that owns it as its prologue
         (:meth:`fused_ragged_step`)."""
-        need = self.ragged_pages_needed(seq_ids, counts)
-        if need > len(self._free):
-            raise RuntimeError(
-                f"KV page pool exhausted: ragged append needs {need} "
-                f"new pages, {len(self._free)} free")
-        pages = []
-        offs = []
-        for s, c in zip(seq_ids, counts):
-            for _ in range(c):
-                page, off = self._next_slot(s)
-                self._lens[s] += 1
-                pages.append(page)
-                offs.append(off)
-        if pages and self._san is not None:
-            self._san.event("append_ragged", seq_ids=list(seq_ids),
-                            counts=list(counts),
-                            pages=[int(p) for p in pages],
-                            offs=[int(o) for o in offs], pool=self)
-        return pages, offs
+        with telemetry.span("pool.book") as sp:
+            need = self.ragged_pages_needed(seq_ids, counts)
+            if need > len(self._free):
+                raise RuntimeError(
+                    f"KV page pool exhausted: ragged append needs "
+                    f"{need} new pages, {len(self._free)} free")
+            pages = []
+            offs = []
+            for s, c in zip(seq_ids, counts):
+                for _ in range(c):
+                    page, off = self._next_slot(s)
+                    self._lens[s] += 1
+                    pages.append(page)
+                    offs.append(off)
+            if pages and self._san is not None:
+                self._san.event("append_ragged", seq_ids=list(seq_ids),
+                                counts=list(counts),
+                                pages=[int(p) for p in pages],
+                                offs=[int(o) for o in offs], pool=self)
+            if sp is not None:
+                sp.attrs.update(slots=len(pages), pages=need)
+            return pages, offs
 
     def append_ragged(self, seq_ids, counts, k_toks, v_toks):
         """Write ``counts[i]`` consecutive tokens' K/V for EVERY listed
@@ -1326,42 +1329,43 @@ class PagedKVCacheManager:
         decode rows must not issue one update per token per layer).
         k_toks/v_toks: (sum(counts), KVH, D) arrays or Tensors, rows
         ordered sequence-major (seq_ids[0]'s tokens first)."""
-        k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
-        v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
-        counts = [int(c) for c in counts]
-        if sum(counts) != k_toks.shape[0]:
-            raise ValueError(
-                f"append_ragged: counts sum to {sum(counts)} but "
-                f"{k_toks.shape[0]} token rows were passed")
-        pages, offs = self._ragged_slots(seq_ids, counts)
-        if not pages:
-            return
-        if self.quantized:
-            # replay the per-token calibration ORDER (wave j = the
-            # j-th token of every chunk): scale growth requantizes
-            # through the same intermediate scales the token-per-step
-            # path would use, so chunked-prefill int8 pages are
-            # BIT-identical to sequential appends (greedy identity —
-            # tests/test_chunked_prefill.py). Same per-token write
-            # cost as the legacy path; the chunking win is in the
-            # attention/projection dispatch, not the pool write.
-            offsets = np.concatenate(
-                [[0], np.cumsum(counts)]).astype(np.int64)
-            for j in range(max(counts)):
-                rows = np.asarray([offsets[i] + j
-                                   for i, c in enumerate(counts)
-                                   if j < c])
-                self._quant_write(
-                    [pages[r] for r in rows],
-                    [offs[r] for r in rows],
-                    k_toks[rows], v_toks[rows])
-            return
-        pg = jnp.asarray(pages, jnp.int32)
-        of = jnp.asarray(offs, jnp.int32)
-        self.k_pages = self.k_pages.at[pg, of].set(
-            k_toks.astype(self.k_pages.dtype))
-        self.v_pages = self.v_pages.at[pg, of].set(
-            v_toks.astype(self.v_pages.dtype))
+        with telemetry.span("pool.fused_step", op="append_ragged"):
+            k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
+            v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
+            counts = [int(c) for c in counts]
+            if sum(counts) != k_toks.shape[0]:
+                raise ValueError(
+                    f"append_ragged: counts sum to {sum(counts)} but "
+                    f"{k_toks.shape[0]} token rows were passed")
+            pages, offs = self._ragged_slots(seq_ids, counts)
+            if not pages:
+                return
+            if self.quantized:
+                # replay the per-token calibration ORDER (wave j = the
+                # j-th token of every chunk): scale growth requantizes
+                # through the same intermediate scales the token-per-step
+                # path would use, so chunked-prefill int8 pages are
+                # BIT-identical to sequential appends (greedy identity —
+                # tests/test_chunked_prefill.py). Same per-token write
+                # cost as the legacy path; the chunking win is in the
+                # attention/projection dispatch, not the pool write.
+                offsets = np.concatenate(
+                    [[0], np.cumsum(counts)]).astype(np.int64)
+                for j in range(max(counts)):
+                    rows = np.asarray([offsets[i] + j
+                                       for i, c in enumerate(counts)
+                                       if j < c])
+                    self._quant_write(
+                        [pages[r] for r in rows],
+                        [offs[r] for r in rows],
+                        k_toks[rows], v_toks[rows])
+                return
+            pg = jnp.asarray(pages, jnp.int32)
+            of = jnp.asarray(offs, jnp.int32)
+            self.k_pages = self.k_pages.at[pg, of].set(
+                k_toks.astype(self.k_pages.dtype))
+            self.v_pages = self.v_pages.at[pg, of].set(
+                v_toks.astype(self.v_pages.dtype))
 
     # -- kernel inputs -----------------------------------------------------
     def page_table(self, seq_ids, max_pages=None):
@@ -1471,25 +1475,30 @@ class PagedKVCacheManager:
         attend program per packed config that replaces the
         attend_padded/attend_prefill pair (which remain as thin
         shape wrappers for single-kind callers)."""
-        q = _as_tensor(q)
-        tbl, lens = self._padded_kernel_inputs(
-            seq_ids, rows_pad, max_pages)
-        if self._san is not None:
-            self._san_check_table(seq_ids, tbl, lens)
-        ql = jnp.zeros((tbl.shape[0],), jnp.int32)
-        ql = ql.at[:len(seq_ids)].set(
-            jnp.asarray(list(q_lens), jnp.int32))
-        kp, vp = self.k_pages, self.v_pages
-        ks = self.k_scales if self.quantized else None
-        vs = self.v_scales if self.quantized else None
+        with telemetry.span("pool.fused_step", op="attend_ragged"):
+            q = _as_tensor(q)
+            with telemetry.span("pool.table") as sp:
+                tbl, lens = self._padded_kernel_inputs(
+                    seq_ids, rows_pad, max_pages)
+                if self._san is not None:
+                    self._san_check_table(seq_ids, tbl, lens)
+                ql = jnp.zeros((tbl.shape[0],), jnp.int32)
+                ql = ql.at[:len(seq_ids)].set(
+                    jnp.asarray(list(q_lens), jnp.int32))
+                if sp is not None:
+                    sp.attrs.update(rows=len(seq_ids), bytes=int(
+                        tbl.nbytes + lens.nbytes + ql.nbytes))
+            kp, vp = self.k_pages, self.v_pages
+            ks = self.k_scales if self.quantized else None
+            vs = self.v_scales if self.quantized else None
 
-        def f(qr):
-            return _ragged_kernel_fn(
-                qr, kp, vp, tbl, lens, q_lens=ql, sm_scale=sm_scale,
-                window=window, k_scales=ks, v_scales=vs)
+            def f(qr):
+                return _ragged_kernel_fn(
+                    qr, kp, vp, tbl, lens, q_lens=ql, sm_scale=sm_scale,
+                    window=window, k_scales=ks, v_scales=vs)
 
-        return apply_op("paged_ragged_attend", f, q,
-                        differentiable=False)
+            return apply_op("paged_ragged_attend", f, q,
+                            differentiable=False)
 
     def fused_ragged_step(self, x, weights, rope, positions, seq_ids,
                           counts, gather_map, scatter_plan,
@@ -1527,57 +1536,66 @@ class PagedKVCacheManager:
         first call, never mid-serving) or a strict-sanitizer
         violation (the pool was already corrupt), the same window
         the unfused path's device scatter has."""
-        if self.quantized:
-            raise ValueError(
-                "fused_ragged_step: int8 KV pools calibrate per "
-                "token on the host — use append_ragged + "
-                "attend_ragged")
-        x = _as_tensor(x)
-        counts = [int(c) for c in counts]
-        n_pad = x._data.shape[0]
-        n_real = sum(counts)
-        mr, mc, mflat = scatter_plan
-        # operand-consistency precheck BEFORE any bookkeeping mutates
-        # (same contract as append_ragged's counts-vs-rows guard): a
-        # mismatched plan must not leave seq lens ahead of device
-        # writes
-        if n_real > n_pad:
-            raise ValueError(
-                f"fused_ragged_step: counts sum to {n_real} but the "
-                f"packed operand carries {n_pad} rows")
-        plan_lens = {len(a) for a in (mr, mc, mflat)}
-        if len(plan_lens) != 1 or next(iter(plan_lens)) not in (
-                n_real, n_pad):
-            raise ValueError(
-                f"fused_ragged_step: scatter plan lengths "
-                f"{[len(a) for a in (mr, mc, mflat)]} match neither "
-                f"the {n_real} real packed tokens nor the padded "
-                f"{n_pad} (pre-padded plans carry out-of-bounds "
-                "drop entries)")
-        pages, offs = self._ragged_slots(seq_ids, counts)
-        tbl, lens = self._padded_kernel_inputs(
-            seq_ids, rows_pad, max_pages)
-        if self._san is not None:
-            self._san_check_table(seq_ids, tbl, lens)
-        ql = jnp.zeros((tbl.shape[0],), jnp.int32)
-        ql = ql.at[:len(seq_ids)].set(jnp.asarray(counts, jnp.int32))
-        wq, wk, wv, wo, biases = weights
-        cos, sin = rope
-        # padding entries: page id num_pages / flat slot n_pad are
-        # OUT OF BOUNDS — the fused program's mode="drop" scatters
-        # skip them, keeping every operand bucket-shaped
-        pg = _pad_plan(np.asarray(pages, np.int32), n_pad,
-                       self.num_pages)
-        of = _pad_plan(np.asarray(offs, np.int32), n_pad, 0)
-        y, kp, vp = _fused_step_fn(
-            x._data, wq, wk, wv, wo, biases, cos, sin, positions,
-            pg, of, gather_map, _pad_plan(mr, n_pad, 0),
-            _pad_plan(mc, n_pad, 0), _pad_plan(mflat, n_pad, n_pad),
-            self.k_pages, self.v_pages, tbl, lens, ql,
-            sm_scale=sm_scale, window=window)
-        self.k_pages = kp
-        self.v_pages = vp
-        return Tensor(y)
+        with telemetry.span("pool.fused_step", op="fused_ragged_step"):
+            if self.quantized:
+                raise ValueError(
+                    "fused_ragged_step: int8 KV pools calibrate per "
+                    "token on the host — use append_ragged + "
+                    "attend_ragged")
+            x = _as_tensor(x)
+            counts = [int(c) for c in counts]
+            n_pad = x._data.shape[0]
+            n_real = sum(counts)
+            mr, mc, mflat = scatter_plan
+            # operand-consistency precheck BEFORE any bookkeeping mutates
+            # (same contract as append_ragged's counts-vs-rows guard): a
+            # mismatched plan must not leave seq lens ahead of device
+            # writes
+            if n_real > n_pad:
+                raise ValueError(
+                    f"fused_ragged_step: counts sum to {n_real} but the "
+                    f"packed operand carries {n_pad} rows")
+            plan_lens = {len(a) for a in (mr, mc, mflat)}
+            if len(plan_lens) != 1 or next(iter(plan_lens)) not in (
+                    n_real, n_pad):
+                raise ValueError(
+                    f"fused_ragged_step: scatter plan lengths "
+                    f"{[len(a) for a in (mr, mc, mflat)]} match neither "
+                    f"the {n_real} real packed tokens nor the padded "
+                    f"{n_pad} (pre-padded plans carry out-of-bounds "
+                    "drop entries)")
+            pages, offs = self._ragged_slots(seq_ids, counts)
+            with telemetry.span("pool.table") as sp:
+                tbl, lens = self._padded_kernel_inputs(
+                    seq_ids, rows_pad, max_pages)
+                if self._san is not None:
+                    self._san_check_table(seq_ids, tbl, lens)
+                ql = jnp.zeros((tbl.shape[0],), jnp.int32)
+                ql = ql.at[:len(seq_ids)].set(
+                    jnp.asarray(counts, jnp.int32))
+                # padding entries: page id num_pages / flat slot n_pad
+                # are OUT OF BOUNDS — the fused program's mode="drop"
+                # scatters skip them, keeping every operand bucket-shaped
+                pg = _pad_plan(np.asarray(pages, np.int32), n_pad,
+                               self.num_pages)
+                of = _pad_plan(np.asarray(offs, np.int32), n_pad, 0)
+                mr = _pad_plan(mr, n_pad, 0)
+                mc = _pad_plan(mc, n_pad, 0)
+                mflat = _pad_plan(mflat, n_pad, n_pad)
+                if sp is not None:
+                    sp.attrs.update(rows=len(seq_ids), bytes=int(
+                        tbl.nbytes + lens.nbytes + ql.nbytes
+                        + pg.nbytes + of.nbytes))
+            wq, wk, wv, wo, biases = weights
+            cos, sin = rope
+            y, kp, vp = _fused_step_fn(
+                x._data, wq, wk, wv, wo, biases, cos, sin, positions,
+                pg, of, gather_map, mr, mc, mflat,
+                self.k_pages, self.v_pages, tbl, lens, ql,
+                sm_scale=sm_scale, window=window)
+            self.k_pages = kp
+            self.v_pages = vp
+            return Tensor(y)
 
     def dense_kv(self, seq_ids):
         """Dense (dequantized) gather of the listed sequences' pages:

@@ -45,6 +45,15 @@ contract:
   and the goodput band between ``FLAGS_engine_goodput_low`` and
   ``FLAGS_engine_goodput_high`` freezes both streaks — hysteresis,
   so the gate doesn't flap at the threshold.
+- **Spans.** While spans are live (``telemetry.span``: trace mode,
+  a profiler RECORD window or a ``jax.profiler`` session) the pump
+  accounts for all of its time outside ``scheduler.step()``:
+  ``engine.ops`` (inbox ops, deadline expiry, retire, drain check,
+  gate), ``engine.flush`` (token marshalling, inside ``engine.ops``)
+  and ``engine.idle`` (parked in the wake event); each flushed batch
+  leaves one ``engine.stream_lag`` range from the commit of its
+  first token on the pump thread to the moment
+  ``TokenStream.__anext__`` hands that token to the client.
 - **Ops front door.** With ``FLAGS_ops_server_port`` set,
   ``start()`` arms the embedded debug server and registers a
   ``/enginez`` section: pump state, inflight streams, backpressure
@@ -98,6 +107,18 @@ _ENGINE_SEQ = [0]  # concurrency: single-writer (engine ctor thread)
 _EOS = object()    # stream terminator sentinel
 
 
+class _LagMark:
+    """Queued ahead of a flushed batch while spans are live: when the
+    batch's first token was committed on the pump thread, and how
+    many tokens the batch holds."""
+
+    __slots__ = ("t0", "n")
+
+    def __init__(self, t0, n):
+        self.t0 = t0
+        self.n = n
+
+
 class EngineClosedError(RuntimeError):
     """Raised by submit() when the engine is not started, draining,
     or stopped."""
@@ -127,6 +148,7 @@ class TokenStream:
         self.req = req
         self._q = asyncio.Queue()
         self._ended = False
+        self._lag = None    # a _LagMark whose token is yet to be read
 
     @property
     def req_id(self):
@@ -148,16 +170,28 @@ class TokenStream:
     async def __anext__(self):
         if self._ended:
             raise StopAsyncIteration
-        try:
-            item = await self._q.get()
-        except asyncio.CancelledError:
-            # consumer disconnected mid-stream: tell the pump to
-            # abort the request (lock-free post; never blocks)
-            self._engine._post(("cancel", self.req.req_id, None, None))
-            raise
+        while True:
+            try:
+                item = await self._q.get()
+            except asyncio.CancelledError:
+                # consumer disconnected mid-stream: tell the pump to
+                # abort the request (lock-free post; never blocks)
+                self._engine._post(
+                    ("cancel", self.req.req_id, None, None))
+                raise
+            if type(item) is not _LagMark:
+                break
+            self._lag = item
         if item is _EOS:
             self._ended = True
             raise StopAsyncIteration
+        lag = self._lag
+        if lag is not None:
+            self._lag = None
+            telemetry.add_complete(
+                "engine.stream_lag", lag.t0,
+                telemetry.clock() - lag.t0, req=self.req.req_id,
+                n=lag.n)
         return item
 
     async def tokens(self):
@@ -181,11 +215,14 @@ class TokenStream:
         if not self._ended:
             self._q.put_nowait(tok)
 
-    def _deliver_many(self, toks):
+    def _deliver_many(self, toks, t0=None):
         # one loop hop delivers a whole step's committed tokens —
         # speculative rounds commit up to draft_k+1 per stream per
-        # step (see ServingEngine._flush_tokens)
+        # step (see ServingEngine._flush_tokens). t0: when the first
+        # of them was committed, given only while spans are live
         if not self._ended:
+            if t0 is not None:
+                self._q.put_nowait(_LagMark(t0, len(toks)))
             for tok in toks:
                 self._q.put_nowait(tok)
 
@@ -452,15 +489,19 @@ class ServingEngine:
             self._pump_arm()
             while True:
                 self._wake.clear()
-                if not self._pump_ops():
+                with telemetry.span("engine.ops"):
+                    alive = self._pump_ops()
+                    if alive:
+                        # satellite: queued requests whose deadline
+                        # lapsed while waiting are aborted BEFORE
+                        # burning a prefill
+                        if sched.expire_queued_deadlines():
+                            self._note_write()
+                        self._pump_retire()
+                        if self._draining:
+                            self._pump_check_drained()
+                if not alive:
                     break
-                # satellite: queued requests whose deadline lapsed
-                # while waiting are aborted BEFORE burning a prefill
-                if sched.expire_queued_deadlines():
-                    self._note_write()
-                self._pump_retire()
-                if self._draining:
-                    self._pump_check_drained()
                 if sched.num_queued or sched.num_active \
                         or sched.num_swapped:
                     now = telemetry.clock()
@@ -475,9 +516,10 @@ class ServingEngine:
                     self._note_write()
                     self._pump_steps += 1
                     self._last_step_wall = last_end - now
-                    self._pump_retire()
-                    if self._pump_steps % self._gate_stride == 0:
-                        self._gate_eval()
+                    with telemetry.span("engine.ops"):
+                        self._pump_retire()
+                        if self._pump_steps % self._gate_stride == 0:
+                            self._gate_eval()
                 else:
                     last_end = None
                     self._note_write()
@@ -488,7 +530,8 @@ class ServingEngine:
                         # keep evaluating while idle or it could
                         # never recover and admit work again
                         self._gate_eval()
-                    self._wake.wait(self._idle_wait)
+                    with telemetry.span("engine.idle"):
+                        self._wake.wait(self._idle_wait)
         except BaseException as e:  # pragma: no cover - defensive
             self._pump_error = repr(e)
             raise
@@ -623,7 +666,11 @@ class ServingEngine:
                 # _flush_tokens ships the step's batch in one hop
                 ent = pending.get(req.req_id)
                 if ent is None:
-                    pending[req.req_id] = ent = (stream, [])
+                    # the batch's first token: its commit time starts
+                    # the stream's engine.stream_lag range
+                    t0 = telemetry.clock() if telemetry.tracing_on() \
+                        else None
+                    pending[req.req_id] = ent = (stream, [], t0)
                 ent[1].append(int(tok))
 
         return hook
@@ -636,13 +683,15 @@ class ServingEngine:
         precede its EOS."""
         if not self._pending_toks:
             return
-        self._note_write()
-        # drain IN PLACE: the on_token hooks hold a reference to this
-        # dict, so swapping in a fresh one would orphan them
-        pending = list(self._pending_toks.values())
-        self._pending_toks.clear()
-        for stream, toks in pending:
-            self._call_loop(stream._deliver_many, toks)
+        with telemetry.span("engine.flush",
+                            streams=len(self._pending_toks)):
+            self._note_write()
+            # drain IN PLACE: the on_token hooks hold a reference to
+            # this dict, so swapping in a fresh one would orphan them
+            pending = list(self._pending_toks.values())
+            self._pending_toks.clear()
+            for stream, toks, t0 in pending:
+                self._call_loop(stream._deliver_many, toks, t0)
 
     def _pump_cancel(self, req_id, fut):
         ok = False

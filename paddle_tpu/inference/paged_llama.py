@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..framework import telemetry
 from ..framework.core import Tensor, no_grad
 from ..framework.flags import flag
 from ..incubate.nn import PagedKVCacheManager
@@ -425,189 +426,207 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
     via the paged decode kernel, prefill rows via the q_lens-masked
     prefill kernel)."""
     cfg = self.cfg
-    b = len(seq_ids)
-    counts = [len(t) for t in token_ids]
-    if b != len(counts) or b == 0:
-        raise ValueError(
-            f"prefill_chunk: {len(counts)} token rows for {b} "
-            "sequences")
-    if min(counts) < 1:
-        raise ValueError(
-            "prefill_chunk: every row must carry at least one token "
-            f"(counts={counts})")
-    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    lens0 = [self.caches[0].seq_len(s) for s in seq_ids]
-    if start_positions is not None:
-        sp = [int(p) for p in start_positions]
-        if sp != lens0:
+    with telemetry.span("model.plan") as plan_span:
+        b = len(seq_ids)
+        counts = [len(t) for t in token_ids]
+        if b != len(counts) or b == 0:
             raise ValueError(
-                f"prefill_chunk: start_positions {sp} disagree with "
-                f"the cached lengths {lens0} — a chunk must resume "
-                "exactly where the cache left off")
-    over = [s for s, n, c in zip(seq_ids, lens0, counts)
-            if n + c > self.max_length]
-    if over:
-        raise ValueError(
-            f"sequences {over} would exceed max_length="
-            f"{self.max_length}; positions beyond it cannot be "
-            "rotary-encoded")
+                f"prefill_chunk: {len(counts)} token rows for {b} "
+                "sequences")
+        if min(counts) < 1:
+            raise ValueError(
+                "prefill_chunk: every row must carry at least one token "
+                f"(counts={counts})")
+        nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                       cfg.head_dim)
+        lens0 = [self.caches[0].seq_len(s) for s in seq_ids]
+        if start_positions is not None:
+            sp = [int(p) for p in start_positions]
+            if sp != lens0:
+                raise ValueError(
+                    f"prefill_chunk: start_positions {sp} disagree with "
+                    f"the cached lengths {lens0} — a chunk must resume "
+                    "exactly where the cache left off")
+        over = [s for s, n, c in zip(seq_ids, lens0, counts)
+                if n + c > self.max_length]
+        if over:
+            raise ValueError(
+                f"sequences {over} would exceed max_length="
+                f"{self.max_length}; positions beyond it cannot be "
+                "rotary-encoded")
 
-    flat = np.concatenate(
-        [np.asarray(t, "int64") for t in token_ids])
-    n_real = int(flat.shape[0])
-    pad_to = int(pad_to) if pad_to else n_real
-    if pad_to < n_real:
-        raise ValueError(
-            f"prefill_chunk: pad_to={pad_to} below the packed token "
-            f"count {n_real}")
-    flat = np.concatenate(
-        [flat, np.zeros(pad_to - n_real, "int64")])
-    pos_np = np.zeros(pad_to, np.int32)
-    starts = np.zeros(b, np.int64)
-    off = 0
-    for i, (n, c) in enumerate(zip(lens0, counts)):
-        starts[i] = off
-        pos_np[off:off + c] = np.arange(n, n + c)
-        off += c
-    last_idx = starts + np.asarray(counts) - 1
-    pos = jnp.asarray(pos_np)[None, :]             # (1, N)
+        flat = np.concatenate(
+            [np.asarray(t, "int64") for t in token_ids])
+        n_real = int(flat.shape[0])
+        pad_to = int(pad_to) if pad_to else n_real
+        if pad_to < n_real:
+            raise ValueError(
+                f"prefill_chunk: pad_to={pad_to} below the packed token "
+                f"count {n_real}")
+        flat = np.concatenate(
+            [flat, np.zeros(pad_to - n_real, "int64")])
+        pos_np = np.zeros(pad_to, np.int32)
+        starts = np.zeros(b, np.int64)
+        off = 0
+        for i, (n, c) in enumerate(zip(lens0, counts)):
+            starts[i] = off
+            pos_np[off:off + c] = np.arange(n, n + c)
+            off += c
+        last_idx = starts + np.asarray(counts) - 1
+        pos = jnp.asarray(pos_np)[None, :]             # (1, N)
 
-    self._dispatch_shapes.add(pad_to)
-    self.chunk_stats["calls"] += 1
-    self.chunk_stats["packed_tokens"] += n_real
-    self.chunk_stats["padded_tokens"] += pad_to - n_real
+        self._dispatch_shapes.add(pad_to)
+        self.chunk_stats["calls"] += 1
+        self.chunk_stats["packed_tokens"] += n_real
+        self.chunk_stats["padded_tokens"] += pad_to - n_real
 
-    mode = str(flag("ragged_attention"))
-    unified = mode != "off"
-    # every layer's cache shares one page size (adapter construction),
-    # so the padded page-table width is loop-invariant
-    mp_pad = _pow2(max(
-        -(-(n + c) // self.caches[0].page_size)
-        for n, c in zip(lens0, counts)))
+        mode = str(flag("ragged_attention"))
+        unified = mode != "off"
+        # every layer's cache shares one page size (adapter construction),
+        # so the padded page-table width is loop-invariant
+        mp_pad = _pow2(max(
+            -(-(n + c) // self.caches[0].page_size)
+            for n, c in zip(lens0, counts)))
 
-    # gather/scatter plans (host-built once, shared by every layer)
-    s_plan = m_plan = None
-    fuse = False
-    if unified:
-        # ONE right-aligned ragged block for EVERY row: decode rows
-        # are q_lens=1 rows of the same kernel call (the Ragged Paged
-        # Attention shape), so each packed config compiles ONE attend
-        # program instead of a decode/prefill pair
-        t_pad = _pow2(max(counts))
-        b_pad = _pow2(b)
-        gm, mr, mc, m_flat = _right_align_plan(
-            range(b), starts, counts, t_pad, b_pad)
-        fuse = mode == "auto" and self._fusion_eligible()
-        # the fused program embeds the packed dense prologue/epilogue,
-        # so its REAL dispatch key includes the packed bucket (pad_to)
-        # — the pure attend program's does not
-        shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
-            if fuse else ("ragged", b_pad, t_pad, mp_pad)
-        self._kernel_shapes.add(shape)
-        self._bucket_programs.setdefault(pad_to, set()).add(shape)
-        pos_flat = jnp.asarray(pos_np)
-        if fuse:
-            # loop-invariant across layers: pad the scatter plan to
-            # the bucket ONCE (out-of-bounds fills drop in the fused
-            # program's scatters) instead of once per layer
-            mr = _pad_plan(mr, pad_to, 0)
-            mc = _pad_plan(mc, pad_to, 0)
-            m_flat = _pad_plan(m_flat, pad_to, pad_to)
-    else:
-        singles = [i for i, c in enumerate(counts) if c == 1]
-        multis = [i for i, c in enumerate(counts) if c > 1]
-        if singles:
-            bs = len(singles)
-            bs_pad = _pow2(bs)
-            s_idx = jnp.asarray(
-                np.concatenate([last_idx[singles],
-                                np.zeros(bs_pad - bs, np.int64)]),
-                jnp.int32)
-            s_seqs = [seq_ids[i] for i in singles]
-            shape = ("decode", bs_pad, 1, mp_pad)
-            self._kernel_shapes.add(shape)
-            self._bucket_programs.setdefault(pad_to, set()).add(shape)
-            s_plan = (s_idx, s_seqs, bs, bs_pad)
-        if multis:
-            t_pad = _pow2(max(counts[i] for i in multis))
-            bm_pad = _pow2(len(multis))
+        # gather/scatter plans (host-built once, shared by every layer)
+        s_plan = m_plan = None
+        fuse = False
+        if unified:
+            # ONE right-aligned ragged block for EVERY row: decode rows
+            # are q_lens=1 rows of the same kernel call (the Ragged Paged
+            # Attention shape), so each packed config compiles ONE attend
+            # program instead of a decode/prefill pair
+            t_pad = _pow2(max(counts))
+            b_pad = _pow2(b)
             gm, mr, mc, m_flat = _right_align_plan(
-                multis, starts, counts, t_pad, bm_pad)
-            q_lens = [counts[i] for i in multis]
-            m_seqs = [seq_ids[i] for i in multis]
-            shape = ("prefill", bm_pad, t_pad, mp_pad)
+                range(b), starts, counts, t_pad, b_pad)
+            fuse = mode == "auto" and self._fusion_eligible()
+            # the fused program embeds the packed dense prologue/epilogue,
+            # so its REAL dispatch key includes the packed bucket (pad_to)
+            # — the pure attend program's does not
+            shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
+                if fuse else ("ragged", b_pad, t_pad, mp_pad)
             self._kernel_shapes.add(shape)
             self._bucket_programs.setdefault(pad_to, set()).add(shape)
-            m_plan = (gm, m_seqs, q_lens, bm_pad, mr, mc, m_flat)
-
-    with no_grad():
-        ids = Tensor(flat[:, None])
-        x = self.model.model.embed_tokens(ids)[:, 0]     # (N, H)
-        for li, layer in enumerate(self.model.model.layers):
-            cache = self.caches[li]
-            xi = layer.input_layernorm(x)
+            pos_flat = jnp.asarray(pos_np)
             if fuse:
-                # FlashFuser path: qkv + RoPE + page scatter fold
-                # into the ragged kernel's prologue and o_proj into
-                # its epilogue — one program, one dispatch per layer
-                att = layer.self_attn
-                biases = None
-                if att.q_proj.bias is not None:
-                    biases = (att.q_proj.bias._data,
-                              att.k_proj.bias._data,
-                              att.v_proj.bias._data)
-                self.chunk_stats["attend_calls"] += 1
-                y = cache.fused_ragged_step(
-                    xi,
-                    (att.q_proj.weight._data, att.k_proj.weight._data,
-                     att.v_proj.weight._data, att.o_proj.weight._data,
-                     biases),
-                    (self._cos, self._sin), pos_flat, seq_ids, counts,
-                    gm, (mr, mc, m_flat), rows_pad=b_pad,
-                    max_pages=mp_pad, window=self._window)
-                x = x + y
-                x = x + layer.mlp(layer.post_attention_layernorm(x))
-                continue
-            q = layer.self_attn.q_proj(xi)
-            k = layer.self_attn.k_proj(xi)
-            v = layer.self_attn.v_proj(xi)
-            qh = q._data.reshape(1, pad_to, nh, hd)
-            kh = k._data.reshape(1, pad_to, nkv, hd)
-            vh = v._data.reshape(1, pad_to, nkv, hd)
-            qh = apply_rotary_emb(
-                qh, self._cos, self._sin, position_ids=pos)[0]
-            kh = apply_rotary_emb(
-                kh, self._cos, self._sin, position_ids=pos)[0]
-            vh = vh[0]
-            cache.append_ragged(
-                seq_ids, counts, kh[:n_real], vh[:n_real])
+                # loop-invariant across layers: pad the scatter plan to
+                # the bucket ONCE (out-of-bounds fills drop in the fused
+                # program's scatters) instead of once per layer
+                mr = _pad_plan(mr, pad_to, 0)
+                mc = _pad_plan(mc, pad_to, 0)
+                m_flat = _pad_plan(m_flat, pad_to, pad_to)
+        else:
+            singles = [i for i, c in enumerate(counts) if c == 1]
+            multis = [i for i, c in enumerate(counts) if c > 1]
+            if singles:
+                bs = len(singles)
+                bs_pad = _pow2(bs)
+                s_idx = jnp.asarray(
+                    np.concatenate([last_idx[singles],
+                                    np.zeros(bs_pad - bs, np.int64)]),
+                    jnp.int32)
+                s_seqs = [seq_ids[i] for i in singles]
+                shape = ("decode", bs_pad, 1, mp_pad)
+                self._kernel_shapes.add(shape)
+                self._bucket_programs.setdefault(pad_to, set()).add(shape)
+                s_plan = (s_idx, s_seqs, bs, bs_pad)
+            if multis:
+                t_pad = _pow2(max(counts[i] for i in multis))
+                bm_pad = _pow2(len(multis))
+                gm, mr, mc, m_flat = _right_align_plan(
+                    multis, starts, counts, t_pad, bm_pad)
+                q_lens = [counts[i] for i in multis]
+                m_seqs = [seq_ids[i] for i in multis]
+                shape = ("prefill", bm_pad, t_pad, mp_pad)
+                self._kernel_shapes.add(shape)
+                self._bucket_programs.setdefault(pad_to, set()).add(shape)
+                m_plan = (gm, m_seqs, q_lens, bm_pad, mr, mc, m_flat)
+        ids = Tensor(flat[:, None])
+        if plan_span is not None:
+            up = [ids._data, pos]
             if unified:
-                qm = qh[gm]                  # (b_pad, t_pad, nh, hd)
-                self.chunk_stats["attend_calls"] += 1
-                out = cache.attend_ragged(
-                    Tensor(qm), seq_ids, counts, rows_pad=b_pad,
-                    max_pages=mp_pad, window=self._window)
-                attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
-                attn = attn.at[m_flat].set(out._data[mr, mc])
-            else:
-                attn = self._attend_rows_two_kernel(
-                    cache, qh, jnp.zeros((pad_to, nh, hd), qh.dtype),
-                    s_plan, m_plan, mp_pad)
-            attn_flat = Tensor(attn.reshape(pad_to, nh * hd))
-            x = x + layer.self_attn.o_proj(attn_flat)
-            x = x + layer.mlp(layer.post_attention_layernorm(x))
-        x_last = Tensor(x._data[jnp.asarray(last_idx, jnp.int32)])
-        h = self.model.model.norm(x_last)
-        last = self.model._head(h)               # (B, vocab)
-        if logits_rows is None:
-            return last
-        # multi-row sampling epilogue: per-position logits for the
-        # listed (verify) rows, concatenated in list order
-        vidx = _packed_position_index(starts, counts, logits_rows)
-        x_full = Tensor(x._data[vidx])
-        full = self.model._head(self.model.model.norm(x_full))
-        return last, full
+                up += [pos_flat, gm, mr, mc, m_flat]
+            plan_span.attrs.update(rows=b, packed=n_real, pad_to=pad_to,
+                            bytes=sum(int(a.nbytes) for a in up))
+
+    span = telemetry.span
+    with no_grad():
+        with span("model.embed"):
+            x = self.model.model.embed_tokens(ids)[:, 0]     # (N, H)
+        for li, layer in enumerate(self.model.model.layers):
+            with span("model.layer", li=li):
+                cache = self.caches[li]
+                with span("model.norm"):
+                    xi = layer.input_layernorm(x)
+                if fuse:
+                    # FlashFuser path: qkv + RoPE + page scatter fold
+                    # into the ragged kernel's prologue and o_proj
+                    # into its epilogue — one program, one dispatch
+                    # per layer
+                    att = layer.self_attn
+                    biases = None
+                    if att.q_proj.bias is not None:
+                        biases = (att.q_proj.bias._data,
+                                  att.k_proj.bias._data,
+                                  att.v_proj.bias._data)
+                    self.chunk_stats["attend_calls"] += 1
+                    y = cache.fused_ragged_step(
+                        xi,
+                        (att.q_proj.weight._data,
+                         att.k_proj.weight._data,
+                         att.v_proj.weight._data,
+                         att.o_proj.weight._data, biases),
+                        (self._cos, self._sin), pos_flat, seq_ids,
+                        counts, gm, (mr, mc, m_flat), rows_pad=b_pad,
+                        max_pages=mp_pad, window=self._window)
+                    x = x + y
+                else:
+                    q = layer.self_attn.q_proj(xi)
+                    k = layer.self_attn.k_proj(xi)
+                    v = layer.self_attn.v_proj(xi)
+                    qh = q._data.reshape(1, pad_to, nh, hd)
+                    kh = k._data.reshape(1, pad_to, nkv, hd)
+                    vh = v._data.reshape(1, pad_to, nkv, hd)
+                    qh = apply_rotary_emb(
+                        qh, self._cos, self._sin, position_ids=pos)[0]
+                    kh = apply_rotary_emb(
+                        kh, self._cos, self._sin, position_ids=pos)[0]
+                    vh = vh[0]
+                    cache.append_ragged(
+                        seq_ids, counts, kh[:n_real], vh[:n_real])
+                    if unified:
+                        qm = qh[gm]          # (b_pad, t_pad, nh, hd)
+                        self.chunk_stats["attend_calls"] += 1
+                        out = cache.attend_ragged(
+                            Tensor(qm), seq_ids, counts,
+                            rows_pad=b_pad, max_pages=mp_pad,
+                            window=self._window)
+                        attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
+                        attn = attn.at[m_flat].set(out._data[mr, mc])
+                    else:
+                        attn = self._attend_rows_two_kernel(
+                            cache, qh,
+                            jnp.zeros((pad_to, nh, hd), qh.dtype),
+                            s_plan, m_plan, mp_pad)
+                    attn_flat = Tensor(attn.reshape(pad_to, nh * hd))
+                    x = x + layer.self_attn.o_proj(attn_flat)
+                with span("model.norm"):
+                    h2 = layer.post_attention_layernorm(x)
+                with span("model.mlp"):
+                    x = x + layer.mlp(h2)
+        with span("model.head"):
+            x_last = Tensor(x._data[jnp.asarray(last_idx, jnp.int32)])
+            h = self.model.model.norm(x_last)
+            last = self.model._head(h)               # (B, vocab)
+            if logits_rows is None:
+                return last
+            # multi-row sampling epilogue: per-position logits for
+            # the listed (verify) rows, concatenated in list order
+            vidx = _packed_position_index(starts, counts, logits_rows)
+            x_full = Tensor(x._data[vidx])
+            full = self.model._head(self.model.model.norm(x_full))
+            return last, full
 
 
 def _attend_rows_two_kernel(self, cache, qh, attn, s_plan, m_plan,

@@ -88,12 +88,16 @@ namespace — per-request TTFT / TPOT / queue-wait / retire-latency
 histograms and token/request counters, surfaced through
 :meth:`BatchScheduler.metrics` as ONE namespaced snapshot (pool,
 prefix and sanitizer counters fold into the same shape; the legacy
-``page_pool_stats()`` keys stay as aliases). In trace mode every step
-additionally records nested wall spans — ``serving.step`` >
-``serving.admit`` / ``serving.prefill_chunk`` / ``serving.decode`` /
-``serving.retire`` — into the telemetry ring (Chrome-trace
-exportable). Off (the default) allocates nothing and costs one
-``is None`` check per site; all timing goes through
+``page_pool_stats()`` keys stay as aliases). While spans are live
+(trace mode, a profiler RECORD window, or a ``jax.profiler`` session —
+decided at each call, not at construction) every step additionally
+records nested wall spans — ``serving.step`` > ``serving.admit`` /
+``serving.pack`` / ``serving.prefill_chunk`` (the model call alone) /
+``serving.logits_pull`` (the wait for the device and the copy) /
+``serving.decode`` / ``serving.retire`` — into the telemetry ring
+(Chrome-trace exportable) and, under a session, into the device
+trace. Off (the default) allocates nothing and costs one
+probe per site; all timing goes through
 ``telemetry.clock()`` — tools/lint_codebase.py's clock-discipline
 rule bans direct ``time.*`` reads in this module.
 
@@ -475,7 +479,11 @@ class BatchScheduler:
         self._san_steps = 0
         # runtime telemetry (framework/telemetry.py): mode read HERE,
         # like the sanitizer — off holds None handles and every
-        # instrumented site below pays one `is None` check
+        # instrumented site below pays one `is None` check. SPANS are
+        # the exception: _span/_req_span ask telemetry at call time,
+        # so a profiler session that starts later is honoured;
+        # _tracer only says whether trace mode was on at construction
+        # (request trace contexts, the flight recorder's ring)
         self._metrics = telemetry.registry()
         self._tracer = telemetry.tracer()
         # per-request trace assembly (trace mode / armed profiler
@@ -1678,11 +1686,20 @@ class BatchScheduler:
         return [c.seq_pages(seq_id) for c in self.model.caches]
 
     def _span(self, name, **attrs):
-        """Span context for a step phase — NULL_SPAN when no tracer
-        is live (the off path never enters telemetry.py; the guard
-        lives here so call sites cannot forget it)."""
-        tr = self._tracer
-        return tr.span(name, **attrs) if tr is not None else _NULL
+        """Span context for a step phase, decided at CALL time by
+        :func:`telemetry.span`: live under ``FLAGS_telemetry=trace``,
+        a profiler RECORD window or a ``jax.profiler`` session —
+        whether it began before or after this scheduler was built —
+        and NULL_SPAN otherwise."""
+        return telemetry.span(name, **attrs)
+
+    def _pull(self, logits):
+        """A model call's logits as a host array, under its own span:
+        the wait for the device and the device->host copy, apart from
+        the dispatch that ``serving.prefill_chunk`` covers."""
+        with self._span("serving.logits_pull"):
+            return np.asarray(
+                logits.numpy() if hasattr(logits, "numpy") else logits)
 
     def _req_span(self, name, request, **attrs):
         """Request-scoped span: recorded under the request's
@@ -1693,7 +1710,7 @@ class BatchScheduler:
         cross-worker handoff. NULL_SPAN when no tracer is live.
         (``request`` is positional-by-convention: the ``req=`` span
         ATTRIBUTE carries the id, like every other span site.)"""
-        tr = self._tracer
+        tr = telemetry.tracer()
         if tr is None:
             return _NULL
         ctx = request.trace_ctx
@@ -2184,9 +2201,7 @@ class BatchScheduler:
             t_exec = telemetry.clock() if self._metrics is not None \
                 else 0.0
             logits = self.model.decode_token(feed, sids)
-            logits_np = np.asarray(
-                logits.numpy() if hasattr(logits, "numpy") else logits
-            )
+            logits_np = self._pull(logits)
             if self._metrics is not None:
                 self._metrics.observe("exec.wall_s.decode_token",
                                       telemetry.clock() - t_exec)
@@ -2308,10 +2323,14 @@ class BatchScheduler:
         and every budget-reached prefill row by its whole chunk —
         greedy outputs are token-identical to the token-per-step path
         (pinned in tests/test_chunked_prefill.py)."""
-        sids = sorted(self._active)
-        rows, feeds, starts, n_pre, n_dec = self._chunk_feeds(sids)
-        packed = sum(len(f) for f in feeds)
-        pad_to = bucket_packed_tokens(packed, self.serving_buckets)
+        with self._span("serving.pack") as sp:
+            sids = sorted(self._active)
+            rows, feeds, starts, n_pre, n_dec = self._chunk_feeds(sids)
+            packed = sum(len(f) for f in feeds)
+            pad_to = bucket_packed_tokens(packed, self.serving_buckets)
+            if sp is not None:
+                sp.attrs.update(rows=len(rows), packed=packed,
+                                pad_to=pad_to)
         t_exec = telemetry.clock() if self._metrics is not None \
             else 0.0
         with self._span("serving.prefill_chunk", rows=len(rows),
@@ -2319,9 +2338,7 @@ class BatchScheduler:
                         decode=n_dec):
             logits = self.model.prefill_chunk(
                 feeds, rows, starts, pad_to=pad_to)
-            logits_np = np.asarray(
-                logits.numpy() if hasattr(logits, "numpy")
-                else logits)
+        logits_np = self._pull(logits)
         if self._metrics is not None:
             # execution stamp for the performance ledger: one ragged
             # program invocation per step under the "prefill_chunk"
@@ -2400,11 +2417,7 @@ class BatchScheduler:
                 # mirror the prompt chunks into the draft's own pool
                 self.draft.prefill_chunk(feeds, rows, starts,
                                          pad_to=pad_to)
-                # the blocking device->host sync belongs to the model
-                # call's span, as in the non-spec paths
-                logits_np = np.asarray(
-                    logits.numpy() if hasattr(logits, "numpy")
-                    else logits)
+            logits_np = self._pull(logits)
             cs = self.chunk_stats
             cs["steps"] += 1
             cs["chunk_calls"] += 2
@@ -2421,8 +2434,7 @@ class BatchScheduler:
                     for s in pre]
             logits = self.model.decode_token(feed, pre)
             self.draft.decode_token(feed, pre)  # mirror the prompt
-            logits_np = np.asarray(
-                logits.numpy() if hasattr(logits, "numpy") else logits)
+            logits_np = self._pull(logits)
             for bi, s in enumerate(pre):
                 req = self._active[s]
                 tok = req.prompt_ids[req._pos]
@@ -2460,7 +2472,7 @@ class BatchScheduler:
                             draft_k=k):
                 props = []
                 for _ in range(k):
-                    dl = np.asarray(
+                    dl = self._pull(
                         self.draft.decode_token(cur, dec)._data)
                     cur = [int(np.argmax(dl[i]))
                            for i in range(len(dec))]
@@ -2478,7 +2490,7 @@ class BatchScheduler:
                 # FLAGS_spec_decode=legacy as the A/B oracle
                 tl = self.model.decode_window(windows, dec)  # trace-lint: ok(legacy A/B lowering)
                 preds = np.argmax(
-                    np.asarray(tl._data), axis=-1)  # (B, k+1)
+                    self._pull(tl._data), axis=-1)  # (B, k+1)
                 self.spec_stats["rounds"] += 1
                 self.spec_stats["target_calls"] += 1
                 self.spec_stats["draft_calls"] += k + 1
@@ -2665,8 +2677,7 @@ class BatchScheduler:
                 dl = self.draft.prefill_chunk(
                     d_feeds, d_rows, d_starts, pad_to=pad0)
             if dec:
-                dl_np = np.asarray(
-                    dl.numpy() if hasattr(dl, "numpy") else dl)
+                dl_np = self._pull(dl)
                 cur = [int(np.argmax(dl_np[i]))
                        for i in range(len(dec))]
                 props.append(cur)
@@ -2680,8 +2691,7 @@ class BatchScheduler:
                         # k-th proposal fed for pool symmetry with
                         # the window; its logits are never sampled
                         break
-                    dl_np = np.asarray(
-                        dl.numpy() if hasattr(dl, "numpy") else dl)
+                    dl_np = self._pull(dl)
                     cur = [int(np.argmax(dl_np[i]))
                            for i in range(len(dec))]
                     props.append(cur)
@@ -2723,17 +2733,14 @@ class BatchScheduler:
                     t_feeds, t_rows, t_starts, pad_to=pad_to,
                     logits_rows=(list(range(len(dec))) if dec
                                  else None))
-                if dec:
-                    last, full = out
-                    full_np = np.asarray(
-                        full.numpy() if hasattr(full, "numpy")
-                        else full)
-                    preds = np.argmax(
-                        full_np.reshape(len(dec), k + 1, -1), axis=-1)
-                else:
-                    last = out
-                last_np = np.asarray(
-                    last.numpy() if hasattr(last, "numpy") else last)
+            if dec:
+                last, full = out
+                preds = np.argmax(
+                    self._pull(full).reshape(len(dec), k + 1, -1),
+                    axis=-1)
+            else:
+                last = out
+            last_np = self._pull(last)
             if self._metrics is not None:
                 self._metrics.observe("exec.wall_s.prefill_chunk",
                                       telemetry.clock() - t_exec)

@@ -49,6 +49,32 @@ def live_static_functions():
     return list(_LIVE_STATICS)
 
 
+# XLA's three build phases as ranges in the span ring, under whatever
+# span is open on the thread that built (an eager op that re-traces,
+# re-lowers and loads from the persistent cache shows up as three
+# ranges inside its model.* span; a cache hit lands in xla.build).
+# ONE listener for the process, registered at import: jax.monitoring
+# has no cheap way to take a listener off again, so it asks telemetry
+# first and is a no-op while no span is live.
+_XLA_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.build",
+}
+
+
+def _xla_phase_listener(event, secs, **kw):
+    name = _XLA_PHASES.get(event)
+    if name is not None and _telemetry.tracing_on():
+        _telemetry.add_complete(
+            name, _telemetry.clock() - secs, secs, cat="compile",
+            fun=str(kw.get("fun_name", "")))
+
+
+jax.monitoring.register_event_duration_secs_listener(
+    _xla_phase_listener)
+
+
 # the one default cache directory: fixed inside the checkout (the path
 # is part of the cache key — a directory that moves never hits)
 _DEFAULT_CACHE_DIR = os.path.join(
@@ -244,9 +270,12 @@ class StaticFunction:
                 else r
                 for r in rw_raws
             ]
-        out_arrs, changed_state, grad_raws = entry["jitted"](
-            rw_raws, ro_raws, tensor_raws
-        )
+        # the entry call: dispatch of the compiled step (and, the
+        # first time, its trace/lower/build: xla.* ranges nest here)
+        with _telemetry.span("jit.call"):
+            out_arrs, changed_state, grad_raws = entry["jitted"](
+                rw_raws, ro_raws, tensor_raws
+            )
         if _exec is not None:
             # host-observed dispatch wall of the compiled program —
             # the measured half of the performance ledger's

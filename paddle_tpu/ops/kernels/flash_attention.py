@@ -152,6 +152,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
@@ -374,6 +375,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, scale,
             _flash_bwd_dkdv_kernel, scale, causal, int(window or 0),
             offset, block_q, block_k, group, nq,
         ),
+        name="flash_bwd_dkv",
         grid=(bhkv, nk, group, nq),
         in_specs=[qspec, qspec, rowspec, rowspec, kvspec, kvspec],
         out_specs=[kvspec, kvspec],
@@ -404,6 +406,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, causal, scale,
             _flash_bwd_dq_kernel, scale, causal, int(window or 0),
             offset, block_q, block_k, nk,
         ),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[qspec2, qspec2, rowspec2, rowspec2, kvspec2, kvspec2],
         out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),

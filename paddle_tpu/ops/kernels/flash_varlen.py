@@ -313,6 +313,7 @@ def _varlen_fwd_pallas(qh, kh, vh, qseg, qloc, kseg, kloc,
         functools.partial(
             _varlen_fwd_kernel, scale, causal, block_q, block_k, nk
         ),
+        name="flash_varlen_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((h, t, d), qh.dtype),
@@ -385,6 +386,7 @@ def _varlen_bwd_pallas(qh, kh, vh, out, lse, do, qseg, qloc, kseg, kloc,
             _varlen_bwd_dkdv_kernel, scale, causal,
             block_q, block_k, group, nq,
         ),
+        name="flash_varlen_bwd_dkv",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hkv, tk, d), kh.dtype),
@@ -422,6 +424,7 @@ def _varlen_bwd_pallas(qh, kh, vh, out, lse, do, qseg, qloc, kseg, kloc,
         functools.partial(
             _varlen_bwd_dq_kernel, scale, causal, block_q, block_k, nk
         ),
+        name="flash_varlen_bwd_dq",
         grid_spec=grid_spec2,
         out_shape=jax.ShapeDtypeStruct((h, t, d), qh.dtype),
         interpret=interpret,

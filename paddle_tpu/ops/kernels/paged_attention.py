@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...framework import telemetry
 from ...framework.flags import flag
 from . import on_tpu  # defined before the package imports its kernels
 from .rope import apply_rotary_emb
@@ -190,6 +191,7 @@ def _build_decode_call(b, h, d, npages, page_size, kvh, max_pages,
         q4 = q.reshape(b, h, 1, d)
         out = pl.pallas_call(
             kernel,
+            name="paged_decode_attention",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
             interpret=interpret,
@@ -496,7 +498,9 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
     args = (q, k_pages, v_pages, *scalar_args)
     if any(isinstance(x, jax.core.Tracer) for x in args):
         return _build_ragged_call(*cfg)(*args)
-    return _jitted_ragged_call(cfg)(*args)
+    with telemetry.span("kernel.ragged", rows=b, t=t,
+                        max_pages=max_pages):
+        return _jitted_ragged_call(cfg)(*args)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, seq_lens,
@@ -562,6 +566,7 @@ def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
         q4 = jnp.transpose(q, (0, 2, 1, 3))  # (B, H, T, D)
         out = pl.pallas_call(
             kernel,
+            name="ragged_paged_attention",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
             interpret=interpret,
@@ -746,4 +751,6 @@ def paged_ragged_fused_step(x, wq, wk, wv, wo, biases, cos, sin, pos,
              jnp.asarray(q_lens).astype(jnp.int32)]
     if any(isinstance(a, jax.core.Tracer) for a in args):
         return _build_fused_call(*cfg)(*args)
-    return _jitted_fused_call(cfg)(*args)
+    with telemetry.span("kernel.ragged", rows=b_pad, t=t_pad,
+                        max_pages=max_pages, fused=1):
+        return _jitted_fused_call(cfg)(*args)

@@ -36,9 +36,10 @@ def _choose_block_rows(n_rows, hidden, itemsize):
     return br
 
 
-def _row_tiled_call(kernel, x2d, vecs, interpret):
+def _row_tiled_call(kernel, x2d, vecs, interpret, name):
     """Run a row-wise ``kernel(x_ref, *vec_refs, o_ref)`` over (n, h)
-    in row blocks; ``vecs`` are (h,) operands every block sees whole."""
+    in row blocks; ``vecs`` are (h,) operands every block sees whole.
+    ``name`` is the kernel's name in a device trace."""
     n, h = x2d.shape
     br = _choose_block_rows(n, h, x2d.dtype.itemsize)
     n_pad = -(-n // br) * br
@@ -46,6 +47,7 @@ def _row_tiled_call(kernel, x2d, vecs, interpret):
         x2d = jnp.pad(x2d, ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(
         kernel,
+        name=name,
         out_shape=jax.ShapeDtypeStruct((n_pad, h), x2d.dtype),
         grid=(n_pad // br,),
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0))]
@@ -72,7 +74,7 @@ def _rms_kernel(eps, has_w, x_ref, *refs):
 def _rms_pallas(x2d, w, eps, interpret=False):
     return _row_tiled_call(
         functools.partial(_rms_kernel, eps, w is not None), x2d,
-        [] if w is None else [w], interpret)
+        [] if w is None else [w], interpret, "rms_norm")
 
 
 def _rows_per_shard(local, x, vecs):
@@ -197,7 +199,7 @@ def layer_norm_fused(x, weight=None, bias=None, eps=1e-5):
         out = _row_tiled_call(
             functools.partial(_ln_kernel, eps, weight is not None,
                               bias is not None),
-            x.reshape(-1, h), vecs, interpret_mode())
+            x.reshape(-1, h), vecs, interpret_mode(), "layer_norm")
         return out.reshape(x.shape)
 
     return _rows_per_shard(local, x,

@@ -40,6 +40,17 @@ import jax
 
 from ..framework import telemetry as _telemetry
 
+# the one switch of the program's spans: while a jax.profiler session
+# collects (this module's Profiler, or a bare jax.profiler.start_trace
+# by whoever drives the process), telemetry.span() is live and writes
+# each range into that session's trace as well. telemetry.py is
+# jax-free by contract, so the probe and the annotation class are
+# handed to it from here. is_enabled() reads a flag of the TraceMe
+# recorder: no backend is touched, the chip is left alone.
+_telemetry.install_session_probe(
+    jax.profiler.TraceAnnotation.is_enabled,
+    jax.profiler.TraceAnnotation)
+
 __all__ = [
     "Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
     "SortedKeys", "SummaryView", "export_chrome_tracing",
