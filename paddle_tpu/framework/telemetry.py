@@ -1677,6 +1677,13 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "fp wire bytes avoided by quantize-on-the-wire (fp payload "
      "minus quantized payload+sidecars; the live side of the "
      "planner's wire-savings assertion)"),
+    # eager kernel programs (ops/kernels.eager_call)
+    ("kernel.program_cache.hit.<kernel>", "counter",
+     "eager calls of a Pallas kernel with concrete arrays that ran "
+     "a cached jitted program (rms_norm, layer_norm_fused)"),
+    ("kernel.program_cache.miss.<kernel>", "counter",
+     "eager calls that built their program: a new shape, dtype, "
+     "eps, flag setting or mesh (a steady serving step reads 0)"),
     # async serving engine (inference/engine.py)
     ("engine.backpressure_state", "gauge",
      "ServingEngine admission-gate level: 0 open, 1 shed "

@@ -9,6 +9,8 @@ Each kernel ships two implementations:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 
 from ...framework.flags import flag
@@ -53,10 +55,54 @@ def record_dispatch(kernel: str, used_pallas: bool) -> None:
 
 
 def kernel_dispatch_stats(reset: bool = False):
-    """{'flash_fwd:pallas': n, 'flash_fwd:xla_fallback': m, ...}"""
+    """{'flash_fwd:pallas': n, 'flash_fwd:xla_fallback': m,
+    'rms_norm:program_hit': h, 'rms_norm:program_miss': k, ...}"""
     out = dict(_DISPATCH)
     if reset:
         _DISPATCH.clear()
+    return out
+
+
+@functools.lru_cache(maxsize=512)
+def _eager_program(kernel, fn, static, interpret, pallas, mesh):
+    """``jax.jit`` of ``fn(*arrays, *static)``. The key is everything
+    ``fn`` reads from outside its arguments while it is traced (the
+    kernels read the two dispatch predicates and the global mesh);
+    ``jax.jit`` keys on the arrays' shapes, dtypes and None-ness."""
+
+    def program(*arrays):
+        # runs only while jax.jit traces: a new shape, dtype or entry
+        _DISPATCH[f"{kernel}:program_miss"] += 1
+        return fn(*arrays, *static)
+
+    program.__name__ = kernel  # jit(<kernel>) in xla.* spans and traces
+    return jax.jit(program)
+
+
+def eager_call(kernel, fn, static, *arrays):
+    """``fn(*arrays, *static)`` for CONCRETE ``arrays`` and a tuple of
+    hashable ``static`` values, through one cached jitted program per
+    shape: an eager ``pl.pallas_call`` is a fresh closure every time,
+    so no cache of JAX's recognises it and it is traced, lowered
+    through Mosaic and loaded again per call.
+    Counted per call as ``<kernel>:program_hit|program_miss`` in
+    ``kernel_dispatch_stats()`` and, where the registry is on, as
+    ``kernel.program_cache.{hit,miss}.<kernel>``."""
+    from ...distributed.mesh import global_mesh
+    from ...framework import telemetry
+
+    prog = _eager_program(kernel, fn, static, interpret_mode(),
+                          use_pallas(), global_mesh())
+    miss_key = f"{kernel}:program_miss"
+    before = _DISPATCH[miss_key]
+    out = prog(*arrays)
+    reg = telemetry.registry()
+    if _DISPATCH[miss_key] == before:
+        _DISPATCH[f"{kernel}:program_hit"] += 1
+        if reg is not None:
+            reg.inc("kernel.program_cache.hit." + kernel)
+    elif reg is not None:
+        reg.inc("kernel.program_cache.miss." + kernel)
     return out
 
 

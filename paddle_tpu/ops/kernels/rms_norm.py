@@ -5,6 +5,16 @@ Welford/rsqrt fused normalize+scale). TPU design: rows are tiled into
 (block_rows, hidden) VMEM blocks; stats in fp32 on the VPU; one pass.
 Backward is XLA (it fuses fine — the win is the fwd fusion on the hot
 decode/train path).
+
+Dispatch caching: an eager caller (the serving step's 17 norms a step,
+eager ``generate()``, tests) with concrete arrays on the Pallas branch
+runs ONE cached ``jax.jit`` program per shape (``kernels.eager_call``,
+wrapped outside the ``custom_vjp``), so a repeated shape is neither
+traced nor lowered through Mosaic again — an eager ``pl.pallas_call``
+is a fresh closure per call that no cache of JAX's recognises. Callers
+already under an outer trace (``to_static``, ``jax.grad``, ``shard_map``)
+inline the identical lowering; the surrounding program owns compilation
+and caching there. The XLA reference branch stays op by op.
 """
 from __future__ import annotations
 
@@ -99,13 +109,30 @@ def _rms_ref(x, w, eps):
     return y.astype(x.dtype)
 
 
+def _pallas_ok(x):
+    from . import interpret_mode, use_pallas
+
+    return (use_pallas() or interpret_mode()) and x.shape[-1] % 128 == 0
+
+
+def _dispatch(kernel, core, eps, *arrays):
+    """The entry of a norm kernel: count the call, then run ``core``
+    inlined (the reference branch, or a caller already under an outer
+    trace) or as the cached eager program."""
+    from . import eager_call, record_dispatch
+
+    ok = _pallas_ok(arrays[0])
+    record_dispatch(kernel, ok)
+    if not ok or any(isinstance(a, jax.core.Tracer) for a in arrays):
+        return core(*arrays, eps)
+    return eager_call(kernel, core, (eps,), *arrays)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _rms_norm_core(x, w, eps):
-    from . import interpret_mode, record_dispatch, use_pallas
+    from . import interpret_mode
 
-    ok = (use_pallas() or interpret_mode()) and x.shape[-1] % 128 == 0
-    record_dispatch("rms_norm", ok)
-    if ok:
+    if _pallas_ok(x):
         def local(x, *w):
             out = _rms_pallas(x.reshape(-1, x.shape[-1]),
                               w[0] if w else None, eps,
@@ -144,7 +171,7 @@ _rms_norm_core.defvjp(_rms_fwd, _rms_bwd)
 
 def rms_norm(x, weight=None, eps=1e-6):
     """rms_norm over the last axis. x: [..., H], weight: [H] or None."""
-    return _rms_norm_core(x, weight, float(eps))
+    return _dispatch("rms_norm", _rms_norm_core, float(eps), x, weight)
 
 
 def _ln_kernel(eps, has_w, has_b, x_ref, *refs):
@@ -183,16 +210,11 @@ def _ln_ref(x, weight, bias, eps):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def layer_norm_fused(x, weight=None, bias=None, eps=1e-5):
-    """Pallas fused layer_norm over the last axis (fwd); XLA autodiff
-    bwd via the reference formula (pallas_call itself has no transpose
-    rule, so reverse-mode MUST go through this custom VJP)."""
-    from . import interpret_mode, record_dispatch, use_pallas
+def _layer_norm_core(x, weight, bias, eps):
+    from . import interpret_mode
 
     h = x.shape[-1]
-    ok = (use_pallas() or interpret_mode()) and h % 128 == 0
-    record_dispatch("layer_norm_fused", ok)
-    if not ok:
+    if not _pallas_ok(x):
         return _ln_ref(x, weight, bias, eps)
 
     def local(x, *vecs):
@@ -207,7 +229,7 @@ def layer_norm_fused(x, weight=None, bias=None, eps=1e-5):
 
 
 def _ln_fwd(x, weight, bias, eps):
-    return layer_norm_fused(x, weight, bias, eps), (x, weight, bias)
+    return _layer_norm_core(x, weight, bias, eps), (x, weight, bias)
 
 
 def _ln_bwd(eps, res, g):
@@ -229,4 +251,12 @@ def _ln_bwd(eps, res, g):
     return dx, dw, db
 
 
-layer_norm_fused.defvjp(_ln_fwd, _ln_bwd)
+_layer_norm_core.defvjp(_ln_fwd, _ln_bwd)
+
+
+def layer_norm_fused(x, weight=None, bias=None, eps=1e-5):
+    """Pallas fused layer_norm over the last axis (fwd); XLA autodiff
+    bwd via the reference formula (pallas_call itself has no transpose
+    rule, so reverse-mode MUST go through the custom VJP)."""
+    return _dispatch("layer_norm_fused", _layer_norm_core, float(eps),
+                     x, weight, bias)
