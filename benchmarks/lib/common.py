@@ -31,6 +31,19 @@ def load_cell(bench, name):
     return cell, config, traffic.load(cell["traffic"])
 
 
+def load_family(config, directory=os.path.join(BENCH_DIR, "families")):
+    """families/<family>.py, named by the configuration's ``program.family``:
+    the one place an architecture lives (its leaves, the program's model,
+    its plain reference, its counts). There is no default."""
+    name = config.get("program", {}).get("family")
+    path = os.path.join(directory, f"{name}.py")
+    if not name or not os.path.isfile(path):
+        raise SystemExit(f"benchmark: the configuration's program.family is "
+                         f"{name!r}: it has to name a file <family>.py in "
+                         f"{directory}")
+    return _load_file("bench_family_" + name, path)
+
+
 def load_limits(cell_name):
     """limits/<cell>.json: {number: limit}. A cell with no file has no
     proven comparison and cannot report correct."""
@@ -49,11 +62,16 @@ def metrics_for(bench, cell_name, kind):
 def read_metric(name, ctx):
     """metrics/<name>.py holds ``read(ctx)``; None: nothing to read."""
     path = os.path.join(BENCH_DIR, "metrics", name + ".py")
+    return _load_file("bench_metric_" + name, path).read(ctx)
+
+
+def _load_file(name, path):
+    """The module in the file at ``path``, under ``name``."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
 
 
 def device_info(chips):
@@ -75,32 +93,20 @@ def device_info(chips):
              "count": len(devs)}, peaks_for(d.device_kind))
 
 
-def build_model(config, seed):
-    """The program's model from the configuration file's constructor and
-    arguments, in bf16, holding the seed's weights. Returns (model, {leaf
-    path: parameter}). The sizes the reference uses are checked against
-    what the program built."""
-    import paddle_tpu as paddle
-    from paddle_tpu import models
-
+def build_model(family, config, seed):
+    """The program's model as the family builds it, holding the seed's
+    weights. Returns (model, {leaf path: parameter}). Program and reference
+    must have the same leaves with the same shapes."""
     from . import weights as W
 
-    prog = config["program"]
-    paddle.seed(0)
-    cfg = getattr(models, prog["constructor"])(**prog["constructor_args"])
-    for k in ("hidden_size", "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "vocab_size", "sliding_window",
-              "num_hidden_layers", "max_position_embeddings", "rope_theta",
-              "rms_norm_eps"):
-        if getattr(cfg, k) != config[k]:
-            raise SystemExit(f"benchmark: the program's {k}="
-                             f"{getattr(cfg, k)} is not the file's {config[k]}")
-    model = models.LlamaForCausalLM(cfg)
-    model.bfloat16()
-    tree = W.make_all(config, seed)
+    model = family.build(config)
+    leaves = family.leaves(config)
+    tree = W.make_all(W.spec(leaves, family.LEAF_NAMES,
+                             config["initializer_range"]), seed)
     named = dict(model.named_parameters())
     params = {}
-    for path, pname in W.flat_names(config):
+    for path in leaves:
+        pname = family.program_name(path)
         p = named.pop(pname)
         leaf = W.get_leaf(tree, path)
         if tuple(p.shape) != tuple(leaf.shape):

@@ -1,18 +1,19 @@
-"""Plain reference of the decoder the cells run: float32 ``jax.numpy``,
-matmuls at ``highest`` precision, no kernels, no cache, no batching tricks.
-It follows the published Mistral/Llama block (pre-norm RMSNorm, rotary
+"""What the plain reference of every family is made of: float32
+``jax.numpy``, matmuls at ``highest`` precision, no kernels, no cache, no
+batching tricks: the matmul with its int8 control, RMSNorm, the rotary
 embedding in the rotate-half form, grouped-query causal attention with a
-sliding window, SwiGLU, untied head). It imports nothing of the program
-and makes its own weights from the seed (weights.py).
+window, AdamW as published, and the loop that follows a family's loss
+through its first optimizer steps. The blocks themselves, ``serve_logits``
+and ``lm_loss`` are the family's (families/<family>.py). Nothing here
+imports the program, and the weights come from the seed (weights.py).
 
 ``mode="int8"`` is the control: the same arithmetic with the operands of
-every projection, MLP and head matmul, forward and backward, rounded to
+every matmul that goes through ``mm``, forward and backward, rounded to
 int8 levels (per token for activations and gradients, per output column
 for weights), the precision one step below the bfloat16 the configurations
 state.
 
-Memory: serving runs layer by layer over a block of sequences; training
-rematerialises each layer and runs attention one group of heads at a time.
+Memory: ``attention`` runs one group of heads at a time.
 """
 import functools
 
@@ -111,103 +112,8 @@ def attention(q, k, v, window):
         b, s, nh, d)
 
 
-def layer(x, lw, pos, cfg, mode):
-    """One decoder block. x [B, S, H] float32."""
-    b, s, _ = x.shape
-    nh, nkv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    h = rms(x, lw["ln1"], eps)
-    q = rope(mm(h, lw["q"], mode).reshape(b, s, nh, d), pos, theta)
-    k = rope(mm(h, lw["k"], mode).reshape(b, s, nkv, d), pos, theta)
-    v = mm(h, lw["v"], mode).reshape(b, s, nkv, d)
-    a = attention(q, k, v, int(cfg.get("sliding_window") or 0))
-    x = x + mm(a.reshape(b, s, nh * d), lw["o"], mode)
-    h = rms(x, lw["ln2"], eps)
-    return x + mm(jax.nn.silu(mm(h, lw["gate"], mode))
-                  * mm(h, lw["up"], mode), lw["down"], mode)
-
-
-def _f32(tree):
+def f32(tree):
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
-
-
-def _static_cfg(cfg):
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str))))
-
-
-# --------------------------------------------------------------------------
-# serving: teacher-forced logits over prompt + served tokens
-# --------------------------------------------------------------------------
-@functools.lru_cache(maxsize=None)
-def _serve_fns(cfg_key, mode):
-    cfg = dict(cfg_key)
-
-    @jax.jit
-    def embed(emb, ids):
-        return emb.astype(jnp.float32)[ids]
-
-    @jax.jit
-    def one_layer(x, lw):
-        pos = jnp.arange(x.shape[1])
-        return layer(x, _f32(lw), pos, cfg, mode)
-
-    @jax.jit
-    def final(x, norm, head, gather):
-        """best logit, argmax and the logits of ``gather`` [B, S, G] at
-        every position, one sequence at a time (the [S, V] logits of a
-        block never all exist at once)."""
-        norm, head = norm.astype(jnp.float32), head.astype(jnp.float32)
-
-        def one(args):
-            xs, gs = args
-            lg = mm(rms(xs, norm, cfg["rms_norm_eps"]), head, mode)  # S V
-            return (lg.max(-1), lg.argmax(-1).astype(jnp.int32),
-                    jnp.take_along_axis(lg, gs, -1))
-        return jax.lax.map(one, (x, gather))
-    return embed, one_layer, final
-
-
-def serve_logits(cfg, seed, ids, gather, mode="f32"):
-    """ids [B, S] int32 (padded on the right; causal, so padding never
-    reaches a real position); gather [B, S, G] token ids whose logits are
-    wanted at each position. Returns numpy (best [B,S], argmax [B,S],
-    gathered [B,S,G]) of the logits that predict position s+1."""
-    embed, one_layer, final = _serve_fns(_static_cfg(cfg), mode)
-    top = W.make_top(cfg, seed)
-    x = embed(top["embed"], jnp.asarray(ids, jnp.int32))
-    for li in range(cfg["num_hidden_layers"]):
-        x = one_layer(x, W.make_layer(cfg, seed, li))
-    best, arg, got = final(x, top["norm"], top["head"],
-                           jnp.asarray(gather, jnp.int32))
-    return np.asarray(best), np.asarray(arg), np.asarray(got)
-
-
-# --------------------------------------------------------------------------
-# training: loss, gradients and AdamW on float32 copies of the bf16 weights
-# --------------------------------------------------------------------------
-def lm_loss(params, ids, cfg, mode="f32", rows=None):
-    """Mean next-token cross-entropy: logits[:, :-1] predict ids[:, 1:].
-    ``rows`` keeps only those sequences (the half-batch fault)."""
-    if rows is not None:
-        ids = ids[jnp.asarray(rows)]
-    pos = jnp.arange(ids.shape[1])
-    x = params["embed"][ids]
-    for lw in params["layers"]:
-        x = jax.checkpoint(
-            lambda x_, lw_: layer(x_, lw_, pos, cfg, mode))(x, lw)
-
-    @jax.checkpoint
-    def seq_loss(args):
-        xs, ys = args
-        lg = mm(rms(xs[:-1], params["norm"], cfg["rms_norm_eps"]),
-                params["head"], mode)
-        lse = jax.nn.logsumexp(lg, -1)
-        return jnp.sum(lse - jnp.take_along_axis(lg, ys[1:, None], -1)[:, 0])
-
-    tot = jnp.sum(jax.lax.map(seq_loss, (x, ids)))
-    return tot / (ids.shape[0] * (ids.shape[1] - 1))
 
 
 def adamw_leaf(p, g, m, v, t, hp):
@@ -222,29 +128,25 @@ def adamw_leaf(p, g, m, v, t, hp):
     return p - hp["learning_rate"] * mh / (jnp.sqrt(vh) + hp["epsilon"]), m, v
 
 
-def tree_norms(tree):
-    """{leaf path: l2 norm} with weights.py's leaf paths."""
-    out = {n: jnp.sqrt(jnp.sum(jnp.square(tree[n].astype(jnp.float32))))
-           for n in W.TOP_LEAVES}
-    for i, lw in enumerate(tree["layers"]):
-        for n in W.LAYER_LEAVES:
-            out[f"layers.{i}.{n}"] = jnp.sqrt(
-                jnp.sum(jnp.square(lw[n].astype(jnp.float32))))
-    return out
+def tree_norms(tree, paths):
+    """{leaf path: l2 norm} of the leaves at ``paths``."""
+    return {p: jnp.sqrt(jnp.sum(jnp.square(
+        W.get_leaf(tree, p).astype(jnp.float32)))) for p in paths}
 
 
-def train_reference(cfg, hp, seed, batches, mode="f32", rows=None,
+def train_reference(family, cfg, hp, seed, batches, mode="f32", rows=None,
                     frozen=False):
-    """Follow ``len(batches)`` optimizer steps from the seed's weights.
-    Returns {"loss": [..], "grad1": {leaf: norm of the first gradient},
-    "delta": {leaf: norm of (params after the steps - initial params)}}.
-    ``rows`` and ``frozen`` plant faults for the tests: a part of the batch
-    left out; a step that returns its state unchanged."""
-    ckey = _static_cfg(cfg)
-    cfgd = dict(ckey)
+    """Follow ``len(batches)`` optimizer steps of the family's ``lm_loss``
+    from the seed's weights. Returns {"loss": [..], "grad1": {leaf: norm of
+    the first gradient}, "delta": {leaf: norm of (params after the steps -
+    initial params)}}. ``rows`` and ``frozen`` plant faults for the tests: a
+    part of the batch left out; a step that returns its state unchanged."""
+    leaves = family.leaves(cfg)
+    paths = list(leaves)
+    spec = W.spec(leaves, family.LEAF_NAMES, cfg["initializer_range"])
 
     grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, ids: lm_loss(p, ids, cfgd, mode, rows)))
+        lambda p, ids: family.lm_loss(p, ids, cfg, mode, rows)))
     hpd = {k: float(hp[k]) for k in
            ("learning_rate", "beta1", "beta2", "epsilon", "weight_decay")}
 
@@ -252,21 +154,13 @@ def train_reference(cfg, hp, seed, batches, mode="f32", rows=None,
     def update(p, g, m, v, t):
         return adamw_leaf(p, g, m, v, t, hpd)
 
-    def leaves(tree):
-        return [(None, n) for n in W.TOP_LEAVES] + [
-            (i, n) for i in range(len(tree["layers"]))
-            for n in W.LAYER_LEAVES]
+    get = W.get_leaf
 
-    def get(tree, i, n):
-        return tree[n] if i is None else tree["layers"][i][n]
+    def put(tree, path, val):
+        layer, name = W.split(path)
+        (tree if layer < 0 else tree["layers"][layer])[name] = val
 
-    def put(tree, i, n, val):
-        if i is None:
-            tree[n] = val
-        else:
-            tree["layers"][i][n] = val
-
-    params = _f32(W.make_all(cfg, seed))
+    params = f32(W.make_all(spec, seed))
     zeros = jax.jit(lambda t: jax.tree_util.tree_map(jnp.zeros_like, t))
     m, v = zeros(params), zeros(params)
     out = {"loss": [], "grad1": None, "delta": None}
@@ -274,25 +168,24 @@ def train_reference(cfg, hp, seed, batches, mode="f32", rows=None,
         loss, grads = grad_fn(params, jnp.asarray(ids, jnp.int32))
         out["loss"].append(float(loss))
         if t == 1:
-            out["grad1"] = {k: float(x) for k, x in tree_norms(grads).items()}
+            out["grad1"] = {k: float(x)
+                            for k, x in tree_norms(grads, paths).items()}
         if frozen:
             del grads
             continue
-        for i, n in leaves(params):
-            p2, m2, v2 = update(get(params, i, n), get(grads, i, n),
-                                get(m, i, n), get(v, i, n), float(t))
-            put(params, i, n, p2)
-            put(m, i, n, m2)
-            put(v, i, n, v2)
-            put(grads, i, n, None)
+        for path in paths:
+            p2, m2, v2 = update(get(params, path), get(grads, path),
+                                get(m, path), get(v, path), float(t))
+            put(params, path, p2)
+            put(m, path, m2)
+            put(v, path, v2)
+            put(grads, path, None)
         del grads
     del m, v
     # the change, leaf by leaf, against the seed's weights made anew
-    init = W.make_all(cfg, seed)
+    init = W.make_all(spec, seed)
     diff = jax.jit(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
         a - b.astype(jnp.float32)))))
-    out["delta"] = {}
-    for i, n in leaves(params):
-        path = n if i is None else f"layers.{i}.{n}"
-        out["delta"][path] = float(diff(get(params, i, n), get(init, i, n)))
+    out["delta"] = {path: float(diff(get(params, path), get(init, path)))
+                    for path in paths}
     return out

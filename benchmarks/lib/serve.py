@@ -93,15 +93,14 @@ class Recorder:
         self.step_no += 1
 
 
-def _build(config, seed, sampler):
+def _build(family, config, seed, sampler):
     from paddle_tpu.inference import BatchScheduler
-    from paddle_tpu.inference.paged_llama import PagedLlamaAdapter
 
-    model, _ = common.build_model(config, seed)
+    model, _ = common.build_model(family, config, seed)
     model.eval()
-    prog = config["program"]
-    adapter = PagedLlamaAdapter(model, **prog["pool"])
-    sched = BatchScheduler(adapter, sampler=sampler, **prog["scheduler"])
+    adapter = family.serving(model, config)
+    sched = BatchScheduler(adapter, sampler=sampler,
+                           **config["program"]["scheduler"])
     return model, adapter, sched
 
 
@@ -192,8 +191,8 @@ async def _load(engine, reqs, mix, rec, seconds, trace, trace_dir, notes):
     return arrivals, tokens_of, failed, late
 
 
-def run(bench, cell, config, mix, seed, seconds, trace, t_proc0, device,
-        peaks, break_with=None, trace_dir=None, limits=None):
+def run(bench, cell, config, family, mix, seed, seconds, trace, t_proc0,
+        device, peaks, break_with=None, trace_dir=None, limits=None):
     """One run of a serving cell. ``break_with`` (tests only) takes the
     sampler and returns the one handed to the scheduler in its place;
     ``limits`` (tests only) stands in for limits/<cell>.json."""
@@ -207,7 +206,7 @@ def run(bench, cell, config, mix, seed, seconds, trace, t_proc0, device,
     rec = Recorder(config.get("sliding_window"))
     sampler = break_with(rec.sampler) if break_with else rec.sampler
     t_b = time.perf_counter()
-    model, adapter, sched = _build(config, seed, sampler)
+    model, adapter, sched = _build(family, config, seed, sampler)
     common.note(phase="built", since_start_s=time.perf_counter() - t_proc0,
                 build_s=time.perf_counter() - t_b, builds=len(watch.builds))
     pool_total = sum(c.num_pages for c in adapter.caches)
@@ -286,7 +285,8 @@ def run(bench, cell, config, mix, seed, seconds, trace, t_proc0, device,
     top_logit = rec.top_logit
     del model, adapter, sched, inner_step, step
     gc.collect()
-    compared, info = check(config, mix, seed, served, gen_tokens, top_logit,
+    compared, info = check(config, family, mix, seed, served, gen_tokens,
+                           top_logit,
                            limits or common.load_limits(cell["name"]))
     common.note(phase="check", **info)
     ok = all(v["ok"] for v in compared.values()) and not failed
@@ -311,13 +311,11 @@ def pick_sample(served, gen_tokens, seed, k):
     return [longest] + rest[:max(0, k - 1)]
 
 
-def check(config, mix, seed, served, gen_tokens, top_logit, limits,
+def check(config, family, mix, seed, served, gen_tokens, top_logit, limits,
           control=False):
-    """Run the reference once over each sampled prompt with its served
-    tokens. With ``control`` the lower-precision reference is read at the
-    same positions instead of the served tokens."""
-    from . import reference
-
+    """Run the family's reference once over each sampled prompt with its
+    served tokens. With ``control`` the lower-precision reference is read
+    at the same positions instead of the served tokens."""
     sample = pick_sample(served, gen_tokens, seed, int(mix["check_sample"]))
     if not sample:
         # nothing was served, so nothing compares: not correct (1e30 and
@@ -336,10 +334,10 @@ def check(config, mix, seed, served, gen_tokens, top_logit, limits,
     gather = np.zeros((len(seqs), s_pad, 1), np.int32)
     gather[:, :-1, 0] = ids[:, 1:]
     if control:
-        _, arg, _ = reference.serve_logits(config, seed, ids, gather,
-                                           mode="int8")
+        _, arg, _ = family.serve_logits(config, seed, ids, gather,
+                                        mode="int8")
         gather[:, :, 0] = arg
-    best, _, got = reference.serve_logits(config, seed, ids, gather)
+    best, _, got = family.serve_logits(config, seed, ids, gather)
     at, errs = [], []
     for r, (rid, p, g) in enumerate(seqs):
         for j, tok in enumerate(g):

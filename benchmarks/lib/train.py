@@ -12,13 +12,13 @@ from . import common, correct, traffic
 from . import weights as W
 
 
-def _build(config, seed):
+def _build(family, config, seed):
     """The program's model with the seed's weights, its optimizer and the
     compiled step. Returns (step, params by leaf path, optimizer)."""
     import paddle_tpu as paddle
     import paddle_tpu.optimizer as optim
 
-    model, params = common.build_model(config, seed)
+    model, params = common.build_model(family, config, seed)
     prog = config["program"]
     o = prog["optimizer"]
     opt = getattr(optim, o["class"])(
@@ -37,7 +37,7 @@ def _build(config, seed):
     return train_step, params, opt
 
 
-def _state_reads(config, seed, params, opt):
+def _state_reads(family, config, seed, params, opt):
     """Two reads of the optimizer's state: the norm of every leaf's first
     moment, and the norm of every master weight's distance from the seed's
     weights (made anew, layer by layer)."""
@@ -66,22 +66,23 @@ def _state_reads(config, seed, params, opt):
         # same program as the subtraction, XLA keeps them in float32 and the
         # bf16 rounding of the start would read as movement
         mw, out = opt._master_weights, {}
-        top = W.make_top(config, seed)
+        spec = W.spec(family.leaves(config), family.LEAF_NAMES,
+                      config["initializer_range"])
+        by_layer = {}
         for p in paths:
-            parts = p.split(".")
-            if len(parts) == 1:
-                out[p] = dist(mw[params[p]._uid]._data, top[p])
-        for li in range(config["num_hidden_layers"]):
-            lw = W.make_layer(config, seed, li)
-            for n in W.LAYER_LEAVES:
-                p = f"layers.{li}.{n}"
-                out[p] = dist(mw[params[p]._uid]._data, lw[n])
+            by_layer.setdefault(W.split(p)[0], []).append(p)
+        top = W.make_top(spec, seed)      # held while the layers are made
+        for layer in sorted(by_layer):
+            made = top if layer < 0 else W.make_layer(spec, seed, layer)
+            for p in by_layer[layer]:
+                out[p] = dist(mw[params[p]._uid]._data,
+                              made[W.split(p)[1]])
         return {p: float(out[p]) for p in paths}
 
     return moment1, change
 
 
-def run(bench, cell, config, mix, seed, seconds, trace, t_proc0,
+def run(bench, cell, config, family, mix, seed, seconds, trace, t_proc0,
         device, peaks, break_with=None, trace_dir=None, limits=None):
     """One run of a training cell. ``break_with`` (tests only) takes the
     compiled step and returns the callable that is driven in its place;
@@ -98,8 +99,8 @@ def run(bench, cell, config, mix, seed, seconds, trace, t_proc0,
     n_check = int(mix["check_steps"])
     beta1 = config["program"]["optimizer"]["beta1"]
 
-    step, params, opt = _build(config, seed)
-    moment1, change = _state_reads(config, seed, params, opt)
+    step, params, opt = _build(family, config, seed)
+    moment1, change = _state_reads(family, config, seed, params, opt)
     drive = break_with(step) if break_with else step
 
     def feed(i):
@@ -161,7 +162,7 @@ def run(bench, cell, config, mix, seed, seconds, trace, t_proc0,
     batches = [traffic.train_batch(mix, seed, k, vocab)
                for k in range(n_check)]
     want = reference.train_reference(
-        config, config["program"]["optimizer"], seed, batches)
+        family, config, config["program"]["optimizer"], seed, batches)
     compared = correct.compare_training(
         got, want, limits or common.load_limits(cell["name"]))
     ok = all(v["ok"] for v in compared.values())

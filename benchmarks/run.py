@@ -4,6 +4,7 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Loads the cell's configuration and traffic from the files BENCHMARK.json
+names, and the architecture from the families/ file that the configuration
 names, builds the system under test with weights from the seed, warms it
 up (set-up), measures for ``--seconds`` seconds, compares what the timed
 path produced with the plain reference, and prints one JSON object as the
@@ -43,6 +44,7 @@ def main(argv=None):
 
     bench = common.load_benchmark()
     cell, config, mix = common.load_cell(bench, args.workload)
+    family = common.load_family(config)
     device, peaks = common.device_info(cell["chips"])
     common.load_limits(cell["name"])         # a cell with no limits fails now
 
@@ -53,15 +55,15 @@ def main(argv=None):
     drivers = {"serve": "benchmarks.lib.serve", "train": "benchmarks.lib.train"}
     import importlib
     driver = importlib.import_module(drivers[mix["driver"]])
-    out = driver.run(bench, cell, config, mix, args.seed, args.seconds,
-                     bool(args.trace), T_PROC0, device, peaks,
+    out = driver.run(bench, cell, config, family, mix, args.seed,
+                     args.seconds, bool(args.trace), T_PROC0, device, peaks,
                      trace_dir=trace_dir)
-    report(bench, cell, config, mix, peaks, device, out,
+    report(bench, cell, config, family, mix, peaks, device, out,
            trace_dir if args.trace else None)
 
 
-def report(bench, cell, config, mix, peaks, device, out, trace_dir):
-    from benchmarks.lib import common, flops
+def report(bench, cell, config, family, mix, peaks, device, out, trace_dir):
+    from benchmarks.lib import common
 
     device = dict(device, memory_peak_bytes=out["peak"])
     breakdown = None
@@ -75,7 +77,7 @@ def report(bench, cell, config, mix, peaks, device, out, trace_dir):
         shutil.rmtree(trace_dir, ignore_errors=True)   # little left on disk
         ctx = {"cell": cell, "config": config, "traffic": mix, "peaks": peaks,
                "window_s": out["window_s"], "counters": out["counters"],
-               "trace": red, "flops": flops, "chips": cell["chips"]}
+               "trace": red, "flops": family, "chips": cell["chips"]}
         metrics = {}
         for m in common.metrics_for(bench, cell["name"], "per_layer"):
             metrics[m["name"]] = common.read_metric(m["name"], ctx)

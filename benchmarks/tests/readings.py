@@ -13,6 +13,7 @@
     python3 benchmarks/tests/readings.py <cell> --seeds 1,2,3 [--control 1,2] [--seconds 10]
 """
 import argparse
+import functools
 import gc
 import json
 import os
@@ -34,10 +35,13 @@ def train_readings(cell, config, mix, seeds, control):
     ensure_compilation_cache()
     vocab, n = config["vocab_size"], int(mix["check_steps"])
     hp = config["program"]["optimizer"]
+    family = common.load_family(config)
+    follow = functools.partial(reference.train_reference, family, config, hp)
     for seed in seeds:
         t0 = time.perf_counter()
-        step, params, opt = train._build(config, seed)
-        moment1, change = train._state_reads(config, seed, params, opt)
+        step, params, opt = train._build(family, config, seed)
+        moment1, change = train._state_reads(family, config, seed, params,
+                                             opt)
         got = {"loss": []}
         for i in range(n):
             ids = traffic.train_batch(mix, seed, i, vocab)
@@ -53,7 +57,7 @@ def train_readings(cell, config, mix, seeds, control):
         gc.collect()
         batches = [traffic.train_batch(mix, seed, k, vocab) for k in range(n)]
         t0 = time.perf_counter()
-        want = reference.train_reference(config, hp, seed, batches)
+        want = follow(seed, batches)
         t_ref = time.perf_counter() - t0
         nums, info = correct.training_numbers(got, want)
         row = {"seed": seed, "program": nums, "info": info,
@@ -62,17 +66,14 @@ def train_readings(cell, config, mix, seeds, control):
                "peak": common.memory_peak_bytes()}
         if seed in control:
             t0 = time.perf_counter()
-            ctl = reference.train_reference(config, hp, seed, batches,
-                                            mode="int8")
+            ctl = follow(seed, batches, mode="int8")
             row["control_int8"], row["control_info"] = \
                 correct.training_numbers(ctl, want)
             row["t_control_s"] = time.perf_counter() - t0
-            half = reference.train_reference(
-                config, hp, seed, batches,
-                rows=list(range(int(mix["batch"]) // 2)))
+            half = follow(seed, batches,
+                          rows=list(range(int(mix["batch"]) // 2)))
             row["fault_half_batch"], _ = correct.training_numbers(half, want)
-            froz = reference.train_reference(config, hp, seed, batches,
-                                             frozen=True)
+            froz = follow(seed, batches, frozen=True)
             froz["delta"] = {k: 0.0 for k in want["delta"]}
             row["fault_frozen"], _ = correct.training_numbers(froz, want)
             # per-leaf detail of the first seed, to see what swings
@@ -93,25 +94,27 @@ def serve_readings(cell, config, mix, seeds, control, seconds):
 
     bench = common.load_benchmark()
     device, peaks = common.device_info(cell["chips"])
+    family = common.load_family(config)
     keep = {}
     inner = serve.check
 
-    def spy(config_, mix_, seed_, served, gens, top, limits, **kw):
+    def spy(config_, family_, mix_, seed_, served, gens, top, limits, **kw):
         keep.update(served=served, gens=gens, top=top)
-        return inner(config_, mix_, seed_, served, gens, top, limits, **kw)
+        return inner(config_, family_, mix_, seed_, served, gens, top, limits,
+                     **kw)
 
     serve.check = spy
     for seed in seeds:
         t0 = time.perf_counter()
-        out = serve.run(bench, cell, config, mix, seed, seconds, False,
-                        time.perf_counter(), device, peaks)
+        out = serve.run(bench, cell, config, family, mix, seed, seconds,
+                        False, time.perf_counter(), device, peaks)
         row = {"seed": seed, "e2e": out["e2e"],
                "program": {k: v["value"] for k, v in out["compared"].items()},
                "steps": out["counters"]["steps"],
                "t_run_s": time.perf_counter() - t0, "peak": out["peak"]}
         if seed in control:
             t0 = time.perf_counter()
-            cmp_, info = inner(config, mix, seed, keep["served"],
+            cmp_, info = inner(config, family, mix, seed, keep["served"],
                                keep["gens"], {}, {"served_gap": 1e30},
                                control=True)
             row["control_int8"] = {k: v["value"] for k, v in cmp_.items()}

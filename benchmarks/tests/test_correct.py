@@ -10,6 +10,7 @@
 
 The tests skip the harness's look for a chip (run.py's device_info) and
 drive the rest of a run through the same drivers."""
+import functools
 import os
 import sys
 
@@ -47,7 +48,9 @@ def train_ref():
     batches = [traffic.train_batch(mix, SEED, k, cfg["vocab_size"])
                for k in range(mix["check_steps"])]
     hp = cfg["program"]["optimizer"]
-    return cfg, hp, batches, reference.train_reference(cfg, hp, SEED, batches)
+    follow = functools.partial(reference.train_reference,
+                               common.load_family(cfg), cfg, hp, SEED, batches)
+    return follow, follow()
 
 
 def test_train_timed_path_is_correct():
@@ -58,15 +61,15 @@ def test_train_timed_path_is_correct():
 
 
 def test_train_control_int8_is_not_correct(train_ref):
-    cfg, hp, batches, want = train_ref
-    got = reference.train_reference(cfg, hp, SEED, batches, mode="int8")
+    follow, want = train_ref
+    got = follow(mode="int8")
     cmp_ = correct.compare_training(got, want, limits(TRAIN))
     assert not all(v["ok"] for v in cmp_.values()), cmp_
 
 
 def test_train_fault_half_batch_reference(train_ref):
-    cfg, hp, batches, want = train_ref
-    got = reference.train_reference(cfg, hp, SEED, batches, rows=[0])
+    follow, want = train_ref
+    got = follow(rows=[0])
     cmp_ = correct.compare_training(got, want, limits(TRAIN))
     assert not all(v["ok"] for v in cmp_.values()), cmp_
 
@@ -107,8 +110,8 @@ def test_serve_timed_path_is_correct_and_control_is_not():
     served = {q["id"]: (q, 24) for q in reqs}
     gens = {q["id"]: rng.integers(1, cfg["vocab_size"], 24).tolist()
             for q in reqs}
-    cmp_, info = serve.check(cfg, mix, SEED, served, gens, {}, lim,
-                             control=True)
+    cmp_, info = serve.check(cfg, common.load_family(cfg), mix, SEED, served,
+                             gens, {}, lim, control=True)
     assert not all(v["ok"] for v in cmp_.values()), (cmp_, info)
 
 

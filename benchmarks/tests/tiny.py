@@ -61,24 +61,23 @@ def tiny_train_mix():
 
 
 def run_tiny(kind, seed, seconds, limits, break_with=None, trace=False,
-             loop="closed", trace_dir=None):
-    """Drive one run of a tiny cell on the CPU; returns the driver's dict."""
+             loop="closed", trace_dir=None, family=None):
+    """Drive one run of a tiny cell on the CPU; returns the driver's dict.
+    ``family`` (a loaded module) stands in for the configuration's own."""
     from benchmarks.lib import common, serve, train
 
     bench = common.load_benchmark()
     name = {"serve": "mistral-7b-serve.decode-closed32",
             "train": "mistral-7b-train.seq4096"}[kind]
     cell = {w["name"]: w for w in bench["workloads"]}[name]
-    if kind == "serve":
-        return serve.run(bench, cell, tiny_config("serve", 2),
-                         tiny_serve_mix(loop), seed, seconds, trace,
-                         time.perf_counter(), CPU_DEVICE, CPU_PEAKS,
-                         break_with=break_with, trace_dir=trace_dir,
-                         limits=limits)
-    return train.run(bench, cell, tiny_config("train", 1), tiny_train_mix(),
-                     seed, seconds, trace, time.perf_counter(), CPU_DEVICE,
-                     CPU_PEAKS, break_with=break_with, trace_dir=trace_dir,
-                     limits=limits)
+    config = tiny_config(kind, 2 if kind == "serve" else 1)
+    fam = family or common.load_family(config)
+    mix = tiny_serve_mix(loop) if kind == "serve" else tiny_train_mix()
+    driver = serve if kind == "serve" else train
+    return driver.run(bench, cell, config, fam, mix, seed, seconds, trace,
+                      time.perf_counter(), CPU_DEVICE, CPU_PEAKS,
+                      break_with=break_with, trace_dir=trace_dir,
+                      limits=limits)
 
 
 if __name__ == "__main__":
