@@ -135,7 +135,7 @@ def _lazy_imports():
     global nn, optimizer, io, jit, static, vision, hapi, metric
     global distributed, incubate, amp, profiler, vision, callbacks, Model
     global DataParallel, utils, inference, sparse, flops, summary
-    global hub, ParamAttr
+    global hub, ParamAttr, LazyGuard
     from . import utils  # noqa
     from . import fft  # noqa
     from . import signal  # noqa
@@ -159,6 +159,7 @@ def _lazy_imports():
     from . import hapi  # noqa
     from . import hub  # noqa
     from .nn.param_attr import ParamAttr  # noqa (top-level like upstream)
+    from .nn.layer.layers import LazyGuard  # noqa (paddle.LazyGuard)
     from .hapi import Model, callbacks, flops, summary  # noqa
     from . import distributed  # noqa
     from . import incubate  # noqa

@@ -1561,6 +1561,19 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("pool.page_allocs", "counter", "pages drawn from the free list"),
     ("pool.page_frees", "counter",
      "pages returned to the free list (last reference dropped)"),
+    # routed experts of the latent-page adapter (inference/paged_xing4.py):
+    # per-expert token counts accumulated on the device a layer call,
+    # crossing to the host with the step's logits pull
+    ("moe.calls", "counter", "expert-layer calls (layers x steps)"),
+    ("moe.assignments", "counter",
+     "(token, expert) assignments the router made, padding left out"),
+    ("moe.experts_touched", "counter",
+     "experts with at least one token, summed over expert-layer calls"),
+    ("moe.expert_tokens_max", "counter",
+     "the fullest expert's tokens, summed over expert-layer calls"),
+    ("moe.expert_tokens_mean", "counter",
+     "assignments / experts, summed over a step's expert-layer calls "
+     "and rounded down a step (exact: assignments / (calls x E))"),
     ("pool.total_pages", "gauge", "pool capacity (all layer caches)"),
     ("pool.free_pages", "gauge", "free pages right now"),
     ("pool.utilization", "gauge", "1 - free/total"),
@@ -1816,6 +1829,26 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("span:model.norm", "span", "an eager rms_norm call of a layer"),
     ("span:model.mlp", "span", "a layer's MLP and its residual add"),
     ("span:model.head", "span", "final norm, row gather, lm head"),
+    ("span:model.hc", "span",
+     "an mHC site's read (coefficients, the one stream F sees) or "
+     "write (X <- Hres X + Hpost^T y) as one program (li/site attrs)"),
+    ("span:model.mla", "span",
+     "latent attention of a layer: the projections to the absorbed "
+     "query and the cached row, the pool's latent step, the output "
+     "projections (li attr)"),
+    ("span:model.moe", "span",
+     "routed experts of a layer: router and sort, kernel.moe_gmm, "
+     "combine and shared expert (li/tokens attrs)"),
+    ("span:kernel.latent_ragged", "span",
+     "the jitted latent ragged call: LRU lookup and dispatch "
+     "(rows/t/max_pages attrs; fused=1 with the page write)"),
+    ("span:kernel.moe_gmm", "span",
+     "the three grouped matmuls over the sorted assignments "
+     "(assignments attr)"),
+    ("span:moe.counts", "span",
+     "a step's expert counts as they reach the host with the logits "
+     "pull (calls/assignments/experts_touched/expert_tokens_max/"
+     "expert_tokens_mean attrs)"),
     ("span:pool.fused_step", "span",
      "fused_ragged_step / append_ragged / attend_ragged, whole "
      "(op attr)"),

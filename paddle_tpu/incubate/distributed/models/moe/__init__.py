@@ -3,6 +3,7 @@ python/paddle/incubate/distributed/models/moe/)."""
 from .gate import BaseGate, GShardGate, MixtralGate, \
     NaiveGate, SwitchGate
 from .grad_clip import ClipGradForMOEByGlobalNorm, ClipGradForMoEByGlobalNorm
+from .dropless import DroplessMoE
 from .moe_layer import ExpertLayer, MoELayer
 from .utils import (
     _limit_by_capacity,
@@ -12,7 +13,7 @@ from .utils import (
 )
 
 __all__ = [
-    "MoELayer", "ExpertLayer",
+    "MoELayer", "ExpertLayer", "DroplessMoE",
     "BaseGate", "NaiveGate", "GShardGate", "SwitchGate",
     "MixtralGate",
     "ClipGradForMOEByGlobalNorm", "ClipGradForMoEByGlobalNorm",

@@ -24,6 +24,18 @@ enabler for cross-request prefix caching (inference/prefix_cache.py):
 * ``incref``/``decref`` let a non-sequence owner (the radix prefix
   tree) hold pages alive after the sequence that wrote them retires.
 
+Page formats (``page_format=``): ``"kv"`` (the default) keeps per-head
+K and V arrays ``(num_pages, page_size, kv_heads, head_dim)``;
+``"latent"`` keeps ONE array ``(num_pages, page_size, head_dim)`` and no
+V array: a latent-attention (MLA) layer caches one row a token, the
+normed latent beside the rotated shared rope key, and the absorbed kernel
+reads that row as the key and its leading ``value_dim`` numbers as the
+value (:meth:`PagedKVCacheManager.latent_ragged_step`). Booking, page
+tables, reference counts, copy-on-write, prefix attach, the sanitizer and
+the ``pool.*`` spans are the same code for both; what moves K/V-shaped
+records (the append/attend family, host swap, the page-chain wire, int8
+calibration) refuses a latent pool by name.
+
 Quantized pages (``kv_dtype="int8"``): pages store int8 with a
 per-page, PER-HEAD float32 scale sidecar ``k_scales``/``v_scales``
 (num_pages, kv_heads) — half the HBM bytes per token, so the same HBM
@@ -96,6 +108,9 @@ from ...ops.kernels.paged_attention import (
 )
 from ...ops.kernels.paged_attention import (
     paged_ragged_fused_step as _fused_step_fn,
+)
+from ...ops.kernels.paged_attention import (
+    latent_ragged_step as _latent_step_fn,
 )
 from ...ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
 from ...ops.kernels.quant import kv_head_scale, quantize_kv
@@ -260,6 +275,8 @@ class HostKVSwapSpace:
         mp_shards = int(mp_shards)
         if mp_shards < 1:
             raise ValueError("export_seq: mp_shards must be >= 1")
+        for pool in pools:
+            pool._kv_only("export_seq")
         if not pools:
             raise ValueError("export_seq: no pools given")
         recs = []
@@ -373,6 +390,8 @@ class HostKVSwapSpace:
         set. Atomic: shard-set completeness, geometry, duplicate keys
         and the byte budget are all validated before any record is
         stored. Returns the host bytes stored."""
+        for pool in pools:
+            pool._kv_only("import_seq")
         parsed = sorted((self._parse_wire(p) for p in payloads),
                         key=lambda hp: hp[0]["shard"]["rank"])
         if not parsed:
@@ -565,15 +584,28 @@ class PagedKVCacheManager:
         "float16": jnp.float16,
     }
 
+    PAGE_FORMATS = ("kv", "latent")
+
     def __init__(self, num_pages, page_size, kv_heads, head_dim,
                  dtype=jnp.bfloat16, kv_dtype=None, sanitizer=None,
-                 mp_size=1, mp_rank=0):
+                 mp_size=1, mp_rank=0, page_format="kv"):
         # the serving path's jax.jit programs persist like to_static's
         from ...jit.api import ensure_compilation_cache
 
         ensure_compilation_cache()
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
+        if page_format not in self.PAGE_FORMATS:
+            raise ValueError(
+                f"page_format must be one of {self.PAGE_FORMATS}, got "
+                f"{page_format!r}")
+        self.page_format = page_format
+        self.latent = page_format == "latent"
+        if self.latent and (int(kv_heads) != 1 or int(mp_size) != 1):
+            raise ValueError(
+                "page_format='latent': one latent row a token, shared "
+                f"by every query head (kv_heads=1, mp_size=1), got "
+                f"kv_heads={kv_heads} mp_size={mp_size}")
         # mp-mesh KV-head sharding (disaggregated serving / tensor
         # parallel): ``kv_heads`` is the GLOBAL head count; a sharded
         # pool stores only the contiguous slice its mp rank owns —
@@ -601,10 +633,20 @@ class PagedKVCacheManager:
             dtype = self._KV_DTYPES[kv_dtype]
         self.kv_dtype = jnp.dtype(dtype).name
         self.quantized = self.kv_dtype == "int8"
-        self.k_pages = jnp.zeros(
-            (num_pages, page_size, kv_heads, head_dim), dtype
-        )
-        self.v_pages = jnp.zeros_like(self.k_pages)
+        if self.latent:
+            if self.quantized:
+                raise ValueError(
+                    "page_format='latent' has no int8 pages: the scale "
+                    "sidecars are per K/V head")
+            # ONE array: the row is key and (its head) value at once
+            self.k_pages = jnp.zeros(
+                (num_pages, page_size, head_dim), dtype)
+            self.v_pages = None
+        else:
+            self.k_pages = jnp.zeros(
+                (num_pages, page_size, kv_heads, head_dim), dtype
+            )
+            self.v_pages = jnp.zeros_like(self.k_pages)
         if self.quantized:
             # per-page, per-head scale sidecars (pool-private: mutate
             # ONLY through the append/COW paths below)
@@ -651,6 +693,16 @@ class PagedKVCacheManager:
         # preemption round trips and the future prefill/decode
         # worker split. Plain strings only; never device state
         self._trace_ctxs = {}
+
+    def _kv_only(self, op):
+        """Refuse, by name, an operation that moves K/V-shaped records
+        on a pool that holds latent pages."""
+        if self.latent:
+            raise ValueError(
+                f"{op}: not available for page_format='latent' (one "
+                f"[num_pages, page_size, {self.k_pages.shape[-1]}] array, "
+                "no V array); latent pools are written and read by "
+                "latent_ragged_step")
 
     # -- bookkeeping -------------------------------------------------------
     def alloc(self, seq_id):
@@ -826,7 +878,8 @@ class PagedKVCacheManager:
 
     def _copy_page(self, dst, src):
         self.k_pages = self.k_pages.at[dst].set(self.k_pages[src])
-        self.v_pages = self.v_pages.at[dst].set(self.v_pages[src])
+        if self.v_pages is not None:
+            self.v_pages = self.v_pages.at[dst].set(self.v_pages[src])
         if self.quantized:
             # the fork COPIES the scale row (the source chain keeps
             # its own); from here the two pages recalibrate
@@ -909,6 +962,7 @@ class PagedKVCacheManager:
         any bookkeeping mutation, so a full space
         (:class:`SwapSpaceFull`) aborts with the pool untouched.
         Returns ``(pages_freed, nbytes_swapped)``."""
+        self._kv_only("swap_out")
         tbl = self._tables.get(seq_id)
         if tbl is None:
             raise KeyError(f"swap_out({seq_id!r}): unknown sequence")
@@ -983,6 +1037,7 @@ class PagedKVCacheManager:
         private positions change; contents and order do not).
         Atomic: capacity is validated before any mutation. Returns
         the number of pages restored from host."""
+        self._kv_only("swap_in")
         if seq_id in self._tables:
             raise ValueError(
                 f"swap_in({seq_id!r}): sequence already allocated")
@@ -1053,7 +1108,7 @@ class PagedKVCacheManager:
     @property
     def kv_heads_local(self) -> int:
         """KV heads THIS shard stores (== global / mp_size)."""
-        return self.k_pages.shape[2]
+        return 1 if self.latent else self.k_pages.shape[2]
 
     @property
     def num_free_pages(self) -> int:
@@ -1207,6 +1262,7 @@ class PagedKVCacheManager:
     def append(self, seq_id, k_tok, v_tok):
         """Write one token's K/V ((KVH, D) arrays or Tensors) into the
         sequence's next slot."""
+        self._kv_only("append")
         page, off = self._next_slot(seq_id)
         k_tok = k_tok._data if isinstance(k_tok, Tensor) else k_tok
         v_tok = v_tok._data if isinstance(v_tok, Tensor) else v_tok
@@ -1235,6 +1291,7 @@ class PagedKVCacheManager:
         scatter per pages array (the hot serving path: B sequences x
         L layers must not issue B*L separate updates). k_toks/v_toks:
         (B, KVH, D) arrays or Tensors."""
+        self._kv_only("append_batch")
         k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
         v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
         # atomicity: validate capacity BEFORE any bookkeeping mutation,
@@ -1329,6 +1386,7 @@ class PagedKVCacheManager:
         decode rows must not issue one update per token per layer).
         k_toks/v_toks: (sum(counts), KVH, D) arrays or Tensors, rows
         ordered sequence-major (seq_ids[0]'s tokens first)."""
+        self._kv_only("append_ragged")
         with telemetry.span("pool.fused_step", op="append_ragged"):
             k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
             v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
@@ -1418,6 +1476,7 @@ class PagedKVCacheManager:
            ``FLAGS_ragged_attention=auto|on`` the kernel beneath is
            the unified ragged program at T=1; mixed packed batches
            should call :meth:`attend_ragged` directly."""
+        self._kv_only("attend_padded")
         q = _as_tensor(q)
         tbl, lens = self._padded_kernel_inputs(
             seq_ids, rows_pad, max_pages)
@@ -1444,6 +1503,7 @@ class PagedKVCacheManager:
         .. deprecated:: alias shape of :meth:`attend_ragged` (the
            q_lens-masked prefill kernel WAS the unified ragged kernel
            all along) — new packed-step callers use attend_ragged."""
+        self._kv_only("attend_prefill")
         q = _as_tensor(q)
         tbl, lens = self._padded_kernel_inputs(
             seq_ids, rows_pad, max_pages)
@@ -1475,6 +1535,7 @@ class PagedKVCacheManager:
         attend program per packed config that replaces the
         attend_padded/attend_prefill pair (which remain as thin
         shape wrappers for single-kind callers)."""
+        self._kv_only("attend_ragged")
         with telemetry.span("pool.fused_step", op="attend_ragged"):
             q = _as_tensor(q)
             with telemetry.span("pool.table") as sp:
@@ -1536,6 +1597,7 @@ class PagedKVCacheManager:
         first call, never mid-serving) or a strict-sanitizer
         violation (the pool was already corrupt), the same window
         the unfused path's device scatter has."""
+        self._kv_only("fused_ragged_step")
         with telemetry.span("pool.fused_step", op="fused_ragged_step"):
             if self.quantized:
                 raise ValueError(
@@ -1597,12 +1659,67 @@ class PagedKVCacheManager:
             self.v_pages = vp
             return Tensor(y)
 
+    def latent_ragged_step(self, q, toks, seq_ids, counts, gather_map,
+                           value_dim, rows_pad=None, max_pages=None,
+                           sm_scale=None):
+        """The packed attention step of a LATENT pool, one compiled
+        program per packed config: this chunk's rows land in the pages
+        (the program's prologue), then the absorbed ragged kernel
+        (ops/kernels/paged_attention.latent_ragged_attention) reads each
+        row's pages once, as key and as value.
+
+        ``q`` (n_pad, H, D): the packed absorbed queries; ``toks``
+        (n_pad, D): what the cache holds of each packed token (rows past
+        ``sum(counts)`` are padding and land nowhere); ``gather_map``
+        (rows_pad, T) right-aligns each row's tokens; ``value_dim``: the
+        leading numbers of a cached row that are its value. Returns the
+        kernel's output (rows_pad, T, H, value_dim) as a Tensor (padded
+        leading rows exact zeros). The pool owns the page mutation as in
+        :meth:`fused_ragged_step`: slots are booked first (capacity
+        precheck, COW forks, sanitizer events), the program's pages are
+        committed before the output is handed back."""
+        with telemetry.span("pool.fused_step", op="latent_ragged_step"):
+            if not self.latent:
+                raise ValueError(
+                    "latent_ragged_step: needs page_format='latent', "
+                    f"this pool holds {self.page_format!r} pages")
+            q = q._data if isinstance(q, Tensor) else q
+            toks = toks._data if isinstance(toks, Tensor) else toks
+            counts = [int(c) for c in counts]
+            n_pad, n_real = toks.shape[0], sum(counts)
+            if n_real > n_pad or q.shape[0] != n_pad:
+                raise ValueError(
+                    f"latent_ragged_step: counts sum to {n_real}, the "
+                    f"packed operands carry {q.shape[0]} queries and "
+                    f"{n_pad} rows")
+            pages, offs = self._ragged_slots(seq_ids, counts)
+            with telemetry.span("pool.table") as sp:
+                tbl, lens = self._padded_kernel_inputs(
+                    seq_ids, rows_pad, max_pages)
+                if self._san is not None:
+                    self._san_check_table(seq_ids, tbl, lens)
+                ql = np.zeros((tbl.shape[0],), np.int32)
+                ql[:len(counts)] = counts
+                ql = jnp.asarray(ql)
+                pg = _pad_plan(np.asarray(pages, np.int32), n_pad,
+                               self.num_pages)
+                of = _pad_plan(np.asarray(offs, np.int32), n_pad, 0)
+                if sp is not None:
+                    sp.attrs.update(rows=len(seq_ids), bytes=int(
+                        tbl.nbytes + lens.nbytes + ql.nbytes
+                        + pg.nbytes + of.nbytes))
+            out, self.k_pages = _latent_step_fn(
+                q, toks, pg, of, gather_map, self.k_pages, tbl, lens, ql,
+                value_dim=value_dim, sm_scale=sm_scale)
+            return Tensor(out)
+
     def dense_kv(self, seq_ids):
         """Dense (dequantized) gather of the listed sequences' pages:
         returns (page_table (B, MP), k (B, MP, P, KVH, D),
         v (...)) with k/v in compute dtype — the supported way for
         serving layers to read quantized pages without touching the
         scale sidecars (multi-token verify windows use this)."""
+        self._kv_only("dense_kv")
         tbl = self.page_table(seq_ids)
         kd = self.k_pages[tbl]
         vd = self.v_pages[tbl]
@@ -1615,13 +1732,17 @@ class PagedKVCacheManager:
 
     @staticmethod
     def page_bytes(page_size, kv_heads, head_dim,
-                   dtype=jnp.bfloat16, kv_dtype=None) -> int:
+                   dtype=jnp.bfloat16, kv_dtype=None,
+                   page_format="kv") -> int:
         """HBM bytes one page costs (K + V payload plus, when
-        quantized, the scale sidecar rows) — pure arithmetic, usable
+        quantized, the scale sidecar rows; a latent page is ONE array
+        of ``head_dim`` numbers a token) — pure arithmetic, usable
         for pool sizing BEFORE allocating anything."""
         if kv_dtype is not None:
             dtype = PagedKVCacheManager._KV_DTYPES[kv_dtype]
         dtype = jnp.dtype(dtype)
+        if page_format == "latent":
+            return page_size * head_dim * dtype.itemsize
         per = page_size * kv_heads * head_dim * dtype.itemsize * 2
         if dtype.name == "int8":
             per += kv_heads * 4 * 2
@@ -1630,8 +1751,9 @@ class PagedKVCacheManager:
     @property
     def page_nbytes(self) -> int:
         return self.page_bytes(
-            self.page_size, self.k_pages.shape[2],
-            self.k_pages.shape[3], dtype=self.k_pages.dtype)
+            self.page_size, 1 if self.latent else self.k_pages.shape[2],
+            self.k_pages.shape[-1], dtype=self.k_pages.dtype,
+            page_format=self.page_format)
 
     @property
     def pool_nbytes(self) -> int:

@@ -23,6 +23,7 @@ __all__ = [
     "RequestState",
     "QueueFullError",
     "PagedLlamaAdapter",
+    "PagedXing4Adapter",
     "RadixPrefixCache",
     "PrefixMatch",
     "bucket_packed_tokens",
@@ -62,6 +63,7 @@ from .disagg import (  # noqa: E402
     role_scheduler_kwargs,
 )
 from .paged_llama import PagedLlamaAdapter  # noqa: E402
+from .paged_xing4 import PagedXing4Adapter  # noqa: E402
 from .prefix_cache import RadixPrefixCache, PrefixMatch  # noqa: E402
 
 

@@ -542,8 +542,11 @@ class ServingEngine:
         """First pump act: publish the initial gauges and register
         /enginez. Runs here, not in start(), because both take
         blocking guarded locks that must never be acquired on the
-        event loop."""
+        event loop. Then the scheduler's set-up compiles
+        (``BatchScheduler.warm``): requests posted meanwhile wait in
+        the inbox, none meets a build in its first steps."""
         self._note_write()
+        self.scheduler.warm()
         if self._metrics is None:
             return
         self._metrics.gauge("engine.backpressure_state", BP_OPEN)

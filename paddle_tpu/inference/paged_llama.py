@@ -25,17 +25,18 @@ from ..framework import telemetry
 from ..framework.core import Tensor, no_grad
 from ..framework.flags import flag
 from ..incubate.nn import PagedKVCacheManager
-from ..ops.kernels.paged_attention import (
-    pad_plan_i32 as _pad_plan,
-    packed_position_index as _packed_position_index,
-)
+from ..ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
 from ..ops.kernels.rope import apply_rotary_emb, build_rope_cache
 from ..tensor.manipulation import reshape
+from .paged_common import (
+    PagedAdapterBase, logits_epilogue, plan_packed_rows,
+    pow2 as _pow2, right_align_plan as _right_align_plan,
+)
 
 __all__ = ["PagedLlamaAdapter"]
 
 
-class PagedLlamaAdapter:
+class PagedLlamaAdapter(PagedAdapterBase):
     """Serve a LlamaForCausalLM from a paged KV pool.
 
     ``num_pages`` x ``page_size`` tokens per layer; ``max_length``
@@ -112,48 +113,8 @@ class PagedLlamaAdapter:
             self.max_length, cfg.head_dim, base=cfg.rope_theta,
             dtype=jnp.float32,
         )
-        # chunked-prefill dispatch accounting (docs/SERVING.md):
-        # _dispatch_shapes holds the distinct BUCKETED packed token
-        # counts prefill_chunk has been fed — each is one compiled
-        # ragged program, so len() is the steady-state compile count
-        # the scheduler and bench report; _kernel_shapes tracks the
-        # (kind, rows, T, max_pages) signatures of the pow2-padded
-        # attention sub-calls underneath.
-        self._dispatch_shapes = set()
-        self._kernel_shapes = set()
-        self._bucket_programs = {}   # pad_to -> set of kernel shapes
+        self._init_dispatch_accounting()
         self._fused_ok = None
-        self.chunk_stats = {"calls": 0, "packed_tokens": 0,
-                            "padded_tokens": 0, "attend_calls": 0}
-
-    @property
-    def compile_count(self) -> int:
-        """Distinct bucketed packed shapes the ragged chunked-prefill
-        dispatch has compiled (<= number of configured buckets in
-        steady state)."""
-        return len(self._dispatch_shapes)
-
-    @property
-    def attend_program_count(self) -> int:
-        """Distinct paged-attention kernel programs the packed step
-        dispatch has compiled. Unified mode
-        (``FLAGS_ragged_attention=auto|on``) launches ONE ragged
-        program per packed config; the legacy two-kernel routing
-        (``off``) compiles a decode AND a prefill program for every
-        mixed config — the per-bucket doubling ROADMAP item 2
-        removes (bench.py --serving gates on the halving)."""
-        return len(self._kernel_shapes)
-
-    @property
-    def attend_kinds_by_bucket(self) -> dict:
-        """Per dispatch bucket (pad_to): the distinct attend KERNEL
-        KINDS its steps launched — the direct measurement of the
-        ISSUE-13 acceptance 'one attend program per bucket, not two':
-        unified mode records exactly {'ragged'} or {'ragged_fused'}
-        per bucket; the legacy routing records {'decode', 'prefill'}
-        on every mixed bucket."""
-        return {b: sorted({k for k, *_ in shapes})
-                for b, shapes in self._bucket_programs.items()}
 
     def _fusion_eligible(self) -> bool:
         """auto-mode fusion gate, computed once per adapter: the
@@ -189,49 +150,6 @@ class PagedLlamaAdapter:
                         break
             self._fused_ok = ok
         return self._fused_ok
-
-    # -- scheduler protocol ------------------------------------------------
-    def alloc(self, seq_id):
-        for c in self.caches:
-            c.alloc(seq_id)
-
-    def free(self, seq_id):
-        for c in self.caches:
-            c.free(seq_id)
-
-    # -- prefix-cache hooks (inference/prefix_cache.py) --------------------
-    def attach_prefix(self, seq_id, chains, length):
-        """Cached prefill: register ``seq_id`` on shared page chains
-        (one per layer) covering its first ``length`` tokens. The
-        pages stay shared until the sequence's first write into the
-        partial tail page, which the pool forks copy-on-write."""
-        if len(chains) != len(self.caches):
-            raise ValueError(
-                f"{len(chains)} chains for {len(self.caches)} layers")
-        for c, chain in zip(self.caches, chains):
-            c.attach(seq_id, chain, length)
-
-    def seq_page_chains(self, seq_id):
-        """The sequence's physical page chain per layer — what the
-        scheduler hands the radix tree at retire."""
-        return [c.seq_pages(seq_id) for c in self.caches]
-
-    # -- preemption hooks (tiered KV swap; docs/SERVING.md) ----------------
-    def swap_out(self, seq_id, space):
-        """Page the sequence out of EVERY layer pool into the shared
-        host swap space (scheduler preemption). Returns
-        (pages_freed, nbytes_swapped) summed across layers."""
-        freed = nbytes = 0
-        for c in self.caches:
-            fp, nb = c.swap_out(seq_id, space)
-            freed += fp
-            nbytes += nb
-        return freed, nbytes
-
-    def swap_in(self, seq_id, space):
-        """Restore a swapped-out sequence into every layer pool
-        (bitwise). Returns pages restored from host."""
-        return sum(c.swap_in(seq_id, space) for c in self.caches)
 
     def decode_token(self, token_ids, seq_ids):
         """One token per listed sequence; returns logits (B, vocab)."""
@@ -357,33 +275,6 @@ def _window_logits(self, token_windows, seq_ids):
         return self.model._head(h)  # (B, w, V)
 
 
-def _pow2(n: int) -> int:
-    return 1 << (max(int(n), 1) - 1).bit_length()
-
-
-def _right_align_plan(row_indices, starts, counts, t_pad, rows_pad):
-    """Host-built gather/scatter plan right-aligning each listed
-    packed row into a (rows_pad, t_pad) block: returns (gm, mr, mc,
-    mflat) — ``gm`` gathers flat packed token indices into the block
-    (row r's last counts[i] columns), and ``mr``/``mc``/``mflat``
-    map the kernel output back to flat packed slots. Shared by the
-    unified dispatch (every row) and the off-mode legacy prefill
-    routing (multi-token rows only), so the two A/B paths can never
-    drift apart on alignment."""
-    gm = np.zeros((rows_pad, t_pad), np.int64)
-    rr, cc, ff = [], [], []
-    for r, i in enumerate(row_indices):
-        c = counts[i]
-        st = starts[i]
-        gm[r, t_pad - c:] = np.arange(st, st + c)
-        for j in range(c):
-            rr.append(r)
-            cc.append(t_pad - c + j)
-            ff.append(st + j)
-    return (jnp.asarray(gm, jnp.int32), jnp.asarray(rr, jnp.int32),
-            jnp.asarray(cc, jnp.int32), jnp.asarray(ff, jnp.int32))
-
-
 def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                    pad_to=None, logits_rows=None):
     """One ragged mixed prefill/decode step (the Ragged Paged
@@ -427,66 +318,19 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
     prefill kernel)."""
     cfg = self.cfg
     with telemetry.span("model.plan") as plan_span:
-        b = len(seq_ids)
-        counts = [len(t) for t in token_ids]
-        if b != len(counts) or b == 0:
-            raise ValueError(
-                f"prefill_chunk: {len(counts)} token rows for {b} "
-                "sequences")
-        if min(counts) < 1:
-            raise ValueError(
-                "prefill_chunk: every row must carry at least one token "
-                f"(counts={counts})")
+        rows = plan_packed_rows(self.caches[0], token_ids, seq_ids,
+                                start_positions, pad_to, self.max_length)
+        b, counts, lens0 = rows.b, rows.counts, rows.lens0
+        flat, pos_np, starts = rows.flat, rows.pos_np, rows.starts
+        last_idx, n_real, pad_to = rows.last_idx, rows.n_real, rows.pad_to
         nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                        cfg.head_dim)
-        lens0 = [self.caches[0].seq_len(s) for s in seq_ids]
-        if start_positions is not None:
-            sp = [int(p) for p in start_positions]
-            if sp != lens0:
-                raise ValueError(
-                    f"prefill_chunk: start_positions {sp} disagree with "
-                    f"the cached lengths {lens0} — a chunk must resume "
-                    "exactly where the cache left off")
-        over = [s for s, n, c in zip(seq_ids, lens0, counts)
-                if n + c > self.max_length]
-        if over:
-            raise ValueError(
-                f"sequences {over} would exceed max_length="
-                f"{self.max_length}; positions beyond it cannot be "
-                "rotary-encoded")
-
-        flat = np.concatenate(
-            [np.asarray(t, "int64") for t in token_ids])
-        n_real = int(flat.shape[0])
-        pad_to = int(pad_to) if pad_to else n_real
-        if pad_to < n_real:
-            raise ValueError(
-                f"prefill_chunk: pad_to={pad_to} below the packed token "
-                f"count {n_real}")
-        flat = np.concatenate(
-            [flat, np.zeros(pad_to - n_real, "int64")])
-        pos_np = np.zeros(pad_to, np.int32)
-        starts = np.zeros(b, np.int64)
-        off = 0
-        for i, (n, c) in enumerate(zip(lens0, counts)):
-            starts[i] = off
-            pos_np[off:off + c] = np.arange(n, n + c)
-            off += c
-        last_idx = starts + np.asarray(counts) - 1
         pos = jnp.asarray(pos_np)[None, :]             # (1, N)
-
-        self._dispatch_shapes.add(pad_to)
-        self.chunk_stats["calls"] += 1
-        self.chunk_stats["packed_tokens"] += n_real
-        self.chunk_stats["padded_tokens"] += pad_to - n_real
+        self._count_packed_step(rows)
 
         mode = str(flag("ragged_attention"))
         unified = mode != "off"
-        # every layer's cache shares one page size (adapter construction),
-        # so the padded page-table width is loop-invariant
-        mp_pad = _pow2(max(
-            -(-(n + c) // self.caches[0].page_size)
-            for n, c in zip(lens0, counts)))
+        mp_pad = rows.mp_pad
 
         # gather/scatter plans (host-built once, shared by every layer)
         s_plan = m_plan = None
@@ -506,8 +350,7 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
             # — the pure attend program's does not
             shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
                 if fuse else ("ragged", b_pad, t_pad, mp_pad)
-            self._kernel_shapes.add(shape)
-            self._bucket_programs.setdefault(pad_to, set()).add(shape)
+            self._count_kernel_shape(pad_to, shape)
             pos_flat = jnp.asarray(pos_np)
             if fuse:
                 # loop-invariant across layers: pad the scatter plan to
@@ -528,8 +371,7 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                     jnp.int32)
                 s_seqs = [seq_ids[i] for i in singles]
                 shape = ("decode", bs_pad, 1, mp_pad)
-                self._kernel_shapes.add(shape)
-                self._bucket_programs.setdefault(pad_to, set()).add(shape)
+                self._count_kernel_shape(pad_to, shape)
                 s_plan = (s_idx, s_seqs, bs, bs_pad)
             if multis:
                 t_pad = _pow2(max(counts[i] for i in multis))
@@ -539,8 +381,7 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                 q_lens = [counts[i] for i in multis]
                 m_seqs = [seq_ids[i] for i in multis]
                 shape = ("prefill", bm_pad, t_pad, mp_pad)
-                self._kernel_shapes.add(shape)
-                self._bucket_programs.setdefault(pad_to, set()).add(shape)
+                self._count_kernel_shape(pad_to, shape)
                 m_plan = (gm, m_seqs, q_lens, bm_pad, mr, mc, m_flat)
         ids = Tensor(flat[:, None])
         if plan_span is not None:
@@ -616,17 +457,12 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                 with span("model.mlp"):
                     x = x + layer.mlp(h2)
         with span("model.head"):
-            x_last = Tensor(x._data[jnp.asarray(last_idx, jnp.int32)])
-            h = self.model.model.norm(x_last)
-            last = self.model._head(h)               # (B, vocab)
-            if logits_rows is None:
-                return last
             # multi-row sampling epilogue: per-position logits for
             # the listed (verify) rows, concatenated in list order
-            vidx = _packed_position_index(starts, counts, logits_rows)
-            x_full = Tensor(x._data[vidx])
-            full = self.model._head(self.model.model.norm(x_full))
-            return last, full
+            return logits_epilogue(
+                x._data, rows,
+                lambda xr: self.model._head(
+                    self.model.model.norm(Tensor(xr))), logits_rows)
 
 
 def _attend_rows_two_kernel(self, cache, qh, attn, s_plan, m_plan,

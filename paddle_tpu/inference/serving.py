@@ -604,6 +604,22 @@ class BatchScheduler:
                         "scheduler." + self._sched_uid,
                         self._statusz_info)
 
+    # -- set-up ------------------------------------------------------------
+    def warm(self):
+        """Build, before the first request, the programs of the steady
+        steps, where the adapter offers ``warm(rows, packed,
+        chunk_tokens)``: every slot decoding, alone or beside one step's
+        share of prompt tokens, at the packed widths this scheduler's
+        buckets give them. ``ServingEngine`` calls it when it starts; a
+        caller that steps the scheduler itself calls it once, or lets
+        the first requests meet the compiles."""
+        warm = getattr(self.model, "warm", None)
+        if warm is None:
+            return
+        rows, chunk = self.max_batch_size, self.prefill_chunk_tokens
+        warm(rows, {bucket_packed_tokens(n, self.serving_buckets)
+                    for n in (rows, rows + chunk)}, chunk)
+
     # -- pool accounting ---------------------------------------------------
     def _pool(self, model=None):
         caches = list((model or self.model).caches)

@@ -7,6 +7,8 @@ this framework ships the acceptance-config model families in-tree:
 
 * :mod:`.llama`  — Llama-2 (RMSNorm / RoPE / GQA / SwiGLU), TP/SP-aware
 * :mod:`.gpt`    — GPT-3 (pre-LN, learned positions, gelu), DP/sharding
+* :mod:`.xing4`  — Xing4.0 (MLA, drop-free sigmoid-routed experts with a
+  shared expert, mHC residual streams, MTP), served from latent pages
 * :mod:`.bert`   — BERT (bidirectional post-norm encoder, MLM +
   sequence-classification heads), non-causal flash path
 """
@@ -58,5 +60,12 @@ from .gpt import (
     gpt_moe_tiny,
     gpt_pipeline_model,
     gpt_tiny,
+)
+from .xing4 import (  # noqa: E402
+    Xing4Config,
+    Xing4ForCausalLM,
+    Xing4Model,
+    xing4_29b_a4b,
+    xing4_tiny,
 )
 from .generation import generate, speculative_generate  # noqa: E402

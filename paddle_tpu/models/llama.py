@@ -494,7 +494,12 @@ class LlamaSparseMoeBlock(Layer):
     dispatch (``config.moe_capacity_factor``) drops tokens past an
     expert's fixed capacity, where HF's dynamic gather processes all
     of them — see the :func:`mixtral_8x7b` docstring for the full
-    caveat and the capacity knob that recovers exact coverage."""
+    caveat and the capacity knob that recovers exact coverage. The
+    layer that drops nothing at any imbalance is
+    ``incubate.distributed.models.moe.DroplessMoE`` (tokens sorted by
+    expert, a grouped matmul with the group sizes as device data; the
+    feed-forward of ``models/xing4.py``): its router is the sigmoid
+    ``noaux_tc`` one, so this block does not use it yet."""
 
     def __init__(self, config: LlamaConfig):
         super().__init__()

@@ -12,6 +12,26 @@ from ...framework.core import EagerParamBase, Parameter, Tensor, no_grad
 from ...framework.dtype import convert_dtype, to_np_dtype
 
 
+class LazyGuard:
+    """Layers constructed under it create their parameters with shape
+    and dtype and no array (upstream: paddle.LazyGuard): the payload is
+    a ``jax.ShapeDtypeStruct`` until the caller hands each parameter its
+    array (``p._data = leaf``). Nothing is allocated, no initializer
+    runs, and ``p.shape`` / ``p.dtype`` work, so a model larger than
+    the float32-then-cast build could hold is filled leaf by leaf in
+    the type it is served in."""
+
+    _depth = 0
+
+    def __enter__(self):
+        LazyGuard._depth += 1
+        return self
+
+    def __exit__(self, *exc):
+        LazyGuard._depth -= 1
+        return False
+
+
 class HookRemoveHelper:
     def __init__(self, hooks, hook_id):
         self._hooks = hooks
@@ -38,7 +58,13 @@ def make_parameter(shape, dtype="float32", name=None, attr=None,
         init = default_initializer
     else:
         init = I.Constant(0.0) if is_bias else I.XavierUniform()
-    data = init(list(shape), to_np_dtype(dtype))
+    if LazyGuard._depth:
+        import jax
+
+        data = jax.ShapeDtypeStruct(tuple(int(d) for d in shape),
+                                    to_np_dtype(dtype))
+    else:
+        data = init(list(shape), to_np_dtype(dtype))
     p = Parameter(data, name=name or (attr.name if attr else None))
     if attr is not None:
         p.optimize_attr["learning_rate"] = attr.learning_rate

@@ -111,6 +111,9 @@ from .rms_norm import rms_norm, layer_norm_fused
 from .flash_attention import flash_attention, flash_attention_with_lse
 from .rope import apply_rotary_emb
 from .paged_attention import (  # noqa
+    latent_ragged_attention,
+    latent_ragged_attention_reference,
+    latent_ragged_step,
     packed_position_index,
     paged_attention,
     paged_attention_reference,

@@ -55,6 +55,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ...framework import telemetry
@@ -672,12 +673,282 @@ def _jitted_fused_call(cfg):
     return jax.jit(_build_fused_call(*cfg))
 
 
+# --------------------------------------------------------------------------
+# latent (MLA) pages: one [num_pages, page_size, D] array whose row is the
+# key of every query head and, in its leading ``value_dim`` numbers, the
+# value. The absorbed ragged kernel.
+# --------------------------------------------------------------------------
+LATENT_PAGES_PER_STEP = 16       # pages DMA'd and multiplied a grid step
+LATENT_ROW_TILE = 256            # (token, head) rows a matmul of the kernel
+
+
+def latent_ragged_attention_reference(q, pages, page_table, seq_lens,
+                                      q_lens, value_dim, sm_scale=None):
+    """``jax.numpy`` float32 reference of the latent ragged kernel: q
+    (B, T, H, D) right-aligned as in :func:`paged_ragged_attention`,
+    pages (NP, P, D). Row i's last q_lens[i] tokens attend causally over
+    its seq_lens[i] cached rows; every head scores against the whole
+    cached row and takes its leading ``value_dim`` numbers as the value.
+    Returns (B, T, H, value_dim) float32, padded leading rows zero."""
+    b, t, h, d = q.shape
+    _, page_size, _ = pages.shape
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    max_pages = page_table.shape[1]
+    kv = pages.astype(jnp.float32)[page_table].reshape(
+        b, max_pages * page_size, d)
+    s = jnp.einsum("bthd,bkd->bthk", q.astype(jnp.float32), kv,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    kpos = jnp.arange(max_pages * page_size)[None, None, :]
+    tok = jnp.arange(t)[None, :, None]
+    qpos = seq_lens[:, None, None] - t + tok
+    keep = (kpos <= qpos) & (kpos < seq_lens[:, None, None]) \
+        & (tok >= t - q_lens[:, None, None])
+    s = jnp.where(keep[:, :, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, -1)
+    out = jnp.einsum("bthk,bkv->bthv", p, kv[..., :value_dim],
+                     precision=jax.lax.Precision.HIGHEST)
+    real = jnp.any(keep, -1)[:, :, None, None]
+    return jnp.where(real, out, 0.0)
+
+
+def _latent_kernel(scale, page_size, ppb, n_steps, t, h, dv, tm,
+                   tbl_ref, lens_ref, qlens_ref, q_ref, *refs):
+    """Grid (row b, page block p). A step holds ``ppb`` pages of row b
+    in VMEM, each read once, and multiplies them with the row's real
+    (token, head) query rows in tiles of ``tm``: scores against the whole
+    cached row, the value its leading ``dv`` numbers. Online softmax
+    state (m, l, acc) stays in VMEM across the page axis, float32."""
+    page_refs = refs[:ppb]
+    o_ref, m_ref, l_ref, acc_ref = refs[ppb:]
+    del tbl_ref
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+    seq_len = lens_ref[b]
+    q_len = qlens_ref[b]
+    n_tiles = (t * h) // tm
+    # rows below (t - q_len) * h are the right-alignment's padding
+    first = ((t - q_len) * h) // tm
+    span = ppb * page_size
+    log_h = h.bit_length() - 1 if h & (h - 1) == 0 else None
+
+    def token_of(row):
+        return jax.lax.shift_right_logical(row, log_h) \
+            if log_h is not None else row // h
+
+    def rows(i):
+        return pl.ds(pl.multiple_of(i * tm, tm), tm)
+
+    @pl.when(p == 0)
+    def _():
+        def init(i, c):
+            m_ref[rows(i), :] = jnp.full((tm, m_ref.shape[1]), NEG_INF,
+                                         jnp.float32)
+            l_ref[rows(i), :] = jnp.zeros((tm, l_ref.shape[1]),
+                                          jnp.float32)
+            acc_ref[rows(i), :] = jnp.zeros((tm, dv), jnp.float32)
+            return c
+        jax.lax.fori_loop(first, n_tiles, init, 0)
+
+    @pl.when(p * span < seq_len)
+    def _():
+        k = jnp.concatenate([r[0] for r in page_refs], 0) \
+            if ppb > 1 else page_refs[0][0]            # (span, D)
+        v = k[:, :dv]
+
+        def tile(i, c):
+            q = q_ref[0, rows(i), :]                     # (tm, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            kpos = p * span + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            tok = token_of(i * tm + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0))
+            qpos = seq_len - t + tok
+            keep = (kpos <= qpos) & (kpos < seq_len) & (tok >= t - q_len)
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[rows(i), :][:, :1]
+            l_prev = l_ref[rows(i), :][:, :1]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            corr = jnp.exp(m_prev - m_cur)
+            pv = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
+            l_ref[rows(i), :] = jnp.broadcast_to(
+                corr * l_prev + jnp.sum(pv, -1, keepdims=True),
+                (tm, l_ref.shape[1]))
+            acc_ref[rows(i), :] = acc_ref[rows(i), :] * corr \
+                + jax.lax.dot_general(
+                    pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            m_ref[rows(i), :] = jnp.broadcast_to(
+                m_cur, (tm, m_ref.shape[1]))
+            return c
+        jax.lax.fori_loop(first, n_tiles, tile, 0)
+
+    @pl.when(p == n_steps - 1)
+    def _():
+        def zero(i, c):
+            o_ref[0, rows(i), :] = jnp.zeros((tm, dv), o_ref.dtype)
+            return c
+        jax.lax.fori_loop(0, first, zero, 0)
+
+        def done(i, c):
+            tok = token_of(i * tm + jax.lax.broadcasted_iota(
+                jnp.int32, (tm, dv), 0))
+            out = acc_ref[rows(i), :] / jnp.maximum(
+                l_ref[rows(i), :][:, :1], 1e-30)
+            o_ref[0, rows(i), :] = jnp.where(
+                tok >= t - q_len, out, 0.0).astype(o_ref.dtype)
+            return c
+        jax.lax.fori_loop(first, n_tiles, done, 0)
+
+
+def _build_latent_call(b, t, h, d, dv, npages, page_size, max_pages,
+                       scale, interpret):
+    """The latent ragged pallas dispatch as a pure function of its
+    static config: run(q (B, T, H, D), pages (NP, P, D), page_table,
+    seq_lens, q_lens) -> (B, T, H, dv)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    ppb = min(LATENT_PAGES_PER_STEP, max_pages)
+    if max_pages % ppb:
+        raise ValueError(
+            f"latent_ragged_attention: the page table's width "
+            f"{max_pages} is no multiple of {ppb} pages a step")
+    n_steps = max_pages // ppb
+    m = t * h
+    tm = min(LATENT_ROW_TILE, m)
+    if m % tm:
+        raise ValueError(
+            f"latent_ragged_attention: {t} tokens x {h} heads is no "
+            f"multiple of the row tile {tm}")
+
+    def q_map(b_, p_, *pref):
+        return (b_, 0, 0)
+
+    def page_map(j):
+        return lambda b_, p_, tbl, *pref: (tbl[b_, p_ * ppb + j], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, n_steps),
+        in_specs=[pl.BlockSpec((1, m, d), q_map)] + [
+            pl.BlockSpec((1, page_size, d), page_map(j))
+            for j in range(ppb)],
+        out_specs=pl.BlockSpec((1, m, dv), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((m, 128), jnp.float32),
+            pltpu.VMEM((m, 128), jnp.float32),
+            pltpu.VMEM((m, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_kernel, scale, page_size, ppb, n_steps, t, h, dv, tm)
+
+    def run(q, pages, tbl, lens, q_lens):
+        out = pl.pallas_call(
+            kernel,
+            name="latent_ragged_attention",
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, m, dv), q.dtype),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=96 * 1024 * 1024,
+            ) if not interpret else None,
+        )(tbl, lens, q_lens, q.reshape(b, m, d), *([pages] * ppb))
+        return out.reshape(b, t, h, dv)
+
+    return run
+
+
+@functools.lru_cache(maxsize=512)
+def _jitted_latent_call(cfg):
+    return jax.jit(_build_latent_call(*cfg))
+
+
+def latent_ragged_attention(q, pages, page_table, seq_lens, q_lens,
+                            value_dim, sm_scale=None, interpret=None):
+    """Absorbed latent attention over pages, decode rows and prompt
+    chunks in one call: q (B, T, H, D) right-aligned like
+    :func:`paged_ragged_attention` (the rows' latent rows already in the
+    pages; seq_lens counts them), pages (NP, P, D) with D the cached row
+    (latent | rope key). Each latent page of a row is read once and used
+    as the key (all D) and the value (the first ``value_dim``) of all H
+    query heads. Returns (B, T, H, value_dim); padded rows exact zeros."""
+    b, t, h, d = q.shape
+    npages, page_size, _ = pages.shape
+    max_pages = page_table.shape[1]
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    if interpret is None:
+        interpret = not on_tpu()
+    cfg = (b, t, h, d, int(value_dim), npages, page_size, max_pages,
+           float(scale), bool(interpret))
+    args = (q, pages, page_table.astype(jnp.int32),
+            seq_lens.astype(jnp.int32),
+            jnp.asarray(q_lens).astype(jnp.int32))
+    if any(isinstance(x, jax.core.Tracer) for x in args):
+        return _build_latent_call(*cfg)(*args)
+    with telemetry.span("kernel.latent_ragged", rows=b, t=t,
+                        max_pages=max_pages):
+        return _jitted_latent_call(cfg)(*args)
+
+
+@functools.lru_cache(maxsize=256)
+def _jitted_latent_step(cfg, donate):
+    """One program: this chunk's rows into the pages (entries whose page
+    id is out of bounds are padding and drop), the queries gathered
+    right-aligned, the latent ragged kernel. Pages donated on the chip:
+    the pool holds the only reference and commits what comes back."""
+    attend = _build_latent_call(*cfg)
+
+    def run(q, toks, pg, of, gm, pages, tbl, lens, q_lens):
+        pages = pages.at[pg, of].set(toks.astype(pages.dtype), mode="drop")
+        return attend(q[gm], pages, tbl, lens, q_lens), pages
+
+    run.__name__ = "latent_ragged_step"
+    return jax.jit(run, donate_argnums=(5,) if donate else ())
+
+
+def latent_ragged_step(q, toks, pg, of, gm, pages, page_table, seq_lens,
+                       q_lens, value_dim, sm_scale=None, interpret=None):
+    """The packed attention step of a latent pool (see
+    ``PagedKVCacheManager.latent_ragged_step``): q (n_pad, H, D), toks
+    (n_pad, D), pg/of (n_pad,) the write plan padded with out-of-bounds
+    page ids, gm (rows_pad, T) the right-align gather. Returns (out
+    (rows_pad, T, H, value_dim), new pages)."""
+    _, h, d = q.shape
+    npages, page_size, _ = pages.shape
+    b, t = gm.shape
+    max_pages = page_table.shape[1]
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    if interpret is None:
+        interpret = not on_tpu()
+    cfg = (b, t, h, d, int(value_dim), npages, page_size, max_pages,
+           float(scale), bool(interpret))
+    with telemetry.span("kernel.latent_ragged", rows=b, t=t,
+                        max_pages=max_pages, fused=1):
+        return _jitted_latent_step(cfg, on_tpu())(
+            q, toks, jnp.asarray(pg, jnp.int32), jnp.asarray(of, jnp.int32),
+            jnp.asarray(gm, jnp.int32), pages,
+            page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+            jnp.asarray(q_lens).astype(jnp.int32))
+
+
 def pad_plan_i32(a, n, fill):
     """Pad a 1-D int32 plan operand of :func:`paged_ragged_fused_step`
     to ``n`` entries with ``fill`` — the single place the fused
     program's out-of-bounds drop-entry contract is encoded for both
     the adapter-side scatter plan (fill = packed length) and the
-    pool-side page plan (fill = num_pages)."""
+    pool-side page plan (fill = num_pages). A host array is padded on
+    the host and crosses once: a device-side concatenate is one program
+    per (length, padding) pair, and the packed length changes every
+    step."""
+    if not isinstance(a, jax.Array):
+        a = np.asarray(a, np.int32)  # trace-lint: ok(a host plan, no tracer)
+        short = n - a.shape[0]
+        if short > 0:
+            a = np.concatenate([a, np.full((short,), fill, np.int32)])
+        return jnp.asarray(a)
     a = jnp.asarray(a, jnp.int32)
     short = n - a.shape[0]
     if short <= 0:

@@ -124,6 +124,22 @@ class TestStreaming:
         assert eng.get("submitted") == 5
         assert "step_lag_s" in eng  # pump step-lag histogram fed
 
+    def test_pump_warms_the_adapter_before_the_first_step(
+            self, tel_metrics):
+        """``BatchScheduler.warm`` hands an adapter that offers ``warm``
+        the scheduler's own batch size, bucketed packed widths and
+        chunk size; the engine's pump calls it before any step."""
+        model, sched = _sched(None, prefill_chunk_tokens=16,
+                              serving_buckets=(8, 32, 64))
+        seen, step = [], sched.step
+        model.warm = lambda rows, packed, chunk: seen.append(
+            ("warm", rows, sorted(packed), chunk))
+        sched.step = lambda: (seen.append(("step",)), step())[1]
+        outs = _engine_run(sched, _reqs())
+        assert outs == _clean_run()
+        assert seen[0] == ("warm", 4, [8, 32], 16)    # 4 and 4 + 16
+        assert [e[0] for e in seen].count("warm") == 1
+
     def test_submit_validation_errors_propagate(self, tel_metrics):
         _, sched = _sched(None)
 

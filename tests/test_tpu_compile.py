@@ -6,7 +6,9 @@ it refuses — a block the (8, 128) tiling cannot hold, more SMEM/VMEM
 than a kernel may use — it would refuse on the chip, and interpret mode
 never sees it. These are the kernels ``chip_smoke.py`` dispatches, at
 Mistral-7B widths (H32 / KV8 / D128, hidden 4096, pages of 16), about
-two seconds each. Nothing runs: a compile that passes is not a chip run.
+two seconds each, and the latent pool's step and the routed-experts
+program at Xing4.0-29B-A4B's widths. Nothing runs: a compile that passes
+is not a chip run.
 
 This is the ONLY file that describes the chip, and it does so inside a
 fixture: one process at a time may load libtpu, xdist workers each
@@ -145,6 +147,47 @@ def test_fused_ragged_step(one_chip):
              ((2048, PAGE, KVH, D), BF16), ((2048, PAGE, KVH, D), BF16),
              ((b_pad, mp), i32), ((b_pad,), i32), ((b_pad,), i32)]
     assert "tpu_custom_call" in _compile(run, one_chip, *specs)
+
+
+@pytest.mark.parametrize("b,t,max_pages", [
+    (64, 1, 256), (64, 64, 256), (64, 16, 64), (8, 1, 1),
+])
+def test_latent_ragged_step(one_chip, b, t, max_pages):
+    """The latent pool's packed step at Xing4.0-29B-A4B's widths (32
+    query heads, a cached row of 512 + 64, 12,288 pages of 16): the page
+    write, the right-align gather and the absorbed ragged kernel as one
+    program. At 64 tokens a row the kernel's blocks and float32 state take
+    about 15 MB of VMEM: the call raises its own limit."""
+    from paddle_tpu.ops.kernels.paged_attention import _jitted_latent_step
+
+    nh, d, dv, npages, n_pad = 32, 576, 512, 12288, 128
+    cfg = (b, t, nh, d, dv, npages, PAGE, max_pages, 0.1, False)
+    run = _jitted_latent_step.__wrapped__(cfg, False)
+    i32 = jnp.int32
+    specs = [((n_pad, nh, d), BF16), ((n_pad, d), BF16), ((n_pad,), i32),
+             ((n_pad,), i32), ((b, t), i32), ((npages, PAGE, d), BF16),
+             ((b, max_pages), i32), ((b,), i32), ((b,), i32)]
+    assert "tpu_custom_call" in _compile(run, one_chip, *specs)
+
+
+def test_routed_experts_program(one_chip):
+    """Router, sort, three grouped matmuls over 64 stacked experts and the
+    combine with the shared expert, 256 assignments: one program with the
+    group sizes as device data (no host value in it)."""
+    from paddle_tpu.incubate.distributed.models.moe.dropless import \
+        dropless_moe
+
+    c, f, e, n = 3584, 1024, 64, 64
+
+    def run(x, wr, bias, wg, wu, wd, sg, su, sd, valid):
+        return dropless_moe(x, wr, bias, wg, wu, wd, (sg, su, sd), 4, 2.0,
+                            True, valid)
+
+    specs = [((n, c), BF16), ((c, e), BF16), ((e,), BF16),
+             ((e, c, f), BF16), ((e, c, f), BF16), ((e, f, c), BF16),
+             ((c, f), BF16), ((c, f), BF16), ((f, c), BF16),
+             ((n,), jnp.bool_)]
+    _compile(run, one_chip, *specs)
 
 
 @pytest.mark.parametrize("window", [0, 1024])
