@@ -1860,7 +1860,7 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "(rows/bytes attrs)"),
     ("span:kernel.ragged", "span",
      "the jitted ragged call: LRU lookup and dispatch "
-     "(rows/t/max_pages attrs)"),
+     "(rows/t/max_pages attrs; grid_steps = rows x page blocks)"),
     ("span:xla.trace", "span",
      "jax.monitoring jaxpr_trace_duration under the open span "
      "(fun attr)"),

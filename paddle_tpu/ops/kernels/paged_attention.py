@@ -15,19 +15,26 @@ TPU paged-attention recipe ("Ragged Paged Attention" — see PAPERS.md):
   caller samples per-position logits via
   :func:`packed_position_index`), so one kernel handles a mixed
   packed batch uniformly (:func:`paged_ragged_attention`);
-* the kernel grid is (batch, q_heads, logical_pages); the page table
-  and both length vectors ride scalar prefetch so each step's
-  BlockSpec index_map can DMA the right physical page while the
-  previous one computes;
-* online softmax (m, l, acc) accumulates in VMEM scratch across the
-  page loop, rows right-aligned (row i's last q_lens[i] rows are its
-  newest tokens; padded leading rows return exact zeros).
+* the kernel grid is (row, block of 16 whole pages), run in order; the
+  page table and both length vectors ride scalar prefetch, the pools
+  stay in HBM as they are held, and a live step copies the NEXT live
+  step's K and V pages into the other half of a VMEM landing buffer
+  while it computes its own — each page once for every head, only the
+  pages the row has; blocks past a row's length, below its window or
+  of a padding row copy and multiply nothing;
+* inside a step the query heads of a KV group are multiplied together
+  against their KV head's keys — rows (token, head-in-group), in tiles,
+  so a right-aligned row's leading padding is skipped by tile — with
+  online softmax (m, l, acc) in VMEM scratch across the page axis
+  (row i's last q_lens[i] rows are its newest tokens; padded leading
+  rows return exact zeros).
 
-GQA maps q-head h to kv-head h // (H // KVH) in the index maps — no KV
-replication in HBM. Int8 pages dequantize in VMEM right after the page
-DMA (per-page per-head scale sidecars ride scalar prefetch). Off-TPU
-(tests) the same kernel runs in pallas interpret mode against a dense
-reference.
+GQA never replicates KV in HBM or in VMEM. Int8 pages dequantize in
+VMEM right after the page DMA (per-page per-head scale sidecars ride
+scalar prefetch). Off-TPU (tests) the same kernel runs in pallas
+interpret mode against a dense reference. The legacy decode kernel of
+``FLAGS_ragged_attention=off`` keeps the older (batch, q_heads,
+logical_pages) grid.
 
 FlashFuser-style fusion (:func:`paged_ragged_fused_step`): once the
 attention path is ONE program, the packed dense neighbours fold into
@@ -57,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...framework import telemetry
 from ...framework.flags import flag
@@ -360,100 +368,220 @@ def paged_ragged_attention_reference(q, k_pages, v_pages, page_table,
     return out
 
 
-def _ragged_kernel(scale, page_size, group, max_pages, t, window,
-                   quant, ragged, *refs):
-    """THE unified kernel: T tokens per row attend causally to the
-    whole paged prefix (the new tokens' K/V already live in the
-    pages; seq_lens counts them). ``window`` > 0 bands the mask
-    (0 <= qpos - kpos < window) and skips pages below every row's
-    window. ``quant``: int8 pages dequantized in VMEM via the
-    scalar-prefetched per-page scale sidecars. ``ragged``: a
-    scalar-prefetched q_lens vector marks how many TRAILING rows of
-    each sequence's T-row block are real new tokens — 1 for decode
-    rows, n for prefill chunks, so one program serves a mixed packed
-    batch; the padded leading rows produce exact zeros."""
+RAGGED_PAGES_PER_STEP = 16       # K and V pages DMA'd a grid step
+RAGGED_ROW_TILE = 64             # (token, head-in-group) rows a matmul
+
+
+def _ragged_tiling(t, group, max_pages):
+    """(pages a step, page blocks a row, query row tile) of the unified
+    kernel, from the static shapes alone. A KV head's query rows are
+    ``t * group`` (token, head-in-group) pairs; they are multiplied in
+    tiles so that a right-aligned row's leading padding is skipped by
+    tile. A row count the tile does not divide is one whole tile."""
+    ppb = min(RAGGED_PAGES_PER_STEP, max_pages)
+    m = t * group
+    tm = m
+    if m > RAGGED_ROW_TILE:
+        # a dynamic row slice of bf16 queries starts on a packed tile
+        for c in range(RAGGED_ROW_TILE, 15, -16):
+            if m % c == 0:
+                tm = c
+                break
+    return ppb, -(-max_pages // ppb), tm
+
+
+def _ragged_grid_steps(b, max_pages):
+    """Grid steps of one call: rows x page blocks (the ``kernel.ragged``
+    span's ``grid_steps``)."""
+    return b * _ragged_tiling(1, 1, max_pages)[1]
+
+
+def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
+                   tm, window, quant, tbl_ref, lens_ref, qlens_ref, *refs):
+    """THE unified kernel, grid (row b, page block p), steps in order. A
+    live step holds the block's K pages and V pages — at most ``ppb``,
+    only those the row has — in VMEM in the pool's own layout
+    (page_size, kv heads, head_dim), each copied from HBM once for every
+    head while the block before it was computed (the copies of a step
+    are issued by the live step before it, across rows too), and lays
+    them head-major. Then, KV head by KV head, the block's keys
+    (ppb * page_size, head_dim) are multiplied with the ``t * group``
+    (token, head-in-group) query rows of the head's group in tiles of
+    ``tm``, from the tile that holds the row's first real token: the
+    last q_lens[b] tokens are real, the rest is the right-alignment's
+    padding and returns exact zeros. Causal; ``window`` > 0 bands the
+    mask (0 <= qpos - kpos < window). ``quant``: int8 pages dequantised
+    on the way by the scalar-prefetched per-page, per-head scale
+    sidecars. Online softmax state (m, l, acc) stays in VMEM across the
+    page axis, float32. Blocks beyond the row's length, below every real
+    token's window, or of a padding row copy and multiply nothing."""
     refs = list(refs)
-    page_tbl_ref = refs.pop(0)
-    lens_ref = refs.pop(0)
-    q_lens_ref = refs.pop(0) if ragged else None
     if quant:
         k_scale_ref = refs.pop(0)
         v_scale_ref = refs.pop(0)
-    else:
-        k_scale_ref = v_scale_ref = None
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    (q_ref, k_hbm, v_hbm, o_ref, k_in, v_in, sems, slot_ref, k_buf, v_buf,
+     m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
-    hq = pl.program_id(1)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
+    seq_len = lens_ref[b]
+    q_len = qlens_ref[b]
+    n_tiles = (t * group) // tm
+    span = ppb * page_size
+    log_g = group.bit_length() - 1 if group & (group - 1) == 0 else None
+
+    def div(a, n):
+        # of non-negative scalars: ``//`` on a traced integer lowers
+        # through sign(), several milliseconds of lowering each
+        return jax.lax.div(a, jnp.int32(n))
+
+    def token_of(row):
+        return jax.lax.shift_right_logical(row, log_g) \
+            if log_g is not None else div(row, group)
+
+    def blocks(row):
+        """(first, last) live block of a row that holds tokens."""
+        n = lens_ref[row]
+        last = div(jnp.maximum(n, 1) - 1, span)
+        if not window:
+            return 0, last
+        # the lowest real token sits at n - q_lens[row]
+        floor = div(jnp.maximum(n - qlens_ref[row] - window + 1, 0),
+                    span)
+        return jnp.minimum(floor, last), last
+
+    def copies(row, blk, slot, j):
+        pg = tbl_ref[row, blk * ppb + j]
+        return (pltpu.make_async_copy(k_hbm.at[pg], k_in.at[slot, j],
+                                      sems.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[pg], v_in.at[slot, j],
+                                      sems.at[slot, 1]))
+
+    def each_page(row, blk, slot, act):
+        # the pages of the block the row has tokens in
+        n = jnp.minimum(
+            ppb, div(lens_ref[row] + page_size - 1, page_size) - blk * ppb)
+
+        def body(j, c):
+            for cp in copies(row, blk, slot, j):
+                act(cp)
+            return c
+        jax.lax.fori_loop(0, n, body, 0)
+
+    def start(row, blk, slot):
+        each_page(row, blk, slot, lambda cp: cp.start())
+
+    @pl.when((b == 0) & (p == 0))
+    def _():
+        slot_ref[0] = 0
+        # a page a block does not copy keeps what the buffer held:
+        # masked, but it goes through the matmul (0 x NaN)
+        k_in[...] = jnp.zeros(k_in.shape, k_in.dtype)
+        v_in[...] = jnp.zeros(v_in.shape, v_in.dtype)
 
     @pl.when(p == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    seq_len = lens_ref[b]
-    valid = p * page_size < seq_len
-    if window:
-        # lowest row position is seq_len - t; its window floor is
-        # seq_len - t - window + 1
-        valid = valid & (
-            (p + 1) * page_size > seq_len - t - window + 1)
+    first, last = blocks(b)
 
-    @pl.when(valid)
+    @pl.when((seq_len > 0) & (p >= first) & (p <= last))
     def _():
-        q = q_ref[0, 0]                   # (T, D)
-        k = k_ref[0, 0]                   # (page_size, D)
-        v = v_ref[0, 0]
-        if quant:
-            si = _scale_index(page_tbl_ref[b, p], hq, group)
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * k_scale_ref[si]
-            v = v.astype(jnp.float32) * v_scale_ref[si]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                          # (T, page_size)
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        # row r is absolute position seq_len - T + r
-        qpos = seq_len - t + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0
-        )
-        keep = (kpos <= qpos) & (kpos < seq_len)
-        if window:
-            keep = keep & (qpos - kpos < window)
-        if ragged:
-            # rows below t - q_lens[b] are padding (right-aligned
-            # chunk shorter than the block): mask their scores too so
-            # the softmax state stays finite
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            keep = keep & (row >= t - q_lens_ref[b])
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)
-        pv = jnp.exp(s - m_cur)
-        l_ref[:] = jnp.broadcast_to(
-            corr * l_ref[:, :1]
-            + jnp.sum(pv, axis=-1, keepdims=True),
-            l_ref.shape,
-        )
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
+        slot = slot_ref[0]
+        # the live step before this one issued this block's copies,
+        # unless there was none: the call's first, or after padding rows
+        before = jnp.maximum(b - 1, 0)
 
-    @pl.when(p == max_pages - 1)
+        @pl.when((p == first) & ((b == 0) | (lens_ref[before] == 0)))
+        def _():
+            start(b, p, slot)
+
+        # the next live step's: this row's next block, else the first
+        # of the row after it, if that row holds tokens
+        after = jnp.minimum(b + 1, n_rows - 1)
+        inside = p < last
+
+        @pl.when(inside | ((b + 1 < n_rows) & (lens_ref[after] > 0)))
+        def _():
+            start(jnp.where(inside, b, after),
+                  jnp.where(inside, p + 1, blocks(after)[0]), 1 - slot)
+
+        each_page(b, p, slot, lambda cp: cp.wait())
+        slot_ref[0] = 1 - slot
+
+        def gather(pages_in, buf, scale_ref):
+            x = pages_in[slot]                       # (ppb, P, KVH, D)
+            if not quant:
+                x = x.reshape(span, kvh, x.shape[-1])
+                for g in range(kvh):
+                    buf[g] = x[:, g, :]
+                return
+            x = x.astype(jnp.float32)
+            for j in range(ppb):
+                pg = tbl_ref[b, jnp.minimum(p * ppb + j,
+                                            tbl_ref.shape[1] - 1)]
+                for g in range(kvh):
+                    buf[g, j * page_size:(j + 1) * page_size, :] = \
+                        x[j, :, g, :] * scale_ref[pg * kvh + g]
+
+        gather(k_in, k_buf, k_scale_ref if quant else None)
+        gather(v_in, v_buf, v_scale_ref if quant else None)
+
+        def head(g, c):
+            k = k_buf[g]                                 # (span, D)
+            v = v_buf[g]
+
+            def tile(i, c):
+                rows = slice(None) if n_tiles == 1 else \
+                    pl.ds(pl.multiple_of(i * tm, tm), tm)
+                q = q_ref[0, g, rows, :]                 # (tm, D)
+                if quant:
+                    q = q.astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                kpos = p * span + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                tok = token_of(i * tm + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0))
+                # token r is absolute position seq_len - t + r
+                qpos = seq_len - t + tok
+                keep = (kpos <= qpos) & (tok >= t - q_len)
+                if window:
+                    keep = keep & (qpos - kpos < window)
+                s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_ref[g, rows, :][:, :1]
+                l_prev = l_ref[g, rows, :][:, :1]
+                m_cur = jnp.maximum(m_prev,
+                                    jnp.max(s, -1, keepdims=True))
+                corr = jnp.exp(m_prev - m_cur)
+                pv = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
+                l_ref[g, rows, :] = jnp.broadcast_to(
+                    corr * l_prev + jnp.sum(pv, -1, keepdims=True),
+                    (tm, l_ref.shape[-1]))
+                acc_ref[g, rows, :] = acc_ref[g, rows, :] * corr \
+                    + jax.lax.dot_general(
+                        pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_ref[g, rows, :] = jnp.broadcast_to(
+                    m_cur, (tm, m_ref.shape[-1]))
+                return c
+
+            if n_tiles == 1:
+                return tile(0, c)
+            # tiles below the first real token's are padding: skipped
+            return jax.lax.fori_loop(
+                div((t - q_len) * group, tm), n_tiles, tile, c)
+
+        jax.lax.fori_loop(0, kvh, head, 0)
+
+    @pl.when(p == n_steps - 1)
     def _():
-        safe_l = jnp.maximum(l_ref[:, :1], 1e-30)
-        out = acc_ref[:] / safe_l
-        if ragged:
-            row = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
-            out = jnp.where(row >= t - q_lens_ref[b], out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...][:, :, :1], 1e-30)
+        tok = token_of(jax.lax.broadcasted_iota(jnp.int32, out.shape, 1))
+        o_ref[0] = jnp.where(tok >= t - q_len, out, 0.0).astype(
+            o_ref.dtype)
 
 
 def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
@@ -500,7 +628,8 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
     if any(isinstance(x, jax.core.Tracer) for x in args):
         return _build_ragged_call(*cfg)(*args)
     with telemetry.span("kernel.ragged", rows=b, t=t,
-                        max_pages=max_pages):
+                        max_pages=max_pages,
+                        grid_steps=_ragged_grid_steps(b, max_pages)):
         return _jitted_ragged_call(cfg)(*args)
 
 
@@ -525,61 +654,62 @@ def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
                        scale, window, quant, ragged, interpret):
     """The unified ragged pallas dispatch as a pure function of the
     static config — same inline-under-trace / cached-jit-when-eager
-    split as :func:`_build_decode_call`."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    split as :func:`_build_decode_call`. The pools stay in HBM as they
+    are held, (NP, P, KVH, D), and the kernel copies the pages it wants
+    by the scalar-prefetched table (on the chip any other view of the
+    pool, the lane-merged (NP, P, KVH * D) one included, is a copy of
+    all of it; a BlockSpec a page costs every program that holds the
+    call a tenth of a second of tracing and lowering more)."""
     group = h // kvh
+    m = t * group
+    ppb, n_steps, tm = _ragged_tiling(t, group, max_pages)
+    span = ppb * page_size
 
-    def q_map(b_, h_, p_, *pref):
-        return (b_, h_, 0, 0)
+    def q_map(b_, p_, *pref):
+        return (b_, 0, 0, 0)
 
-    def kv_map(b_, h_, p_, tbl, *pref):
-        return (h_ // group, tbl[b_, p_], 0, 0)
-
-    n_scalars = 2 + (1 if ragged else 0) + (2 if quant else 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalars,
-        grid=(b, h, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, t, d), q_map),
-            pl.BlockSpec((1, 1, page_size, d), kv_map),
-            pl.BlockSpec((1, 1, page_size, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, t, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t, 8), jnp.float32),
-            pltpu.VMEM((t, 8), jnp.float32),
-            pltpu.VMEM((t, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _ragged_kernel, scale, page_size, group, max_pages, t,
-        window, quant, ragged,
-    )
-
-    def run(q, k_pages, v_pages, *scalar_args):
-        kp = jnp.transpose(k_pages, (2, 0, 1, 3)).reshape(
-            kvh, npages, page_size, d
+    def run(q, k_pages, v_pages, tbl, lens, *scalar_args):
+        if not ragged:
+            scalar_args = (jnp.full((b,), t, jnp.int32), *scalar_args)
+        kv_dtype = jnp.float32 if quant else k_pages.dtype
+        pool = pl.BlockSpec(memory_space=pl.ANY)
+        landing = pltpu.VMEM((2, ppb, page_size, kvh, d), k_pages.dtype)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3 + (2 if quant else 0),
+            grid=(b, n_steps),
+            in_specs=[pl.BlockSpec((1, kvh, m, d), q_map), pool, pool],
+            out_specs=pl.BlockSpec((1, kvh, m, d), q_map),
+            scratch_shapes=[
+                landing, landing,
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((kvh, span, d), kv_dtype),
+                pltpu.VMEM((kvh, span, d), kv_dtype),
+                pltpu.VMEM((kvh, m, 128), jnp.float32),
+                pltpu.VMEM((kvh, m, 128), jnp.float32),
+                pltpu.VMEM((kvh, m, d), jnp.float32),
+            ],
         )
-        vp = jnp.transpose(v_pages, (2, 0, 1, 3)).reshape(
-            kvh, npages, page_size, d
-        )
-        q4 = jnp.transpose(q, (0, 2, 1, 3))  # (B, H, T, D)
+        # (B, T, H, D) -> (B, KVH, T * group, D): a KV head's query
+        # rows together, ordered (token, head-in-group)
+        qg = jnp.transpose(q.reshape(b, t, kvh, group, d),
+                           (0, 2, 1, 3, 4)).reshape(b, kvh, m, d)
         out = pl.pallas_call(
-            kernel,
+            functools.partial(
+                _ragged_kernel, scale, page_size, ppb, n_steps, b, t,
+                group, kvh, tm, window, quant),
             name="ragged_paged_attention",
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, m, d), q.dtype),
             interpret=interpret,
+            # in order: a step issues the next live step's page copies
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=96 * 1024 * 1024,
             ) if not interpret else None,
-        )(
-            *scalar_args,
-            q4, kp, vp,
-        )
-        return jnp.transpose(out, (0, 2, 1, 3))
+        )(tbl, lens, *scalar_args, qg, k_pages, v_pages)
+        return jnp.transpose(out.reshape(b, kvh, t, group, d),
+                             (0, 2, 1, 3, 4)).reshape(b, t, h, d)
 
     return run
 
@@ -1023,5 +1153,6 @@ def paged_ragged_fused_step(x, wq, wk, wv, wo, biases, cos, sin, pos,
     if any(isinstance(a, jax.core.Tracer) for a in args):
         return _build_fused_call(*cfg)(*args)
     with telemetry.span("kernel.ragged", rows=b_pad, t=t_pad,
-                        max_pages=max_pages, fused=1):
+                        max_pages=max_pages, fused=1,
+                        grid_steps=_ragged_grid_steps(b_pad, max_pages)):
         return _jitted_fused_call(cfg)(*args)

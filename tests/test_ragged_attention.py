@@ -63,27 +63,31 @@ class TestUnifiedKernelParity:
     row-kind matrix — the tentpole's correctness core."""
 
     def _run(self, lens, q_lens, T, quant=False, window=0, H=4,
-             KVH=2, D=32, seed=0):
+             KVH=2, D=32, seed=0, MAXP=None, dtype=jnp.float32,
+             tol=2e-4):
         rng = np.random.RandomState(seed)
         B = len(lens)
         P = PAGE
-        MAXP = max(-(-max(lens) // P), 1)
+        MAXP = MAXP or max(-(-max(lens) // P), 1)
         NP = B * MAXP + 4
         kp, vp, ks, vs = _pages(rng, NP, P, KVH, D, quant)
+        if not quant:
+            kp, vp = kp.astype(dtype), vp.astype(dtype)
         tbl = jnp.asarray(
             rng.permutation(NP)[:B * MAXP].reshape(B, MAXP), jnp.int32)
         ln = jnp.asarray(lens, jnp.int32)
         ql = jnp.asarray(q_lens, jnp.int32)
-        q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
+        q = jnp.asarray(rng.randn(B, T, H, D), dtype)
         out = paged_ragged_attention(
             q, kp, vp, tbl, ln, q_lens=ql, window=window,
             k_scales=ks, v_scales=vs)
         ref = paged_ragged_attention_reference(
             q, kp, vp, tbl, ln, q_lens=ql, window=window,
             k_scales=ks, v_scales=vs)
-        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-4,
-                                   rtol=2e-4)
-        return np.asarray(out)
+        assert out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                                   atol=tol, rtol=tol)
+        return np.asarray(out, np.float32)
 
     @pytest.mark.parametrize("quant", [False, True])
     def test_decode_only_rows(self, quant):
@@ -122,6 +126,187 @@ class TestUnifiedKernelParity:
         # returns exact zeros without poisoning the softmax state
         out = self._run(lens=(9, 0), q_lens=(2, 1), T=2)
         np.testing.assert_array_equal(out[1], 0.0)
+
+    # -- the (row, page block) grid of ISSUE 31: a step is 16 whole
+    # pages (64 tokens at this page size), a KV head's query rows are
+    # (token, head-in-group) pairs in tiles of 64
+
+    @pytest.mark.parametrize("maxp", [1, 2, 3, 24, 32])
+    def test_table_widths_around_the_pages_a_step(self, maxp):
+        # widths that are no multiple of the 16 pages a step (the last
+        # block names columns past the table: clamped, masked) and one
+        # that is; the longest row fills the table to its last token
+        full = maxp * PAGE
+        lens = (full, max(full // 2, 1), 1)
+        self._run(lens=lens, q_lens=(1, 1, 1), T=1, MAXP=maxp)
+
+    @pytest.mark.parametrize("tail", [1, PAGE - 1, PAGE + 1])
+    def test_row_ends_inside_a_blocks_first_pages(self, tail):
+        # 64 tokens a block: the second block holds `tail` tokens
+        self._run(lens=(64 + tail, 64, 128 + tail),
+                  q_lens=(1, 2, 2), T=2, MAXP=40)
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_padding_rows_between_real_ones(self, quant):
+        out = self._run(lens=(70, 0, 9, 0, 0, 131),
+                        q_lens=(1, 0, 3, 1, 0, 4), T=4, quant=quant,
+                        MAXP=33)
+        for r in (1, 3, 4):
+            np.testing.assert_array_equal(out[r], 0.0)
+
+    @pytest.mark.parametrize("group", [1, 4, 8])
+    def test_chunk_and_decode_rows_of_64_tokens(self, group):
+        # t = 64 with q_lens 1, 17 and 64 in one call: the decode rows
+        # of a step that carries a prompt chunk. A group's 64 * group
+        # query rows go tile by tile from the first real token's tile
+        out = self._run(lens=(130, 19, 70, 0), q_lens=(1, 17, 64, 0),
+                        T=64, H=2 * group, KVH=2, MAXP=40)
+        np.testing.assert_array_equal(out[0, :63], 0.0)
+        np.testing.assert_array_equal(out[1, :47], 0.0)
+        np.testing.assert_array_equal(out[3], 0.0)
+
+    @pytest.mark.parametrize("window", [50, 64, 70, 129])
+    def test_window_floor_inside_a_page_block(self, window):
+        # rows of 130-200 tokens under a band of 50-129: the floor of
+        # the lowest real token falls inside block 0, 1 or 2, on a
+        # block's edge (64) and inside a page (50, 70, 129)
+        self._run(lens=(130, 200, 66, 141), q_lens=(1, 4, 2, 3), T=4,
+                  window=window, H=8, KVH=2, MAXP=50)
+
+    @pytest.mark.parametrize("group,window", [(1, 0), (4, 0), (8, 0),
+                                              (4, 37)])
+    def test_int8_pages_over_several_blocks(self, group, window):
+        # every page of a block dequantises by its own scale
+        self._run(lens=(150, 64, 65, 7), q_lens=(1, 5, 2, 1), T=5,
+                  quant=True, window=window, H=2 * group, KVH=2,
+                  MAXP=38)
+
+    @pytest.mark.parametrize("window", [0, 70])
+    def test_bf16_pages_and_queries(self, window):
+        # the serving dtype: bf16 operands, float32 accumulation and
+        # softmax state, the probabilities cast to the value dtype
+        self._run(lens=(130, 19, 0, 70), q_lens=(1, 17, 0, 64), T=64,
+                  window=window, H=8, KVH=2, MAXP=40, dtype=jnp.bfloat16,
+                  tol=2e-2)
+
+    @pytest.mark.parametrize("t,group,want", [
+        (1, 4, 4), (64, 4, 64), (64, 1, 64), (16, 4, 64), (5, 8, 40),
+        (24, 4, 48), (7, 4, 28), (40, 2, 16), (64, 8, 64)])
+    def test_row_tile_divides_a_groups_rows(self, t, group, want):
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _ragged_tiling
+
+        ppb, blocks, tm = _ragged_tiling(t, group, 24)
+        assert (ppb, blocks, tm) == (16, 2, want)
+        assert (t * group) % tm == 0
+
+
+def _eqns(jaxpr):
+    """Every equation of a program, nested programs opened; a pallas
+    call is one equation (its kernel is not entered)."""
+    for e in jaxpr.eqns:
+        subs = [] if e.primitive.name == "pallas_call" else [
+            v for v in e.params.values()
+            if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        for sub in subs:
+            yield from _eqns(getattr(sub, "jaxpr", sub))
+        if not subs:
+            yield e
+
+
+class TestGridStructure:
+    """ISSUE 31, so that the faults of the (row, head, page) grid cannot
+    come back: at the shapes of ``mistral-7b-serve.decode-closed32`` (32
+    rows, 4,096 pages of 16, a table 64 wide) the pool reaches the kernel
+    as it is held, and a call is rows x page blocks grid steps."""
+    B, T, H, KVH, D, NP, P, MP = 32, 1, 32, 8, 128, 4096, 16, 64
+    POOL = NP * P * KVH * D
+
+    def _ragged(self):
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _build_ragged_call
+
+        bf, i32 = jnp.bfloat16, jnp.int32
+        S = jax.ShapeDtypeStruct
+        run = _build_ragged_call(
+            self.B, self.T, self.H, self.D, self.NP, self.P, self.KVH,
+            self.MP, self.D ** -0.5, 4096, False, True, False)
+        pool = S((self.NP, self.P, self.KVH, self.D), bf)
+        return jax.make_jaxpr(run)(
+            S((self.B, self.T, self.H, self.D), bf), pool, pool,
+            S((self.B, self.MP), i32), S((self.B,), i32),
+            S((self.B,), i32)).jaxpr
+
+    def _fused(self):
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _build_fused_call
+
+        bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+        S = jax.ShapeDtypeStruct
+        n, e = 32, 4096
+        run = _build_fused_call(
+            n, e, self.H, self.KVH, self.D, self.NP, self.P, self.B,
+            self.T, self.MP, self.D ** -0.5, 4096, False, False)
+        pool = S((self.NP, self.P, self.KVH, self.D), bf)
+        return jax.make_jaxpr(run)(
+            S((n, e), bf), S((e, self.H * self.D), bf),
+            S((e, self.KVH * self.D), bf), S((e, self.KVH * self.D), bf),
+            S((self.H * self.D, e), bf), S((32768, self.D), f32),
+            S((32768, self.D), f32), S((n,), i32), S((n,), i32),
+            S((n,), i32), S((self.B, self.T), i32), S((n,), i32),
+            S((n,), i32), S((n,), i32), pool, pool,
+            S((self.B, self.MP), i32), S((self.B,), i32),
+            S((self.B,), i32)).jaxpr
+
+    @pytest.mark.parametrize("which,allowed", [
+        ("_ragged", set()), ("_fused", {"scatter"})])
+    def test_no_pool_sized_copy_outside_the_kernel(self, which, allowed):
+        # the old wrapper transposed both pools on every call (134 MB
+        # each at these shapes); the fused step's page write is the one
+        # operation that may return a pool
+        made = {e.primitive.name for e in _eqns(getattr(self, which)())
+                if e.primitive.name != "pallas_call"
+                and any(v.aval.size == self.POOL for v in e.outvars)}
+        assert made == allowed
+
+    @pytest.mark.parametrize("which", ["_ragged", "_fused"])
+    def test_grid_is_rows_by_page_blocks(self, which):
+        from paddle_tpu.ops.kernels.paged_attention import (
+            RAGGED_PAGES_PER_STEP, _ragged_grid_steps)
+
+        (call,) = [e for e in _eqns(getattr(self, which)())
+                   if e.primitive.name == "pallas_call"]
+        grid = tuple(call.params["grid_mapping"].grid)
+        assert grid == (self.B, -(-self.MP // RAGGED_PAGES_PER_STEP))
+        assert grid[0] * grid[1] == _ragged_grid_steps(self.B, self.MP)
+        # both pools go in whole, as they are held: the kernel copies
+        # the pages it wants itself
+        pools = [v for v in call.invars
+                 if tuple(v.aval.shape) == (self.NP, self.P, self.KVH,
+                                            self.D)]
+        assert len(pools) == 2
+
+    @pytest.mark.parametrize("rows,t,maxp", [(3, 1, 5), (2, 4, 24)])
+    def test_span_reports_the_grid_steps(self, rows, t, maxp):
+        from paddle_tpu.framework import telemetry
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _ragged_grid_steps
+
+        rng = np.random.RandomState(0)
+        kp, vp, _, _ = _pages(rng, 8, PAGE, 2, 32)
+        q = jnp.asarray(rng.randn(rows, t, 4, 32), jnp.float32)
+        tbl = jnp.zeros((rows, maxp), jnp.int32)
+        ln = jnp.full((rows,), 3, jnp.int32)
+        paddle.set_flags({"telemetry": "trace"})
+        try:
+            paged_ragged_attention(q, kp, vp, tbl, ln,
+                                   q_lens=jnp.ones((rows,), jnp.int32))
+        finally:
+            paddle.set_flags({"telemetry": "off"})
+        span = [s for s in telemetry.peek_tracer().spans()
+                if s.name == "kernel.ragged"][-1]
+        assert span.attrs["grid_steps"] == _ragged_grid_steps(rows, maxp)
+        assert span.attrs["grid_steps"] == rows * -(-maxp // min(16, maxp))
 
 
 class TestThinWrappers:

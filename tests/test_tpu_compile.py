@@ -16,6 +16,7 @@ import every test file, and a topology call at import would give the
 workers different tests to collect (the whole suite then counts 0).
 """
 import functools
+import math
 import os
 
 import jax
@@ -72,16 +73,35 @@ def _ragged_specs(b, t, npages, max_pages, kv_dtype):
     return specs
 
 
+def _pool_sized(text, npages):
+    """The operations of a compiled program whose result has the pool's
+    element count, parameters aside: [(shape, layout, line)]."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\](\{[\d,]*)", line)
+        if not m or " parameter(" in line:
+            continue
+        dims = [int(x) for x in m.group(1).split(",")]
+        if math.prod(dims) == npages * PAGE * KVH * D:
+            found.append((dims, m.group(2), line.strip()[:160]))
+    return found
+
+
+# the last three: mistral-7b-serve.decode-closed32's own calls (32 rows,
+# 4,096 pages, tables 32-128 wide, a step with a 64-token prompt chunk)
 @pytest.mark.parametrize("b,t,max_pages,window", [
     (8, 1, 128, 0), (8, 1, 128, 4096), (8, 16, 128, 4096),
     (8, 64, 128, 0), (8, 64, 128, 4096), (1, 64, 16, 4096),
     (64, 1, 256, 4096),
+    (32, 1, 32, 4096), (32, 1, 128, 4096), (32, 64, 64, 4096),
 ])
 def test_ragged_bf16(one_chip, b, t, max_pages, window):
     from paddle_tpu.ops.kernels.paged_attention import \
         paged_ragged_attention
 
-    npages = 4096 if b == 64 else 2048
+    npages = 4096 if b >= 32 else 2048
 
     def f(q, kp, vp, tbl, lens, ql):
         return paged_ragged_attention(q, kp, vp, tbl, lens, q_lens=ql,
@@ -90,6 +110,10 @@ def test_ragged_bf16(one_chip, b, t, max_pages, window):
     text = _compile(f, one_chip,
                     *_ragged_specs(b, t, npages, max_pages, BF16))
     assert "tpu_custom_call" in text
+    # the pages reach the kernel as the pool holds them: under the
+    # chip's tiling a transpose of the pool, and the lane-merged view
+    # (pages, 16, kv heads * head_dim) too, is a copy of all of it
+    assert _pool_sized(text, npages) == []
 
 
 @pytest.mark.parametrize("t", [1, 16])
@@ -128,13 +152,18 @@ def test_legacy_decode_kernel(one_chip, kv_dtype):
     assert "tpu_custom_call" in _compile(run, one_chip, *specs)
 
 
-def test_fused_ragged_step(one_chip):
+@pytest.mark.parametrize("n_pad,b_pad,t_pad,mp,npages", [
+    (128, 8, 64, 128, 2048),
+    (32, 32, 1, 32, 4096), (32, 32, 1, 128, 4096), (128, 32, 64, 64, 4096),
+])
+def test_fused_ragged_step(one_chip, n_pad, b_pad, t_pad, mp, npages):
     """qkv + RoPE + page scatter + ragged attend + o_proj as ONE program
-    at one serving bucket (128 packed tokens, 8 rows x 64)."""
+    at one serving bucket (128 packed tokens, 8 rows x 64), and at the
+    Mistral serving cell's: 32 decode rows, and 31 with a 64-token
+    prompt chunk."""
     from paddle_tpu.ops.kernels.paged_attention import _build_fused_call
 
-    n_pad, b_pad, t_pad, mp = 128, 8, 64, 128
-    run = _build_fused_call(n_pad, E, H, KVH, D, 2048, PAGE, b_pad,
+    run = _build_fused_call(n_pad, E, H, KVH, D, npages, PAGE, b_pad,
                             t_pad, mp, D ** -0.5, 4096, False, False)
     i32 = jnp.int32
     specs = [((n_pad, E), BF16), ((E, H * D), BF16),
@@ -144,9 +173,16 @@ def test_fused_ragged_step(one_chip):
              ((n_pad,), i32), ((n_pad,), i32), ((n_pad,), i32),
              ((b_pad, t_pad), i32), ((n_pad,), i32), ((n_pad,), i32),
              ((n_pad,), i32),
-             ((2048, PAGE, KVH, D), BF16), ((2048, PAGE, KVH, D), BF16),
+             ((npages, PAGE, KVH, D), BF16),
+             ((npages, PAGE, KVH, D), BF16),
              ((b_pad, mp), i32), ((b_pad,), i32), ((b_pad,), i32)]
-    assert "tpu_custom_call" in _compile(run, one_chip, *specs)
+    text = _compile(run, one_chip, *specs)
+    assert "tpu_custom_call" in text
+    # the page write returns a pool (and, undonated, copies it first);
+    # nothing lays the pool out another way for the kernel
+    assert {(tuple(dims), layout) for dims, layout, _ in
+            _pool_sized(text, npages)} <= {
+                ((npages, PAGE, KVH, D), "{3,2,1,0")}
 
 
 @pytest.mark.parametrize("b,t,max_pages", [
