@@ -1117,415 +1117,6 @@ def bench_chunked_prefill(users=8, prompt_len=96, new_tokens=8,
     return _merge_serving_rec("chunked_prefill", rec)
 
 
-# aux: unified ragged attention — two-kernel routing vs ONE program
-# ---------------------------------------------------------------------------
-
-
-def bench_ragged_serving(budget=64):
-    """Unified ragged-attention arm (ISSUE 13, ROADMAP item 2): the
-    chunked workload run under FLAGS_ragged_attention=off (the legacy
-    per-row-kind decode/prefill kernel pair) vs auto (ONE ragged
-    kernel per packed config, plus the FlashFuser-fused qkv+RoPE
-    prologue / o_proj epilogue where eligible). Records per-step
-    walls, the attend KERNEL PROGRAM counts (the per-bucket doubling
-    the unification removes), the per-layer attend dispatch counts
-    (exactly halved on mixed decode+prefill steps), and the ledger's
-    share_of_step_wall attribution of the unified program. The
-    --serving gate requires greedy identity, >= 1 mixed step whose
-    dispatches halved, and no attend-program growth."""
-    import paddle_tpu as paddle
-    from paddle_tpu.framework.flags import set_flags
-    from paddle_tpu.inference import (
-        BatchScheduler,
-        PagedLlamaAdapter,
-        Request,
-    )
-    from paddle_tpu.models import LlamaForCausalLM, llama_tiny
-
-    kind = _device_kind()
-    cpu = kind.startswith("cpu")
-    page_size = 4
-    if cpu:
-        users, prompt_len, new_tokens = 4, 48, 6
-        cfg = llama_tiny(num_hidden_layers=2,
-                         max_position_embeddings=256)
-    else:
-        users, prompt_len, new_tokens = 8, 256, 16
-        cfg = llama_tiny(
-            hidden_size=512, intermediate_size=1024,
-            num_hidden_layers=8, num_attention_heads=8,
-            num_key_value_heads=8, max_position_embeddings=2048,
-        )
-        page_size = 16
-    paddle.seed(3)
-    model = LlamaForCausalLM(cfg)
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(1, cfg.vocab_size, prompt_len).tolist()
-               for _ in range(users)]
-    pages_per_seq = -(-(prompt_len + new_tokens) // page_size)
-    num_pages = 2 * users * pages_per_seq + 16
-    layers = cfg.num_hidden_layers
-
-    def _kernel_caches():
-        from paddle_tpu.ops.kernels.paged_attention import (
-            _jitted_decode_call,
-            _jitted_fused_call,
-            _jitted_ragged_call,
-        )
-
-        return (_jitted_decode_call, _jitted_ragged_call,
-                _jitted_fused_call)
-
-    def _cold_compile_count(mode):
-        """REAL compiled pallas entry count for one cold run of the
-        arm: clear the shape-keyed dispatch caches, run, and count
-        the entries that landed — a regression that silently splits
-        the unified cfg key (per row kind, per real-token count)
-        shows up here even when the adapter's own accounting looks
-        stable."""
-        for c in _kernel_caches():
-            c.cache_clear()
-        run(mode)
-        return sum(c.cache_info().currsize for c in _kernel_caches())
-
-    def run(mode, telemetry_mode=None):
-        set_flags({"ragged_attention": mode})
-        adapter = PagedLlamaAdapter(
-            model, num_pages=num_pages, page_size=page_size,
-            max_length=cfg.max_position_embeddings)
-        sched = BatchScheduler(adapter, max_batch_size=users,
-                               chunked_prefill=True,
-                               prefill_chunk_tokens=budget)
-        for i, p in enumerate(prompts):
-            sched.submit(Request(f"r{i}", list(p),
-                                 max_new_tokens=new_tokens))
-        step_walls = []
-        mixed_walls = []
-        while sched.num_active or sched.num_queued:
-            ts = time.perf_counter()
-            ev = sched.step()
-            dt = time.perf_counter() - ts
-            step_walls.append(dt)
-            if ev["prefill_tokens"] and ev["decode_tokens"]:
-                mixed_walls.append(dt)
-        gen = {f"r{i}": sched.result(f"r{i}").generated_ids
-               for i in range(users)}
-        share = None
-        if telemetry_mode is not None:
-            row = sched.metrics().get("ledger", {}).get(
-                "prefill_chunk", {})
-            share = row.get("share_of_step_wall")
-        return {
-            "gen": gen,
-            "step_p50_ms": 1e3 * float(np.median(step_walls)),
-            "mixed_step_p50_ms": 1e3 * float(np.median(mixed_walls))
-            if mixed_walls else None,
-            "attend_programs": adapter.attend_program_count,
-            "attend_calls": adapter.chunk_stats["attend_calls"],
-            "chunk_calls": adapter.chunk_stats["calls"],
-            "kernel_kinds": sorted(
-                {k for k, *_ in adapter._kernel_shapes}),
-            "kinds_by_bucket": {
-                str(b): kinds for b, kinds in
-                sorted(adapter.attend_kinds_by_bucket.items())},
-            "compile_count": adapter.compile_count,
-            "ledger_share_of_step_wall": share,
-        }
-
-    def ledger_share():
-        """The PR-12 ledger attributes the unified program: run the
-        auto arm under FLAGS_telemetry=metrics and read the attend
-        program's share of total step wall back from the plan-vs-
-        actual join (the model call rides the prefill_chunk exec
-        stamp; bench_chunked_prefill registers the ragged attend
-        plan under the same key)."""
-        from paddle_tpu.framework import telemetry as _tel
-        from paddle_tpu.framework.flags import set_flags as _sf
-
-        _tel.reset()
-        _sf({"telemetry": "metrics"})
-        try:
-            return run("auto", telemetry_mode="metrics")
-        finally:
-            _sf({"telemetry": "off"})
-            _tel.reset()
-
-    try:
-        # cold passes double as warmups (compiles land outside the
-        # measured runs) and count the REAL compiled pallas entries
-        off_compiles = _cold_compile_count("off")
-        off = run("off")
-        auto_compiles = _cold_compile_count("auto")
-        auto = run("auto")
-        ledger = ledger_share()
-    finally:
-        set_flags({"ragged_attention": "auto"})
-
-    assert auto["gen"] == off["gen"], (
-        "unified ragged dispatch diverged from the two-kernel path")
-    assert ledger["gen"] == off["gen"]
-    # the adapter's claimed program count is the TRUE compile count:
-    # every unified attend program is one dispatch-cache entry (no
-    # hidden per-row-kind or per-real-token-count cfg splits)
-    assert auto_compiles == auto["attend_programs"], (
-        auto_compiles, auto["attend_programs"])
-    # ISSUE-13 acceptance, measured per bucket: the legacy arm pays
-    # the decode+prefill PAIR on mixed buckets; unified runs exactly
-    # ONE kernel kind on every bucket
-    assert all(len(k) == 1 for k in auto["kinds_by_bucket"].values()
-               ), auto["kinds_by_bucket"]
-    doubled = [b for b, k in off["kinds_by_bucket"].items()
-               if len(k) == 2]
-    assert doubled, (
-        "no bucket paid the two-kernel pair in the legacy arm — the "
-        "halving claim was not exercised")
-    # the new DEFAULT must not regress step wall (generous bound for
-    # CPU noise; the cpu run is ~25-35% FASTER from the fusion)
-    assert auto["step_p50_ms"] <= off["step_p50_ms"] * 1.25, (
-        auto["step_p50_ms"], off["step_p50_ms"])
-    # the unified path issues EXACTLY one attend dispatch per layer
-    # per packed step; the legacy path adds one more per layer on
-    # every step that mixes single-token and multi-token rows — the
-    # per-step dispatch halving of ROADMAP item 2
-    assert auto["attend_calls"] == auto["chunk_calls"] * layers, auto
-    mixed_kernel_steps = (off["attend_calls"]
-                          - off["chunk_calls"] * layers) // layers
-    assert mixed_kernel_steps >= 1, (
-        "workload produced no mixed steps — the two-kernel arm never "
-        "paid the pair")
-    assert auto["attend_programs"] <= off["attend_programs"], (
-        off["attend_programs"], auto["attend_programs"])
-    share = ledger["ledger_share_of_step_wall"]
-    share_ok = share is not None and 0.0 < float(share) <= 1.0
-    rec = {
-        "config": "serving_ragged_attention",
-        "mode": "tpu-single-chip" if not cpu else "cpu",
-        "users": users,
-        "prompt_len": prompt_len,
-        "new_tokens": new_tokens,
-        "budget": budget,
-        "layers": layers,
-        "greedy_identical": True,        # asserted above
-        "two_kernel": {
-            "step_p50_ms": round(off["step_p50_ms"], 2),
-            "mixed_step_p50_ms": round(off["mixed_step_p50_ms"], 2)
-            if off["mixed_step_p50_ms"] is not None else None,
-            "attend_programs": off["attend_programs"],
-            "attend_calls": off["attend_calls"],
-            "kernel_kinds": off["kernel_kinds"],
-            "kinds_by_bucket": off["kinds_by_bucket"],
-            "cold_pallas_compiles": int(off_compiles),
-            "compile_count": off["compile_count"],
-        },
-        "unified": {
-            "step_p50_ms": round(auto["step_p50_ms"], 2),
-            "mixed_step_p50_ms": round(auto["mixed_step_p50_ms"], 2)
-            if auto["mixed_step_p50_ms"] is not None else None,
-            "attend_programs": auto["attend_programs"],
-            "attend_calls": auto["attend_calls"],
-            "kernel_kinds": auto["kernel_kinds"],
-            "kinds_by_bucket": auto["kinds_by_bucket"],
-            "cold_pallas_compiles": int(auto_compiles),
-            "compile_count": auto["compile_count"],
-        },
-        "doubled_buckets_two_kernel": sorted(doubled),
-        "per_bucket_kinds_halved": True,        # asserted above
-        "step_wall_ratio": round(
-            auto["step_p50_ms"] / max(off["step_p50_ms"], 1e-9), 3),
-        "mixed_kernel_steps": int(mixed_kernel_steps),
-        "attend_calls_saved": off["attend_calls"]
-        - auto["attend_calls"],
-        "mixed_step_dispatches_halved": True,   # asserted above
-        "ledger_share_of_step_wall": round(float(share), 4)
-        if share is not None else None,
-        "ledger_share_ok": bool(share_ok),
-    }
-    return _merge_serving_rec("ragged", rec)
-
-
-# aux: unified speculative decoding — verify rows on the ragged kernel
-# ---------------------------------------------------------------------------
-
-
-def bench_spec_serving(users=4, prompt_len=48, new_tokens=32,
-                       draft_k=8, budget=64):
-    """Unified speculative-decoding arm (ISSUE 19): the decode-heavy
-    workload served three ways — FLAGS_spec_decode=off (plain packed
-    decode), legacy (per-sequence ``decode_window`` target passes),
-    and ragged (each spec-active row rides the ordinary packed
-    ``prefill_chunk`` step as ONE right-aligned (k+1)-token verify
-    row; draft propose + target verify = two bucketed ragged programs
-    per round).
-
-    The draft is PERFECTLY DISTILLED from the target: the target's
-    layers beyond the first have their o_proj / down_proj weights
-    zeroed (pre-norm residual blocks collapse to identity), so a
-    1-layer weight-shared draft reproduces the target logits exactly
-    — acceptance is 100% by construction and the measured win is the
-    verify-row packing, not draft luck. Gates: greedy identity to
-    BOTH non-spec and legacy arms, decode tokens/s >= 1.3x off, and
-    no attend-program growth over the non-spec bucket bound."""
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import (
-        BatchScheduler,
-        PagedLlamaAdapter,
-        Request,
-    )
-    from paddle_tpu.models import LlamaForCausalLM, llama_tiny
-
-    kind = _device_kind()
-    cpu = kind.startswith("cpu")
-    page_size = 4
-    layers = 10
-    if cpu:
-        cfg = llama_tiny(num_hidden_layers=layers,
-                         max_position_embeddings=256)
-        dcfg = llama_tiny(num_hidden_layers=1,
-                          max_position_embeddings=256)
-    else:
-        users, prompt_len, new_tokens = 8, 128, 48
-        layers = 8
-        mk = dict(hidden_size=512, intermediate_size=1024,
-                  num_attention_heads=8, num_key_value_heads=8,
-                  max_position_embeddings=2048)
-        cfg = llama_tiny(num_hidden_layers=layers, **mk)
-        dcfg = llama_tiny(num_hidden_layers=1, **mk)
-        page_size = 16
-    paddle.seed(3)
-    target = LlamaForCausalLM(cfg)
-    for layer in target.model.layers[1:]:
-        for lin in (layer.self_attn.o_proj, layer.mlp.down_proj):
-            lin.weight._data = jnp.zeros_like(lin.weight._data)
-    draft = LlamaForCausalLM(dcfg)
-    tgt_params = dict(target.named_parameters())
-    for name, p in draft.named_parameters():
-        p._data = tgt_params[name]._data
-
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(1, cfg.vocab_size, prompt_len).tolist()
-               for _ in range(users)]
-    pages_per_seq = -(-(prompt_len + new_tokens) // page_size)
-    num_pages = 2 * users * pages_per_seq + 16
-
-    def run(mode):
-        adapter = PagedLlamaAdapter(
-            target, num_pages=num_pages, page_size=page_size,
-            max_length=cfg.max_position_embeddings)
-        kw = {}
-        if mode != "off":
-            kw = dict(
-                draft_model=PagedLlamaAdapter(
-                    draft, num_pages=num_pages, page_size=page_size,
-                    max_length=cfg.max_position_embeddings),
-                draft_k=draft_k, spec_decode=mode)
-        sched = BatchScheduler(adapter, max_batch_size=users,
-                               chunked_prefill=True,
-                               prefill_chunk_tokens=budget, **kw)
-        for i, p in enumerate(prompts):
-            sched.submit(Request(f"r{i}", list(p),
-                                 max_new_tokens=new_tokens))
-        step_walls = []
-        dec_walls = []
-        dec_tokens = 0
-        while sched.num_active or sched.num_queued:
-            ts = time.perf_counter()
-            ev = sched.step()
-            dt = time.perf_counter() - ts
-            step_walls.append(dt)
-            if ev["decode_tokens"] and not ev["prefill_tokens"]:
-                dec_walls.append(dt)
-                dec_tokens += ev["decode_tokens"]
-        gen = {f"r{i}": sched.result(f"r{i}").generated_ids
-               for i in range(users)}
-        st = dict(sched.spec_stats) if sched.draft is not None \
-            else None
-        return {
-            "gen": gen,
-            "decode_tok_s": dec_tokens / max(sum(dec_walls), 1e-9),
-            "decode_steps": len(dec_walls),
-            "step_p50_ms": 1e3 * float(np.median(step_walls)),
-            "accepted_tok_per_step": (
-                st["committed_tokens"] / max(st["rounds"], 1)
-                if st else dec_tokens / max(len(dec_walls), 1)),
-            "attend_programs": adapter.attend_program_count,
-            "compile_count": adapter.compile_count,
-            "kernel_kinds": sorted(
-                {k for k, *_ in adapter._kernel_shapes}),
-            "spec_stats": st,
-            "num_buckets": len(sched.serving_buckets),
-        }
-
-    for mode in ("off", "legacy", "ragged"):
-        run(mode)        # warmup: compiles land outside the timing
-    off = run("off")
-    legacy = run("legacy")
-    ragged = run("ragged")
-
-    # ISSUE-19 acceptance: the unified lowering changes the SCHEDULE,
-    # never the tokens — identical to the non-spec scheduler AND to
-    # the legacy per-sequence lowering it replaces
-    assert ragged["gen"] == off["gen"], (
-        "ragged spec decode diverged from the non-spec scheduler")
-    assert legacy["gen"] == off["gen"], (
-        "legacy spec decode diverged from the non-spec scheduler")
-    st = ragged["spec_stats"]
-    accept_rate = (st["accepted_draft_tokens"]
-                   / max(st["proposed_tokens"], 1))
-    assert accept_rate == 1.0, (
-        "distilled draft must be accepted verbatim", st)
-    # verify rows ride the EXISTING packed buckets: no program growth
-    # over the non-spec arm, compile count bounded by the buckets
-    assert ragged["attend_programs"] <= off["attend_programs"] \
-        or ragged["compile_count"] <= ragged["num_buckets"], (
-        off["attend_programs"], ragged["attend_programs"])
-    assert ragged["kernel_kinds"] == off["kernel_kinds"], (
-        off["kernel_kinds"], ragged["kernel_kinds"])
-    speedup = ragged["decode_tok_s"] / max(off["decode_tok_s"], 1e-9)
-    assert speedup >= 1.3, (
-        "unified spec decode won less than 1.3x over non-spec "
-        "decode", ragged["decode_tok_s"], off["decode_tok_s"])
-
-    def _arm(a):
-        return {
-            "decode_tok_s": round(a["decode_tok_s"], 1),
-            "decode_steps": a["decode_steps"],
-            "step_p50_ms": round(a["step_p50_ms"], 2),
-            "accepted_tok_per_step":
-                round(a["accepted_tok_per_step"], 2),
-            "attend_programs": a["attend_programs"],
-            "compile_count": a["compile_count"],
-            "kernel_kinds": a["kernel_kinds"],
-        }
-
-    rec = {
-        "config": "serving_spec_decode",
-        "mode": "tpu-single-chip" if not cpu else "cpu",
-        "users": users,
-        "prompt_len": prompt_len,
-        "new_tokens": new_tokens,
-        "draft_k": draft_k,
-        "target_layers": layers,
-        "draft_layers": 1,
-        "greedy_identical": True,       # asserted above
-        "legacy_identical": True,       # asserted above
-        "accept_rate": round(accept_rate, 4),
-        "decode_speedup_vs_off": round(speedup, 3),
-        "decode_speedup_vs_legacy": round(
-            ragged["decode_tok_s"]
-            / max(legacy["decode_tok_s"], 1e-9), 3),
-        "num_buckets": ragged["num_buckets"],
-        "program_count_bounded": True,  # asserted above
-        "off": _arm(off),
-        "legacy": _arm(legacy),
-        "ragged": _arm(ragged),
-        "spec_rounds": st["rounds"],
-        "spec_refill_tokens": st["refill_tokens"],
-    }
-    return _merge_serving_rec("spec", rec)
-
-
 # aux: page-sanitizer overhead — strict shadow-heap checking vs off
 # ---------------------------------------------------------------------------
 
@@ -4107,8 +3698,6 @@ def main() -> int:
                     help="run only the serving workloads: shared-"
                          "prefix (radix prefix cache on vs off), "
                          "quantized, chunked-prefill budget sweep, "
-                         "the unified ragged-attention arm (two-"
-                         "kernel vs one program per bucket), "
                          "the page-sanitizer overhead arm, the "
                          "concurrency-sanitizer overhead arm "
                          "(strict lockset/HB audit vs off under a "
@@ -4147,8 +3736,6 @@ def main() -> int:
         rec = _emit(bench_prefix_serving())
         qrec = _emit(bench_quant_serving())
         crec = _emit(bench_chunked_prefill())
-        rgrec = _emit(bench_ragged_serving())
-        sprec = _emit(bench_spec_serving())
         srec = _emit(bench_sanitizer_serving())
         ccrec = _emit(bench_concurrency_serving())
         trec = _emit(bench_telemetry_serving())
@@ -4182,35 +3769,6 @@ def main() -> int:
             bool(crec.get("ledger", {}).get("bytes_per_s_finite")) \
             and not crec.get("ledger", {}).get("drifting", True) \
             and crec.get("ledger", {}).get("plan_drift_trips", 1) == 0
-        # ISSUE-13 unified-ragged acceptance: greedy outputs identical
-        # to the two-kernel path, at least one mixed step whose
-        # per-layer attend dispatches halved (2 -> 1), no attend-
-        # program growth, and the ledger attributing the unified
-        # program's share of step wall
-        ragged_ok = bool(rgrec.get("greedy_identical")) and \
-            bool(rgrec.get("mixed_step_dispatches_halved")) and \
-            bool(rgrec.get("per_bucket_kinds_halved")) and \
-            rgrec.get("mixed_kernel_steps", 0) >= 1 and \
-            len(rgrec.get("doubled_buckets_two_kernel", [])) >= 1 \
-            and rgrec.get("unified", {}).get(
-                "attend_programs", 1 << 30) \
-            <= rgrec.get("two_kernel", {}).get("attend_programs", 0) \
-            and rgrec.get("unified", {}).get("cold_pallas_compiles") \
-            == rgrec.get("unified", {}).get("attend_programs") \
-            and rgrec.get("step_wall_ratio", 9.9) <= 1.25 \
-            and bool(rgrec.get("ledger_share_ok"))
-        # ISSUE-19 unified-spec acceptance: ragged verify rows greedy-
-        # identical to BOTH the non-spec scheduler and the legacy
-        # decode_window lowering, the distilled draft accepted
-        # verbatim, decode tokens/s >= 1.3x non-spec, and the target
-        # program count bounded by the existing packed buckets
-        spec_ok = bool(sprec.get("greedy_identical")) and \
-            bool(sprec.get("legacy_identical")) and \
-            sprec.get("accept_rate", 0.0) >= 1.0 and \
-            sprec.get("decode_speedup_vs_off", 0.0) >= 1.3 and \
-            bool(sprec.get("program_count_bounded")) and \
-            sprec.get("ragged", {}).get("kernel_kinds") \
-            == sprec.get("off", {}).get("kernel_kinds")
         # ISSUE-6 sanitizer acceptance: off-mode serving allocates
         # NOTHING in page_sanitizer.py, strict mode is output-identical
         # and violation-free on a healthy pool
@@ -4324,7 +3882,7 @@ def main() -> int:
             rec.get("prefill_skip_frac", 0.0) >= 0.5 and \
             qrec.get("greedy_match_rate", 0.0) >= 1.0 and \
             qrec.get("seq_capacity_ratio", 0.0) >= 1.8 and \
-            chunk_ok and ragged_ok and spec_ok and san_ok and \
+            chunk_ok and san_ok and \
             conc_ok and tel_ok and over_ok and engine_ok and \
             disagg_ok and autotune_ok
         _emit({"metric": "serving_prefix_cache",
@@ -4344,28 +3902,6 @@ def main() -> int:
                    max((a["compile_count"] or 0
                         for a in crec.get("budgets", {}).values()),
                        default=0),
-               "ragged_attend_programs_two_kernel":
-                   rgrec.get("two_kernel", {}).get("attend_programs"),
-               "ragged_attend_programs_unified":
-                   rgrec.get("unified", {}).get("attend_programs"),
-               "ragged_mixed_kernel_steps":
-                   rgrec.get("mixed_kernel_steps"),
-               "ragged_attend_calls_saved":
-                   rgrec.get("attend_calls_saved"),
-               "ragged_ledger_share_of_step_wall":
-                   rgrec.get("ledger_share_of_step_wall"),
-               "spec_decode_speedup_vs_off":
-                   sprec.get("decode_speedup_vs_off"),
-               "spec_decode_speedup_vs_legacy":
-                   sprec.get("decode_speedup_vs_legacy"),
-               "spec_accept_rate": sprec.get("accept_rate"),
-               "spec_accepted_tok_per_step":
-                   sprec.get("ragged", {}).get(
-                       "accepted_tok_per_step"),
-               "spec_step_p50_ms":
-                   sprec.get("ragged", {}).get("step_p50_ms"),
-               "spec_attend_programs":
-                   sprec.get("ragged", {}).get("attend_programs"),
                "sanitizer_overhead_pct": srec.get("overhead_pct"),
                "sanitizer_events": srec.get("sanitizer_events", 0),
                "sanitizer_off_zero_alloc":
@@ -4579,7 +4115,6 @@ def main() -> int:
         _single("serving_prefix_cache", bench_prefix_serving)
         _single("serving_quantized", bench_quant_serving)
         _single("serving_chunked_prefill", bench_chunked_prefill)
-        _single("serving_spec", bench_spec_serving)
         _single("serving_sanitizer", bench_sanitizer_serving)
         _single("serving_telemetry", bench_telemetry_serving)
         _single("serving_overload", bench_overload_serving)

@@ -193,42 +193,6 @@ define_flag("prefill_chunk_tokens", 64,
             "PagedLlamaAdapter.prefill_chunk — Sarathi-style budget "
             "packing keeps decode latency flat while prefill "
             "saturates the chip (docs/SERVING.md)")
-define_flag("ragged_attention", "auto",
-            "unified ragged paged-attention dispatch for the chunked "
-            "serving step (ops/kernels/paged_attention.py): 'auto' "
-            "(default) routes every packed row — single-token decode "
-            "rows and multi-token prefill chunks alike — through ONE "
-            "ragged kernel call per layer (per-row q_lens/kv_lens "
-            "ride scalar prefetch; right-aligned rows) and, where "
-            "eligible (fp KV pages, unquantized non-distributed "
-            "projection weights), fuses the packed dense prologue "
-            "(qkv projection + RoPE + page scatter) and epilogue "
-            "(o_proj) into the same compiled program FlashFuser-"
-            "style; 'on' forces the unified kernel but never the "
-            "fused prologue/epilogue (the pure-kernel unification, "
-            "for A/B isolation); 'off' restores the historical "
-            "two-kernel lowering (decode rows via the paged decode "
-            "kernel, prefill rows via the q_lens-masked prefill "
-            "kernel) bitwise (docs/SERVING.md)")
-define_flag("spec_decode", "ragged",
-            "speculative-decoding lowering for the paged serving "
-            "scheduler (inference/serving.py, draft_model= set): "
-            "'ragged' (default) packs each spec-active sequence's "
-            "draft-k verify window as ONE right-aligned (k+1)-token "
-            "row of the ordinary prefill_chunk ragged step (per-"
-            "position logits out of the epilogue; draft proposals "
-            "ride the draft adapter's own bucketed chunked step), so "
-            "a decode round is two bucketed ragged program families "
-            "instead of a per-round dense decode_window pass; "
-            "'legacy' restores the PR-4 lowering (sequential "
-            "draft.decode_token proposals + one dense-gather "
-            "decode_window verify) bitwise for A/B; 'off' ignores "
-            "the draft model entirely — the scheduler serves plain "
-            "greedy decode (the trivial A/B baseline). Ragged mode "
-            "also lifts the legacy restrictions: prefix caching and "
-            "host-swap preemption compose with speculative decoding "
-            "(the draft KV is discarded at swap-out and re-prefilled "
-            "from the committed prefix at swap-in) (docs/SERVING.md)")
 define_flag("serving_buckets", "8,16,32,64,128,256",
             "comma-separated packed-token buckets for the chunked-"
             "prefill ragged dispatch: the per-step packed token count "

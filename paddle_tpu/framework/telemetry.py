@@ -1482,9 +1482,8 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "overwrite each other's counts"),
     ("serving.attend_programs", "gauge",
      "distinct paged-attention kernel programs the packed step has "
-     "compiled (adapter.attend_program_count): ONE per packed config "
-     "under FLAGS_ragged_attention=auto|on, a decode/prefill pair "
-     "per mixed config under off. Shared alias, last-writer-wins"),
+     "compiled (adapter.attend_program_count): ONE per packed "
+     "config. Shared alias, last-writer-wins"),
     ("serving.attend_programs.<scheduler>", "gauge",
      "per-scheduler attend kernel program count (uid-namespaced, "
      "same contract as serving.compile_count.<scheduler>)"),
@@ -1539,11 +1538,10 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "host swap-space bytes in use right now"),
     ("serving.step_retries", "counter",
      "step attempts abandoned by an injected fail_step fault"),
-    # unified speculative decoding (FLAGS_spec_decode; ISSUE 19)
+    # speculative decoding (ISSUE 19)
     ("serving.spec_accept_rate", "histogram",
      "per-row draft acceptance per verify round: accepted draft "
-     "tokens / draft_k (both spec lowerings observe it through the "
-     "shared commit helper)"),
+     "tokens / draft_k"),
     ("serving.spec_rounds", "counter",
      "draft-propose / target-verify rounds executed (one per step "
      "with any spec-active decode row)"),

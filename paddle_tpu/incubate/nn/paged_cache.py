@@ -101,9 +101,6 @@ from ...framework.core import Tensor, apply_op, _as_tensor
 from ...framework.flags import flag
 from ...ops.kernels.paged_attention import paged_attention as _kernel
 from ...ops.kernels.paged_attention import (
-    paged_prefill_attention as _prefill_kernel,
-)
-from ...ops.kernels.paged_attention import (
     paged_ragged_attention as _ragged_kernel_fn,
 )
 from ...ops.kernels.paged_attention import (
@@ -1443,14 +1440,18 @@ class PagedKVCacheManager:
         ``window`` > 0: sliding-window attention over the last
         ``window`` cached tokens (out-of-window pages skipped).
         Quantized pools pass their scale sidecars into the kernel
-        (dequant fused after the page DMA)."""
-        return self.attend_padded(q, seq_ids, sm_scale=sm_scale,
-                                  window=window)
+        (dequant fused after the page DMA). The T=1 shape of
+        :meth:`attend_ragged`: every row's ``q_len`` is 1."""
+        self._kv_only("attend")
+        out = self.attend_ragged(
+            Tensor(_as_tensor(q)._data[:, None]), seq_ids,
+            [1] * len(seq_ids), sm_scale=sm_scale, window=window)
+        return Tensor(out._data[:, 0])
 
     def _padded_kernel_inputs(self, seq_ids, rows_pad, max_pages):
         """Page table + lens padded to ``rows_pad`` rows x
         ``max_pages`` columns. Padding rows carry seq_len 0, which
-        both paged kernels treat as inert (no page is valid, output
+        the paged kernels treat as inert (no page is valid, output
         exact zeros) — the shape-bucketing enabler for the chunked-
         prefill dispatch."""
         rows_pad = max(int(rows_pad or len(seq_ids)), len(seq_ids))
@@ -1464,65 +1465,35 @@ class PagedKVCacheManager:
             lens[i] = self._lens[s]
         return jnp.asarray(tbl), jnp.asarray(lens)
 
-    def attend_padded(self, q, seq_ids, rows_pad=None, max_pages=None,
-                      sm_scale=None, window=0):
-        """Decode attend over a row/column-PADDED batch: ``q`` is
-        (rows_pad, H, D) whose first ``len(seq_ids)`` rows are real
-        decode tokens; padding rows (any content) return exact zeros.
-        ``max_pages`` pads the page-table width. The shape-stable
-        flavor of :meth:`attend` the bucketed ragged dispatch needs.
-
-        .. deprecated:: thin single-kind wrapper — under
-           ``FLAGS_ragged_attention=auto|on`` the kernel beneath is
-           the unified ragged program at T=1; mixed packed batches
-           should call :meth:`attend_ragged` directly."""
-        self._kv_only("attend_padded")
-        q = _as_tensor(q)
-        tbl, lens = self._padded_kernel_inputs(
-            seq_ids, rows_pad, max_pages)
-        if self._san is not None:
-            self._san_check_table(seq_ids, tbl, lens)
-        kp, vp = self.k_pages, self.v_pages
-        ks = self.k_scales if self.quantized else None
-        vs = self.v_scales if self.quantized else None
-
-        def f(qr):
-            return _kernel(qr, kp, vp, tbl, lens, sm_scale=sm_scale,
-                           window=window, k_scales=ks, v_scales=vs)
-
-        return apply_op("paged_attend", f, q, differentiable=False)
-
-    def attend_prefill(self, q, seq_ids, q_lens, rows_pad=None,
-                       max_pages=None, sm_scale=None, window=0):
-        """Chunked-prefill attend over a padded ragged batch: ``q`` is
-        (rows_pad, T, H, D); row i's last ``q_lens[i]`` rows are the
-        newest tokens of seq_ids[i] (K/V already appended — seq_len
-        counts them), earlier rows and batch-padding rows return exact
-        zeros. One fused kernel call for the whole mixed batch.
-
-        .. deprecated:: alias shape of :meth:`attend_ragged` (the
-           q_lens-masked prefill kernel WAS the unified ragged kernel
-           all along) — new packed-step callers use attend_ragged."""
-        self._kv_only("attend_prefill")
-        q = _as_tensor(q)
-        tbl, lens = self._padded_kernel_inputs(
-            seq_ids, rows_pad, max_pages)
-        if self._san is not None:
-            self._san_check_table(seq_ids, tbl, lens)
-        ql = jnp.zeros((tbl.shape[0],), jnp.int32)
-        ql = ql.at[:len(seq_ids)].set(
-            jnp.asarray(list(q_lens), jnp.int32))
-        kp, vp = self.k_pages, self.v_pages
-        ks = self.k_scales if self.quantized else None
-        vs = self.v_scales if self.quantized else None
-
-        def f(qr):
-            return _prefill_kernel(
-                qr, kp, vp, tbl, lens, sm_scale=sm_scale,
-                window=window, k_scales=ks, v_scales=vs, q_lens=ql)
-
-        return apply_op("paged_prefill_attend", f, q,
-                        differentiable=False)
+    def _step_tables(self, seq_ids, q_lens, rows_pad, max_pages,
+                     slots=None, n_pad=None):
+        """What one attend call hands its kernel beside the pages, built
+        in one place: the padded page table and lens
+        (:meth:`_padded_kernel_inputs`, checked by the sanitizer where
+        one is on), ``q_lens`` padded with zeros to the table's rows
+        and, where ``slots`` = (pages, offsets) of a booked write plan
+        is given, that plan padded to ``n_pad`` entries — page id
+        ``num_pages`` is OUT OF BOUNDS, so the step programs'
+        ``mode="drop"`` scatters skip the padding and every operand
+        stays bucket-shaped. Everything is laid out on the host and
+        crosses once. Returns ``(tbl, lens, ql)`` or ``(tbl, lens, ql,
+        pg, of)``; the one ``pool.table`` span site."""
+        with telemetry.span("pool.table") as sp:
+            tbl, lens = self._padded_kernel_inputs(
+                seq_ids, rows_pad, max_pages)
+            if self._san is not None:
+                self._san_check_table(seq_ids, tbl, lens)
+            ql = np.zeros((tbl.shape[0],), np.int32)
+            ql[:len(seq_ids)] = q_lens
+            out = (tbl, lens, jnp.asarray(ql))
+            if slots is not None:
+                pages, offs = slots
+                out += (_pad_plan(pages, n_pad, self.num_pages),
+                        _pad_plan(offs, n_pad, 0))
+            if sp is not None:
+                sp.attrs.update(rows=len(seq_ids), bytes=int(
+                    sum(a.nbytes for a in out)))
+        return out
 
     def attend_ragged(self, q, seq_ids, q_lens, rows_pad=None,
                       max_pages=None, sm_scale=None, window=0):
@@ -1532,23 +1503,12 @@ class PagedKVCacheManager:
         prefill chunks (K/V already appended; seq_len counts them).
         Earlier rows and batch-padding rows return exact zeros. One
         ragged kernel call for the whole mixed batch: the single
-        attend program per packed config that replaces the
-        attend_padded/attend_prefill pair (which remain as thin
-        shape wrappers for single-kind callers)."""
+        attend program per packed config."""
         self._kv_only("attend_ragged")
         with telemetry.span("pool.fused_step", op="attend_ragged"):
             q = _as_tensor(q)
-            with telemetry.span("pool.table") as sp:
-                tbl, lens = self._padded_kernel_inputs(
-                    seq_ids, rows_pad, max_pages)
-                if self._san is not None:
-                    self._san_check_table(seq_ids, tbl, lens)
-                ql = jnp.zeros((tbl.shape[0],), jnp.int32)
-                ql = ql.at[:len(seq_ids)].set(
-                    jnp.asarray(list(q_lens), jnp.int32))
-                if sp is not None:
-                    sp.attrs.update(rows=len(seq_ids), bytes=int(
-                        tbl.nbytes + lens.nbytes + ql.nbytes))
+            tbl, lens, ql = self._step_tables(
+                seq_ids, list(q_lens), rows_pad, max_pages)
             kp, vp = self.k_pages, self.v_pages
             ks = self.k_scales if self.quantized else None
             vs = self.v_scales if self.quantized else None
@@ -1626,28 +1586,14 @@ class PagedKVCacheManager:
                     f"the {n_real} real packed tokens nor the padded "
                     f"{n_pad} (pre-padded plans carry out-of-bounds "
                     "drop entries)")
-            pages, offs = self._ragged_slots(seq_ids, counts)
-            with telemetry.span("pool.table") as sp:
-                tbl, lens = self._padded_kernel_inputs(
-                    seq_ids, rows_pad, max_pages)
-                if self._san is not None:
-                    self._san_check_table(seq_ids, tbl, lens)
-                ql = jnp.zeros((tbl.shape[0],), jnp.int32)
-                ql = ql.at[:len(seq_ids)].set(
-                    jnp.asarray(counts, jnp.int32))
-                # padding entries: page id num_pages / flat slot n_pad
-                # are OUT OF BOUNDS — the fused program's mode="drop"
-                # scatters skip them, keeping every operand bucket-shaped
-                pg = _pad_plan(np.asarray(pages, np.int32), n_pad,
-                               self.num_pages)
-                of = _pad_plan(np.asarray(offs, np.int32), n_pad, 0)
-                mr = _pad_plan(mr, n_pad, 0)
-                mc = _pad_plan(mc, n_pad, 0)
-                mflat = _pad_plan(mflat, n_pad, n_pad)
-                if sp is not None:
-                    sp.attrs.update(rows=len(seq_ids), bytes=int(
-                        tbl.nbytes + lens.nbytes + ql.nbytes
-                        + pg.nbytes + of.nbytes))
+            tbl, lens, ql, pg, of = self._step_tables(
+                seq_ids, counts, rows_pad, max_pages,
+                slots=self._ragged_slots(seq_ids, counts), n_pad=n_pad)
+            # the adapter's scatter plan pads the same way: flat slot
+            # n_pad is out of bounds and drops
+            mr = _pad_plan(mr, n_pad, 0)
+            mc = _pad_plan(mc, n_pad, 0)
+            mflat = _pad_plan(mflat, n_pad, n_pad)
             wq, wk, wv, wo, biases = weights
             cos, sin = rope
             y, kp, vp = _fused_step_fn(
@@ -1692,22 +1638,9 @@ class PagedKVCacheManager:
                     f"latent_ragged_step: counts sum to {n_real}, the "
                     f"packed operands carry {q.shape[0]} queries and "
                     f"{n_pad} rows")
-            pages, offs = self._ragged_slots(seq_ids, counts)
-            with telemetry.span("pool.table") as sp:
-                tbl, lens = self._padded_kernel_inputs(
-                    seq_ids, rows_pad, max_pages)
-                if self._san is not None:
-                    self._san_check_table(seq_ids, tbl, lens)
-                ql = np.zeros((tbl.shape[0],), np.int32)
-                ql[:len(counts)] = counts
-                ql = jnp.asarray(ql)
-                pg = _pad_plan(np.asarray(pages, np.int32), n_pad,
-                               self.num_pages)
-                of = _pad_plan(np.asarray(offs, np.int32), n_pad, 0)
-                if sp is not None:
-                    sp.attrs.update(rows=len(seq_ids), bytes=int(
-                        tbl.nbytes + lens.nbytes + ql.nbytes
-                        + pg.nbytes + of.nbytes))
+            tbl, lens, ql, pg, of = self._step_tables(
+                seq_ids, counts, rows_pad, max_pages,
+                slots=self._ragged_slots(seq_ids, counts), n_pad=n_pad)
             out, self.k_pages = _latent_step_fn(
                 q, toks, pg, of, gather_map, self.k_pages, tbl, lens, ql,
                 value_dim=value_dim, sm_scale=sm_scale)
@@ -1716,9 +1649,8 @@ class PagedKVCacheManager:
     def dense_kv(self, seq_ids):
         """Dense (dequantized) gather of the listed sequences' pages:
         returns (page_table (B, MP), k (B, MP, P, KVH, D),
-        v (...)) with k/v in compute dtype — the supported way for
-        serving layers to read quantized pages without touching the
-        scale sidecars (multi-token verify windows use this)."""
+        v (...)) with k/v in compute dtype — the supported way to
+        read quantized pages without touching the scale sidecars."""
         self._kv_only("dense_kv")
         tbl = self.page_table(seq_ids)
         kd = self.k_pages[tbl]

@@ -44,10 +44,8 @@ def right_align_plan_np(row_indices, starts, counts, t_pad, rows_pad):
     packed row into a (rows_pad, t_pad) block: returns (gm, mr, mc,
     mflat) — ``gm`` gathers flat packed token indices into the block
     (row r's last counts[i] columns), and ``mr``/``mc``/``mflat``
-    map the kernel output back to flat packed slots. Shared by the
-    unified dispatch (every row) and the off-mode legacy prefill
-    routing (multi-token rows only), so the two A/B paths can never
-    drift apart on alignment. int32 numpy arrays."""
+    map the kernel output back to flat packed slots. int32 numpy
+    arrays."""
     gm = np.zeros((rows_pad, t_pad), np.int32)
     rr, cc, ff = [], [], []
     for r, i in enumerate(row_indices):
@@ -188,22 +186,16 @@ class PagedAdapterBase:
     @property
     def attend_program_count(self) -> int:
         """Distinct paged-attention kernel programs the packed step
-        dispatch has compiled. Unified mode
-        (``FLAGS_ragged_attention=auto|on``) launches ONE ragged
-        program per packed config; the legacy two-kernel routing
-        (``off``) compiles a decode AND a prefill program for every
-        mixed config — the per-bucket doubling ROADMAP item 2
-        removes (bench.py --serving gates on the halving)."""
+        dispatch has compiled: ONE ragged program per packed config,
+        whatever kinds of row it mixes."""
         return len(self._kernel_shapes)
 
     @property
     def attend_kinds_by_bucket(self) -> dict:
         """Per dispatch bucket (pad_to): the distinct attend KERNEL
-        KINDS its steps launched — the direct measurement of the
-        ISSUE-13 acceptance 'one attend program per bucket, not two':
-        unified mode records exactly {'ragged'} or {'ragged_fused'}
-        per bucket; the legacy routing records {'decode', 'prefill'}
-        on every mixed bucket."""
+        KINDS its steps launched — exactly {'ragged'},
+        {'ragged_fused'} or {'latent_ragged'} per bucket: one attend
+        program per bucket."""
         return {b: sorted({k for k, *_ in shapes})
                 for b, shapes in self._bucket_programs.items()}
 

@@ -14,16 +14,12 @@ paged append + attend → o_proj → mlp) → final norm → lm head.
 """
 from __future__ import annotations
 
-import math
-
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from ..framework import telemetry
 from ..framework.core import Tensor, no_grad
-from ..framework.flags import flag
 from ..incubate.nn import PagedKVCacheManager
 from ..ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
 from ..ops.kernels.rope import apply_rotary_emb, build_rope_cache
@@ -117,7 +113,7 @@ class PagedLlamaAdapter(PagedAdapterBase):
         self._fused_ok = None
 
     def _fusion_eligible(self) -> bool:
-        """auto-mode fusion gate, computed once per adapter: the
+        """The fusion gate, computed once per adapter: the
         fused prologue/epilogue consumes raw [in, out] projection
         weights and writes fp pages, so every layer's q/k/v/o
         projection must be a plain (non-distributed, non-weight-
@@ -197,84 +193,6 @@ class PagedLlamaAdapter(PagedAdapterBase):
             return self.model._head(h)
 
 
-def _window_logits(self, token_windows, seq_ids):
-    """Verify a w-token window per sequence in ONE forward pass
-    (the speculative-decoding verify step; upstream: the serving role
-    of fused_multi_transformer's multi-token branch).
-
-    token_windows: (B, w) ints. Appends all w tokens to the caches
-    (reject by rolling back with ``cache.truncate``) and returns
-    logits (B, w, vocab): logits[:, j] conditions on everything
-    through window token j.
-
-    TPU-first: the w queries attend over the paged pool via a DENSE
-    gather of each sequence's pages + one masked attention einsum —
-    regular compute XLA tiles onto the MXU, instead of w sequential
-    single-token kernel calls (which would erase the speculative
-    speedup)."""
-    cfg = self.cfg
-    toks = np.asarray(token_windows, "int64")
-    b, w = toks.shape
-    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    group = nh // nkv
-    lens0 = [self.caches[0].seq_len(s) for s in seq_ids]
-    over = [s for s, n in zip(seq_ids, lens0)
-            if n + w > self.max_length]
-    if over:
-        raise ValueError(
-            f"sequences {over} would exceed max_length="
-            f"{self.max_length} verifying a {w}-token window")
-    pos = (jnp.asarray(lens0, jnp.int32)[:, None]
-           + jnp.arange(w, dtype=jnp.int32)[None, :])  # (B, w)
-
-    with no_grad():
-        x = self.model.model.embed_tokens(Tensor(toks))  # (B, w, H)
-        xr = x._data
-        for li, layer in enumerate(self.model.model.layers):
-            xi = layer.input_layernorm(Tensor(xr))
-            q = layer.self_attn.q_proj(xi)
-            k = layer.self_attn.k_proj(xi)
-            v = layer.self_attn.v_proj(xi)
-            qh = q._data.reshape(b, w, nh, hd)
-            kh = k._data.reshape(b, w, nkv, hd)
-            vh = v._data.reshape(b, w, nkv, hd)
-            qh = apply_rotary_emb(qh, self._cos, self._sin,
-                                  position_ids=pos)
-            kh = apply_rotary_emb(kh, self._cos, self._sin,
-                                  position_ids=pos)
-            for j in range(w):
-                self.caches[li].append_batch(
-                    seq_ids, kh[:, j], vh[:, j])
-            c = self.caches[li]
-            # pool-API read: dense_kv dequantizes int8 pages against
-            # the scale sidecars (serving code never touches them)
-            tbl, kd, vd = c.dense_kv(seq_ids)    # (B, MP, P, KVH, D)
-            mp = tbl.shape[1]
-            kd = kd.reshape(b, mp * c.page_size, nkv, hd)
-            vd = vd.reshape(b, mp * c.page_size, nkv, hd)
-            if group > 1:
-                kd = jnp.repeat(kd, group, axis=2)
-                vd = jnp.repeat(vd, group, axis=2)
-            s = jnp.einsum(
-                "bwhd,bkhd->bhwk", qh.astype(jnp.float32),
-                kd.astype(jnp.float32)) / math.sqrt(hd)
-            kpos = jnp.arange(mp * c.page_size)[None, None, None, :]
-            ok = kpos <= pos[:, None, :, None]  # causal within window
-            if self._window:
-                ok = ok & (kpos > pos[:, None, :, None] - self._window)
-            s = jnp.where(ok, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bhwk,bkhd->bwhd", p,
-                              vd.astype(jnp.float32))
-            attn = attn.astype(xr.dtype).reshape(b, w, nh * hd)
-            xr = xr + layer.self_attn.o_proj(Tensor(attn))._data
-            h2 = layer.mlp(layer.post_attention_layernorm(Tensor(xr)))
-            xr = xr + h2._data
-        h = self.model.model.norm(Tensor(xr))
-        return self.model._head(h)  # (B, w, V)
-
-
 def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                    pad_to=None, logits_rows=None):
     """One ragged mixed prefill/decode step (the Ragged Paged
@@ -303,91 +221,56 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
     ONE flat packed token axis padded to ``pad_to`` (the scheduler
     buckets it — serving.bucket_packed_tokens — so steady-state
     serving compiles one program per bucket, not per packed length).
-    Attention is ONE ``cache.attend_ragged`` call per layer for the
-    whole mixed batch (``FLAGS_ragged_attention=auto|on``): every row
-    — single-token decode rows and multi-token chunks alike — rides
-    the unified ragged kernel right-aligned with its own q_lens
-    (fused int8-KV dequant included), padded to power-of-two
+    Attention is ONE ragged kernel call per layer for the whole mixed
+    batch: every row — single-token decode rows and multi-token chunks
+    alike — rides the unified ragged kernel right-aligned with its own
+    q_lens (fused int8-KV dequant included), padded to power-of-two
     row/length/page-table shapes so the kernel programs are
-    shape-stable. Where eligible (auto + fp pages + plain projection
-    weights) the whole layer attention step fuses FlashFuser-style:
-    qkv + RoPE + page scatter as the kernel's prologue, o_proj as its
-    epilogue (``cache.fused_ragged_step``). ``off`` restores the
-    historical two-kernel per-row-kind routing bitwise (decode rows
-    via the paged decode kernel, prefill rows via the q_lens-masked
-    prefill kernel)."""
+    shape-stable. Where :meth:`_fusion_eligible` (fp pages + plain
+    projection weights) the whole layer attention step fuses
+    FlashFuser-style: qkv + RoPE + page scatter as the kernel's
+    prologue, o_proj as its epilogue (``cache.fused_ragged_step``);
+    otherwise the same plan runs unfused (``cache.append_ragged`` +
+    ``cache.attend_ragged``)."""
     cfg = self.cfg
     with telemetry.span("model.plan") as plan_span:
         rows = plan_packed_rows(self.caches[0], token_ids, seq_ids,
                                 start_positions, pad_to, self.max_length)
-        b, counts, lens0 = rows.b, rows.counts, rows.lens0
-        flat, pos_np, starts = rows.flat, rows.pos_np, rows.starts
-        last_idx, n_real, pad_to = rows.last_idx, rows.n_real, rows.pad_to
+        b, counts, starts = rows.b, rows.counts, rows.starts
+        flat, pos_np = rows.flat, rows.pos_np
+        n_real, pad_to, mp_pad = rows.n_real, rows.pad_to, rows.mp_pad
         nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                        cfg.head_dim)
         pos = jnp.asarray(pos_np)[None, :]             # (1, N)
         self._count_packed_step(rows)
 
-        mode = str(flag("ragged_attention"))
-        unified = mode != "off"
-        mp_pad = rows.mp_pad
-
-        # gather/scatter plans (host-built once, shared by every layer)
-        s_plan = m_plan = None
-        fuse = False
-        if unified:
-            # ONE right-aligned ragged block for EVERY row: decode rows
-            # are q_lens=1 rows of the same kernel call (the Ragged Paged
-            # Attention shape), so each packed config compiles ONE attend
-            # program instead of a decode/prefill pair
-            t_pad = _pow2(max(counts))
-            b_pad = _pow2(b)
-            gm, mr, mc, m_flat = _right_align_plan(
-                range(b), starts, counts, t_pad, b_pad)
-            fuse = mode == "auto" and self._fusion_eligible()
-            # the fused program embeds the packed dense prologue/epilogue,
-            # so its REAL dispatch key includes the packed bucket (pad_to)
-            # — the pure attend program's does not
-            shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
-                if fuse else ("ragged", b_pad, t_pad, mp_pad)
-            self._count_kernel_shape(pad_to, shape)
-            pos_flat = jnp.asarray(pos_np)
-            if fuse:
-                # loop-invariant across layers: pad the scatter plan to
-                # the bucket ONCE (out-of-bounds fills drop in the fused
-                # program's scatters) instead of once per layer
-                mr = _pad_plan(mr, pad_to, 0)
-                mc = _pad_plan(mc, pad_to, 0)
-                m_flat = _pad_plan(m_flat, pad_to, pad_to)
-        else:
-            singles = [i for i, c in enumerate(counts) if c == 1]
-            multis = [i for i, c in enumerate(counts) if c > 1]
-            if singles:
-                bs = len(singles)
-                bs_pad = _pow2(bs)
-                s_idx = jnp.asarray(
-                    np.concatenate([last_idx[singles],
-                                    np.zeros(bs_pad - bs, np.int64)]),
-                    jnp.int32)
-                s_seqs = [seq_ids[i] for i in singles]
-                shape = ("decode", bs_pad, 1, mp_pad)
-                self._count_kernel_shape(pad_to, shape)
-                s_plan = (s_idx, s_seqs, bs, bs_pad)
-            if multis:
-                t_pad = _pow2(max(counts[i] for i in multis))
-                bm_pad = _pow2(len(multis))
-                gm, mr, mc, m_flat = _right_align_plan(
-                    multis, starts, counts, t_pad, bm_pad)
-                q_lens = [counts[i] for i in multis]
-                m_seqs = [seq_ids[i] for i in multis]
-                shape = ("prefill", bm_pad, t_pad, mp_pad)
-                self._count_kernel_shape(pad_to, shape)
-                m_plan = (gm, m_seqs, q_lens, bm_pad, mr, mc, m_flat)
+        # gather/scatter plan (host-built once, shared by every layer):
+        # ONE right-aligned ragged block for EVERY row — decode rows are
+        # q_lens=1 rows of the same kernel call (the Ragged Paged
+        # Attention shape), so each packed config compiles ONE attend
+        # program
+        t_pad = _pow2(max(counts))
+        b_pad = _pow2(b)
+        gm, mr, mc, m_flat = _right_align_plan(
+            range(b), starts, counts, t_pad, b_pad)
+        fuse = self._fusion_eligible()
+        # the fused program embeds the packed dense prologue/epilogue,
+        # so its REAL dispatch key includes the packed bucket (pad_to)
+        # — the pure attend program's does not
+        shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
+            if fuse else ("ragged", b_pad, t_pad, mp_pad)
+        self._count_kernel_shape(pad_to, shape)
+        pos_flat = jnp.asarray(pos_np)
+        if fuse:
+            # loop-invariant across layers: pad the scatter plan to
+            # the bucket ONCE (out-of-bounds fills drop in the fused
+            # program's scatters) instead of once per layer
+            mr = _pad_plan(mr, pad_to, 0)
+            mc = _pad_plan(mc, pad_to, 0)
+            m_flat = _pad_plan(m_flat, pad_to, pad_to)
         ids = Tensor(flat[:, None])
         if plan_span is not None:
-            up = [ids._data, pos]
-            if unified:
-                up += [pos_flat, gm, mr, mc, m_flat]
+            up = [ids._data, pos, pos_flat, gm, mr, mc, m_flat]
             plan_span.attrs.update(rows=b, packed=n_real, pad_to=pad_to,
                             bytes=sum(int(a.nbytes) for a in up))
 
@@ -436,20 +319,14 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                     vh = vh[0]
                     cache.append_ragged(
                         seq_ids, counts, kh[:n_real], vh[:n_real])
-                    if unified:
-                        qm = qh[gm]          # (b_pad, t_pad, nh, hd)
-                        self.chunk_stats["attend_calls"] += 1
-                        out = cache.attend_ragged(
-                            Tensor(qm), seq_ids, counts,
-                            rows_pad=b_pad, max_pages=mp_pad,
-                            window=self._window)
-                        attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
-                        attn = attn.at[m_flat].set(out._data[mr, mc])
-                    else:
-                        attn = self._attend_rows_two_kernel(
-                            cache, qh,
-                            jnp.zeros((pad_to, nh, hd), qh.dtype),
-                            s_plan, m_plan, mp_pad)
+                    qm = qh[gm]              # (b_pad, t_pad, nh, hd)
+                    self.chunk_stats["attend_calls"] += 1
+                    out = cache.attend_ragged(
+                        Tensor(qm), seq_ids, counts,
+                        rows_pad=b_pad, max_pages=mp_pad,
+                        window=self._window)
+                    attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
+                    attn = attn.at[m_flat].set(out._data[mr, mc])
                     attn_flat = Tensor(attn.reshape(pad_to, nh * hd))
                     x = x + layer.self_attn.o_proj(attn_flat)
                 with span("model.norm"):
@@ -465,34 +342,5 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                     self.model.model.norm(Tensor(xr))), logits_rows)
 
 
-def _attend_rows_two_kernel(self, cache, qh, attn, s_plan, m_plan,
-                            mp_pad):
-    """``FLAGS_ragged_attention=off``: the historical per-row-kind
-    routing — decode rows through the paged decode kernel, prefill
-    rows right-aligned through the q_lens-masked prefill kernel —
-    kept bitwise for A/B against the unified path. The codebase lint
-    (unified-attention rule) bars NEW two-kernel call sites; this is
-    the one sanctioned legacy body."""
-    if s_plan is not None:
-        s_idx, s_seqs, bs, bs_pad = s_plan
-        qs = qh[s_idx]                       # (bs_pad, nh, hd)
-        self.chunk_stats["attend_calls"] += 1
-        out = cache.attend_padded(  # trace-lint: ok (off-mode legacy two-kernel routing)
-            Tensor(qs), s_seqs, rows_pad=bs_pad,
-            max_pages=mp_pad, window=self._window)
-        attn = attn.at[s_idx[:bs]].set(out._data[:bs])
-    if m_plan is not None:
-        gm, m_seqs, q_lens, bm_pad, mr, mc, m_flat = m_plan
-        qm = qh[gm]                          # (bm_pad, t_pad, nh, hd)
-        self.chunk_stats["attend_calls"] += 1
-        out = cache.attend_prefill(  # trace-lint: ok (off-mode legacy two-kernel routing)
-            Tensor(qm), m_seqs, q_lens, rows_pad=bm_pad,
-            max_pages=mp_pad, window=self._window)
-        attn = attn.at[m_flat].set(out._data[mr, mc])
-    return attn
-
-
-PagedLlamaAdapter.decode_window = _window_logits
 PagedLlamaAdapter.prefill_chunk = _prefill_chunk
-PagedLlamaAdapter._attend_rows_two_kernel = _attend_rows_two_kernel
-del _window_logits, _prefill_chunk, _attend_rows_two_kernel
+del _prefill_chunk

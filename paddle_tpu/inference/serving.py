@@ -301,8 +301,7 @@ class BatchScheduler:
                  prefill_chunk_tokens=None, serving_buckets=None,
                  prefix_align=1, slo=None, watchdog=None,
                  max_queue=None, max_inflight_per_tenant=None,
-                 preempt=None, swap_bytes=None, fault_injector=None,
-                 spec_decode=None):
+                 preempt=None, swap_bytes=None, fault_injector=None):
         self.model = model
         self.max_batch_size = int(max_batch_size)
         self.page_watermark = float(page_watermark)
@@ -310,19 +309,6 @@ class BatchScheduler:
         self._queue = collections.deque()
         self._active = {}
         self._finished = {}
-        # speculative-decoding lowering (ISSUE 19): 'ragged' packs
-        # verify windows as rows of the ordinary prefill_chunk step,
-        # 'legacy' keeps the PR-4 decode_window pass for A/B, 'off'
-        # ignores the draft entirely (the trivial non-spec baseline)
-        self.spec_mode = str(
-            flag("spec_decode") if spec_decode is None
-            else spec_decode).lower()
-        if self.spec_mode not in ("off", "legacy", "ragged"):
-            raise ValueError(
-                "spec_decode must be 'off', 'legacy' or 'ragged', "
-                f"got {self.spec_mode!r} (FLAGS_spec_decode)")
-        if self.spec_mode == "off":
-            draft_model = None
         # chunked prefill (module docstring): None -> auto (on when
         # the model implements prefill_chunk), True/False force.
         # Models that only speak decode_token keep the token-per-step
@@ -345,23 +331,26 @@ class BatchScheduler:
         # land only BETWEEN steps — apply_capacity_config refuses to
         # run while this is True
         self._in_step = False
-        # speculative prompt phase rides chunked prefill only when the
-        # DRAFT adapter can mirror the chunks too
-        self._spec_chunked = self.chunked_prefill and (
-            draft_model is None
-            or hasattr(draft_model, "prefill_chunk"))
-        # unified ragged spec (ISSUE 19): verify windows ride the
+        # speculative decoding (ISSUE 19): verify windows ride the
         # ordinary packed prefill_chunk step as (k+1)-token rows, so a
         # decode round is two bucketed ragged programs (draft propose +
-        # target verify) instead of a per-round decode_window pass.
-        # Needs chunked prefill on both adapters and the per-position
-        # logits epilogue (prefill_chunk(..., logits_rows=)).
-        self._spec_ragged = bool(
-            draft_model is not None
-            and self.spec_mode == "ragged"
-            and self._spec_chunked
-            and hasattr(draft_model, "prefill_chunk")
-            and _accepts_logits_rows(model))
+        # target verify). Needs the chunked step on both adapters and
+        # the per-position logits epilogue on the target.
+        if draft_model is not None:
+            missing = []
+            if not hasattr(draft_model, "prefill_chunk"):
+                missing.append("the draft adapter has no prefill_chunk")
+            if not hasattr(model, "prefill_chunk"):
+                missing.append("the target adapter has no prefill_chunk")
+            elif not _accepts_logits_rows(model):
+                missing.append(
+                    "the target's prefill_chunk takes no logits_rows=")
+            elif not self.chunked_prefill:
+                missing.append("chunked_prefill=False was given")
+            if missing:
+                raise ValueError(
+                    "speculative decoding packs its verify windows as "
+                    "rows of the chunked step: " + "; ".join(missing))
         self.chunk_stats = {
             "steps": 0, "chunk_calls": 0, "prefill_tokens": 0,
             "decode_tokens": 0, "packed_tokens": 0, "padded_tokens": 0,
@@ -370,15 +359,6 @@ class BatchScheduler:
         # True builds a RadixPrefixCache over the model's own caches;
         # or pass a pre-built instance (shared across schedulers)
         if prefix_cache:
-            if draft_model is not None and not self._spec_ragged:
-                raise ValueError(
-                    "prefix caching is not supported with LEGACY "
-                    "speculative decoding: the draft adapter keeps its "
-                    "OWN KV pool, so a cached (skipped) target prefill "
-                    "would leave the draft cache without the prompt; "
-                    "spec_decode='ragged' lifts this (the ragged spec "
-                    "step refills a lagging draft cache from the "
-                    "committed prefix)")
             if prefix_cache is True:
                 from .prefix_cache import RadixPrefixCache
 
@@ -405,8 +385,8 @@ class BatchScheduler:
         # speculative decoding (upstream: the serving role of
         # fused_multi_transformer's draft-verify deployments): a small
         # draft adapter proposes draft_k tokens per sequence per round;
-        # the target verifies the whole window in ONE decode_window
-        # call. Greedy acceptance — output token-identical to the
+        # the target verifies the whole window as one row of its
+        # packed step. Greedy acceptance — output token-identical to the
         # non-speculative scheduler. Batch>1 is native: per-row
         # acceptance lengths live in the paged caches' per-sequence
         # lens (rejections roll back with cache.truncate).
@@ -444,15 +424,10 @@ class BatchScheduler:
         swap_bytes = int(flag("serving_swap_bytes")
                          if swap_bytes is None else swap_bytes)
         self.swap_space = None
-        if preempt and swap_bytes > 0 and (draft_model is None
-                                           or self._spec_ragged):
-            # legacy spec: the draft adapter keeps its OWN KV pool;
-            # swapping the target without the draft would
-            # desynchronize them, so it keeps wait-in-queue admission.
-            # Ragged spec lifts this: the draft KV is disposable — it
-            # is discarded at swap-out and re-prefilled from the
-            # committed prefix at swap-in (the draft pool never swaps,
-            # so it stays wait-free)
+        if preempt and swap_bytes > 0:
+            # a draft adapter keeps its OWN KV pool, which never swaps:
+            # the draft KV is disposable — discarded at swap-out and
+            # re-prefilled from the committed prefix at swap-in
             from ..incubate.nn.paged_cache import HostKVSwapSpace
 
             self.swap_space = HostKVSwapSpace(swap_bytes)
@@ -765,7 +740,7 @@ class BatchScheduler:
             proposed = ss["proposed_tokens"]
             rounds = ss["rounds"]
             info["spec"] = {
-                "mode": "ragged" if self._spec_ragged else "legacy",
+                "mode": "ragged",
                 "rounds": rounds,
                 "committed_tokens": ss["committed_tokens"],
                 "accept_rate": (
@@ -861,7 +836,7 @@ class BatchScheduler:
             # a speculative verify window transiently appends up to
             # draft_k+1 tokens beyond the committed prefix before the
             # rollback — admission must leave that headroom or
-            # decode_window raises mid-batch near the end
+            # the verify row raises mid-batch near the end
             limit = limit - (self.draft_k + 1)
         if limit is not None and req.total_tokens() > limit:
             raise ValueError(
@@ -1322,11 +1297,10 @@ class BatchScheduler:
                     freed += fp
                     nbytes += nb
             if self.draft is not None:
-                # ragged spec only (legacy never builds a swap space
-                # with a draft): the draft KV is disposable — discard
-                # it here and let the ragged step re-prefill it from
-                # the committed prefix after swap-in. The draft pool
-                # itself never swaps, so it stays wait-free.
+                # the draft KV is disposable — discard it here and
+                # let the spec step re-prefill it from the committed
+                # prefix after swap-in. The draft pool itself never
+                # swaps, so it stays wait-free.
                 self.draft.free(rid)
                 self.spec_stats["draft_discards"] += 1
         req.state = RequestState.SWAPPED
@@ -1986,9 +1960,8 @@ class BatchScheduler:
             apc = getattr(self.model, "attend_program_count", None)
             if apc is not None:
                 # distinct attend kernel programs (ONE per packed
-                # config under FLAGS_ragged_attention=auto|on, a
-                # decode/prefill pair per mixed config under off) —
-                # same per-scheduler namespacing as compile_count
+                # config) — same per-scheduler namespacing as
+                # compile_count
                 m.gauge("serving.attend_programs", apc)
                 m.gauge("serving.attend_programs." + self._sched_uid,
                         apc)
@@ -2190,9 +2163,7 @@ class BatchScheduler:
                     "prefill_tokens": 0, "decode_tokens": 0}
 
         if self.draft is not None:
-            if self._spec_ragged:
-                return self._step_spec_ragged(admitted, hit_tokens)
-            return self._step_spec(admitted)
+            return self._step_spec_ragged(admitted, hit_tokens)
         if self.chunked_prefill:
             return self._step_chunked(admitted, hit_tokens)
 
@@ -2403,146 +2374,15 @@ class BatchScheduler:
                 self.model, "attend_program_count", None),
         }
 
-    def _step_spec(self, admitted) -> dict:
-        """Speculative scheduler step: prefill rows advance on BOTH
-        adapters — chunked (one ``prefill_chunk`` call per adapter
-        under the shared token budget) when both adapters implement
-        it, one prompt token per step otherwise; decode rows run one
-        draft-propose / target-verify round each, committing
-        1..draft_k+1 tokens. Output is token-identical to the plain
-        greedy scheduler."""
-        sids = sorted(self._active)
-        pre = [s for s in sids
-               if self._active[s].state == RequestState.PREFILL]
-        dec = [s for s in sids
-               if self._active[s].state == RequestState.DECODE]
-        finished = 0
-        advanced = 0
-        pre_tokens = 0
-        dec_tokens = 0
-
-        if pre and self._spec_chunked:
-            rows, feeds, starts, n_pre, _ = self._chunk_feeds(pre)
-            packed = sum(len(f) for f in feeds)
-            pad_to = bucket_packed_tokens(packed, self.serving_buckets)
-            with self._span("serving.prefill_chunk", rows=len(rows),
-                            packed=packed, pad_to=pad_to,
-                            prefill=n_pre, decode=0):
-                logits = self.model.prefill_chunk(
-                    feeds, rows, starts, pad_to=pad_to)
-                # mirror the prompt chunks into the draft's own pool
-                self.draft.prefill_chunk(feeds, rows, starts,
-                                         pad_to=pad_to)
-            logits_np = self._pull(logits)
-            cs = self.chunk_stats
-            cs["steps"] += 1
-            cs["chunk_calls"] += 2
-            cs["prefill_tokens"] += n_pre
-            cs["packed_tokens"] += packed
-            cs["padded_tokens"] += pad_to - packed
-            pre_tokens = n_pre
-            for bi, s in enumerate(rows):
-                finished += self._advance_prefill_row(
-                    self._active[s], feeds[bi], logits_np[bi])
-            advanced += len(rows)
-        elif pre:
-            feed = [self._active[s].prompt_ids[self._active[s]._pos]
-                    for s in pre]
-            logits = self.model.decode_token(feed, pre)
-            self.draft.decode_token(feed, pre)  # mirror the prompt
-            logits_np = self._pull(logits)
-            for bi, s in enumerate(pre):
-                req = self._active[s]
-                tok = req.prompt_ids[req._pos]
-                req._pos += 1
-                if self._traces is not None:
-                    self._traces.event(
-                        req.req_id, "prefill_chunk",
-                        telemetry.clock(), self._step_epoch,
-                        tokens=1, pos=req._pos)
-                if req.on_token is not None:
-                    req.on_token(req, tok, True)
-                if req._pos == len(req.prompt_ids):
-                    if req.max_new_tokens == 0:
-                        self._retire(req)
-                        finished += 1
-                        continue
-                    req.state = RequestState.DECODE
-                    first = int(np.argmax(logits_np[bi]))
-                    req.generated_ids.append(first)
-                    self._note_gen_token(req)
-                    if req.on_token is not None:
-                        req.on_token(req, first, False)
-                    if self._done(req, first):
-                        self._retire(req)
-                        finished += 1
-            advanced += len(pre)
-            pre_tokens = len(pre)
-
-        if dec:
-            k = self.draft_k
-            base_t = {s: self.model.caches[0].seq_len(s) for s in dec}
-            base_d = {s: self.draft.caches[0].seq_len(s) for s in dec}
-            cur = [self._active[s].generated_ids[-1] for s in dec]
-            with self._span("serving.decode", rows=len(dec),
-                            draft_k=k):
-                props = []
-                for _ in range(k):
-                    dl = self._pull(
-                        self.draft.decode_token(cur, dec)._data)
-                    cur = [int(np.argmax(dl[i]))
-                           for i in range(len(dec))]
-                    props.append(cur)
-                # feed the k-th proposal too, so the draft cache never
-                # lags the committed prefix (rejections roll back by
-                # truncate)
-                self.draft.decode_token(cur, dec)
-                windows = np.asarray(
-                    [[self._active[s].generated_ids[-1]]
-                     + [props[j][i] for j in range(k)]
-                     for i, s in enumerate(dec)], np.int64)
-                # the legacy dense verify pass this PR's unified
-                # ragged lowering replaces — kept verbatim behind
-                # FLAGS_spec_decode=legacy as the A/B oracle
-                tl = self.model.decode_window(windows, dec)  # trace-lint: ok(legacy A/B lowering)
-                preds = np.argmax(
-                    self._pull(tl._data), axis=-1)  # (B, k+1)
-                self.spec_stats["rounds"] += 1
-                self.spec_stats["target_calls"] += 1
-                self.spec_stats["draft_calls"] += k + 1
-                if self._metrics is not None:
-                    self._metrics.inc("serving.spec_rounds")
-
-                # accept/commit (and retire/rollback) stay inside the
-                # decode span — same schema as the non-spec paths
-                for i, s in enumerate(dec):
-                    committed, retired = self._commit_spec_row(
-                        s, [props[j][i] for j in range(k)], preds[i],
-                        base_t[s], base_d[s])
-                    dec_tokens += committed
-                    finished += int(retired)
-            advanced += len(dec)
-
-        # prefix caching is mutually exclusive with speculative
-        # decoding (see __init__), but the step summary keeps a
-        # uniform shape across both schedulers
-        return {"admitted": admitted, "advanced": advanced,
-                "finished": finished, "prefix_hit_tokens": 0,
-                "prefill_tokens": pre_tokens,
-                "decode_tokens": dec_tokens}
-
     def _commit_spec_row(self, s, props_i, preds_i, base_t, base_d):
         """Greedy acceptance for ONE spec-active decode row: commit
         the longest draft-proposal prefix matching the target's
         per-position argmax, plus the target's bonus token, then roll
         BOTH pools back to the committed prefix (everything except
-        the newest token, which feeds the next round). Shared by the
-        legacy ``decode_window`` path and the unified ragged step —
-        one acceptance rule is the token-identity guarantee between
-        the two lowerings. ``props_i`` is the row's draft_k
-        proposals; ``preds_i`` the target argmax at each of the
-        draft_k+1 window positions; ``base_t``/``base_d`` the
-        target/draft cache lengths before the round. Returns
+        the newest token, which feeds the next round). ``props_i`` is
+        the row's draft_k proposals; ``preds_i`` the target argmax at
+        each of the draft_k+1 window positions; ``base_t``/``base_d``
+        the target/draft cache lengths before the round. Returns
         ``(committed, retired)``."""
         req = self._active[s]
         k = len(props_i)
@@ -2596,23 +2436,21 @@ class BatchScheduler:
         return committed, False
 
     def _step_spec_ragged(self, admitted, hit_tokens) -> dict:
-        """Unified speculative scheduler step (ISSUE 19,
-        ``FLAGS_spec_decode=ragged``): one decode round is exactly
-        TWO bucketed ragged program families. The draft adapter
-        proposes ``draft_k`` tokens through its OWN chunked step —
-        call 0 packs every propose row together with prompt-mirror
-        chunks and draft-refill rows, calls 1..k feed successive
-        proposals (the k-th feed keeps the draft pool at committed
-        prefix + window, as in the legacy path) — then the target
-        verifies EVERY window in the ordinary :meth:`prefill_chunk`
-        step: each spec-active sequence contributes one right-aligned
+        """The speculative scheduler step (ISSUE 19): one decode
+        round is exactly TWO bucketed ragged program families. The
+        draft adapter proposes ``draft_k`` tokens through its OWN
+        chunked step — call 0 packs every propose row together with
+        prompt-mirror chunks and draft-refill rows, calls 1..k feed
+        successive proposals (the k-th feed keeps the draft pool at
+        committed prefix + window) — then the target verifies EVERY
+        window in the ordinary :meth:`prefill_chunk` step: each
+        spec-active sequence contributes one right-aligned
         ``draft_k+1``-token row next to the regular prefill-chunk
-        rows, and the per-position logits epilogue
-        (``logits_rows=``) hands back the window argmax for greedy
-        acceptance. ``cache.truncate`` rolls both pools back past
+        rows, and the per-position logits epilogue (``logits_rows=``)
+        hands back the window argmax for greedy acceptance. ``cache.truncate`` rolls both pools back past
         the first mismatch (COW/prefix-shared pages survive — page
         sanitizer strict). No per-sequence target forward exists on
-        this path (tools/lint_codebase.py ``spec-row-discipline``).
+        this path.
 
         Draft-lag rows: after a prefix-cache hit or a swap-in the
         draft pool is behind the committed prefix (its KV was never
@@ -2679,8 +2517,8 @@ class BatchScheduler:
                 if req.state == RequestState.DECODE:
                     lag_refilled += 1
             # mirror this step's prompt chunks for draft-synced
-            # prefill rows (same feed, same start — the legacy
-            # prompt-phase mirroring, packed into the same call)
+            # prefill rows (same feed, same start), packed into the
+            # same call
             for bi, r in enumerate(rows):
                 if d_cache.seq_len(r) == starts[bi]:
                     d_rows.append(r)

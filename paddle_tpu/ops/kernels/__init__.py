@@ -117,7 +117,6 @@ from .paged_attention import (  # noqa
     packed_position_index,
     paged_attention,
     paged_attention_reference,
-    paged_prefill_attention,
     paged_ragged_attention,
     paged_ragged_attention_reference,
     paged_ragged_fused_step,

@@ -32,9 +32,13 @@ TPU paged-attention recipe ("Ragged Paged Attention" — see PAPERS.md):
 GQA never replicates KV in HBM or in VMEM. Int8 pages dequantize in
 VMEM right after the page DMA (per-page per-head scale sidecars ride
 scalar prefetch). Off-TPU (tests) the same kernel runs in pallas
-interpret mode against a dense reference. The legacy decode kernel of
-``FLAGS_ragged_attention=off`` keeps the older (batch, q_heads,
-logical_pages) grid.
+interpret mode against a dense reference. :func:`paged_attention`,
+the (B, H, D) decode entry, is the T=1 shape of the same call.
+
+Latent pages (:func:`latent_ragged_attention`, MLA): one
+``(num_pages, page_size, latent_dim)`` pool holds a token's compressed
+K/V once for every head; the absorbed kernel reads it through the same
+page table, lengths and right-aligned rows.
 
 FlashFuser-style fusion (:func:`paged_ragged_fused_step`): once the
 attention path is ONE program, the packed dense neighbours fold into
@@ -42,18 +46,13 @@ it — qkv projection + RoPE + the K/V page scatter run as the kernel's
 prologue and o_proj as its epilogue, inside the same compiled program,
 so a serving layer step is a single dispatch instead of five.
 
-``FLAGS_ragged_attention`` gates the dispatch: ``auto``/``on`` route
-the legacy decode entry through the ragged kernel at T=1; ``off``
-restores the historical dedicated decode kernel bitwise (and the
-serving adapter's two-kernel row routing with it).
-
 Dispatch caching: eager callers (the serving step loop, tests) hit a
 shape-keyed LRU of ``jax.jit``-ted entry points, so stepping the same
 shapes never re-traces the pallas call — the historical per-call
-build cost was pure trace/compile overhead. The unified kernel keys
-ONE cache for every row kind (no decode/prefill split). Callers
-already under an outer trace (``to_static``) inline the identical
-lowering; the surrounding program owns compilation and caching there.
+build cost was pure trace/compile overhead. The kernel keys ONE
+cache for every row kind. Callers already under an outer trace
+(``to_static``) inline the identical lowering; the surrounding program
+owns compilation and caching there.
 """
 from __future__ import annotations
 
@@ -67,160 +66,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...framework import telemetry
-from ...framework.flags import flag
 from . import on_tpu  # defined before the package imports its kernels
 from .rope import apply_rotary_emb
 
 NEG_INF = -1e30
-
-
-def _scale_index(phys_page, q_head, group):
-    """Flat index of (page, kv head) into the scale sidecars. They ride
-    scalar prefetch FLATTENED: a 2-D SMEM operand pads its last dim to
-    128 words (2048 pages x 8 heads would take 1 MiB each — all of
-    SMEM), a 1-D one only rounds up its length."""
-    return phys_page * (pl.num_programs(1) // group) + q_head // group
-
-
-def _decode_kernel(scale, page_size, kvh_per_q, max_pages, window,
-                   quant, *refs):
-    """Legacy dedicated decode kernel — the FLAGS_ragged_attention=off
-    lowering. The unified :func:`_ragged_kernel` at T=1 supersedes it;
-    kept verbatim so ``off`` restores the historical program bitwise."""
-    if quant:
-        # int8 pages: per-page, per-head scale sidecars ride scalar
-        # prefetch; dequant happens in VMEM right after the page DMA
-        (page_tbl_ref, lens_ref, k_scale_ref, v_scale_ref,
-         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (page_tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-        k_scale_ref = v_scale_ref = None
-    b = pl.program_id(0)
-    hq = pl.program_id(1)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _():
-        # m/l live in SMEM: the TPU lowering stores scalars there only
-        m_ref[0, 0] = NEG_INF
-        l_ref[0, 0] = 0.0
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    seq_len = lens_ref[b]
-    # tokens covered by this logical page: [p*page_size, ...). With a
-    # sliding window the decode token (position seq_len-1) only sees
-    # keys >= seq_len - window, so pages wholly below that are skipped
-    # (real work saved, not just masked).
-    valid = p * page_size < seq_len
-    if window:
-        valid = valid & ((p + 1) * page_size > seq_len - window)
-
-    @pl.when(valid)
-    def _():
-        q = q_ref[0, 0]                   # (1, D) — the decode token
-        k = k_ref[0, 0]                   # (page_size, D)
-        v = v_ref[0, 0]
-        if quant:
-            si = _scale_index(page_tbl_ref[b, p], hq, kvh_per_q)
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * k_scale_ref[si]
-            v = v.astype(jnp.float32) * v_scale_ref[si]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                          # (1, page_size)
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        keep = pos < seq_len
-        if window:
-            keep = keep & (pos >= seq_len - window)
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s))
-        corr = jnp.exp(m_prev - m_cur)
-        pvals = jnp.exp(s - m_cur)
-        l_ref[0, 0] = corr * l_ref[0, 0] + jnp.sum(pvals)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pvals.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[0, 0] = m_cur
-
-    @pl.when(p == max_pages - 1)
-    def _():
-        safe_l = jnp.maximum(l_ref[0, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-
-
-def _build_decode_call(b, h, d, npages, page_size, kvh, max_pages,
-                       scale, window, quant, interpret):
-    """The legacy decode pallas dispatch as a pure function of the
-    static config: returns ``run(q, k_pages, v_pages, *scalar_args)``.
-    Traced callers inline it (identical to the historical lowering);
-    eager callers go through :func:`_jitted_decode_call`'s cached
-    ``jax.jit`` of the same body, so a serving loop stepping the same
-    shapes never re-traces the kernel."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    group = h // kvh
-
-    def q_map(b_, h_, p_, *pref):
-        return (b_, h_, 0, 0)
-
-    def kv_map(b_, h_, p_, tbl, *pref):
-        return (h_ // group, tbl[b_, p_], 0, 0)
-
-    n_scalars = 4 if quant else 2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalars,
-        grid=(b, h, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d), q_map),
-            pl.BlockSpec((1, 1, page_size, d), kv_map),
-            pl.BlockSpec((1, 1, page_size, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, d), q_map),
-        scratch_shapes=[
-            pltpu.SMEM((1, 1), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel, scale, page_size, group, max_pages, window,
-        quant,
-    )
-
-    def run(q, k_pages, v_pages, *scalar_args):
-        # (NP, P, KVH, D) -> (KVH, NP, P, D): page-major per kv head
-        kp = jnp.transpose(k_pages, (2, 0, 1, 3))
-        vp = jnp.transpose(v_pages, (2, 0, 1, 3))
-        q4 = q.reshape(b, h, 1, d)
-        out = pl.pallas_call(
-            kernel,
-            name="paged_decode_attention",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")
-            ) if not interpret else None,
-        )(
-            *scalar_args,
-            q4, kp.reshape(kvh, npages, page_size, d),
-            vp.reshape(kvh, npages, page_size, d),
-        )
-        return out.reshape(b, h, d)
-
-    return run
-
-
-@functools.lru_cache(maxsize=512)
-def _jitted_decode_call(cfg):
-    return jax.jit(_build_decode_call(*cfg))
 
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
@@ -239,47 +88,17 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
     int8 (half the HBM traffic) and dequantize in VMEM inside the
     kernel, scales riding scalar prefetch.
 
-    .. deprecated:: this is now a thin T=1 wrapper over the unified
-       :func:`paged_ragged_attention` kernel (one compiled program per
-       packed config serves decode AND prefill rows). Under
-       ``FLAGS_ragged_attention=off`` the historical dedicated decode
-       kernel lowers bitwise instead.
+    This is the T=1 shape wrapper over :func:`paged_ragged_attention`:
+    a decode batch is a packed batch whose rows all have ``q_len`` 1,
+    and compiles the same program as one.
     """
-    b, h, d = q.shape
-    if str(flag("ragged_attention")) != "off":
-        out = paged_ragged_attention(
-            q[:, None], k_pages, v_pages, page_table, seq_lens,
-            q_lens=jnp.ones((b,), jnp.int32), sm_scale=sm_scale,
-            interpret=interpret, window=window, k_scales=k_scales,
-            v_scales=v_scales)
-        return out[:, 0]
-    npages, page_size, kvh, _ = k_pages.shape
-    max_pages = page_table.shape[1]
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    quant = k_scales is not None
-    if quant != (v_scales is not None):
-        raise ValueError(
-            "paged_attention: pass both k_scales and v_scales or "
-            "neither")
-
-    if interpret is None:
-        interpret = not on_tpu()
-
-    scalar_args = [page_table.astype(jnp.int32),
-                   seq_lens.astype(jnp.int32)]
-    if quant:
-        scalar_args += [k_scales.astype(jnp.float32).reshape(-1),
-                        v_scales.astype(jnp.float32).reshape(-1)]
-    cfg = (b, h, d, npages, page_size, kvh, max_pages, float(scale),
-           int(window or 0), quant, bool(interpret))
-    args = (q, k_pages, v_pages, *scalar_args)
-    if any(isinstance(x, jax.core.Tracer) for x in args):
-        # already under an outer trace (to_static / jit): inline —
-        # the surrounding program owns compilation and caching
-        return _build_decode_call(*cfg)(*args)
-    # eager serving/test loops: same shapes hit the cached compiled
-    # program instead of re-tracing the pallas call every step
-    return _jitted_decode_call(cfg)(*args)
+    b = q.shape[0]
+    out = paged_ragged_attention(
+        q[:, None], k_pages, v_pages, page_table, seq_lens,
+        q_lens=jnp.ones((b,), jnp.int32), sm_scale=sm_scale,
+        interpret=interpret, window=window, k_scales=k_scales,
+        v_scales=v_scales)
+    return out[:, 0]
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table,
@@ -633,28 +452,14 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
         return _jitted_ragged_call(cfg)(*args)
 
 
-def paged_prefill_attention(q, k_pages, v_pages, page_table, seq_lens,
-                            sm_scale=None, interpret=None, window=0,
-                            k_scales=None, v_scales=None, q_lens=None):
-    """Ragged chunked-prefill over a paged KV cache.
-
-    .. deprecated:: alias of :func:`paged_ragged_attention` — the
-       q_lens-masked prefill kernel WAS the unified ragged kernel all
-       along; this name is kept for existing callers and compiles the
-       identical program (there is no separate prefill lowering to
-       restore under ``FLAGS_ragged_attention=off``).
-    """
-    return paged_ragged_attention(
-        q, k_pages, v_pages, page_table, seq_lens, q_lens=q_lens,
-        sm_scale=sm_scale, interpret=interpret, window=window,
-        k_scales=k_scales, v_scales=v_scales)
-
-
 def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
                        scale, window, quant, ragged, interpret):
     """The unified ragged pallas dispatch as a pure function of the
-    static config — same inline-under-trace / cached-jit-when-eager
-    split as :func:`_build_decode_call`. The pools stay in HBM as they
+    static config: returns ``run(q, k_pages, v_pages, *scalar_args)``.
+    Traced callers inline it; eager callers go through
+    :func:`_jitted_ragged_call`'s cached ``jax.jit`` of the same body,
+    so a serving loop stepping the same shapes never re-traces the
+    kernel. The pools stay in HBM as they
     are held, (NP, P, KVH, D), and the kernel copies the pages it wants
     by the scalar-prefetched table (on the chip any other view of the
     pool, the lane-merged (NP, P, KVH * D) one included, is a copy of

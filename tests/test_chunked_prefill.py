@@ -378,7 +378,7 @@ class TestBucketHelper:
 
 class TestRaggedPrefillKernel:
     def test_q_lens_masks_padded_rows(self):
-        from paddle_tpu.ops.kernels import paged_prefill_attention
+        from paddle_tpu.ops.kernels import paged_ragged_attention
 
         rng = np.random.RandomState(3)
         np_, p, kvh, d, h = 8, 4, 2, 8, 2
@@ -389,14 +389,14 @@ class TestRaggedPrefillKernel:
         t = 4
         q = rng.randn(2, t, h, d).astype("float32")
         q_lens = np.asarray([4, 2], np.int32)
-        out = np.asarray(paged_prefill_attention(
+        out = np.asarray(paged_ragged_attention(
             jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(tbl), jnp.asarray(lens),
             q_lens=jnp.asarray(q_lens)))
         # padded leading rows are exact zeros
         np.testing.assert_array_equal(out[1, :2], 0.0)
         # real rows match the unmasked kernel at matching alignment
-        full = np.asarray(paged_prefill_attention(
+        full = np.asarray(paged_ragged_attention(
             jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(tbl), jnp.asarray(lens)))
         np.testing.assert_allclose(out[0], full[0], rtol=1e-5,

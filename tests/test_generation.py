@@ -332,32 +332,6 @@ class TestSchedulerSpeculative:
         tpc = stats["committed_tokens"] / stats["target_calls"]
         assert tpc > 1.0, stats  # strictly better than 1 token/call
 
-    def test_decode_window_matches_sequential(self):
-        from paddle_tpu.inference.paged_llama import PagedLlamaAdapter
-        from paddle_tpu.models import LlamaForCausalLM, llama_tiny
-
-        cfg = llama_tiny()
-        paddle.seed(0)
-        model = LlamaForCausalLM(cfg)
-        a1 = PagedLlamaAdapter(model, num_pages=64, page_size=4)
-        a2 = PagedLlamaAdapter(model, num_pages=64, page_size=4)
-        for s in ("r0", "r1"):
-            a1.alloc(s)
-            a2.alloc(s)
-        rng = np.random.RandomState(0)
-        toks = rng.randint(0, cfg.vocab_size, (2, 6))
-        outs1 = []
-        for j in range(6):
-            l = a1.decode_token(toks[:, j].tolist(), ["r0", "r1"])
-            outs1.append(np.asarray(l._data))
-        outs1 = np.stack(outs1, axis=1)
-        for j in range(3):
-            a2.decode_token(toks[:, j].tolist(), ["r0", "r1"])
-        outs2 = np.asarray(
-            a2.decode_window(toks[:, 3:], ["r0", "r1"])._data)
-        np.testing.assert_allclose(outs2, outs1[:, 3:], rtol=2e-4,
-                                   atol=2e-4)
-
     def test_cache_truncate_rollback(self):
         from paddle_tpu.incubate.nn import PagedKVCacheManager
 

@@ -277,8 +277,7 @@ K = jnp.zeros((1, 1, 40), jnp.float32)
     ("append", lambda p, sp: p.append("s", K[0], K[0])),
     ("append_batch", lambda p, sp: p.append_batch(["s"], K, K)),
     ("append_ragged", lambda p, sp: p.append_ragged(["s"], [1], K, K)),
-    ("attend_padded", lambda p, sp: p.attend(K, ["s"])),
-    ("attend_prefill", lambda p, sp: p.attend_prefill(K[None], ["s"], [1])),
+    ("attend", lambda p, sp: p.attend(K, ["s"])),
     ("attend_ragged", lambda p, sp: p.attend_ragged(K[None], ["s"], [1])),
     ("fused_ragged_step", lambda p, sp: p.fused_ragged_step(
         K[0], (K, K, K, K, None), (K, K), K, ["s"], [1], K, (K, K, K))),

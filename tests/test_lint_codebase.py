@@ -919,52 +919,14 @@ class TestFlagInventory:
 class TestUnifiedAttention:
     """ISSUE-13 satellite: packed-step attention in the serving
     layers routes through the single attend_ragged/fused_ragged_step
-    pool API — no function may re-grow the legacy attend_padded +
-    attend_prefill kernel pair, and a ragged append's function must
-    attend through the unified entry in the same scope."""
-
-    def test_seeded_two_kernel_pair_flagged(self):
-        bad = (
-            "class Adapter:\n"
-            "    def step(self, cache, q):\n"
-            "        a = cache.attend_padded(q, self.sids)\n"
-            "        b = cache.attend_prefill(q, self.sids, [2])\n"
-            "        return a, b\n"
-        )
-        v = lint_codebase.lint_unified_attention_file(
-            "fake/paged_llama.py", text=bad)
-        assert len(v) == 1, v
-        assert "attend_padded" in v[0] and "attend_prefill" in v[0]
-        assert "attend_ragged" in v[0]
-
-    def test_single_kind_call_is_clean(self):
-        # one kernel kind alone is a thin-wrapper caller (tests,
-        # decode-only paths) — only the PAIR is the two-kernel routing
-        ok = (
-            "def decode(cache, q, sids):\n"
-            "    return cache.attend_padded(q, sids)\n"
-            "def prefill(cache, q, sids):\n"
-            "    return cache.attend_prefill(q, sids, [4])\n"
-        )
-        assert lint_codebase.lint_unified_attention_file(
-            "fake/serving.py", text=ok) == []
-
-    def test_pair_waiver_suppresses(self):
-        waived = (
-            "def legacy(cache, q, sids):\n"
-            "    a = cache.attend_padded(q, sids)"
-            "  # trace-lint: ok(off-mode legacy)\n"
-            "    b = cache.attend_prefill(q, sids, [2])\n"
-            "    return a, b\n"
-        )
-        assert lint_codebase.lint_unified_attention_file(
-            "fake/paged_llama.py", text=waived) == []
+    pool API — a ragged append's function must attend through the
+    unified entry in the same scope."""
 
     def test_seeded_ragged_append_without_unified_attend(self):
         bad = (
             "def chunk(cache, sids, counts, kh, vh, q):\n"
             "    cache.append_ragged(sids, counts, kh, vh)\n"
-            "    return cache.attend_padded(q, sids)\n"
+            "    return cache.attend(q, sids)\n"
         )
         v = lint_codebase.lint_unified_attention_file(
             "fake/paged_llama.py", text=bad)
@@ -1017,62 +979,6 @@ class TestUnifiedAttention:
     def test_rule_inventory_has_unified_attention(self):
         ids = [r for r, _ in lint_codebase.RULES]
         assert "unified-attention" in ids
-
-
-class TestSpecRowDiscipline:
-    """ISSUE-19 satellite: no per-sequence target forward outside the
-    packed ragged step in the serving layers — speculative verify
-    windows ride prefill_chunk as (draft_k+1)-token rows; a
-    decode_window call is the banned legacy dispatch lane unless it
-    carries the explicit legacy-body waiver."""
-
-    def test_seeded_decode_window_call_flagged(self):
-        bad = (
-            "def verify(model, windows, sids):\n"
-            "    return model.decode_window(windows, sids)\n"
-        )
-        v = lint_codebase.lint_spec_rows_file(
-            "fake/serving.py", text=bad)
-        assert len(v) == 1, v
-        assert "decode_window" in v[0]
-        assert "prefill_chunk" in v[0]
-
-    def test_waiver_suppresses(self):
-        waived = (
-            "def verify(model, windows, sids):\n"
-            "    return model.decode_window(windows, sids)"
-            "  # trace-lint: ok(legacy A/B)\n"
-        )
-        assert lint_codebase.lint_spec_rows_file(
-            "fake/serving.py", text=waived) == []
-
-    def test_binding_the_legacy_entry_is_clean(self):
-        # defining/attaching the legacy surface is fine — only a
-        # CALL re-opens the per-sequence verify dispatch lane
-        ok = (
-            "def _window_logits(self, windows, sids):\n"
-            "    return windows\n"
-            "class A:\n"
-            "    pass\n"
-            "A.decode_window = _window_logits\n"
-        )
-        assert lint_codebase.lint_spec_rows_file(
-            "fake/paged_llama.py", text=ok) == []
-
-    def test_serving_layers_covered_and_clean(self):
-        covered = [os.path.join(REPO, f)
-                   for f in lint_codebase.SPEC_ROW_FILES]
-        assert any(p.endswith(os.path.join("inference", "serving.py"))
-                   for p in covered)
-        for p in covered:
-            assert os.path.exists(p), p
-        # the retained legacy body carries its waiver; everything
-        # else routes verify through the packed ragged step
-        assert lint_codebase.check_spec_rows() == []
-
-    def test_rule_inventory_has_spec_row_discipline(self):
-        ids = [r for r, _ in lint_codebase.RULES]
-        assert "spec-row-discipline" in ids
 
 
 class TestWireQuantOwnership:

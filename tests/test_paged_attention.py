@@ -176,7 +176,7 @@ class TestPagedPrefill:
             jnp.int32)
         lens = jnp.asarray([27, 12], jnp.int32)
         q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-        out = pa.paged_prefill_attention(q, kp, vp, tbl, lens)
+        out = pa.paged_ragged_attention(q, kp, vp, tbl, lens)
         ref = self._ref(q, kp, vp, tbl, lens, P, H, KVH, D, T)
         np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4)
 
@@ -199,8 +199,8 @@ class TestPagedPrefill:
             jnp.int32)
         lens = jnp.asarray([27, 12], jnp.int32)
         q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-        out = pa.paged_prefill_attention(q, kp, vp, tbl, lens,
-                                         window=window)
+        out = pa.paged_ragged_attention(q, kp, vp, tbl, lens,
+                                        window=window)
         ref = self._ref(q, kp, vp, tbl, lens, P, H, KVH, D, T,
                         window=window)
         np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4)
@@ -220,7 +220,7 @@ class TestPagedPrefill:
             jnp.int32)
         lens = jnp.asarray([20, 9], jnp.int32)
         q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-        pre = pa.paged_prefill_attention(q, kp, vp, tbl, lens)
+        pre = pa.paged_ragged_attention(q, kp, vp, tbl, lens)
         dec = pa.paged_attention(q[:, -1], kp, vp, tbl, lens)
         np.testing.assert_allclose(
             np.asarray(pre[:, -1]), np.asarray(dec), atol=1e-5)

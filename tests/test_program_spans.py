@@ -382,7 +382,6 @@ def test_ring_holds_a_session_at_a_hundred_times_todays_steps():
 
 @pytest.mark.parametrize("build,name", [
     ("ragged", "ragged_paged_attention"),
-    ("decode", "paged_decode_attention"),
     ("rms", "rms_norm"),
     ("flash_fwd", "flash_fwd"),
     ("flash_bwd", "flash_bwd_dkv"),
@@ -407,12 +406,6 @@ def test_pallas_kernels_carry_a_stable_name(build, name):
                 jnp.zeros((8, 4, 2, 64), f32),
                 jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32),
                 jnp.ones((2,), jnp.int32))
-    elif build == "decode":
-        fn = pa._build_decode_call(2, 2, 64, 8, 4, 2, 2, 0.125, 0,
-                                   False, True)
-        args = (jnp.zeros((2, 2, 64), f32), jnp.zeros((8, 4, 2, 64), f32),
-                jnp.zeros((8, 4, 2, 64), f32),
-                jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32))
     elif build == "rms":
         fn = lambda x, w: rn._rms_pallas(x, w, 1e-6, True)  # noqa: E731
         args = (jnp.ones((8, 128), f32), jnp.ones((128,), f32))

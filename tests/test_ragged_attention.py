@@ -5,12 +5,14 @@ rows and multi-token prefill chunks alike carry their own q_lens and
 ride right-aligned through ONE program per packed config, replacing
 the decode/prefill kernel pair. The acceptance matrix here: kernel
 parity vs the dense reference for decode-only / prefill-only / mixed
-batches x kv {float32, int8} x window on/off, the pool's
-attend_ragged vs the legacy pair, warm LRU-dispatch reuse across pool
-instances, the FlashFuser-fused prologue/epilogue (qkv + RoPE + page
-scatter in, o_proj out), end-to-end scheduler greedy identity across
-FLAGS_ragged_attention={off,on,auto} x prefix on/off, and the attend
-program count bound (one program per config, not two).
+batches x kv {float32, int8} x window on/off, the (B, H, D) decode
+entries as the T=1 shape of the same call, the one place the pool
+builds a step's tables, warm LRU-dispatch reuse across pool instances,
+the FlashFuser-fused prologue/epilogue (qkv + RoPE + page scatter in,
+o_proj out), which body an adapter runs from what it can observe,
+end-to-end greedy identity of both bodies with the token-per-step
+scheduler x prefix on/off, and the attend program count bound (one
+program per config).
 """
 import numpy as np
 import pytest
@@ -29,20 +31,13 @@ from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.ops.kernels.paged_attention import (
     _jitted_ragged_call,
     paged_attention,
+    paged_attention_reference,
     paged_ragged_attention,
     paged_ragged_attention_reference,
 )
 
 PAGE = 4
 _slow = pytest.mark.slow
-
-
-@pytest.fixture(autouse=True)
-def _auto_mode():
-    """Every test starts from the default unified dispatch."""
-    paddle.set_flags({"ragged_attention": "auto"})
-    yield
-    paddle.set_flags({"ragged_attention": "auto"})
 
 
 def _pages(rng, NP, P, KVH, D, quant=False):
@@ -309,114 +304,221 @@ class TestGridStructure:
         assert span.attrs["grid_steps"] == rows * -(-maxp // min(16, maxp))
 
 
-class TestThinWrappers:
-    """Satellite: the legacy entries stay as thin wrappers — decode
-    routes through the unified kernel at T=1 under auto/on, and off
-    restores the dedicated decode kernel lowering bitwise."""
+class TestDecodeWrapper:
+    """``paged_attention(q (B, H, D), ...)`` is the T=1 shape of the
+    ragged call: against the dense DECODE reference, over what the
+    dedicated decode kernel's tests used to cover."""
 
-    def _case(self, seed=0):
-        rng = np.random.RandomState(seed)
-        B, H, KVH, D, NP, P, MAXP = 2, 4, 2, 32, 8, 8, 3
+    @pytest.mark.parametrize("quant", [False, True],
+                             ids=["float", "int8"])
+    @pytest.mark.parametrize("window", [0, 6])
+    def test_t1_wrapper_matches_decode_reference(self, window, quant):
+        rng = np.random.RandomState(0)
+        B, H, KVH, D, NP, P, MAXP = 3, 4, 2, 32, 12, 8, 3
         q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(NP, P, KVH, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(NP, P, KVH, D), jnp.float32)
+        kp, vp, ks, vs = _pages(rng, NP, P, KVH, D, quant)
         tbl = jnp.asarray(
-            rng.permutation(NP)[:B * MAXP].reshape(B, MAXP),
-            jnp.int32)
-        lens = jnp.asarray([20, 9], jnp.int32)
-        return q, kp, vp, tbl, lens
+            rng.permutation(NP)[:B * MAXP].reshape(B, MAXP), jnp.int32)
+        lens = jnp.asarray([20, 9, 1], jnp.int32)
+        out = paged_attention(q, kp, vp, tbl, lens, window=window,
+                              k_scales=ks, v_scales=vs)
+        ref = paged_attention_reference(q, kp, vp, tbl, lens,
+                                        window=window, k_scales=ks,
+                                        v_scales=vs)
+        assert out.shape == (B, H, D)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-4,
+                                   rtol=2e-4)
+        # the same program as a packed batch of q_len-1 rows, bitwise
+        rag = paged_ragged_attention(
+            q[:, None], kp, vp, tbl, lens,
+            q_lens=jnp.ones((B,), jnp.int32), window=window,
+            k_scales=ks, v_scales=vs)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(rag[:, 0]))
 
-    def test_decode_wrapper_matches_legacy_kernel(self):
-        q, kp, vp, tbl, lens = self._case()
-        out = paged_attention(q, kp, vp, tbl, lens)   # unified T=1
-        paddle.set_flags({"ragged_attention": "off"})
-        legacy = paged_attention(q, kp, vp, tbl, lens)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(legacy),
-                                   atol=1e-6)
 
-    def test_off_restores_decode_lowering_bitwise(self):
-        # under off the public wrapper lowers EXACTLY the historical
-        # dedicated decode program (jaxpr-identical to the builder)
-        from paddle_tpu.ops.kernels.paged_attention import (
-            _build_decode_call,
-        )
+def _filled_pool(kv=None, seed=2, lens=(6, 9, 1), **kw):
+    rng = np.random.RandomState(seed)
+    pool = PagedKVCacheManager(32, PAGE, 2, 8, dtype=jnp.float32,
+                               kv_dtype=kv, **kw)
+    for i, n in enumerate(lens):
+        sid = f"s{i}"
+        pool.alloc(sid)
+        for _ in range(n):
+            pool.append(sid, rng.randn(2, 8).astype("float32"),
+                        rng.randn(2, 8).astype("float32"))
+    return pool, rng
 
-        q, kp, vp, tbl, lens = self._case()
-        paddle.set_flags({"ragged_attention": "off"})
-        b, h, d = q.shape
-        npages, P, kvh, _ = kp.shape
-        import math
 
-        cfg = (b, h, d, npages, P, kvh, tbl.shape[1],
-               1.0 / math.sqrt(d), 0, False, True)
-        wrapped = jax.make_jaxpr(
-            lambda *a: paged_attention(*a, interpret=True))(
-            q, kp, vp, tbl, lens)
-        direct = jax.make_jaxpr(_build_decode_call(*cfg))(
-            q, kp, vp, tbl, lens)
-        assert str(wrapped) == str(direct)
+class TestPoolAttend:
+    @pytest.mark.parametrize("kv", [None, "int8"],
+                             ids=["float", "int8"])
+    def test_attend_is_attend_ragged_at_t1(self, kv):
+        pool, rng = _filled_pool(kv=kv)
+        sids = ["s0", "s1", "s2"]
+        q = jnp.asarray(rng.randn(3, 2, 8), jnp.float32)
+        out = pool.attend(q, sids, window=5)
+        rag = pool.attend_ragged(q[:, None], sids, [1, 1, 1], window=5)
+        assert out.shape == [3, 2, 8]
+        np.testing.assert_array_equal(out.numpy(), rag.numpy()[:, 0])
+        # and the kernel's own (B, H, D) entry over the pool's arrays
+        ks = pool.k_scales if pool.quantized else None
+        vs = pool.v_scales if pool.quantized else None
+        direct = paged_attention(
+            q, pool.k_pages, pool.v_pages, pool.page_table(sids),
+            pool.seq_lens(sids), window=5, k_scales=ks, v_scales=vs)
+        np.testing.assert_array_equal(out.numpy(), np.asarray(direct))
 
-    def test_prefill_wrapper_is_unified_alias(self):
-        rng = np.random.RandomState(1)
-        from paddle_tpu.ops.kernels import paged_prefill_attention
 
-        B, T, H, KVH, D, NP, P, MAXP = 2, 3, 4, 2, 32, 8, 8, 3
-        q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(NP, P, KVH, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(NP, P, KVH, D), jnp.float32)
-        tbl = jnp.asarray(
-            rng.permutation(NP)[:B * MAXP].reshape(B, MAXP),
-            jnp.int32)
-        lens = jnp.asarray([14, 9], jnp.int32)
-        ql = jnp.asarray([3, 2], jnp.int32)
-        a = paged_prefill_attention(q, kp, vp, tbl, lens, q_lens=ql)
-        b_ = paged_ragged_attention(q, kp, vp, tbl, lens, q_lens=ql)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+def _latent_pool(**kw):
+    pool = PagedKVCacheManager(8, 16, 1, 40, dtype=jnp.float32,
+                               page_format="latent", **kw)
+    pool.alloc("s0")
+    return pool
+
+
+def _call_attend_ragged(**kw):
+    pool, rng = _filled_pool(**kw)
+    q = jnp.asarray(rng.randn(4, 2, 2, 8), jnp.float32)
+    return pool, lambda: pool.attend_ragged(
+        q, ["s0", "s1"], [2, 1], rows_pad=4, max_pages=4)
+
+
+def _call_fused_step(**kw):
+    from paddle_tpu.ops.kernels.rope import build_rope_cache
+
+    pool, rng = _filled_pool(**kw)
+    E, NH, KVH, HD, n_pad = 16, 2, 2, 8, 8
+    w = [jnp.asarray(rng.randn(*sh) * 0.1, jnp.float32) for sh in
+         ((E, NH * HD), (E, KVH * HD), (E, KVH * HD), (NH * HD, E))]
+    gm = np.zeros((2, 4), np.int32)
+    gm[0, 2:] = [0, 1]
+    gm[1, 3:] = [2]
+    plan = tuple(jnp.asarray(a, jnp.int32)
+                 for a in ([0, 0, 1], [2, 3, 3], [0, 1, 2]))
+    pos = np.zeros(n_pad, np.int32)
+    pos[:3] = [6, 7, 9]
+    x = jnp.asarray(rng.randn(n_pad, E), jnp.float32)
+    return pool, lambda: pool.fused_ragged_step(
+        x, (*w, None), build_rope_cache(64, HD), jnp.asarray(pos),
+        ["s0", "s1"], [2, 1], jnp.asarray(gm), plan, rows_pad=2,
+        max_pages=4)
+
+
+def _call_latent_step(**kw):
+    pool = _latent_pool(**kw)
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(8, 2, 40), jnp.float32)
+    toks = jnp.asarray(rng.randn(8, 40), jnp.float32)
+    gm = np.zeros((1, 8), np.int32)
+    gm[0, 3:] = np.arange(5)
+    return pool, lambda: pool.latent_ragged_step(
+        q, toks, ["s0"], [5], jnp.asarray(gm), 32, rows_pad=1,
+        max_pages=2)
+
+
+_TABLE_CALLERS = {"attend_ragged": _call_attend_ragged,
+                  "fused_ragged_step": _call_fused_step,
+                  "latent_ragged_step": _call_latent_step}
+
+
+class TestStepTables:
+    """The pool builds what a step's kernel reads beside the pages —
+    page table, lens, q_lens, the padded slot plan — in ONE function,
+    under the one ``pool.table`` span site."""
+
+    def test_padding_rows_carry_length_0_and_q_len_0(self):
+        pool, _ = _filled_pool()
+        tbl, lens, ql = pool._step_tables(["s0", "s1"], [2, 1], 4, 4)
+        assert tbl.shape == (4, 4) and tbl.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(lens), [6, 9, 0, 0])
+        np.testing.assert_array_equal(np.asarray(ql), [2, 1, 0, 0])
+        assert ql.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(tbl)[2:], 0)
+        assert list(np.asarray(tbl)[1, :3]) == pool.seq_pages("s1")
+
+    @pytest.mark.parametrize("max_pages,want", [(None, 3), (2, 3),
+                                                (8, 8)])
+    def test_width_is_max_of_max_pages_and_longest_chain(self, max_pages,
+                                                         want):
+        pool, _ = _filled_pool()          # s1: 9 tokens = 3 pages of 4
+        tbl, _, _ = pool._step_tables(["s0", "s1"], [1, 1], None,
+                                      max_pages)
+        assert tbl.shape == (2, want)
+
+    def test_rows_pad_below_the_row_count_is_raised_to_it(self):
+        pool, _ = _filled_pool()
+        tbl, lens, ql = pool._step_tables(["s0", "s1", "s2"], [1, 1, 1],
+                                          2, 4)
+        assert tbl.shape[0] == lens.shape[0] == ql.shape[0] == 3
+
+    def test_padded_slots_carry_page_id_num_pages(self):
+        pool, _ = _filled_pool()
+        out = pool._step_tables(["s0"], [2], 1, 4,
+                                slots=([5, 5], [2, 3]), n_pad=8)
+        assert len(out) == 5
+        pg, of = np.asarray(out[3]), np.asarray(out[4])
+        np.testing.assert_array_equal(pg, [5, 5] + [pool.num_pages] * 6)
+        np.testing.assert_array_equal(of, [2, 3] + [0] * 6)
+        assert pg.dtype == of.dtype == np.int32
+        # without a plan there is nothing to pad
+        assert len(pool._step_tables(["s0"], [2], 1, 4)) == 3
+
+    @pytest.mark.parametrize("caller", sorted(_TABLE_CALLERS))
+    def test_caller_emits_one_table_span_with_rows_and_bytes(self, caller):
+        from paddle_tpu.framework import telemetry
+
+        pool, call = _TABLE_CALLERS[caller]()
+        telemetry.reset()                # an empty ring of its own
+        paddle.set_flags({"telemetry": "trace"})
+        try:
+            call()
+            spans = telemetry.peek_tracer().spans()
+        finally:
+            paddle.set_flags({"telemetry": "off"})
+            telemetry.reset()
+        table = [s for s in spans if s.name == "pool.table"]
+        assert len(table) == 1
+        rows = 1 if caller == "latent_ragged_step" else 2
+        assert table[0].attrs["rows"] == rows
+        assert table[0].attrs["bytes"] > 0
+        (step,) = [s for s in spans if s.name == "pool.fused_step"]
+        assert step.attrs["op"] == caller
+
+    @pytest.mark.parametrize("caller", sorted(_TABLE_CALLERS))
+    def test_sanitizer_checks_the_table_once_a_call(self, caller):
+        pool, call = _TABLE_CALLERS[caller](sanitizer="strict")
+        before = pool.sanitizer_stats["by_op"].get("page-table", 0)
+        call()
+        stats = pool.sanitizer_stats
+        assert stats["by_op"]["page-table"] == before + 1
+        assert stats["violations"] == 0
+
+    def test_one_builder_one_span_site(self):
+        import inspect
+
+        from paddle_tpu.incubate.nn import paged_cache
+
+        src = inspect.getsource(paged_cache)
+        assert src.count('span("pool.table")') == 1
+        for caller in _TABLE_CALLERS:
+            body = inspect.getsource(
+                getattr(PagedKVCacheManager, caller))
+            assert body.count("self._step_tables(") == 1, caller
+            assert "_padded_kernel_inputs" not in body, caller
 
 
 class TestPoolAttendRagged:
-    def _pool(self, kv=None, seed=2, lens=(6, 9, 1)):
-        rng = np.random.RandomState(seed)
-        pool = PagedKVCacheManager(32, PAGE, 2, 8, dtype=jnp.float32,
-                                   kv_dtype=kv)
-        for i, n in enumerate(lens):
-            sid = f"s{i}"
-            pool.alloc(sid)
-            for _ in range(n):
-                pool.append(sid, rng.randn(2, 8).astype("float32"),
-                            rng.randn(2, 8).astype("float32"))
-        return pool, rng
-
-    @pytest.mark.parametrize("kv", [None, "int8"])
-    def test_matches_legacy_pair_composition(self, kv):
-        # one attend_ragged call == the decode-kernel rows + the
-        # prefill-kernel rows of the legacy two-kernel routing
-        pool, rng = self._pool(kv=kv)
-        sids = ["s0", "s1", "s2"]
-        T = 4
-        q = rng.randn(4, T, 2, 8).astype("float32")
-        q_lens = [2, 3, 1]
-        out = pool.attend_ragged(jnp.asarray(q), sids, q_lens,
-                                 rows_pad=4, max_pages=4)
-        ref = pool.attend_prefill(jnp.asarray(q), sids, q_lens,
-                                  rows_pad=4, max_pages=4)
-        np.testing.assert_array_equal(out.numpy(), ref.numpy())
-        # the decode row agrees with attend_padded on its token
-        dec = pool.attend_padded(
-            jnp.asarray(q[:, T - 1]), ["s2"], rows_pad=4, max_pages=4)
-        np.testing.assert_allclose(out.numpy()[2, T - 1],
-                                   dec.numpy()[0], atol=1e-5)
-
     def test_warm_dispatch_reuse_across_pools(self):
         # satellite: the unified kernel keys ONE shape-keyed LRU —
         # a second pool instance at the same shapes reuses the
         # compiled entry instead of re-tracing
-        pool_a, rng = self._pool(seed=3)
+        pool_a, rng = _filled_pool(seed=3)
         q = jnp.asarray(rng.randn(4, 2, 2, 8), jnp.float32)
         pool_a.attend_ragged(q, ["s0", "s1"], [2, 1], rows_pad=4,
                              max_pages=4)
         info0 = _jitted_ragged_call.cache_info()
-        pool_b, _ = self._pool(seed=4)
+        pool_b, _ = _filled_pool(seed=4)
         pool_b.attend_ragged(q, ["s0", "s1"], [2, 1], rows_pad=4,
                              max_pages=4)
         info1 = _jitted_ragged_call.cache_info()
@@ -426,7 +528,7 @@ class TestPoolAttendRagged:
     def test_single_cache_serves_decode_and_prefill_kinds(self):
         # no per-row-kind cache split: a decode-shaped (T=1) call and
         # a prefill-shaped call both land in _jitted_ragged_call
-        pool, rng = self._pool(seed=5)
+        pool, rng = _filled_pool(seed=5)
         size0 = _jitted_ragged_call.cache_info().currsize
         q1 = jnp.asarray(rng.randn(2, 1, 2, 8), jnp.float32)
         pool.attend_ragged(q1, ["s0", "s1"], [1, 1], max_pages=4)
@@ -579,7 +681,8 @@ class TestFusedStep:
 
 
 # ---------------------------------------------------------------------------
-# end-to-end: the chunked scheduler across dispatch modes
+# end-to-end: both bodies of the chunked step against the token-per-step
+# scheduler
 
 
 def _tiny_cfg(**kw):
@@ -592,10 +695,14 @@ def _tiny_cfg(**kw):
     return llama_tiny(**kw)
 
 
+def _fresh_model(seed=17, **kw):
+    paddle.seed(seed)
+    return LlamaForCausalLM(_tiny_cfg(**kw))
+
+
 @pytest.fixture(scope="module")
 def model():
-    paddle.seed(17)
-    return LlamaForCausalLM(_tiny_cfg())
+    return _fresh_model()
 
 
 _RNG = np.random.RandomState(0)
@@ -607,32 +714,69 @@ PROMPTS = {
 N_NEW = {"a": 4, "b": 5, "c": 3}
 
 
-def _serve(model, mode, kv=None, prefix=False, budget=8):
-    paddle.set_flags({"ragged_attention": mode})
-    try:
-        adapter = PagedLlamaAdapter(model, num_pages=96,
-                                    page_size=PAGE, max_length=128,
-                                    kv_cache_dtype=kv)
-        sched = BatchScheduler(
-            adapter, max_batch_size=4, prefix_cache=prefix,
-            chunked_prefill=True, prefill_chunk_tokens=budget)
-        out = {}
-        for wave in (0, 1) if prefix else (0,):
-            for rid, p in PROMPTS.items():
-                sched.submit(Request(f"{rid}w{wave}", list(p),
-                                     max_new_tokens=N_NEW[rid]))
-            done = sched.run_until_complete()
-            for k, v in done.items():
-                out[k] = v.generated_ids
-        return out, sched, adapter
-    finally:
-        paddle.set_flags({"ragged_attention": "auto"})
+def _serve(model, kv=None, prefix=False, budget=8, chunked=True,
+           weight_dtype=None, waves=None):
+    """Serve PROMPTS (twice over a prefix cache) and return (generated
+    ids, scheduler, adapter). ``chunked=False`` is the token-per-step
+    scheduler over ``decode_token`` — the oracle of both chunked
+    bodies. ``weight_dtype`` quantizes ``model`` in place: hand it a
+    :func:`_fresh_model`."""
+    adapter = PagedLlamaAdapter(model, num_pages=96, page_size=PAGE,
+                                max_length=128, kv_cache_dtype=kv,
+                                weight_dtype=weight_dtype)
+    kw = dict(prefill_chunk_tokens=budget) if chunked else {}
+    sched = BatchScheduler(adapter, max_batch_size=4,
+                           prefix_cache=prefix, chunked_prefill=chunked,
+                           **kw)
+    out = {}
+    for wave in range(waves or (2 if prefix else 1)):
+        for rid, p in PROMPTS.items():
+            sched.submit(Request(f"{rid}w{wave}", list(p),
+                                 max_new_tokens=N_NEW[rid]))
+        done = sched.run_until_complete()
+        for k, v in done.items():
+            out[k] = v.generated_ids
+    return out, sched, adapter
+
+
+def _kinds(adapter):
+    return {k for k, *_ in adapter._kernel_shapes}
+
+
+class TestFusionChoice:
+    """Which body ``prefill_chunk`` runs follows from what the adapter
+    can observe of its pages and projections, nothing else."""
+
+    @pytest.mark.parametrize("case,want", [
+        ("float", "ragged_fused"),
+        ("int8_kv", "ragged"),
+        ("int8_weights", "ragged"),
+        ("partial_qkv_bias", "ragged"),
+    ])
+    def test_body_follows_eligibility(self, case, want):
+        m = _fresh_model(attention_bias=(case == "partial_qkv_bias"))
+        if case == "partial_qkv_bias":
+            m.model.layers[0].self_attn.k_proj.bias = None
+        ad = PagedLlamaAdapter(
+            m, num_pages=16, page_size=PAGE, max_length=64,
+            kv_cache_dtype="int8" if case == "int8_kv" else None,
+            weight_dtype="int8" if case == "int8_weights" else None)
+        assert ad._fusion_eligible() == (want == "ragged_fused")
+        for sid in "ab":
+            ad.alloc(sid)
+        logits = ad.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], [0, 0],
+                                  pad_to=8)
+        assert logits.shape == [2, m.config.vocab_size]
+        assert np.isfinite(logits.numpy()).all()
+        assert _kinds(ad) == {want}
+        assert ad.caches[0].seq_len("a") == 3
 
 
 class TestEndToEndGreedyIdentity:
-    """The scheduler's greedy outputs must be token-identical across
-    off (legacy two-kernel), on (unified kernel), and auto (unified +
-    fused prologue/epilogue where eligible)."""
+    """The chunked scheduler's greedy outputs must be token-identical
+    to the token-per-step scheduler's, through the fused body (float
+    pages, plain projections) and through the unfused one (int8 pages,
+    or int8 weights over float pages)."""
 
     @pytest.mark.parametrize("kv,prefix", [
         (None, False),
@@ -641,35 +785,32 @@ class TestEndToEndGreedyIdentity:
         pytest.param("int8", True, marks=_slow),
     ])
     def test_modes_agree(self, model, kv, prefix):
-        base, _, ad_off = _serve(model, "off", kv=kv, prefix=prefix)
-        got_on, _, ad_on = _serve(model, "on", kv=kv, prefix=prefix)
-        got_auto, _, ad_auto = _serve(model, "auto", kv=kv,
-                                      prefix=prefix)
-        assert got_on == base, (kv, prefix)
-        assert got_auto == base, (kv, prefix)
-        # unified mode compiled ONE attend program per packed config
-        for ad in (ad_on, ad_auto):
-            kinds = {k for k, *_ in ad._kernel_shapes}
-            assert kinds <= {"ragged", "ragged_fused"}, kinds
-        # the legacy run compiled the decode/prefill pair
-        assert {k for k, *_ in ad_off._kernel_shapes} <= \
-            {"decode", "prefill"}
-        assert ad_on.attend_program_count <= \
-            ad_off.attend_program_count
+        waves = 2 if prefix else 1
+        base, _, _ = _serve(model, kv=kv, chunked=False, waves=waves)
+        got, _, ad = _serve(model, kv=kv, prefix=prefix)
+        assert got == base, (kv, prefix)
+        # ONE attend program per packed config
+        assert _kinds(ad) == ({"ragged"} if kv else {"ragged_fused"})
+        if kv is None:
+            # the unfused body over float pages: int8 projections
+            base_w, _, _ = _serve(_fresh_model(), weight_dtype="int8",
+                                  chunked=False, waves=waves)
+            got_w, _, ad_w = _serve(_fresh_model(), weight_dtype="int8",
+                                    prefix=prefix)
+            assert got_w == base_w, prefix
+            assert _kinds(ad_w) == {"ragged"}
 
-    def test_auto_fuses_fp_and_declines_int8(self, model):
-        _, _, ad_fp = _serve(model, "auto")
-        assert {k for k, *_ in ad_fp._kernel_shapes} == \
-            {"ragged_fused"}
-        _, _, ad_i8 = _serve(model, "auto", kv="int8")
-        assert {k for k, *_ in ad_i8._kernel_shapes} == {"ragged"}
+    def test_fuses_fp_and_declines_int8(self, model):
+        _, _, ad_fp = _serve(model)
+        assert _kinds(ad_fp) == {"ragged_fused"}
+        _, _, ad_i8 = _serve(model, kv="int8")
+        assert _kinds(ad_i8) == {"ragged"}
 
     def test_attend_program_count_bounded_by_buckets(self, model):
-        got, sched, adapter = _serve(model, "auto")
-        assert got == _serve(model, "off")[0]
+        got, sched, adapter = _serve(model)
+        assert got == _serve(model, chunked=False)[0]
         # satellite acceptance: one attend program per packed config
         # keeps the compiled-program count within the bucket ladder
-        # (the legacy pair pushed it toward 2x)
         assert adapter.compile_count <= len(sched.serving_buckets)
         assert adapter.attend_program_count <= \
             len(sched.serving_buckets)
@@ -687,7 +828,6 @@ class TestEndToEndGreedyIdentity:
             _jitted_fused_call,
         )
 
-        paddle.set_flags({"ragged_attention": "auto"})
         ad = PagedLlamaAdapter(model, num_pages=32, page_size=16,
                                max_length=128)
         for s in "abcd":
@@ -709,7 +849,6 @@ class TestEndToEndGreedyIdentity:
             ad.free(s)
 
     def test_step_event_reports_attend_programs(self, model):
-        paddle.set_flags({"ragged_attention": "auto"})
         adapter = PagedLlamaAdapter(model, num_pages=96,
                                     page_size=PAGE, max_length=128)
         sched = BatchScheduler(adapter, max_batch_size=4,
@@ -724,18 +863,22 @@ class TestEndToEndGreedyIdentity:
 
     def test_qkv_bias_model_fuses_and_agrees(self):
         # Qwen2-style q/k/v biases ride the fused prologue
-        paddle.seed(29)
-        bmodel = LlamaForCausalLM(_tiny_cfg(attention_bias=True))
-        base, _, _ = _serve(bmodel, "off")
-        got_auto, _, ad = _serve(bmodel, "auto")
-        assert got_auto == base
-        assert {k for k, *_ in ad._kernel_shapes} == {"ragged_fused"}
+        bmodel = _fresh_model(seed=29, attention_bias=True)
+        base, _, _ = _serve(bmodel, chunked=False)
+        got, _, ad = _serve(bmodel)
+        assert got == base
+        assert _kinds(ad) == {"ragged_fused"}
 
     @_slow
     def test_windowed_model_modes_agree(self):
-        paddle.seed(23)
-        wmodel = LlamaForCausalLM(_tiny_cfg(sliding_window=6))
-        base, _, _ = _serve(wmodel, "off")
-        got_auto, _, ad = _serve(wmodel, "auto")
-        assert got_auto == base
-        assert {k for k, *_ in ad._kernel_shapes} == {"ragged_fused"}
+        wmodel = _fresh_model(seed=23, sliding_window=6)
+        base, _, _ = _serve(wmodel, chunked=False)
+        got, _, ad = _serve(wmodel)
+        assert got == base
+        assert _kinds(ad) == {"ragged_fused"}
+
+
+@pytest.mark.parametrize("name", ["ragged_attention", "spec_decode"])
+def test_removed_flag_is_unknown(name):
+    with pytest.raises(Exception, match="unknown flag"):
+        paddle.set_flags({name: "off"})

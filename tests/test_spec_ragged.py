@@ -1,18 +1,17 @@
 """Unified speculative decoding (ISSUE 19): verify rows ride the
 ragged kernel.
 
-The acceptance matrix: ``FLAGS_spec_decode=ragged`` packs each
+The acceptance matrix: a scheduler given a draft model packs each
 spec-active sequence's draft-k verify window as ONE right-aligned
 (k+1)-token row of the ordinary ``prefill_chunk`` ragged step (per-
 position logits out of the epilogue) and must be GREEDY-IDENTICAL to
-both the non-speculative scheduler and the legacy ``decode_window``
-lowering (``FLAGS_spec_decode=legacy``) — with no new per-k attend
-program family. The lifted legacy restrictions are pinned too:
-spec × prefix-cache × kv {float32, int8} verify-rollback under the
-strict page sanitizer (COW/shared pages survive ``truncate``, zero
-leaks), and spec × host-swap preemption (draft KV discarded at
-swap-out, re-prefilled from the committed prefix at swap-in) under a
-forced preemption storm.
+the non-speculative scheduler — with no new per-k attend program
+family. What composes with it is pinned too: spec × prefix-cache × kv
+{float32, int8} verify-rollback under the strict page sanitizer
+(COW/shared pages survive ``truncate``, zero leaks), and spec ×
+host-swap preemption (draft KV discarded at swap-out, re-prefilled
+from the committed prefix at swap-in) under a forced preemption storm;
+adapters that cannot carry a verify row are refused at construction.
 """
 import numpy as np
 import pytest
@@ -65,7 +64,7 @@ PROMPTS = {
 N_NEW = {"a": 6, "b": 5, "c": 4}
 
 
-def _serve(target, draft=None, mode="ragged", kv=None, prefix=False,
+def _serve(target, draft=None, kv=None, prefix=False,
            sanitizer=None, waves=None, faults=None, preempt=False,
            draft_k=3, buckets=None, max_new=None):
     """Run the standard workload; returns (generated, sched,
@@ -80,7 +79,7 @@ def _serve(target, draft=None, mode="ragged", kv=None, prefix=False,
             draft_model=PagedLlamaAdapter(
                 draft, num_pages=96, page_size=PAGE, max_length=128,
                 sanitizer=sanitizer),
-            draft_k=draft_k, spec_decode=mode)
+            draft_k=draft_k)
     if preempt:
         kw.update(preempt=True, swap_bytes=1 << 22)
     fi = FaultInjector(faults) if faults else None
@@ -105,33 +104,24 @@ def _serve(target, draft=None, mode="ragged", kv=None, prefix=False,
 
 
 class TestUnifiedSpecIdentity:
-    def test_ragged_identical_to_nonspec_and_legacy(self, target,
-                                                    draft):
-        base, _, _ = _serve(target)
-        leg, s_leg, _ = _serve(target, draft, mode="legacy")
-        rag, s_rag, _ = _serve(target, draft, mode="ragged")
-        off, s_off, _ = _serve(target, draft, mode="off")
+    def test_ragged_identical_to_nonspec(self, target, draft):
+        base, s_base, _ = _serve(target)
+        rag, s_rag, _ = _serve(target, draft)
         assert rag == base
-        assert leg == base
-        assert off == base
-        assert not s_leg._spec_ragged and s_rag._spec_ragged
-        # mode off really ignored the draft
-        assert s_off.draft is None
-        # both lowerings took the same rounds and commits (the shared
-        # _commit_spec_row acceptance rule)
-        for key in ("rounds", "committed_tokens", "proposed_tokens",
-                    "accepted_draft_tokens"):
-            assert s_rag.spec_stats[key] == s_leg.spec_stats[key], key
-        assert s_rag.spec_stats["rounds"] > 0
-        # strictly better than one token per target call
+        assert s_base.draft is None and s_rag.draft is not None
         st = s_rag.spec_stats
+        assert st["rounds"] > 0
+        assert s_base.spec_stats["rounds"] == 0
+        # a different draft: proposals get rejected and rolled back
+        assert st["accepted_draft_tokens"] < st["proposed_tokens"]
+        # strictly better than one token per target call
         assert st["committed_tokens"] / st["target_calls"] > 1.0
 
     def test_full_acceptance_same_weights_draft(self, target):
         # draft == target: every proposal accepted, k+1 tokens per
         # round, still greedy-identical
         base, _, _ = _serve(target, max_new=9)
-        got, s, _ = _serve(target, draft=target, mode="ragged",
+        got, s, _ = _serve(target, draft=target,
                            max_new=9)
         assert got == base
         st = s.spec_stats
@@ -147,7 +137,7 @@ class TestUnifiedSpecIdentity:
         # target program match the non-spec chunked run
         buckets = (16, 32)
         _, _, ad0 = _serve(target, buckets=buckets)
-        _, _, ad1 = _serve(target, draft, mode="ragged",
+        _, _, ad1 = _serve(target, draft,
                            buckets=buckets)
         kinds0 = sorted({k for k, *_ in ad0._kernel_shapes})
         kinds1 = sorted({k for k, *_ in ad1._kernel_shapes})
@@ -158,7 +148,7 @@ class TestUnifiedSpecIdentity:
         assert set(ad1._dispatch_shapes) <= set(buckets)
 
     def test_statusz_accept_rate_column(self, target, draft):
-        _, s, _ = _serve(target, draft, mode="ragged")
+        _, s, _ = _serve(target, draft)
         info = s._statusz_info()
         spec = info["spec"]
         assert spec["mode"] == "ragged"
@@ -166,10 +156,63 @@ class TestUnifiedSpecIdentity:
         assert 0.0 <= spec["accept_rate"] <= 1.0
         assert spec["tokens_per_round"] > 1.0
 
-    def test_bad_mode_rejected(self, target, draft):
-        ad = PagedLlamaAdapter(target, num_pages=16, page_size=PAGE)
-        with pytest.raises(ValueError, match="spec_decode"):
-            BatchScheduler(ad, spec_decode="bogus")
+class _NoChunk:
+    """An adapter that only speaks ``decode_token``."""
+
+    def __init__(self, adapter):
+        self.caches = adapter.caches
+        self.alloc, self.free = adapter.alloc, adapter.free
+        self.decode_token = adapter.decode_token
+
+
+class _NoLogitsRows(_NoChunk):
+    """...and one whose chunked step has no per-position epilogue."""
+
+    def __init__(self, adapter):
+        super().__init__(adapter)
+        self._chunk = adapter.prefill_chunk
+
+    def prefill_chunk(self, token_ids, seq_ids, start_positions=None,
+                      pad_to=None):
+        return self._chunk(token_ids, seq_ids, start_positions, pad_to)
+
+
+class TestSpecNeedsTheChunkedStep:
+    def _adapters(self, target, draft):
+        return (PagedLlamaAdapter(target, num_pages=32, page_size=PAGE),
+                PagedLlamaAdapter(draft, num_pages=32, page_size=PAGE))
+
+    def test_draft_without_prefill_chunk_refused_by_name(self, target,
+                                                         draft):
+        ad, da = self._adapters(target, draft)
+        with pytest.raises(ValueError,
+                           match="draft adapter has no prefill_chunk"):
+            BatchScheduler(ad, draft_model=_NoChunk(da))
+
+    def test_target_without_logits_rows_refused_by_name(self, target,
+                                                        draft):
+        ad, da = self._adapters(target, draft)
+        with pytest.raises(ValueError, match="takes no logits_rows="):
+            BatchScheduler(_NoLogitsRows(ad), draft_model=da)
+        with pytest.raises(ValueError,
+                           match="target adapter has no prefill_chunk"):
+            BatchScheduler(_NoChunk(ad), draft_model=da)
+        with pytest.raises(ValueError, match="chunked_prefill=False"):
+            BatchScheduler(ad, draft_model=da, chunked_prefill=False)
+        # without a draft the same adapters serve as before
+        assert not BatchScheduler(_NoChunk(ad)).chunked_prefill
+        assert BatchScheduler(_NoLogitsRows(ad)).chunked_prefill
+
+    def test_prefix_cache_and_preemption_always_available(self, target,
+                                                          draft):
+        ad, da = self._adapters(target, draft)
+        s = BatchScheduler(ad, draft_model=da, prefix_cache=True,
+                           preempt=True, swap_bytes=1 << 20)
+        assert s.prefix_cache is not None
+        assert s.swap_space is not None and s._preempt_enabled
+        assert s._statusz_info()["spec"]["mode"] == "ragged"
+        with pytest.raises(TypeError, match="spec_decode"):
+            BatchScheduler(ad, draft_model=da, spec_decode="legacy")
 
 
 class TestSpecPrefixKvRollback:
@@ -183,7 +226,7 @@ class TestSpecPrefixKvRollback:
                                                kv):
         waves = [["a"], ["b"], ["c"]]  # b hits a's cached prefix
         base, _, _ = _serve(target, kv=kv, waves=waves)
-        got, s, ad = _serve(target, draft, mode="ragged", kv=kv,
+        got, s, ad = _serve(target, draft, kv=kv,
                             prefix=True, sanitizer="strict",
                             waves=waves)
         assert got == base
@@ -199,24 +242,15 @@ class TestSpecPrefixKvRollback:
         stats = s.page_pool_stats()
         assert stats["free_pages"] == stats["total_pages"], stats
 
-    def test_legacy_mode_still_rejects_prefix_cache(self, target,
-                                                    draft):
-        ad = PagedLlamaAdapter(target, num_pages=32, page_size=PAGE)
-        da = PagedLlamaAdapter(draft, num_pages=32, page_size=PAGE)
-        with pytest.raises(ValueError, match="LEGACY"):
-            BatchScheduler(ad, draft_model=da, prefix_cache=True,
-                           spec_decode="legacy")
-
 
 class TestSpecPreemptionStorm:
-    """ISSUE-19 satellite: the PR-9 spec-mode preemption restriction
-    is lifted under the ragged lowering — a spec-active victim swaps
-    out with its draft KV discarded and resumes with the draft
-    re-prefilled from the committed prefix (wait-free)."""
+    """ISSUE-19 satellite: a spec-active victim swaps out with its
+    draft KV discarded and resumes with the draft re-prefilled from the
+    committed prefix (wait-free)."""
 
     def test_storm_identity_and_draft_refill(self, target, draft):
         base, _, _ = _serve(target)
-        got, s, _ = _serve(target, draft, mode="ragged",
+        got, s, _ = _serve(target, draft,
                            sanitizer="strict", preempt=True,
                            faults="preempt_storm@6:2")
         assert got == base
@@ -227,18 +261,3 @@ class TestSpecPreemptionStorm:
         # the storm genuinely fired and fully unwound
         assert s._faults.counts["preempt_storm"] > 0
         assert s._swapped == {}
-
-    def test_legacy_mode_keeps_wait_in_queue(self, target, draft):
-        # the pinned restriction: legacy spec never builds the swap
-        # space, so preemption stays disabled there
-        ad = PagedLlamaAdapter(target, num_pages=32, page_size=PAGE)
-        da = PagedLlamaAdapter(draft, num_pages=32, page_size=PAGE)
-        s = BatchScheduler(ad, draft_model=da, spec_decode="legacy",
-                           preempt=True, swap_bytes=1 << 20)
-        assert s.swap_space is None and not s._preempt_enabled
-        s2 = BatchScheduler(
-            PagedLlamaAdapter(target, num_pages=32, page_size=PAGE),
-            draft_model=PagedLlamaAdapter(draft, num_pages=32,
-                                          page_size=PAGE),
-            spec_decode="ragged", preempt=True, swap_bytes=1 << 20)
-        assert s2.swap_space is not None and s2._preempt_enabled

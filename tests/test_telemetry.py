@@ -104,19 +104,12 @@ class _FakeModel:
         return logits
 
 
-class _L:
-    """Tensor-shaped wrapper (the spec scheduler reads ._data)."""
-
-    def __init__(self, data):
-        self._data = data
-
-
 class _FakeChunkModel(_FakeModel):
     """Ragged chunked-prefill + spec-decode fake: implements
     prefill_chunk (with the per-position ``logits_rows`` epilogue the
-    unified ragged spec step samples verify windows from) and the
-    legacy decode_window, on host arrays, always emitting token 1
-    (so draft and target agree and every proposal is accepted)."""
+    spec step samples verify windows from) on host arrays, always
+    emitting token 1 (so draft and target agree and every proposal is
+    accepted)."""
 
     def prefill_chunk(self, feeds, rows, starts, pad_to=None,
                       logits_rows=None):
@@ -131,18 +124,6 @@ class _FakeChunkModel(_FakeModel):
         full = np.zeros((n_full, self.vocab), np.float32)
         full[:, 1] = 1.0
         return logits, full
-
-    def decode_token(self, feed, sids):
-        return _L(super().decode_token(feed, sids))
-
-    def decode_window(self, windows, sids):
-        c = self.caches[0]
-        w = windows.shape[1]
-        for s in sids:
-            c.lens[s] += w
-        logits = np.zeros((len(sids), w, self.vocab), np.float32)
-        logits[:, :, 1] = 1.0
-        return _L(logits)
 
 
 class _StubPrefixCache:

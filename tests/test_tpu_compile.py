@@ -134,24 +134,6 @@ def test_ragged_int8_kv_2048_pages(one_chip, t):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8],
-                         ids=["bf16", "int8"])
-def test_legacy_decode_kernel(one_chip, kv_dtype):
-    """FLAGS_ragged_attention=off lowering (the dedicated decode
-    kernel), built directly so no flag is touched."""
-    from paddle_tpu.ops.kernels.paged_attention import _build_decode_call
-
-    quant = kv_dtype == jnp.int8
-    run = _build_decode_call(8, H, D, 2048, PAGE, KVH, 128,
-                             D ** -0.5, 4096, quant, False)
-    specs = [((8, H, D), BF16), ((2048, PAGE, KVH, D), kv_dtype),
-             ((2048, PAGE, KVH, D), kv_dtype),
-             ((8, 128), jnp.int32), ((8,), jnp.int32)]
-    if quant:
-        specs += [((2048 * KVH,), jnp.float32)] * 2
-    assert "tpu_custom_call" in _compile(run, one_chip, *specs)
-
-
 @pytest.mark.parametrize("n_pad,b_pad,t_pad,mp,npages", [
     (128, 8, 64, 128, 2048),
     (32, 32, 1, 32, 4096), (32, 32, 1, 128, 4096), (128, 32, 64, 64, 4096),

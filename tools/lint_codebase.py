@@ -1021,35 +1021,24 @@ def check_serving_buckets(root=REPO):
 
 # the packed serving step must route attention through the unified
 # ragged pool API (ROADMAP item 2: one attend program per packed
-# config): the historical decode/prefill kernel PAIR may not reappear
-# in a single packed-step function of the serving layers, and a
-# function landing a ragged append must attend through the unified
-# entry in the same scope
+# config): a function landing a ragged append must attend through the
+# unified entry in the same scope
 UNIFIED_ATTENTION_FILES = (
     os.path.join("paddle_tpu", "inference", "serving.py"),
     os.path.join("paddle_tpu", "inference", "paged_llama.py"),
 )
 
-_LEGACY_ATTEND_PAIR = frozenset({"attend_padded", "attend_prefill"})
 _UNIFIED_ATTEND_CALLS = frozenset({"attend_ragged",
                                    "fused_ragged_step"})
 _PACKED_STEP_MARKERS = frozenset({"append_ragged"})
 
 
 class _UnifiedAttentionVisitor(ast.NodeVisitor):
-    """Per innermost function, two checks over the serving layers:
-
-    (a) calling BOTH ``attend_padded`` and ``attend_prefill`` is the
-        two-kernel per-row-kind routing the unified ragged kernel
-        replaced — a mixed packed batch must be ONE
-        ``attend_ragged``/``fused_ragged_step`` call (the sanctioned
-        legacy body behind ``FLAGS_ragged_attention=off`` carries a
-        waiver);
-    (b) a function that lands a ragged append (``append_ragged`` —
-        the packed-step marker) must route its attention through the
-        unified pool API in the same scope — a packed step that
-        appends ragged K/V but attends per row kind re-splits the
-        compiled-program count the unification halved.
+    """Per innermost function of the serving layers: a function that
+    lands a ragged append (``append_ragged`` — the packed-step marker)
+    must route its attention through the unified pool API in the same
+    scope — a packed step that appends ragged K/V but attends some
+    other way re-splits the one attend program per packed config.
     """
 
     def __init__(self, relpath, source_lines):
@@ -1082,28 +1071,14 @@ class _UnifiedAttentionVisitor(ast.NodeVisitor):
         return _WAIVER_MARK in line
 
     def _check_fn(self, node):
-        pair = {}
         unified = False
         appends = []
         for sub in self._scoped_calls(node):
             name = self._call_name(sub)
-            if name in _LEGACY_ATTEND_PAIR:
-                pair.setdefault(name, sub.lineno)
-            elif name in _UNIFIED_ATTEND_CALLS:
+            if name in _UNIFIED_ATTEND_CALLS:
                 unified = True
             elif name in _PACKED_STEP_MARKERS:
                 appends.append(sub.lineno)
-        if len(pair) == len(_LEGACY_ATTEND_PAIR) and \
-                not any(self._waived(ln) for ln in pair.values()):
-            lineno = min(pair.values())
-            self.violations.append(
-                "%s:%d: function %r calls both attend_padded and "
-                "attend_prefill — the two-kernel per-row-kind routing "
-                "the unified ragged kernel replaced (ROADMAP item 2); "
-                "route the packed batch through ONE attend_ragged/"
-                "fused_ragged_step call, or waive the sanctioned "
-                "legacy body with '%s(<reason>)'"
-                % (self.relpath, lineno, node.name, _WAIVER_MARK))
         if appends and not unified:
             lineno = min(appends)
             if not self._waived(lineno):
@@ -1142,79 +1117,6 @@ def check_unified_attention(root=REPO):
     out = []
     for f in UNIFIED_ATTENTION_FILES:
         out.extend(lint_unified_attention_file(os.path.join(root, f)))
-    return out
-
-
-# unified speculative decoding (ISSUE 19): the packed ragged
-# prefill_chunk step IS the target verify pass — each spec-active
-# sequence rides it as one right-aligned (draft_k+1)-token row with
-# per-position logits out of the epilogue. A per-sequence / dense
-# target forward outside that step (`decode_window`, the legacy
-# dense-gather verify) re-opens the extra dispatch lane per decode
-# round the unification removed; the sanctioned legacy body behind
-# FLAGS_spec_decode=legacy carries an explicit waiver.
-SPEC_ROW_FILES = UNIFIED_ATTENTION_FILES
-
-_SPEC_ROW_BANNED = frozenset({"decode_window"})
-
-
-class _SpecRowVisitor(ast.NodeVisitor):
-    """Flag every ``decode_window`` CALL in the serving layers that
-    does not carry a same-line waiver (defining/binding the legacy
-    entry point is fine — only invoking it re-splits the verify
-    dispatch)."""
-
-    def __init__(self, relpath, source_lines):
-        self.relpath = relpath
-        self.lines = source_lines
-        self.violations = []
-
-    def _call_name(self, node):
-        fn = node.func
-        if isinstance(fn, ast.Attribute):
-            return fn.attr
-        if isinstance(fn, ast.Name):
-            return fn.id
-        return None
-
-    def _waived(self, lineno):
-        line = self.lines[lineno - 1] \
-            if lineno - 1 < len(self.lines) else ""
-        return _WAIVER_MARK in line
-
-    def visit_Call(self, node):
-        name = self._call_name(node)
-        if name in _SPEC_ROW_BANNED and not self._waived(node.lineno):
-            self.violations.append(
-                "%s:%d: %r is a per-sequence target forward outside "
-                "the packed ragged step — speculative verify windows "
-                "must ride prefill_chunk as (draft_k+1)-token rows "
-                "(ISSUE 19 spec-row-discipline); fix it or waive the "
-                "sanctioned FLAGS_spec_decode=legacy body with "
-                "'%s(<reason>)'"
-                % (self.relpath, node.lineno, name, _WAIVER_MARK))
-        self.generic_visit(node)
-
-
-def lint_spec_rows_file(path, text=None):
-    """Spec-row-discipline check; returns violation strings."""
-    if text is None:
-        with open(path, encoding="utf-8") as f:
-            text = f.read()
-    rel = os.path.relpath(path, REPO) if os.path.isabs(path) else path
-    try:
-        tree = ast.parse(text, filename=rel)
-    except SyntaxError as e:
-        return ["%s: syntax error during lint: %s" % (rel, e)]
-    v = _SpecRowVisitor(rel, text.splitlines())
-    v.visit(tree)
-    return v.violations
-
-
-def check_spec_rows(root=REPO):
-    out = []
-    for f in SPEC_ROW_FILES:
-        out.extend(lint_spec_rows_file(os.path.join(root, f)))
     return out
 
 
@@ -2731,18 +2633,9 @@ RULES = (
      "bucket_packed_tokens (bounded XLA compile count)"),
     ("unified-attention",
      "packed-step attention in serving.py/paged_llama.py routes "
-     "through the single attend_ragged/fused_ragged_step pool API — "
-     "no function may call the legacy attend_padded + attend_prefill "
-     "kernel pair (one attend program per packed config, not two; "
-     "the FLAGS_ragged_attention=off legacy body carries a waiver), "
-     "and a ragged append's function must attend unified in-scope"),
-    ("spec-row-discipline",
-     "no per-sequence target forward outside the packed ragged step "
-     "in serving.py/paged_llama.py — speculative verify windows ride "
-     "prefill_chunk as (draft_k+1)-token rows with per-position "
-     "logits out of the epilogue (decode_window calls are banned; "
-     "the sanctioned FLAGS_spec_decode=legacy body carries a "
-     "waiver)"),
+     "through the single attend_ragged/fused_ragged_step pool API "
+     "(one attend program per packed config): a ragged append's "
+     "function must attend unified in-scope"),
     ("serving-terminal-trace",
      "any serving.py function that moves a request to a terminal "
      "state (FINISHED/ABORTED_DEADLINE or a _finished[] write) must "
@@ -2826,7 +2719,6 @@ def run_lint(root=REPO, with_op_table=True):
     out.extend(check_pool_mutation_audit(root))
     out.extend(check_serving_buckets(root))
     out.extend(check_unified_attention(root))
-    out.extend(check_spec_rows(root))
     out.extend(check_serving_terminal_trace(root))
     out.extend(check_flag_inventory(root))
     out.extend(check_metric_names(root))
