@@ -1823,9 +1823,13 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "host prep of prefill_chunk: lengths, positions, right-align "
      "plan, uploads (rows/packed/pad_to/bytes attrs)"),
     ("span:model.embed", "span", "the embedding gather"),
-    ("span:model.layer", "span", "one decoder layer (li attr)"),
-    ("span:model.norm", "span", "an eager rms_norm call of a layer"),
-    ("span:model.mlp", "span", "a layer's MLP and its residual add"),
+    ("span:model.layer", "span",
+     "one decoder layer (li attr; program = 1 where the layer ran as "
+     "one compiled program, 0 on the op-by-op body)"),
+    ("span:model.norm", "span",
+     "an eager rms_norm call of a layer (op-by-op body only)"),
+    ("span:model.mlp", "span",
+     "a layer's MLP and its residual add (op-by-op body only)"),
     ("span:model.head", "span", "final norm, row gather, lm head"),
     ("span:model.hc", "span",
      "an mHC site's read (coefficients, the one stream F sees) or "
@@ -1848,13 +1852,14 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "pull (calls/assignments/experts_touched/expert_tokens_max/"
      "expert_tokens_mean attrs)"),
     ("span:pool.fused_step", "span",
-     "fused_ragged_step / append_ragged / attend_ragged, whole "
-     "(op attr)"),
+     "layer_step (a layer as one program) / append_ragged / "
+     "attend_ragged / latent_ragged_step, whole (op attr)"),
     ("span:pool.book", "span",
      "_ragged_slots: capacity check, COW forks, slot plan "
      "(slots/pages attrs)"),
     ("span:pool.table", "span",
-     "page table / scatter plan built in numpy and uploaded "
+     "page table / slot plan built in numpy and uploaded in one "
+     "call: once a programmed step, once a layer call elsewhere "
      "(rows/bytes attrs)"),
     ("span:kernel.ragged", "span",
      "the jitted ragged call: LRU lookup and dispatch "

@@ -104,12 +104,12 @@ from ...ops.kernels.paged_attention import (
     paged_ragged_attention as _ragged_kernel_fn,
 )
 from ...ops.kernels.paged_attention import (
-    paged_ragged_fused_step as _fused_step_fn,
+    paged_ragged_layer_step as _layer_step_fn,
 )
 from ...ops.kernels.paged_attention import (
     latent_ragged_step as _latent_step_fn,
 )
-from ...ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
+from ...ops.kernels.paged_attention import upload_plan as _upload
 from ...ops.kernels.quant import kv_head_scale, quantize_kv
 
 __all__ = ["PagedKVCacheManager", "paged_attention",
@@ -557,6 +557,18 @@ class HostKVSwapSpace:
         del self._swap_store[key]
         self._swap_used -= rec.nbytes
         return rec
+
+
+class StepTables(tuple):
+    """What a step's kernel reads beside the pages, on the device:
+    ``(tbl, lens, q_lens)``, ``(tbl, lens, q_lens, pg, of)`` or, merged
+    for the layer program, ``(rows, slots)``
+    (:meth:`PagedKVCacheManager._step_tables`). Beside them the host's
+    table and lens (``host_tbl`` / ``host_lens``, what a sanitizer
+    checks) and, for a step shared between the pools of the layers,
+    what it was ``booked`` from (:meth:`PagedKVCacheManager.book_step`)."""
+
+    host_tbl = host_lens = booked = None
 
 
 class PagedKVCacheManager:
@@ -1352,7 +1364,7 @@ class PagedKVCacheManager:
         sanitizer event. Returns the (pages, offs) write plan; the
         device scatter belongs to the caller — :meth:`append_ragged`,
         or the fused program that owns it as its prologue
-        (:meth:`fused_ragged_step`)."""
+        (:meth:`layer_step`, :meth:`latent_ragged_step`)."""
         with telemetry.span("pool.book") as sp:
             need = self.ragged_pages_needed(seq_ids, counts)
             if need > len(self._free):
@@ -1428,7 +1440,7 @@ class PagedKVCacheManager:
             seq_ids, len(seq_ids), max_pages)
         if self._san is not None:
             self._san_check_table(seq_ids, tbl, lens)
-        return tbl
+        return jnp.asarray(tbl)
 
     def seq_lens(self, seq_ids):
         return jnp.asarray(
@@ -1450,7 +1462,7 @@ class PagedKVCacheManager:
 
     def _padded_kernel_inputs(self, seq_ids, rows_pad, max_pages):
         """Page table + lens padded to ``rows_pad`` rows x
-        ``max_pages`` columns. Padding rows carry seq_len 0, which
+        ``max_pages`` columns, numpy. Padding rows carry seq_len 0, which
         the paged kernels treat as inert (no page is valid, output
         exact zeros) — the shape-bucketing enabler for the chunked-
         prefill dispatch."""
@@ -1463,10 +1475,10 @@ class PagedKVCacheManager:
             pages = self._tables[s]
             tbl[i, :len(pages)] = pages
             lens[i] = self._lens[s]
-        return jnp.asarray(tbl), jnp.asarray(lens)
+        return tbl, lens
 
     def _step_tables(self, seq_ids, q_lens, rows_pad, max_pages,
-                     slots=None, n_pad=None):
+                     slots=None, n_pad=None, merged=False):
         """What one attend call hands its kernel beside the pages, built
         in one place: the padded page table and lens
         (:meth:`_padded_kernel_inputs`, checked by the sanitizer where
@@ -1476,8 +1488,12 @@ class PagedKVCacheManager:
         ``num_pages`` is OUT OF BOUNDS, so the step programs'
         ``mode="drop"`` scatters skip the padding and every operand
         stays bucket-shaped. Everything is laid out on the host and
-        crosses once. Returns ``(tbl, lens, ql)`` or ``(tbl, lens, ql,
-        pg, of)``; the one ``pool.table`` span site."""
+        crosses in one transfer call. Returns a :class:`StepTables`:
+        ``(tbl, lens, ql)`` or ``(tbl, lens, ql, pg, of)`` on the device
+        — ``merged`` (the layer program's form, fewer arrays to send):
+        ``(rows, slots)`` with ``rows`` = table | lens | q_lens column
+        by column and ``slots`` = (pg, of) — with the host's table and
+        lens beside them; the one ``pool.table`` span site."""
         with telemetry.span("pool.table") as sp:
             tbl, lens = self._padded_kernel_inputs(
                 seq_ids, rows_pad, max_pages)
@@ -1485,14 +1501,48 @@ class PagedKVCacheManager:
                 self._san_check_table(seq_ids, tbl, lens)
             ql = np.zeros((tbl.shape[0],), np.int32)
             ql[:len(seq_ids)] = q_lens
-            out = (tbl, lens, jnp.asarray(ql))
+            host = (tbl, lens, ql)
             if slots is not None:
                 pages, offs = slots
-                out += (_pad_plan(pages, n_pad, self.num_pages),
-                        _pad_plan(offs, n_pad, 0))
+                plan = np.zeros((2, max(int(n_pad), len(pages))), np.int32)
+                plan[0, len(pages):] = self.num_pages
+                plan[0, :len(pages)] = pages
+                plan[1, :len(offs)] = offs
+                host += (plan[0], plan[1])
+            if merged:
+                host = (np.concatenate(
+                    [tbl, lens[:, None], ql[:, None]], 1), plan)
+            out = StepTables(_upload(*host))
+            out.host_tbl, out.host_lens = tbl, lens
             if sp is not None:
                 sp.attrs.update(rows=len(seq_ids), bytes=int(
-                    sum(a.nbytes for a in out)))
+                    sum(a.nbytes for a in host)))
+        return out
+
+    def book_step(self, seq_ids, counts, rows_pad, max_pages, n_pad,
+                  like=None):
+        """This pool's half of one packed step that every layer's pool
+        takes part in: book the rows' slots here (:meth:`_ragged_slots`:
+        capacity precheck, COW forks, length advance, sanitizer event)
+        and return the step's :class:`StepTables`. ``like``: the tables
+        another layer's pool built for the SAME step. Where this pool's
+        booking, page chains and lengths are what ``like`` was built
+        from (pools of one adapter are driven in lockstep, so always in
+        practice) they are shared, checked against this pool's own
+        shadow heap where a sanitizer is on, and nothing is built or
+        uploaded; else this pool builds its own."""
+        counts = [int(c) for c in counts]
+        slots = self._ragged_slots(seq_ids, counts)
+        chains = [self._tables[s] for s in seq_ids]
+        lens = [self._lens[s] for s in seq_ids]
+        if like is not None and like.booked == (slots, chains, lens):
+            if self._san is not None:
+                self._san_check_table(seq_ids, like.host_tbl,
+                                      like.host_lens)
+            return like
+        out = self._step_tables(seq_ids, counts, rows_pad, max_pages,
+                                slots=slots, n_pad=n_pad, merged=True)
+        out.booked = (slots, chains, lens)
         return out
 
     def attend_ragged(self, q, seq_ids, q_lens, rows_pad=None,
@@ -1521,89 +1571,53 @@ class PagedKVCacheManager:
             return apply_op("paged_ragged_attend", f, q,
                             differentiable=False)
 
-    def fused_ragged_step(self, x, weights, rope, positions, seq_ids,
-                          counts, gather_map, scatter_plan,
-                          rows_pad=None, max_pages=None, sm_scale=None,
-                          window=0):
-        """FlashFuser-fused packed attention layer step: qkv
-        projection + RoPE + THIS chunk's K/V page scatter run as the
-        unified ragged kernel's PROLOGUE and o_proj as its EPILOGUE —
-        one compiled program per packed config
-        (ops/kernels/paged_attention.paged_ragged_fused_step). The
-        pool owns the page mutation: the ragged slot plan is booked
-        here (capacity precheck, COW forks, sanitizer events — the
-        forks run BEFORE the program captures the page arrays) and
-        the program's returned pages are committed before the output
-        is handed back.
+    def layer_step(self, x, weights, rope, plan, tables, eps,
+                   sm_scale=None, window=0):
+        """One decoder layer of a packed step as ONE compiled program
+        over this pool's pages (ops/kernels/paged_attention.
+        paged_ragged_layer_step): norm, qkv projection + RoPE + THIS
+        chunk's K/V page scatter, the unified ragged kernel, o_proj,
+        residual, norm, the gated MLP, residual. The pool owns the page
+        mutation: the rows' slots were booked by :meth:`book_step`
+        (which returned ``tables``; the forks ran BEFORE the program
+        takes the page arrays), the arrays are handed over — donated on
+        the chip, this pool holds the only reference — and the
+        program's pages are committed before the output is handed back,
+        so whatever reads ``k_pages`` next reads them after the commit.
 
-        ``x``: (n_pad, E) normed packed hidden states; ``weights`` =
-        (wq, wk, wv, wo, biases) raw [in, out] arrays (biases None or
-        (bq, bk, bv)); ``rope`` = (cos, sin); ``positions`` (n_pad,)
-        absolute positions; ``gather_map`` (rows_pad, T) flat packed
-        indices right-aligning each row; ``scatter_plan`` = (rows,
-        cols, flat) arrays mapping kernel output back to packed
-        slots (real-token length — padded HERE to the bucketed
-        packed length with out-of-bounds drop entries, so the fused
-        dispatch cache keys only bucketed shapes, never the per-step
-        real-token count). Returns the o_proj output (n_pad, E) as a
-        Tensor. Float pools only — int8 page calibration is a
-        host-driven per-token wave replay the fused program cannot
-        express (callers use append_ragged + attend_ragged instead).
-
-        Failure atomicity matches :meth:`append_ragged`: the capacity
-        precheck runs before ANY mutation; past it, the only raises
-        left between slot booking and the page commit are
-        config-class errors (operand shape mismatch — fails the
-        first call, never mid-serving) or a strict-sanitizer
-        violation (the pool was already corrupt), the same window
-        the unfused path's device scatter has."""
-        self._kv_only("fused_ragged_step")
-        with telemetry.span("pool.fused_step", op="fused_ragged_step"):
+        ``x`` (n_pad, E): the packed residual stream (raw array);
+        ``weights`` = (ln1, wq, wk, wv, wo, biases, ln2, wg, wu, wd) raw
+        arrays, [in, out] (biases None or (bq, bk, bv)); ``rope`` =
+        (cos, sin); ``plan`` = (tok, gm) int32 device arrays: ``tok``
+        (5, n_pad), a packed token's id, position and the scatter of
+        the kernel's output back to the packed axis (mr, mc, mflat,
+        padded with out-of-bounds drop entries), ``gm`` (rows_pad, T)
+        the right-aligning gather map; ``tables`` the step's
+        :class:`StepTables` of :meth:`book_step`. Returns the layer's
+        output stream (n_pad, E), a raw array. Float pools only — int8
+        page calibration is a host-driven per-token wave replay the
+        program cannot express (callers use append_ragged +
+        attend_ragged)."""
+        self._kv_only("layer_step")
+        with telemetry.span("pool.fused_step", op="layer_step"):
             if self.quantized:
                 raise ValueError(
-                    "fused_ragged_step: int8 KV pools calibrate per "
-                    "token on the host — use append_ragged + "
-                    "attend_ragged")
-            x = _as_tensor(x)
-            counts = [int(c) for c in counts]
-            n_pad = x._data.shape[0]
-            n_real = sum(counts)
-            mr, mc, mflat = scatter_plan
-            # operand-consistency precheck BEFORE any bookkeeping mutates
-            # (same contract as append_ragged's counts-vs-rows guard): a
-            # mismatched plan must not leave seq lens ahead of device
-            # writes
-            if n_real > n_pad:
+                    "layer_step: int8 KV pools calibrate per token on "
+                    "the host — use append_ragged + attend_ragged")
+            tok, gm = plan
+            rows, slots = tables
+            n_pad = x.shape[0]
+            if not tok.shape[1] == slots.shape[1] == n_pad:
                 raise ValueError(
-                    f"fused_ragged_step: counts sum to {n_real} but the "
-                    f"packed operand carries {n_pad} rows")
-            plan_lens = {len(a) for a in (mr, mc, mflat)}
-            if len(plan_lens) != 1 or next(iter(plan_lens)) not in (
-                    n_real, n_pad):
-                raise ValueError(
-                    f"fused_ragged_step: scatter plan lengths "
-                    f"{[len(a) for a in (mr, mc, mflat)]} match neither "
-                    f"the {n_real} real packed tokens nor the padded "
-                    f"{n_pad} (pre-padded plans carry out-of-bounds "
-                    "drop entries)")
-            tbl, lens, ql, pg, of = self._step_tables(
-                seq_ids, counts, rows_pad, max_pages,
-                slots=self._ragged_slots(seq_ids, counts), n_pad=n_pad)
-            # the adapter's scatter plan pads the same way: flat slot
-            # n_pad is out of bounds and drops
-            mr = _pad_plan(mr, n_pad, 0)
-            mc = _pad_plan(mc, n_pad, 0)
-            mflat = _pad_plan(mflat, n_pad, n_pad)
-            wq, wk, wv, wo, biases = weights
-            cos, sin = rope
-            y, kp, vp = _fused_step_fn(
-                x._data, wq, wk, wv, wo, biases, cos, sin, positions,
-                pg, of, gather_map, mr, mc, mflat,
-                self.k_pages, self.v_pages, tbl, lens, ql,
-                sm_scale=sm_scale, window=window)
-            self.k_pages = kp
-            self.v_pages = vp
-            return Tensor(y)
+                    f"layer_step: the packed operand carries {n_pad} "
+                    f"rows, its plan {tok.shape[1]} and {slots.shape[1]}"
+                    " (every plan operand is padded to the packed "
+                    "length)")
+            y, self.k_pages, self.v_pages = _layer_step_fn(
+                self.k_pages, self.v_pages, x, weights, rope,
+                (tok, gm, slots, rows), eps, sm_scale=sm_scale,
+                window=window)
+            return y
 
     def latent_ragged_step(self, q, toks, seq_ids, counts, gather_map,
                            value_dim, rows_pad=None, max_pages=None,
@@ -1621,7 +1635,7 @@ class PagedKVCacheManager:
         leading numbers of a cached row that are its value. Returns the
         kernel's output (rows_pad, T, H, value_dim) as a Tensor (padded
         leading rows exact zeros). The pool owns the page mutation as in
-        :meth:`fused_ragged_step`: slots are booked first (capacity
+        :meth:`layer_step`: slots are booked first (capacity
         precheck, COW forks, sanitizer events), the program's pages are
         committed before the output is handed back."""
         with telemetry.span("pool.fused_step", op="latent_ragged_step"):

@@ -24,19 +24,12 @@ from ..ops.kernels.paged_attention import (
     packed_position_index as _packed_position_index,
 )
 
-__all__ = ["PagedAdapterBase", "PackedRows", "pow2", "right_align_plan",
-           "right_align_plan_np",
-           "plan_packed_rows", "logits_epilogue"]
+__all__ = ["PagedAdapterBase", "PackedRows", "pow2",
+           "right_align_plan_np", "plan_packed_rows", "logits_epilogue"]
 
 
 def pow2(n: int) -> int:
     return 1 << (max(int(n), 1) - 1).bit_length()
-
-
-def right_align_plan(row_indices, starts, counts, t_pad, rows_pad):
-    """:func:`right_align_plan_np` with every operand on the device."""
-    return tuple(jnp.asarray(a) for a in right_align_plan_np(
-        row_indices, starts, counts, t_pad, rows_pad))
 
 
 def right_align_plan_np(row_indices, starts, counts, t_pad, rows_pad):
@@ -163,8 +156,11 @@ class PagedAdapterBase:
         self._dispatch_shapes = set()
         self._kernel_shapes = set()
         self._bucket_programs = {}   # pad_to -> set of kernel shapes
+        # attend_calls counts a step's per-layer attend dispatches,
+        # layer_programs those that ran the whole layer as one program
         self.chunk_stats = {"calls": 0, "packed_tokens": 0,
-                            "padded_tokens": 0, "attend_calls": 0}
+                            "padded_tokens": 0, "attend_calls": 0,
+                            "layer_programs": 0}
 
     def _count_packed_step(self, rows: PackedRows):
         self._dispatch_shapes.add(rows.pad_to)

@@ -11,22 +11,37 @@ whole ragged batch, with pages shared from a fixed pool.
 The adapter reuses the model's own weights/layers (no copy): embed →
 per layer (rms_norm → qkv → RoPE at each sequence's own position →
 paged append + attend → o_proj → mlp) → final norm → lm head.
+
+A packed step (``prefill_chunk``) has one plan and two ways to dispatch
+it, told apart by what the adapter can observe (``_fusion_eligible``):
+a dense float model runs ONE compiled program a layer over the pools'
+raw arrays (``PagedKVCacheManager.layer_step``), with embed and head as
+programs too and every index operand of the step built in numpy and
+uploaded once; anything else (int8 pages or weights, routed experts,
+sharded or biased projections) runs the same plan op by op.
 """
 from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..framework import telemetry
 from ..framework.core import Tensor, no_grad
 from ..incubate.nn import PagedKVCacheManager
-from ..ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
+from ..nn.layer.norm import RMSNorm
+from ..ops.kernels.paged_attention import (
+    packed_position_index_np as _position_index,
+    pad_plan_np as _pad_plan,
+    upload_plan as _upload,
+)
+from ..ops.kernels.rms_norm import rms_norm as _rms_norm
 from ..ops.kernels.rope import apply_rotary_emb, build_rope_cache
 from ..tensor.manipulation import reshape
 from .paged_common import (
     PagedAdapterBase, logits_epilogue, plan_packed_rows,
-    pow2 as _pow2, right_align_plan as _right_align_plan,
+    pow2 as _pow2, right_align_plan_np as _right_align_plan,
 )
 
 __all__ = ["PagedLlamaAdapter"]
@@ -111,41 +126,78 @@ class PagedLlamaAdapter(PagedAdapterBase):
         )
         self._init_dispatch_accounting()
         self._fused_ok = None
+        self._programs = None
 
     def _fusion_eligible(self) -> bool:
-        """The fusion gate, computed once per adapter: the
-        fused prologue/epilogue consumes raw [in, out] projection
-        weights and writes fp pages, so every layer's q/k/v/o
-        projection must be a plain (non-distributed, non-weight-
-        quantized) linear and the KV pool must be float — int8 page
-        calibration is a host-driven per-token wave replay. Ineligible
-        adapters keep the unified attend, just unfused."""
+        """The gate of the programmed body, computed once per adapter
+        from what it can observe: the layer program takes raw [in, out]
+        float weights as operands and writes float pages, so the KV
+        pool must be float (int8 page calibration is a host-driven
+        per-token wave replay) and every layer a dense one of plain
+        parts — q/k/v/o and the gate/up/down of a ``LlamaMLP`` plain
+        (non-distributed, non-weight-quantized) linears, bias-free but
+        for an all-or-none q/k/v bias, ``RMSNorm`` norms of one epsilon
+        — with a plain embedding and head. Anything else keeps the same
+        plan, op by op."""
         if self._fused_ok is None:
-            ok = not self.caches[0].quantized \
-                and self.weight_dtype is None
-            if ok:
-                for layer in self.model.model.layers:
-                    att = layer.self_attn
-                    projs = (att.q_proj, att.k_proj, att.v_proj,
-                             att.o_proj)
-                    for proj in projs:
-                        w = getattr(proj, "weight", None)
-                        if (w is None
-                                or getattr(w, "is_distributed", False)
-                                or getattr(getattr(w, "_data", None),
-                                           "ndim", 0) != 2):
-                            ok = False
-                            break
-                    has = [getattr(p, "bias", None) is not None
-                           for p in projs[:3]]
-                    if any(has) and not all(has):
-                        ok = False
-                    if getattr(att.o_proj, "bias", None) is not None:
-                        ok = False  # epilogue models bias-free o_proj
-                    if not ok:
-                        break
-            self._fused_ok = ok
+            self._fused_ok = not self.caches[0].quantized \
+                and self.weight_dtype is None and self._plain_dense()
         return self._fused_ok
+
+    def _plain_dense(self) -> bool:
+        from ..models.llama import LlamaMLP
+
+        def plain(w, ndim=2):
+            data = getattr(w, "_data", None)
+            return (w is not None
+                    and not getattr(w, "is_distributed", False)
+                    and getattr(data, "ndim", 0) == ndim
+                    and jnp.issubdtype(data.dtype, jnp.floating))
+
+        def linear(proj, bias_ok=False):
+            return plain(getattr(proj, "weight", None)) and (
+                bias_ok or getattr(proj, "bias", None) is None)
+
+        core = self.model.model
+        norms = [core.norm]
+        for layer in core.layers:
+            att, mlp = layer.self_attn, layer.mlp
+            qkv = (att.q_proj, att.k_proj, att.v_proj)
+            has = [getattr(p, "bias", None) is not None for p in qkv]
+            if not (all(linear(p, bias_ok=True) for p in qkv)
+                    and (all(has) or not any(has))
+                    and all(plain(p.bias, 1) for p in qkv if has[0])
+                    and linear(att.o_proj)   # bias-free epilogue
+                    and type(mlp) is LlamaMLP
+                    and all(linear(p) for p in (
+                        mlp.gate_proj, mlp.up_proj, mlp.down_proj))):
+                return False
+            norms += [layer.input_layernorm, layer.post_attention_layernorm]
+        head = self.model.lm_head
+        return (all(type(n) is RMSNorm and plain(n.weight, 1)
+                    for n in norms)
+                and len({float(n._epsilon) for n in norms}) == 1
+                and plain(core.embed_tokens.weight)
+                and (head is None or linear(head)))
+
+    def _step_programs(self):
+        """(embed, head, eps) of the programmed body: the two programs
+        beside the layers', each ``jax.jit`` of a pure function of arrays
+        (jit keys on their bucketed shapes), and the norms' epsilon."""
+        if self._programs is None:
+            eps = float(self.model.model.norm._epsilon)
+            tied = self.model.lm_head is None
+
+            def embed(emb, tok):
+                return jnp.take(emb, tok[0], axis=0)
+
+            def head(x, idx, norm_w, head_w):
+                h = _rms_norm(x[idx], norm_w, eps)
+                return h @ head_w.T if tied else jnp.matmul(h, head_w)
+
+            embed.__name__, head.__name__ = "llama_embed", "llama_head"
+            self._programs = jax.jit(embed), jax.jit(head), eps
+        return self._programs
 
     def decode_token(self, token_ids, seq_ids):
         """One token per listed sequence; returns logits (B, vocab)."""
@@ -192,143 +244,180 @@ class PagedLlamaAdapter(PagedAdapterBase):
             h = self.model.model.norm(x)
             return self.model._head(h)
 
+    def prefill_chunk(self, token_ids, seq_ids, start_positions=None,
+                      pad_to=None, logits_rows=None):
+        """One ragged mixed prefill/decode step (the Ragged Paged
+        Attention shape — see PAPERS.md): row i appends the
+        ``len(token_ids[i])`` tokens of ``token_ids[i]`` to sequence
+        ``seq_ids[i]`` and the call returns the logits of every row's
+        LAST token, (B, vocab) — single-token rows are exactly
+        ``decode_token`` rows, multi-token rows are prefill chunks
+        resuming at ``start_positions[i]`` (validated against the cache;
+        mid-prompt resume and mid-page cached-prefix resume both work).
 
-def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
-                   pad_to=None, logits_rows=None):
-    """One ragged mixed prefill/decode step (the Ragged Paged
-    Attention shape — see PAPERS.md): row i appends the
-    ``len(token_ids[i])`` tokens of ``token_ids[i]`` to sequence
-    ``seq_ids[i]`` and the call returns the logits of every row's
-    LAST token, (B, vocab) — single-token rows are exactly
-    ``decode_token`` rows, multi-token rows are prefill chunks
-    resuming at ``start_positions[i]`` (validated against the cache;
-    mid-prompt resume and mid-page cached-prefix resume both work).
+        ``logits_rows`` (ISSUE 19, speculative VERIFY rows): a list of
+        row indices whose PER-POSITION logits the caller needs — the
+        greedy verify step compares the target argmax at every window
+        slot against the draft proposal there. The return value becomes
+        ``(last_logits, full_logits)`` where ``full_logits`` is the
+        ``(sum(counts[i] for i in logits_rows), vocab)`` concatenation
+        of the listed rows' positions in list order (split host-side by
+        the known counts). The multi-row sampling epilogue is a gather
+        (ops/kernels/paged_attention.packed_position_index) + norm +
+        lm-head over the packed activations the step already computed,
+        so verify rows add NO compiled attend program beyond the existing
+        bucketed ragged family.
 
-    ``logits_rows`` (ISSUE 19, speculative VERIFY rows): a list of
-    row indices whose PER-POSITION logits the caller needs — the
-    greedy verify step compares the target argmax at every window
-    slot against the draft proposal there. The return value becomes
-    ``(last_logits, full_logits)`` where ``full_logits`` is the
-    ``(sum(counts[i] for i in logits_rows), vocab)`` concatenation
-    of the listed rows' positions in list order (split host-side by
-    the known counts). The multi-row sampling epilogue is a gather
-    (ops/kernels/paged_attention.packed_position_index) + norm +
-    lm-head over the packed activations the step already computed —
-    eager like the chunk body, so verify rows add NO compiled attend
-    program beyond the existing bucketed ragged family.
+        All dense compute (embed / qkv / o_proj / mlp / norms) runs over
+        ONE flat packed token axis padded to ``pad_to`` (the scheduler
+        buckets it — serving.bucket_packed_tokens — so steady-state
+        serving compiles one program per bucket, not per packed length).
+        Attention is ONE ragged kernel call per layer for the whole mixed
+        batch: every row — single-token decode rows and multi-token chunks
+        alike — rides the unified ragged kernel right-aligned with its own
+        q_lens (fused int8-KV dequant included), padded to power-of-two
+        row/length/page-table shapes so the kernel programs are
+        shape-stable. Where :meth:`_fusion_eligible` (a dense float model
+        over float pages) a layer is ONE compiled program
+        (``cache.layer_step``: norm, qkv + RoPE + page scatter, the
+        kernel, o_proj, residual, norm, MLP, residual; the layer's weights
+        are operands, so the layers share the program of a shape), embed
+        and head are programs too, and the step's index operands cross to
+        the device once; otherwise the same plan runs op by op
+        (``cache.append_ragged`` + ``cache.attend_ragged``)."""
+        with telemetry.span("model.plan") as plan_span:
+            rows = plan_packed_rows(self.caches[0], token_ids, seq_ids,
+                                    start_positions, pad_to, self.max_length)
+            b, counts, pad_to = rows.b, rows.counts, rows.pad_to
+            self._count_packed_step(rows)
 
-    All dense compute (embed / qkv / o_proj / mlp / norms) runs over
-    ONE flat packed token axis padded to ``pad_to`` (the scheduler
-    buckets it — serving.bucket_packed_tokens — so steady-state
-    serving compiles one program per bucket, not per packed length).
-    Attention is ONE ragged kernel call per layer for the whole mixed
-    batch: every row — single-token decode rows and multi-token chunks
-    alike — rides the unified ragged kernel right-aligned with its own
-    q_lens (fused int8-KV dequant included), padded to power-of-two
-    row/length/page-table shapes so the kernel programs are
-    shape-stable. Where :meth:`_fusion_eligible` (fp pages + plain
-    projection weights) the whole layer attention step fuses
-    FlashFuser-style: qkv + RoPE + page scatter as the kernel's
-    prologue, o_proj as its epilogue (``cache.fused_ragged_step``);
-    otherwise the same plan runs unfused (``cache.append_ragged`` +
-    ``cache.attend_ragged``)."""
-    cfg = self.cfg
-    with telemetry.span("model.plan") as plan_span:
-        rows = plan_packed_rows(self.caches[0], token_ids, seq_ids,
-                                start_positions, pad_to, self.max_length)
-        b, counts, starts = rows.b, rows.counts, rows.starts
-        flat, pos_np = rows.flat, rows.pos_np
-        n_real, pad_to, mp_pad = rows.n_real, rows.pad_to, rows.mp_pad
+            # gather/scatter plan (host-built once, shared by every layer):
+            # ONE right-aligned ragged block for EVERY row — decode rows are
+            # q_lens=1 rows of the same kernel call (the Ragged Paged
+            # Attention shape), so each packed config compiles ONE attend
+            # program
+            t_pad = _pow2(max(counts))
+            b_pad = _pow2(b)
+            gm, mr, mc, m_flat = _right_align_plan(
+                range(b), rows.starts, counts, t_pad, b_pad)
+            fuse = self._fusion_eligible()
+            # the layer program embeds the packed dense work, so its REAL
+            # dispatch key includes the packed bucket (pad_to) — the pure
+            # attend program's does not
+            shape = ("ragged_fused", b_pad, t_pad, rows.mp_pad, pad_to) \
+                if fuse else ("ragged", b_pad, t_pad, rows.mp_pad)
+            self._count_kernel_shape(pad_to, shape)
+            if fuse:
+                # every index operand bucket-shaped (out-of-bounds fills
+                # drop in the program's scatters), the per-token ones
+                # merged into one array, and all across in one call
+                tok = np.stack([rows.flat, rows.pos_np,
+                                _pad_plan(mr, pad_to, 0),
+                                _pad_plan(mc, pad_to, 0),
+                                _pad_plan(m_flat, pad_to, pad_to)])
+                host = [tok, gm, rows.last_idx]
+                if logits_rows is not None:
+                    host.append(_position_index(rows.starts, counts,
+                                                logits_rows))
+                up = _upload(*host)
+            else:
+                up = [jnp.asarray(a) for a in (
+                    rows.flat[:, None], rows.pos_np, gm, mr, mc, m_flat)]
+            if plan_span is not None:
+                plan_span.attrs.update(
+                    rows=b, packed=rows.n_real, pad_to=pad_to,
+                    bytes=sum(int(a.nbytes) for a in up))
+        with no_grad():
+            if fuse:
+                return self._run_programs(rows, seq_ids, up, b_pad)
+            return self._run_eager(rows, seq_ids, up, b_pad, logits_rows)
+
+    def _run_programs(self, rows, seq_ids, up, b_pad):
+        """The programmed body of a packed step: one dispatch for the
+        embedding, one a layer, one for the head (two with verify rows,
+        whose positions are the fourth operand of ``up``)."""
+        span = telemetry.span
+        embed, head, eps = self._step_programs()
+        core, caches = self.model.model, self.caches
+        tok, gm, last, *verify = up
+        # every layer's pool books its own slots BEFORE the first layer
+        # runs (an exhausted pool raises with every page array untouched);
+        # the tables are built and uploaded by the first and shared
+        tables = []
+        for cache in caches:
+            tables.append(cache.book_step(
+                seq_ids, rows.counts, b_pad, rows.mp_pad, rows.pad_to,
+                like=tables[-1] if tables else None))
+        plan, rope = (tok, gm), (self._cos, self._sin)
+        with span("model.embed"):
+            x = embed(core.embed_tokens.weight._data, tok)      # (N, H)
+        for li, layer in enumerate(core.layers):
+            with span("model.layer", li=li, program=1):
+                att, mlp = layer.self_attn, layer.mlp
+                biases = None
+                if att.q_proj.bias is not None:
+                    biases = (att.q_proj.bias._data, att.k_proj.bias._data,
+                              att.v_proj.bias._data)
+                self.chunk_stats["attend_calls"] += 1
+                self.chunk_stats["layer_programs"] += 1
+                x = caches[li].layer_step(
+                    x, (layer.input_layernorm.weight._data,
+                        att.q_proj.weight._data, att.k_proj.weight._data,
+                        att.v_proj.weight._data, att.o_proj.weight._data,
+                        biases, layer.post_attention_layernorm.weight._data,
+                        mlp.gate_proj.weight._data, mlp.up_proj.weight._data,
+                        mlp.down_proj.weight._data),
+                    rope, plan, tables[li], eps, window=self._window)
+        with span("model.head"):
+            head_w = (core.norm.weight._data,
+                      (core.embed_tokens if self.model.lm_head is None
+                       else self.model.lm_head).weight._data)
+            logits = Tensor(head(x, last, *head_w))
+            if not verify:
+                return logits
+            # multi-row sampling epilogue: per-position logits for
+            # the listed (verify) rows, concatenated in list order
+            return logits, Tensor(head(x, verify[0], *head_w))
+
+    def _run_eager(self, rows, seq_ids, up, b_pad, logits_rows):
+        """The op-by-op body of a packed step (int8 pages or weights,
+        routed experts, sharded or biased projections)."""
+        cfg, span = self.cfg, telemetry.span
+        counts, n_real, pad_to = rows.counts, rows.n_real, rows.pad_to
         nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                        cfg.head_dim)
-        pos = jnp.asarray(pos_np)[None, :]             # (1, N)
-        self._count_packed_step(rows)
-
-        # gather/scatter plan (host-built once, shared by every layer):
-        # ONE right-aligned ragged block for EVERY row — decode rows are
-        # q_lens=1 rows of the same kernel call (the Ragged Paged
-        # Attention shape), so each packed config compiles ONE attend
-        # program
-        t_pad = _pow2(max(counts))
-        b_pad = _pow2(b)
-        gm, mr, mc, m_flat = _right_align_plan(
-            range(b), starts, counts, t_pad, b_pad)
-        fuse = self._fusion_eligible()
-        # the fused program embeds the packed dense prologue/epilogue,
-        # so its REAL dispatch key includes the packed bucket (pad_to)
-        # — the pure attend program's does not
-        shape = ("ragged_fused", b_pad, t_pad, mp_pad, pad_to) \
-            if fuse else ("ragged", b_pad, t_pad, mp_pad)
-        self._count_kernel_shape(pad_to, shape)
-        pos_flat = jnp.asarray(pos_np)
-        if fuse:
-            # loop-invariant across layers: pad the scatter plan to
-            # the bucket ONCE (out-of-bounds fills drop in the fused
-            # program's scatters) instead of once per layer
-            mr = _pad_plan(mr, pad_to, 0)
-            mc = _pad_plan(mc, pad_to, 0)
-            m_flat = _pad_plan(m_flat, pad_to, pad_to)
-        ids = Tensor(flat[:, None])
-        if plan_span is not None:
-            up = [ids._data, pos, pos_flat, gm, mr, mc, m_flat]
-            plan_span.attrs.update(rows=b, packed=n_real, pad_to=pad_to,
-                            bytes=sum(int(a.nbytes) for a in up))
-
-    span = telemetry.span
-    with no_grad():
+        ids, pos, gm, mr, mc, m_flat = up
+        ids, pos = Tensor(ids), pos[None, :]                       # (1, N)
         with span("model.embed"):
             x = self.model.model.embed_tokens(ids)[:, 0]     # (N, H)
         for li, layer in enumerate(self.model.model.layers):
-            with span("model.layer", li=li):
+            with span("model.layer", li=li, program=0):
                 cache = self.caches[li]
                 with span("model.norm"):
                     xi = layer.input_layernorm(x)
-                if fuse:
-                    # FlashFuser path: qkv + RoPE + page scatter fold
-                    # into the ragged kernel's prologue and o_proj
-                    # into its epilogue — one program, one dispatch
-                    # per layer
-                    att = layer.self_attn
-                    biases = None
-                    if att.q_proj.bias is not None:
-                        biases = (att.q_proj.bias._data,
-                                  att.k_proj.bias._data,
-                                  att.v_proj.bias._data)
-                    self.chunk_stats["attend_calls"] += 1
-                    y = cache.fused_ragged_step(
-                        xi,
-                        (att.q_proj.weight._data,
-                         att.k_proj.weight._data,
-                         att.v_proj.weight._data,
-                         att.o_proj.weight._data, biases),
-                        (self._cos, self._sin), pos_flat, seq_ids,
-                        counts, gm, (mr, mc, m_flat), rows_pad=b_pad,
-                        max_pages=mp_pad, window=self._window)
-                    x = x + y
-                else:
-                    q = layer.self_attn.q_proj(xi)
-                    k = layer.self_attn.k_proj(xi)
-                    v = layer.self_attn.v_proj(xi)
-                    qh = q._data.reshape(1, pad_to, nh, hd)
-                    kh = k._data.reshape(1, pad_to, nkv, hd)
-                    vh = v._data.reshape(1, pad_to, nkv, hd)
-                    qh = apply_rotary_emb(
-                        qh, self._cos, self._sin, position_ids=pos)[0]
-                    kh = apply_rotary_emb(
-                        kh, self._cos, self._sin, position_ids=pos)[0]
-                    vh = vh[0]
-                    cache.append_ragged(
-                        seq_ids, counts, kh[:n_real], vh[:n_real])
-                    qm = qh[gm]              # (b_pad, t_pad, nh, hd)
-                    self.chunk_stats["attend_calls"] += 1
-                    out = cache.attend_ragged(
-                        Tensor(qm), seq_ids, counts,
-                        rows_pad=b_pad, max_pages=mp_pad,
-                        window=self._window)
-                    attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
-                    attn = attn.at[m_flat].set(out._data[mr, mc])
-                    attn_flat = Tensor(attn.reshape(pad_to, nh * hd))
-                    x = x + layer.self_attn.o_proj(attn_flat)
+                q = layer.self_attn.q_proj(xi)
+                k = layer.self_attn.k_proj(xi)
+                v = layer.self_attn.v_proj(xi)
+                qh = q._data.reshape(1, pad_to, nh, hd)
+                kh = k._data.reshape(1, pad_to, nkv, hd)
+                vh = v._data.reshape(1, pad_to, nkv, hd)
+                qh = apply_rotary_emb(
+                    qh, self._cos, self._sin, position_ids=pos)[0]
+                kh = apply_rotary_emb(
+                    kh, self._cos, self._sin, position_ids=pos)[0]
+                vh = vh[0]
+                cache.append_ragged(
+                    seq_ids, counts, kh[:n_real], vh[:n_real])
+                qm = qh[gm]              # (b_pad, t_pad, nh, hd)
+                self.chunk_stats["attend_calls"] += 1
+                out = cache.attend_ragged(
+                    Tensor(qm), seq_ids, counts,
+                    rows_pad=b_pad, max_pages=rows.mp_pad,
+                    window=self._window)
+                attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
+                attn = attn.at[m_flat].set(out._data[mr, mc])
+                attn_flat = Tensor(attn.reshape(pad_to, nh * hd))
+                x = x + layer.self_attn.o_proj(attn_flat)
                 with span("model.norm"):
                     h2 = layer.post_attention_layernorm(x)
                 with span("model.mlp"):
@@ -340,7 +429,3 @@ def _prefill_chunk(self, token_ids, seq_ids, start_positions=None,
                 x._data, rows,
                 lambda xr: self.model._head(
                     self.model.model.norm(Tensor(xr))), logits_rows)
-
-
-PagedLlamaAdapter.prefill_chunk = _prefill_chunk
-del _prefill_chunk

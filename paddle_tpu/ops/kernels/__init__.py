@@ -119,7 +119,7 @@ from .paged_attention import (  # noqa
     paged_attention_reference,
     paged_ragged_attention,
     paged_ragged_attention_reference,
-    paged_ragged_fused_step,
+    paged_ragged_layer_step,
 )
 from .collective_matmul import (  # noqa
     all_gather_matmul,

@@ -40,11 +40,13 @@ Latent pages (:func:`latent_ragged_attention`, MLA): one
 K/V once for every head; the absorbed kernel reads it through the same
 page table, lengths and right-aligned rows.
 
-FlashFuser-style fusion (:func:`paged_ragged_fused_step`): once the
-attention path is ONE program, the packed dense neighbours fold into
-it — qkv projection + RoPE + the K/V page scatter run as the kernel's
-prologue and o_proj as its epilogue, inside the same compiled program,
-so a serving layer step is a single dispatch instead of five.
+The layer program (:func:`paged_ragged_layer_step`): once the
+attention path is ONE program, a dense decoder layer folds into it —
+``rms_norm``, qkv projection + RoPE + the K/V page scatter, the kernel,
+o_proj, the residual, ``rms_norm``, the gated MLP and its residual are
+one compiled program with the layer's weights as operands (every layer
+shares the program of a shape) and the pools donated on the chip, so a
+serving layer step is a single dispatch.
 
 Dispatch caching: eager callers (the serving step loop, tests) hit a
 shape-keyed LRU of ``jax.jit``-ted entry points, so stepping the same
@@ -67,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...framework import telemetry
 from . import on_tpu  # defined before the package imports its kernels
+from .rms_norm import rms_norm
 from .rope import apply_rotary_emb
 
 NEG_INF = -1e30
@@ -528,54 +531,62 @@ def _jitted_ragged_call(cfg):
     return jax.jit(_build_ragged_call(*cfg))
 
 
-def _build_fused_call(n_pad, e, nh, kvh, hd, npages,
-                      page_size, b_pad, t_pad, max_pages, scale,
-                      window, has_bias, interpret):
-    """FlashFuser-style fused packed attention step: qkv projection +
-    RoPE + the K/V page scatter as the ragged kernel's PROLOGUE and
-    o_proj as its EPILOGUE, one compiled program per packed config.
+def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
+                      t_pad, max_pages, scale, window, has_bias, eps,
+                      interpret):
+    """One decoder layer of the packed serving step as ONE program:
+    ``rms_norm`` -> qkv projection + RoPE + this chunk's K/V page scatter
+    -> the ragged kernel over the right-aligned rows -> scatter back +
+    ``o_proj`` -> residual -> ``rms_norm`` -> ``down(silu(gate) * up)``
+    -> residual. The layer's weights are OPERANDS, so every layer of a
+    model shares the program of a shape.
 
-    Operands (all arrays; statics live in the cfg key — every operand
-    is padded to the BUCKETED shapes, so the per-step real-token
-    count never re-keys the dispatch cache):
+    Operands (all arrays; statics live in the cfg key — every operand is
+    padded to the BUCKETED shapes, so neither the layer nor the step's
+    real token count re-keys the dispatch cache):
 
-    * ``x`` (n_pad, e) — the normed packed hidden states;
-    * ``wq/wk/wv`` (e, nh*hd / kvh*hd) and ``wo`` (nh*hd, e) — the
-      layer's projection weights ([in, out] paddle layout); optional
-      q/k/v biases when ``has_bias``;
-    * ``cos/sin`` (S, hd) RoPE tables, ``pos`` (n_pad,) per-token
-      absolute positions;
-    * ``pg/of`` (n_pad,) physical page / in-page slot per written
-      token; PADDING entries carry an out-of-bounds page id and the
-      scatter runs mode="drop", so they write nothing;
-    * ``gm`` (b_pad, t_pad) flat-index gather map right-aligning each
-      row's tokens, ``mr/mc/mflat`` (n_pad,) the inverse scatter
-      (padding entries gather slot (0, 0) and drop on an
-      out-of-bounds ``mflat``);
-    * ``k_pages/v_pages`` + ``tbl/lens/q_lens`` as in
-      :func:`paged_ragged_attention`.
+    * ``k_pages/v_pages`` first (the donated pair on the chip), then
+      ``x`` (n_pad, e), the packed residual stream;
+    * ``ln1``, ``wq/wk/wv`` (e, nh*hd / kvh*hd), ``wo`` (nh*hd, e)
+      ([in, out] paddle layout), the q/k/v biases when ``has_bias``,
+      ``ln2``, ``wg/wu`` (e, f), ``wd`` (f, e);
+    * ``cos/sin`` (S, hd) RoPE tables;
+    * the step's index operands, merged so that few arrays cross to the
+      device: ``tok`` (5, n_pad), a packed token's id (unused here),
+      absolute position and ``mr/mc/mflat``, the scatter of the
+      kernel's output back to the packed axis (padding entries gather
+      slot (0, 0) and drop on an out-of-bounds ``mflat``); ``gm``
+      (b_pad, t_pad), the flat-index gather map right-aligning each
+      row's tokens; ``slots`` (2, n_pad), physical page / in-page slot
+      per written token (PADDING entries carry an out-of-bounds page id
+      and the scatter runs mode="drop", so they write nothing);
+      ``rows`` (b_pad, max_pages + 2), a row's page table, then its
+      ``seq_len`` and ``q_len`` as in :func:`paged_ragged_attention`.
 
-    Returns ``(y (n_pad, e), new_k_pages, new_v_pages)`` — the caller
-    (the pool, which owns page state) commits the returned pages.
+    Returns ``(x_out (n_pad, e), new_k_pages, new_v_pages)`` — the caller
+    (the pool, which owns page state) commits the returned pages. The
+    mathematics is the eager layer's: the same ``rms_norm`` kernel and
+    epsilon, ``jnp.matmul`` on the operands' own dtype (``F.linear``),
+    activations in the stream's dtype.
     """
     attend = _build_ragged_call(
         b_pad, t_pad, nh, hd, npages, page_size, kvh, max_pages,
         scale, window, False, True, interpret)
 
-    def run(x, wq, wk, wv, wo, *rest):
+    def run(k_pages, v_pages, x, ln1, wq, wk, wv, wo, *rest):
         rest = list(rest)
         bq = bk = bv = None
         if has_bias:
             bq, bk, bv = rest[:3]
             rest = rest[3:]
-        (cos, sin, pos, pg, of, gm, mr, mc, mflat,
-         k_pages, v_pages, tbl, lens, q_lens) = rest
-        # -- prologue: qkv projection + RoPE (same jnp.matmul as
-        # F.linear, so the fused program is numerically identical to
-        # the eager layer path)
-        xq = jnp.matmul(x, wq)
-        xk = jnp.matmul(x, wk)
-        xv = jnp.matmul(x, wv)
+        ln2, wg, wu, wd, cos, sin, tok, gm, slots, rows = rest
+        _, pos, mr, mc, mflat = tok
+        (pg, of), tbl = slots, rows[:, :max_pages]
+        lens, q_lens = rows[:, max_pages], rows[:, max_pages + 1]
+        h = rms_norm(x, ln1, eps)
+        xq = jnp.matmul(h, wq)
+        xk = jnp.matmul(h, wk)
+        xv = jnp.matmul(h, wv)
         if has_bias:
             xq, xk, xv = xq + bq, xk + bk, xv + bv
         qh = xq.reshape(1, n_pad, nh, hd)
@@ -583,29 +594,36 @@ def _build_fused_call(n_pad, e, nh, kvh, hd, npages,
         vh = xv.reshape(n_pad, kvh, hd)
         qh = apply_rotary_emb(qh, cos, sin, position_ids=pos)[0]
         kh = apply_rotary_emb(kh, cos, sin, position_ids=pos)[0]
-        # -- prologue: land this chunk's K/V in the pages (the pool
-        # computed the slot plan; the scatter itself fuses here —
-        # padding rows carry out-of-bounds page ids and drop)
+        # land this chunk's K/V in the pages (the pool computed the
+        # slot plan; padding rows carry out-of-bounds page ids and drop)
         kp = k_pages.at[pg, of].set(
             kh.astype(k_pages.dtype), mode="drop")
         vp = v_pages.at[pg, of].set(
             vh.astype(v_pages.dtype), mode="drop")
-        # -- the unified ragged kernel over the right-aligned rows
         qm = qh[gm]                        # (b_pad, t_pad, nh, hd)
         out = attend(qm, kp, vp, tbl, lens, q_lens)
-        # -- epilogue: scatter back to the packed axis + o_proj
-        # (padding entries target the out-of-bounds slot n_pad: drop)
+        # scatter back to the packed axis (padding entries target the
+        # out-of-bounds slot n_pad: drop) + o_proj
         attn = jnp.zeros((n_pad, nh, hd), qh.dtype)
         attn = attn.at[mflat].set(out[mr, mc], mode="drop")
-        y = jnp.matmul(attn.reshape(n_pad, nh * hd), wo)
-        return y, kp, vp
+        x = x + jnp.matmul(attn.reshape(n_pad, nh * hd), wo)
+        h2 = rms_norm(x, ln2, eps)
+        y = jnp.matmul(
+            jax.nn.silu(jnp.matmul(h2, wg)) * jnp.matmul(h2, wu), wd)
+        return x + y, kp, vp
 
+    run.__name__ = "ragged_layer_step"      # jit(<name>) in the traces
     return run
 
 
 @functools.lru_cache(maxsize=256)
-def _jitted_fused_call(cfg):
-    return jax.jit(_build_fused_call(*cfg))
+def _jitted_layer_step(cfg, donate, mesh):
+    """The layer program of a shape. Pools donated on the chip: the pool
+    holds the only reference and commits what comes back. ``mesh`` keys
+    the entry only (the norm kernel wraps itself per mesh)."""
+    del mesh
+    return jax.jit(_build_layer_call(*cfg),
+                   donate_argnums=(0, 1) if donate else ())
 
 
 # --------------------------------------------------------------------------
@@ -869,27 +887,24 @@ def latent_ragged_step(q, toks, pg, of, gm, pages, page_table, seq_lens,
             jnp.asarray(q_lens).astype(jnp.int32))
 
 
-def pad_plan_i32(a, n, fill):
-    """Pad a 1-D int32 plan operand of :func:`paged_ragged_fused_step`
-    to ``n`` entries with ``fill`` — the single place the fused
-    program's out-of-bounds drop-entry contract is encoded for both
-    the adapter-side scatter plan (fill = packed length) and the
-    pool-side page plan (fill = num_pages). A host array is padded on
-    the host and crosses once: a device-side concatenate is one program
-    per (length, padding) pair, and the packed length changes every
-    step."""
-    if not isinstance(a, jax.Array):
-        a = np.asarray(a, np.int32)  # trace-lint: ok(a host plan, no tracer)
-        short = n - a.shape[0]
-        if short > 0:
-            a = np.concatenate([a, np.full((short,), fill, np.int32)])
-        return jnp.asarray(a)
-    a = jnp.asarray(a, jnp.int32)
+def pad_plan_np(a, n, fill):
+    """Pad a 1-D int32 plan operand of a step program to ``n`` entries
+    with ``fill``, on the host — the single place the programs'
+    out-of-bounds drop-entry contract is encoded (fill = the packed
+    length for a scatter back to the packed axis, ``num_pages`` for a
+    page plan)."""
+    a = np.asarray(a, np.int32)      # trace-lint: ok(a host plan, no tracer)
     short = n - a.shape[0]
-    if short <= 0:
-        return a
-    return jnp.concatenate(
-        [a, jnp.full((short,), fill, jnp.int32)])
+    if short > 0:
+        a = np.concatenate([a, np.full((short,), fill, np.int32)])
+    return a
+
+
+def pad_plan_i32(a, n, fill):
+    """:func:`pad_plan_np` on the device: padded on the host, crossing
+    once — a device-side concatenate is one program per (length,
+    padding) pair, and the packed length changes every step."""
+    return jnp.asarray(pad_plan_np(a, n, fill))
 
 
 def packed_position_index(starts, counts, rows):
@@ -903,61 +918,66 @@ def packed_position_index(starts, counts, rows):
     window slot j against draft proposal j), so the epilogue gathers
     ``starts[i] .. starts[i] + counts[i] - 1`` for each verify row
     and runs norm + lm-head over that concatenation — host-built like
-    the right-align plan, eager like the chunk body, so it adds no
-    compiled program (the acceptance bound of ISSUE 19: spec rows
-    reuse the existing bucketed kernel family)."""
-    idx = []
-    for i in rows:
-        s = int(starts[i])
-        idx.append(jnp.arange(s, s + int(counts[i]), dtype=jnp.int32))
-    return jnp.concatenate(idx)
+    the right-align plan, so it adds no compiled attend program (the
+    acceptance bound of ISSUE 19: spec rows reuse the existing bucketed
+    kernel family)."""
+    return jnp.asarray(packed_position_index_np(starts, counts, rows))
 
 
-def paged_ragged_fused_step(x, wq, wk, wv, wo, biases, cos, sin, pos,
-                            pg, of, gm, mr, mc, mflat, k_pages,
-                            v_pages, page_table, seq_lens, q_lens,
-                            sm_scale=None, window=0,
+def packed_position_index_np(starts, counts, rows):
+    """:func:`packed_position_index` on the host (int32 numpy), for a
+    caller that uploads its step's index operands together."""
+    return np.concatenate([
+        np.arange(int(starts[i]), int(starts[i]) + int(counts[i]),
+                  dtype=np.int32) for i in rows])
+
+
+def upload_plan(*arrays):
+    """A step's host-built int32 index operands onto the device in ONE
+    transfer call, in order."""
+    return jax.device_put(tuple(
+        np.ascontiguousarray(a, np.int32) for a in arrays))
+
+
+def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
+                            eps, sm_scale=None, window=0,
                             interpret=None):
-    """One fused packed attention layer step (see
-    :func:`_build_fused_call` for the operand contract: pg/of and
-    mr/mc/mflat arrive PADDED to the bucketed packed length, with
-    padding entries out-of-bounds so the mode="drop" scatters skip
-    them — the dispatch cache keys only bucketed shapes, never the
-    per-step real-token count). ``biases`` is ``None`` or the
-    (bq, bk, bv) triple. Float KV pages only — int8 calibration is a
-    host-driven wave replay the fused program cannot express (callers
-    fall back to the unfused unified path).
+    """One decoder layer of the packed serving step, one dispatch (see
+    :func:`_build_layer_call` for the operand contract). ``weights`` =
+    (ln1, wq, wk, wv, wo, biases, ln2, wg, wu, wd) with ``biases``
+    ``None`` or the (bq, bk, bv) triple; ``rope`` = (cos, sin);
+    ``index`` = (tok, gm, slots, rows): int32 device arrays of the
+    bucketed shapes, built once a step and shared by every layer's
+    call. Float KV pages only — int8 calibration is a host-driven wave
+    replay the program cannot express.
 
-    Returns ``(y, new_k_pages, new_v_pages)``; the page-pool owner
-    commits the returned page arrays.
+    Returns ``(x_out, new_k_pages, new_v_pages)``; on the chip the pools
+    handed in are DONATED: the page-pool owner holds the only reference
+    and commits the returned arrays.
     """
+    from ...distributed.mesh import global_mesh
+
+    ln1, wq, wk, wv, wo, biases, ln2, wg, wu, wd = weights
+    cos, sin = rope
+    _, gm, _, rows = index
     n_pad, e = x.shape
     hd = cos.shape[1]
     nh = wq.shape[1] // hd
     kvh = wk.shape[1] // hd
     npages, page_size, _, _ = k_pages.shape
     b_pad, t_pad = gm.shape
-    max_pages = page_table.shape[1]
+    max_pages = rows.shape[1] - 2
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
     if interpret is None:
         interpret = not on_tpu()
     has_bias = biases is not None
     cfg = (n_pad, e, nh, kvh, hd, npages, page_size,
            b_pad, t_pad, max_pages, float(scale), int(window or 0),
-           has_bias, bool(interpret))
-    args = [x, wq, wk, wv, wo]
-    if has_bias:
-        args += list(biases)
-    args += [cos, sin, jnp.asarray(pos, jnp.int32),
-             jnp.asarray(pg, jnp.int32), jnp.asarray(of, jnp.int32),
-             jnp.asarray(gm, jnp.int32), jnp.asarray(mr, jnp.int32),
-             jnp.asarray(mc, jnp.int32), jnp.asarray(mflat, jnp.int32),
-             k_pages, v_pages, page_table.astype(jnp.int32),
-             seq_lens.astype(jnp.int32),
-             jnp.asarray(q_lens).astype(jnp.int32)]
-    if any(isinstance(a, jax.core.Tracer) for a in args):
-        return _build_fused_call(*cfg)(*args)
+           has_bias, float(eps), bool(interpret))
     with telemetry.span("kernel.ragged", rows=b_pad, t=t_pad,
                         max_pages=max_pages, fused=1,
                         grid_steps=_ragged_grid_steps(b_pad, max_pages)):
-        return _jitted_fused_call(cfg)(*args)
+        return _jitted_layer_step(cfg, on_tpu(), global_mesh())(
+            k_pages, v_pages, x, ln1, wq, wk, wv, wo,
+            *(biases if has_bias else ()), ln2, wg, wu, wd, cos, sin,
+            *index)

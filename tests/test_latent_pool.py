@@ -279,8 +279,8 @@ K = jnp.zeros((1, 1, 40), jnp.float32)
     ("append_ragged", lambda p, sp: p.append_ragged(["s"], [1], K, K)),
     ("attend", lambda p, sp: p.attend(K, ["s"])),
     ("attend_ragged", lambda p, sp: p.attend_ragged(K[None], ["s"], [1])),
-    ("fused_ragged_step", lambda p, sp: p.fused_ragged_step(
-        K[0], (K, K, K, K, None), (K, K), K, ["s"], [1], K, (K, K, K))),
+    ("layer_step", lambda p, sp: p.layer_step(
+        K[0], (K,) * 10, (K, K), (K, K), (K, K), 1e-6)),
     ("dense_kv", lambda p, sp: p.dense_kv(["s"])),
     ("swap_out", lambda p, sp: p.swap_out("s", sp)),
     ("swap_in", lambda p, sp: p.swap_in("s", sp)),

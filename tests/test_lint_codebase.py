@@ -918,7 +918,7 @@ class TestFlagInventory:
 
 class TestUnifiedAttention:
     """ISSUE-13 satellite: packed-step attention in the serving
-    layers routes through the single attend_ragged/fused_ragged_step
+    layers routes through the single attend_ragged/layer_step
     pool API — a ragged append's function must attend through the
     unified entry in the same scope."""
 
@@ -942,11 +942,11 @@ class TestUnifiedAttention:
         assert lint_codebase.lint_unified_attention_file(
             "fake/paged_llama.py", text=ok) == []
 
-    def test_fused_step_counts_as_unified(self):
+    def test_layer_step_counts_as_unified(self):
         ok = (
             "def chunk(cache, x, w, sids, counts):\n"
             "    cache.append_ragged(sids, counts, x, x)\n"
-            "    return cache.fused_ragged_step(x, w, sids, counts)\n"
+            "    return cache.layer_step(x, w, sids, counts)\n"
         )
         assert lint_codebase.lint_unified_attention_file(
             "fake/paged_llama.py", text=ok) == []

@@ -34,7 +34,10 @@ PROMPTS = {f"r{i}": _RNG.randint(1, 500, n).tolist()
            for i, n in enumerate((9, 4, 6))}
 N_NEW = 5
 
-# child -> the parent it must lie inside (the tree of ISSUE 27 §2)
+# child -> the parent it must lie inside (the tree of ISSUE 27 §2); a
+# step's booking and its one table lie before the first layer where a
+# layer runs as one program (ISSUE 33), under the pool's call on the
+# op-by-op body
 PARENT = {
     "serving.admit": "serving.step",
     "serving.pack": "serving.step",
@@ -48,11 +51,11 @@ PARENT = {
     "model.norm": "model.layer",
     "model.mlp": "model.layer",
     "pool.fused_step": "model.layer",
-    "pool.book": "pool.fused_step",
-    "pool.table": "pool.fused_step",
     "kernel.ragged": "pool.fused_step",
     "engine.flush": "engine.ops",
 }
+POOL_PARENT = {"program": "serving.prefill_chunk",
+               "eager": "pool.fused_step"}
 
 
 @pytest.fixture(scope="module")
@@ -100,9 +103,11 @@ def session(monkeypatch):
     telemetry.reset()
 
 
-def _sched(model):
-    adapter = PagedLlamaAdapter(model, num_pages=96, page_size=4,
-                                max_length=128)
+def _sched(model, body="program"):
+    adapter = PagedLlamaAdapter(
+        model, num_pages=96, page_size=4, max_length=128,
+        kv_cache_dtype="int8" if body == "eager" else None)
+    assert adapter._fusion_eligible() == (body == "program")
     return BatchScheduler(adapter, max_batch_size=4,
                           prefill_chunk_tokens=8)
 
@@ -130,14 +135,18 @@ def _serve(sched, on_first_token=None):
     return asyncio.run(main())
 
 
-@pytest.fixture
-def traced(model, session):
-    """One whole run with the session on; (tokens, spans, scheduler)."""
-    sched = _sched(model)
+def _traced(model, session, body="program"):
+    sched = _sched(model, body)
     session.on = True
     toks = _serve(sched)
     session.on = False
     return toks, telemetry.peek_tracer().spans(), sched
+
+
+@pytest.fixture
+def traced(model, session):
+    """One whole run with the session on; (tokens, spans, scheduler)."""
+    return _traced(model, session)
 
 
 def _inside(child, parent, slack=1e-9):
@@ -155,26 +164,38 @@ def test_probe_is_installed_by_the_profiler_module():
     assert telemetry.span("x") is telemetry.NULL_SPAN
 
 
-def test_every_step_yields_the_tree(traced):
-    _, spans, _ = traced
+@pytest.mark.parametrize("body", ["program", "eager"])
+def test_every_step_yields_the_tree(model, session, body):
+    _, spans, _ = _traced(model, session, body)
     by_id = {s.span_id: s for s in spans}
     steps = [s for s in spans if s.name == "serving.step"]
     assert len(steps) >= N_NEW
+    program = body == "program"
     for step in steps:
         kids = [s for s in spans if s.path.startswith("serving.step/")
                 and _inside(s, step) and s.tid == step.tid]
         names = {s.name for s in kids}
         assert {"serving.admit", "serving.pack", "serving.prefill_chunk",
                 "serving.logits_pull", "serving.decode", "model.plan",
-                "model.embed", "model.layer", "model.norm", "model.mlp",
+                "model.embed", "model.layer",
                 "model.head", "pool.fused_step", "pool.book",
                 "pool.table", "kernel.ragged"} <= names
-        assert sum(s.name == "model.layer" for s in kids) == LAYERS
-        assert sum(s.name == "model.norm" for s in kids) == 2 * LAYERS
-        assert sorted(s.attrs["li"] for s in kids
-                      if s.name == "model.layer") == list(range(LAYERS))
+        layers = [s for s in kids if s.name == "model.layer"]
+        assert len(layers) == LAYERS
+        assert sorted(s.attrs["li"] for s in layers) == list(range(LAYERS))
+        assert {s.attrs["program"] for s in layers} == {int(program)}
+        # model.norm / model.mlp: the op-by-op body only
+        for name, n in (("model.norm", 2 * LAYERS), ("model.mlp", LAYERS)):
+            assert sum(s.name == name for s in kids) == \
+                (0 if program else n)
+        # a programmed step builds its tables once, an eager one a layer
+        assert sum(s.name == "pool.table" for s in kids) == \
+            (1 if program else LAYERS)
+        assert sum(s.name == "pool.book" for s in kids) == LAYERS
+    parents = dict(PARENT, **{"pool.book": POOL_PARENT[body],
+                              "pool.table": POOL_PARENT[body]})
     for s in spans:
-        want = PARENT.get(s.name)
+        want = parents.get(s.name)
         if want is None or s.parent_id is None:
             continue
         parent = by_id[s.parent_id]
@@ -219,7 +240,7 @@ def test_span_attrs_carry_the_counters(traced):
     fed = sum(len(p) for p in PROMPTS.values()) \
         + len(PROMPTS) * (N_NEW - 1)
     assert booked == LAYERS * fed
-    assert all(s.attrs["op"] == "fused_ragged_step"
+    assert all(s.attrs["op"] == "layer_step"
                for s in by["pool.fused_step"])
 
 

@@ -8,11 +8,13 @@ parity vs the dense reference for decode-only / prefill-only / mixed
 batches x kv {float32, int8} x window on/off, the (B, H, D) decode
 entries as the T=1 shape of the same call, the one place the pool
 builds a step's tables, warm LRU-dispatch reuse across pool instances,
-the FlashFuser-fused prologue/epilogue (qkv + RoPE + page scatter in,
-o_proj out), which body an adapter runs from what it can observe,
-end-to-end greedy identity of both bodies with the token-per-step
-scheduler x prefix on/off, and the attend program count bound (one
-program per config).
+the layer program (norm, qkv + RoPE + page scatter, the kernel, o_proj,
+norm, MLP: one dispatch over the pool's pages, the weights operands),
+which body an adapter runs from what it can observe, the programmed
+step against the eager body and the ``decode_token`` oracle, the step's
+tables built and uploaded once, end-to-end greedy identity of both
+bodies with the token-per-step scheduler x prefix on/off, and the
+attend program count bound (one program per config).
 """
 import numpy as np
 import pytest
@@ -232,39 +234,39 @@ class TestGridStructure:
             S((self.B, self.MP), i32), S((self.B,), i32),
             S((self.B,), i32)).jaxpr
 
-    def _fused(self):
+    def _layer(self):
         from paddle_tpu.ops.kernels.paged_attention import \
-            _build_fused_call
+            _build_layer_call
 
         bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
         S = jax.ShapeDtypeStruct
-        n, e = 32, 4096
-        run = _build_fused_call(
+        n, e, f = 32, 4096, 14336
+        run = _build_layer_call(
             n, e, self.H, self.KVH, self.D, self.NP, self.P, self.B,
-            self.T, self.MP, self.D ** -0.5, 4096, False, False)
+            self.T, self.MP, self.D ** -0.5, 4096, False, 1e-5, False)
         pool = S((self.NP, self.P, self.KVH, self.D), bf)
         return jax.make_jaxpr(run)(
-            S((n, e), bf), S((e, self.H * self.D), bf),
+            pool, pool, S((n, e), bf), S((e,), bf),
+            S((e, self.H * self.D), bf),
             S((e, self.KVH * self.D), bf), S((e, self.KVH * self.D), bf),
-            S((self.H * self.D, e), bf), S((32768, self.D), f32),
-            S((32768, self.D), f32), S((n,), i32), S((n,), i32),
-            S((n,), i32), S((self.B, self.T), i32), S((n,), i32),
-            S((n,), i32), S((n,), i32), pool, pool,
-            S((self.B, self.MP), i32), S((self.B,), i32),
-            S((self.B,), i32)).jaxpr
+            S((self.H * self.D, e), bf), S((e,), bf), S((e, f), bf),
+            S((e, f), bf), S((f, e), bf), S((32768, self.D), f32),
+            S((32768, self.D), f32), S((5, n), i32),
+            S((self.B, self.T), i32), S((2, n), i32),
+            S((self.B, self.MP + 2), i32)).jaxpr
 
     @pytest.mark.parametrize("which,allowed", [
-        ("_ragged", set()), ("_fused", {"scatter"})])
+        ("_ragged", set()), ("_layer", {"scatter"})])
     def test_no_pool_sized_copy_outside_the_kernel(self, which, allowed):
         # the old wrapper transposed both pools on every call (134 MB
-        # each at these shapes); the fused step's page write is the one
-        # operation that may return a pool
+        # each at these shapes); the layer program's page write is the
+        # one operation that may return a pool
         made = {e.primitive.name for e in _eqns(getattr(self, which)())
                 if e.primitive.name != "pallas_call"
                 and any(v.aval.size == self.POOL for v in e.outvars)}
         assert made == allowed
 
-    @pytest.mark.parametrize("which", ["_ragged", "_fused"])
+    @pytest.mark.parametrize("which", ["_ragged", "_layer"])
     def test_grid_is_rows_by_page_blocks(self, which):
         from paddle_tpu.ops.kernels.paged_attention import (
             RAGGED_PAGES_PER_STEP, _ragged_grid_steps)
@@ -384,25 +386,41 @@ def _call_attend_ragged(**kw):
         q, ["s0", "s1"], [2, 1], rows_pad=4, max_pages=4)
 
 
-def _call_fused_step(**kw):
+def _layer_weights(rng, E, NH, KVH, HD, F=24):
+    """(ln1, wq, wk, wv, wo, None, ln2, wg, wu, wd) of one tiny layer."""
+    def w(*sh):
+        return jnp.asarray(rng.randn(*sh) * 0.1, jnp.float32)
+
+    def gain():
+        return jnp.asarray(1 + 0.1 * rng.randn(E), jnp.float32)
+
+    return (gain(), w(E, NH * HD), w(E, KVH * HD), w(E, KVH * HD),
+            w(NH * HD, E), None, gain(), w(E, F), w(E, F), w(F, E))
+
+
+def _call_layer_step(**kw):
+    from paddle_tpu.ops.kernels.paged_attention import upload_plan
     from paddle_tpu.ops.kernels.rope import build_rope_cache
 
     pool, rng = _filled_pool(**kw)
     E, NH, KVH, HD, n_pad = 16, 2, 2, 8, 8
-    w = [jnp.asarray(rng.randn(*sh) * 0.1, jnp.float32) for sh in
-         ((E, NH * HD), (E, KVH * HD), (E, KVH * HD), (NH * HD, E))]
     gm = np.zeros((2, 4), np.int32)
     gm[0, 2:] = [0, 1]
     gm[1, 3:] = [2]
-    plan = tuple(jnp.asarray(a, jnp.int32)
-                 for a in ([0, 0, 1], [2, 3, 3], [0, 1, 2]))
     pos = np.zeros(n_pad, np.int32)
     pos[:3] = [6, 7, 9]
+    tok = [[0] * n_pad, pos, [0, 0, 1] + [0] * 5, [2, 3, 3] + [0] * 5,
+           [0, 1, 2] + [n_pad] * 5]   # id, position, mr, mc, mflat
+    plan = upload_plan(tok, gm)
     x = jnp.asarray(rng.randn(n_pad, E), jnp.float32)
-    return pool, lambda: pool.fused_ragged_step(
-        x, (*w, None), build_rope_cache(64, HD), jnp.asarray(pos),
-        ["s0", "s1"], [2, 1], jnp.asarray(gm), plan, rows_pad=2,
-        max_pages=4)
+    weights = _layer_weights(rng, E, NH, KVH, HD)
+
+    def call():
+        tables = pool.book_step(["s0", "s1"], [2, 1], 2, 4, n_pad)
+        return pool.layer_step(x, weights, build_rope_cache(64, HD),
+                               plan, tables, 1e-6)
+
+    return pool, call
 
 
 def _call_latent_step(**kw):
@@ -418,7 +436,7 @@ def _call_latent_step(**kw):
 
 
 _TABLE_CALLERS = {"attend_ragged": _call_attend_ragged,
-                  "fused_ragged_step": _call_fused_step,
+                  "layer_step": _call_layer_step,
                   "latent_ragged_step": _call_latent_step}
 
 
@@ -466,17 +484,8 @@ class TestStepTables:
 
     @pytest.mark.parametrize("caller", sorted(_TABLE_CALLERS))
     def test_caller_emits_one_table_span_with_rows_and_bytes(self, caller):
-        from paddle_tpu.framework import telemetry
-
         pool, call = _TABLE_CALLERS[caller]()
-        telemetry.reset()                # an empty ring of its own
-        paddle.set_flags({"telemetry": "trace"})
-        try:
-            call()
-            spans = telemetry.peek_tracer().spans()
-        finally:
-            paddle.set_flags({"telemetry": "off"})
-            telemetry.reset()
+        spans = _spans_of(call)
         table = [s for s in spans if s.name == "pool.table"]
         assert len(table) == 1
         rows = 1 if caller == "latent_ragged_step" else 2
@@ -501,11 +510,16 @@ class TestStepTables:
 
         src = inspect.getsource(paged_cache)
         assert src.count('span("pool.table")') == 1
-        for caller in _TABLE_CALLERS:
+        # the layer program's tables come from book_step (once a step,
+        # shared by the layers' pools); layer_step itself builds none
+        for caller in ("attend_ragged", "book_step",
+                       "latent_ragged_step"):
             body = inspect.getsource(
                 getattr(PagedKVCacheManager, caller))
             assert body.count("self._step_tables(") == 1, caller
             assert "_padded_kernel_inputs" not in body, caller
+        body = inspect.getsource(PagedKVCacheManager.layer_step)
+        assert "_step_tables" not in body and "_upload" not in body
 
 
 class TestPoolAttendRagged:
@@ -537,20 +551,34 @@ class TestPoolAttendRagged:
         assert _jitted_ragged_call.cache_info().currsize >= size0 + 1
 
 
-class TestFusedStep:
-    """FlashFuser prologue/epilogue: qkv + RoPE + page scatter fold
-    into the ragged kernel's program, o_proj into its epilogue — the
-    fused pool step must be numerically identical to the unfused
-    unified path AND leave identical page state behind."""
+def _np_plan(counts, n_pad, t_pad, b_pad):
+    """(gm, mr, mc, mflat) of packed rows, padded to n_pad (numpy)."""
+    from paddle_tpu.inference.paged_common import right_align_plan_np
+    from paddle_tpu.ops.kernels.paged_attention import pad_plan_np
+
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    gm, mr, mc, mflat = right_align_plan_np(
+        range(len(counts)), starts, counts, t_pad, b_pad)
+    return (gm, pad_plan_np(mr, n_pad, 0), pad_plan_np(mc, n_pad, 0),
+            pad_plan_np(mflat, n_pad, n_pad))
+
+
+class TestLayerStep:
+    """The layer program: norm, qkv + RoPE + page scatter, the ragged
+    kernel, o_proj, residual, norm, MLP, residual as ONE dispatch over
+    the pool's pages — numerically the op-by-op layer over the unfused
+    pool path, leaving identical page state behind; the layer's weights
+    are operands."""
+
+    E, NH, KVH, HD = 16, 2, 2, 8
 
     def _setup(self, seed=7):
         from paddle_tpu.ops.kernels.rope import build_rope_cache
 
         rng = np.random.RandomState(seed)
-        E, NH, KVH, HD = 16, 2, 2, 8
-        pool_f = PagedKVCacheManager(16, PAGE, KVH, HD,
+        pool_f = PagedKVCacheManager(16, PAGE, self.KVH, self.HD,
                                      dtype=jnp.float32)
-        pool_u = PagedKVCacheManager(16, PAGE, KVH, HD,
+        pool_u = PagedKVCacheManager(16, PAGE, self.KVH, self.HD,
                                      dtype=jnp.float32)
         lens = (5, 1)
         for pool in (pool_f, pool_u):
@@ -559,62 +587,67 @@ class TestFusedStep:
                 pool.alloc(sid)
                 for _ in range(n):
                     rs = np.random.RandomState(100 + i)
-                    pool.append(sid,
-                                rs.randn(KVH, HD).astype("float32"),
-                                rs.randn(KVH, HD).astype("float32"))
-        wq = jnp.asarray(rng.randn(E, NH * HD) * 0.1, jnp.float32)
-        wk = jnp.asarray(rng.randn(E, KVH * HD) * 0.1, jnp.float32)
-        wv = jnp.asarray(rng.randn(E, KVH * HD) * 0.1, jnp.float32)
-        wo = jnp.asarray(rng.randn(NH * HD, E) * 0.1, jnp.float32)
-        cos, sin = build_rope_cache(64, HD)
-        return (rng, pool_f, pool_u, lens, E, NH, KVH, HD,
-                (wq, wk, wv, wo), (cos, sin))
+                    pool.append(
+                        sid, rs.randn(self.KVH, self.HD).astype("float32"),
+                        rs.randn(self.KVH, self.HD).astype("float32"))
+        weights = _layer_weights(rng, self.E, self.NH, self.KVH, self.HD)
+        return (rng, pool_f, pool_u, lens, weights,
+                build_rope_cache(64, self.HD))
 
-    def test_fused_matches_unfused_and_pages_identical(self):
+    def _step(self, pool, x, weights, rope, counts, positions,
+              n_pad=8, t_pad=4, b_pad=2):
+        from paddle_tpu.ops.kernels.paged_attention import upload_plan
+
+        pos = np.zeros(n_pad, np.int32)
+        pos[:sum(counts)] = positions
+        gm, mr, mc, mflat = _np_plan(counts, n_pad, t_pad, b_pad)
+        plan = upload_plan([0 * pos, pos, mr, mc, mflat], gm)
+        tables = pool.book_step(["s0", "s1"], counts, b_pad, 4, n_pad)
+        return pool.layer_step(x, weights, rope, plan, tables, 1e-6)
+
+    def test_program_matches_the_ops_and_pages_identical(self):
         from paddle_tpu.framework.core import Tensor
+        from paddle_tpu.ops.kernels.rms_norm import rms_norm
         from paddle_tpu.ops.kernels.rope import apply_rotary_emb
 
-        (rng, pool_f, pool_u, lens, E, NH, KVH, HD,
-         (wq, wk, wv, wo), (cos, sin)) = self._setup()
+        rng, pool_f, pool_u, lens, weights, (cos, sin) = self._setup()
+        ln1, wq, wk, wv, wo, _, ln2, wg, wu, wd = weights
+        E, NH, KVH, HD = self.E, self.NH, self.KVH, self.HD
         sids = ["s0", "s1"]
         counts = [3, 1]            # one prefill chunk + one decode row
-        n_real, n_pad = 4, 8
+        n_real, n_pad, t_pad, b_pad = 4, 8, 4, 2
         x = jnp.asarray(rng.randn(n_pad, E), jnp.float32)
         pos = np.zeros(n_pad, np.int32)
-        pos[0:3] = [5, 6, 7]
-        pos[3] = 1
-        t_pad, b_pad = 4, 2
-        gm = np.zeros((b_pad, t_pad), np.int64)
-        gm[0, 1:] = [0, 1, 2]
-        gm[1, 3:] = [3]
-        mr = jnp.asarray([0, 0, 0, 1], jnp.int32)
-        mc = jnp.asarray([1, 2, 3, 3], jnp.int32)
-        mflat = jnp.asarray([0, 1, 2, 3], jnp.int32)
-        y = pool_f.fused_ragged_step(
-            x, (wq, wk, wv, wo, None), (cos, sin),
-            jnp.asarray(pos), sids, counts, jnp.asarray(gm, jnp.int32),
-            (mr, mc, mflat), rows_pad=b_pad, max_pages=4)
+        pos[:4] = [5, 6, 7, 1]
+        y = self._step(pool_f, x, weights, (cos, sin), counts,
+                       [5, 6, 7, 1])
 
-        # unfused unified path on the twin pool
-        xq = (x @ wq).reshape(1, n_pad, NH, HD)
-        xk = (x @ wk).reshape(1, n_pad, KVH, HD)
-        vh = (x @ wv).reshape(n_pad, KVH, HD)
+        # the same layer op by op over the unfused pool path
+        gm, mr, mc, mflat = _np_plan(counts, n_real, t_pad, b_pad)
+        h = rms_norm(x, ln1, 1e-6)
+        xq = (h @ wq).reshape(1, n_pad, NH, HD)
+        xk = (h @ wk).reshape(1, n_pad, KVH, HD)
+        vh = (h @ wv).reshape(n_pad, KVH, HD)
         qh = apply_rotary_emb(xq, cos, sin,
                               position_ids=jnp.asarray(pos))[0]
         kh = apply_rotary_emb(xk, cos, sin,
                               position_ids=jnp.asarray(pos))[0]
         pool_u.append_ragged(sids, counts, kh[:n_real], vh[:n_real])
         out = pool_u.attend_ragged(
-            Tensor(qh[jnp.asarray(gm, jnp.int32)]), sids, counts,
+            Tensor(qh[jnp.asarray(gm)]), sids, counts,
             rows_pad=b_pad, max_pages=4)
         attn = jnp.zeros((n_pad, NH, HD), jnp.float32)
         attn = attn.at[mflat].set(out._data[mr, mc])
-        y_ref = attn.reshape(n_pad, NH * HD) @ wo
+        x1 = x + attn.reshape(n_pad, NH * HD) @ wo
+        h2 = rms_norm(x1, ln2, 1e-6)
+        y_ref = x1 + (jax.nn.silu(h2 @ wg) * (h2 @ wu)) @ wd
 
-        np.testing.assert_allclose(y.numpy(), np.asarray(y_ref),
-                                   atol=1e-6)
-        # page payloads: the fused program computes K/V in-graph, so
-        # XLA's fusion may differ from the eager path by float ulps —
+        # padding rows of the packed axis carry no attention output but
+        # still run the dense work: the whole (n_pad, E) block agrees
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   atol=2e-6)
+        # page payloads: the program computes K/V in-graph, so XLA's
+        # fusion may differ from the eager path by float ulps —
         # allclose, while the BOOKKEEPING (tables, lens) is exact
         np.testing.assert_allclose(np.asarray(pool_f.k_pages),
                                    np.asarray(pool_u.k_pages),
@@ -627,57 +660,66 @@ class TestFusedStep:
         assert pool_f.seq_len("s0") == lens[0] + 3
         assert pool_f.seq_len("s1") == lens[1] + 1
 
-    def test_fused_cache_stable_across_real_token_counts(self):
-        # the fused dispatch cache keys only BUCKETED shapes: a
-        # second step with a different real-token count but the same
-        # padded config reuses the compiled program instead of
-        # re-tracing (the padded plans' out-of-bounds entries drop)
-        from paddle_tpu.ops.kernels.paged_attention import (
-            _jitted_fused_call,
-        )
+    def test_one_program_for_every_layer_and_real_token_count(self):
+        # the dispatch cache keys only BUCKETED shapes and no weight: a
+        # second step with another layer's weights and another real
+        # token count but the same padded config reuses the compiled
+        # program instead of re-tracing (the padded plans'
+        # out-of-bounds entries drop)
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _jitted_layer_step
 
-        (rng, pool, _, lens, E, NH, KVH, HD,
-         weights, rope) = self._setup(seed=11)
-        wq, wk, wv, wo = weights
-        n_pad, t_pad, b_pad = 8, 4, 2
-
-        def step(counts, positions):
-            n_real = sum(counts)
-            gm = np.zeros((b_pad, t_pad), np.int64)
-            rr, cc, ff = [], [], []
-            off = 0
-            for r, c in enumerate(counts):
-                gm[r, t_pad - c:] = np.arange(off, off + c)
-                for j in range(c):
-                    rr.append(r)
-                    cc.append(t_pad - c + j)
-                    ff.append(off + j)
-                off += c
-            x = jnp.asarray(rng.randn(n_pad, E), jnp.float32)
-            pos = np.zeros(n_pad, np.int32)
-            pos[:n_real] = positions
-            return pool.fused_ragged_step(
-                x, (wq, wk, wv, wo, None), rope, jnp.asarray(pos),
-                ["s0", "s1"], counts, jnp.asarray(gm, jnp.int32),
-                (jnp.asarray(rr, jnp.int32), jnp.asarray(cc, jnp.int32),
-                 jnp.asarray(ff, jnp.int32)),
-                rows_pad=b_pad, max_pages=4)
-
-        step([3, 1], [5, 6, 7, 1])
-        info0 = _jitted_fused_call.cache_info()
-        step([2, 1], [8, 9, 2])      # fewer real tokens, same buckets
-        info1 = _jitted_fused_call.cache_info()
+        rng, pool, _, _, weights, rope = self._setup(seed=11)
+        other = _layer_weights(rng, self.E, self.NH, self.KVH, self.HD)
+        x = jnp.asarray(rng.randn(8, self.E), jnp.float32)
+        y0 = self._step(pool, x, weights, rope, [3, 1], [5, 6, 7, 1])
+        info0 = _jitted_layer_step.cache_info()
+        out = []
+        built = _builds_during(lambda: out.append(self._step(
+            pool, x, other, rope, [2, 1], [8, 9, 2])))
+        info1 = _jitted_layer_step.cache_info()
         assert info1.currsize == info0.currsize
         assert info1.hits == info0.hits + 1
+        assert built == []          # neither traced, lowered nor built
+        assert not np.allclose(np.asarray(y0), np.asarray(out[0]))
 
-    def test_int8_pool_refuses_fusion(self):
+    def test_int8_pool_refuses_the_program(self):
         pool = PagedKVCacheManager(8, PAGE, 2, 8, dtype=jnp.float32,
                                    kv_dtype="int8")
         pool.alloc("s")
         with pytest.raises(ValueError, match="int8"):
-            pool.fused_ragged_step(
-                jnp.zeros((4, 16)), (None,) * 5, (None, None),
-                None, ["s"], [1], None, (None, None, None))
+            pool.layer_step(jnp.zeros((4, 16)), (None,) * 10,
+                            (None, None), (None,) * 2, (None,) * 2, 1e-6)
+
+    def test_plan_of_another_length_is_refused_before_the_dispatch(self):
+        rng, pool, _, _, weights, rope = self._setup(seed=13)
+        x = jnp.asarray(rng.randn(16, self.E), jnp.float32)
+        pages = pool.k_pages
+        with pytest.raises(ValueError, match="padded to the packed"):
+            self._step(pool, x, weights, rope, [3, 1], [5, 6, 7, 1])
+        assert pool.k_pages is pages
+
+
+def _spans_of(call):
+    """``call()`` with spans live, in a ring of its own; the spans it
+    leaves."""
+    from paddle_tpu.framework import telemetry
+
+    telemetry.reset()
+    paddle.set_flags({"telemetry": "trace"})
+    try:
+        call()
+        return telemetry.peek_tracer().spans()
+    finally:
+        paddle.set_flags({"telemetry": "off"})
+        telemetry.reset()
+
+
+def _builds_during(call):
+    """The ``xla.trace`` / ``xla.lower`` / ``xla.build`` ranges that
+    ``call()`` leaves in the span ring: [(name, fun)]."""
+    return [(s.name, s.attrs.get("fun")) for s in _spans_of(call)
+            if s.name.startswith("xla.")]
 
 
 # ---------------------------------------------------------------------------
@@ -745,31 +787,281 @@ def _kinds(adapter):
 
 class TestFusionChoice:
     """Which body ``prefill_chunk`` runs follows from what the adapter
-    can observe of its pages and projections, nothing else."""
+    can observe of its pages, projections, norms and feed-forward,
+    nothing else: a dense float model runs one program a layer, anything
+    else the same plan op by op."""
 
     @pytest.mark.parametrize("case,want", [
         ("float", "ragged_fused"),
+        ("qkv_bias", "ragged_fused"),
         ("int8_kv", "ragged"),
         ("int8_weights", "ragged"),
         ("partial_qkv_bias", "ragged"),
+        ("routed_experts", "ragged"),
+        ("mlp_bias", "ragged"),
+        ("o_proj_bias", "ragged"),
+        ("other_norm", "ragged"),
     ])
     def test_body_follows_eligibility(self, case, want):
-        m = _fresh_model(attention_bias=(case == "partial_qkv_bias"))
+        from paddle_tpu.nn import LayerNorm
+
+        kw = {}
+        if case in ("partial_qkv_bias", "qkv_bias"):
+            kw["attention_bias"] = True
+        if case == "routed_experts":
+            kw.update(num_local_experts=4, num_experts_per_tok=2)
+        m = _fresh_model(**kw)
+        layer = m.model.layers[0]
         if case == "partial_qkv_bias":
-            m.model.layers[0].self_attn.k_proj.bias = None
+            layer.self_attn.k_proj.bias = None
+        for name, proj in (("mlp_bias", layer.mlp.gate_proj
+                            if case == "mlp_bias" else None),
+                           ("o_proj_bias", layer.self_attn.o_proj)):
+            if case == name:
+                proj.bias = proj.create_parameter(
+                    [proj.weight.shape[1]], is_bias=True)
+        if case == "other_norm":
+            layer.post_attention_layernorm = LayerNorm(
+                m.config.hidden_size)
         ad = PagedLlamaAdapter(
             m, num_pages=16, page_size=PAGE, max_length=64,
             kv_cache_dtype="int8" if case == "int8_kv" else None,
             weight_dtype="int8" if case == "int8_weights" else None)
-        assert ad._fusion_eligible() == (want == "ragged_fused")
+        program = want == "ragged_fused"
+        assert ad._fusion_eligible() == program
         for sid in "ab":
             ad.alloc(sid)
-        logits = ad.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], [0, 0],
-                                  pad_to=8)
+        out = []
+        spans = _spans_of(lambda: out.append(ad.prefill_chunk(
+            [[5, 6, 7], [9]], ["a", "b"], [0, 0], pad_to=8)))
+        logits = out[0]
         assert logits.shape == [2, m.config.vocab_size]
         assert np.isfinite(logits.numpy()).all()
         assert _kinds(ad) == {want}
         assert ad.caches[0].seq_len("a") == 3
+        # the counter: every layer span says how it ran, and the share
+        # engaged is layer_programs / attend_calls
+        layers = [s for s in spans if s.name == "model.layer"]
+        assert [s.attrs["program"] for s in layers] == [int(program)]
+        assert ad.chunk_stats["attend_calls"] == 1
+        assert ad.chunk_stats["layer_programs"] == int(program)
+        eager_only = {s.name for s in spans} & {"model.norm", "model.mlp"}
+        assert eager_only == (set() if program
+                              else {"model.norm", "model.mlp"})
+
+
+def _three(model, **kw):
+    """Three adapters over one model: the programmed body, the eager
+    body of the same plan, and one for the ``decode_token`` oracle."""
+    ads = [PagedLlamaAdapter(model, num_pages=48, page_size=PAGE,
+                             max_length=128, **kw) for _ in range(3)]
+    assert ads[0]._fusion_eligible()
+    ads[1]._fused_ok = False              # the same plan, op by op
+    return ads
+
+
+class TestProgrammedStep:
+    """The programmed body of ``prefill_chunk`` (one compiled program a
+    layer, embed and head programs, index operands uploaded once)
+    against the eager body and against ``decode_token``."""
+
+    # (case, model options): mixed decode and chunk rows throughout
+    CASES = [("plain", {}),
+             ("window_inside_context", {"sliding_window": 6}),
+             ("qkv_bias", {"attention_bias": True}),
+             ("tied_head", {"tie_word_embeddings": True})]
+
+    @pytest.mark.parametrize("case,kw", CASES,
+                             ids=[c for c, _ in CASES])
+    def test_logits_match_eager_body_and_oracle(self, case, kw):
+        m = _fresh_model(seed=31, num_hidden_layers=2, **kw)
+        prog, eager, oracle = _three(m)
+        rng = np.random.RandomState(5)
+        sids = ["a", "b", "c"]
+        feeds = [  # (tokens a row, pad_to): prompt chunks, then mixed
+            ([9, 2, 5], 16), ([3, 1, 4], 8), ([1, 1, 6], 8),
+            ([1, 1, 1], 4)]
+        for ad in (prog, eager, oracle):
+            for sid in sids:
+                ad.alloc(sid)
+        for counts, pad_to in feeds:
+            toks = [rng.randint(1, 500, c).tolist() for c in counts]
+            starts = [prog.caches[0].seq_len(s) for s in sids]
+            got = prog.prefill_chunk(toks, sids, starts,
+                                     pad_to=pad_to).numpy()
+            want = eager.prefill_chunk(toks, sids, starts,
+                                       pad_to=pad_to).numpy()
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+            # the oracle: the same tokens one at a time
+            for j in range(max(counts)):
+                rows = [i for i, c in enumerate(counts) if j < c]
+                lg = oracle.decode_token(
+                    [toks[i][j] for i in rows],
+                    [sids[i] for i in rows]).numpy()
+                for r, i in enumerate(rows):
+                    if j == counts[i] - 1:
+                        np.testing.assert_allclose(
+                            got[i], lg[r], atol=5e-5, rtol=1e-4)
+        assert prog.chunk_stats["layer_programs"] == \
+            prog.chunk_stats["attend_calls"] == 2 * len(feeds)
+        assert eager.chunk_stats["layer_programs"] == 0
+        # the pages both bodies leave behind agree, layer by layer
+        for cp, ce in zip(prog.caches, eager.caches):
+            np.testing.assert_allclose(
+                np.asarray(cp.k_pages), np.asarray(ce.k_pages),
+                atol=1e-5)
+            assert [cp.seq_pages(s) for s in sids] == \
+                [ce.seq_pages(s) for s in sids]
+
+    def test_verify_rows_get_every_position(self):
+        m = _fresh_model(seed=37, num_hidden_layers=2)
+        prog, eager, _ = _three(m)
+        sids = ["a", "b", "c"]
+        toks = [[5, 6, 7, 8], [9], [3, 4, 2]]
+        outs = []
+        for ad in (prog, eager):
+            for sid in sids:
+                ad.alloc(sid)
+            ad.prefill_chunk([[1, 2], [3], [4, 5, 6]], sids, pad_to=8)
+            last, full = ad.prefill_chunk(toks, sids, pad_to=8,
+                                          logits_rows=[2, 0])
+            assert last.shape == [3, 512] and full.shape == [7, 512]
+            outs.append((last.numpy(), full.numpy()))
+        for got, want in zip(*outs):
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+        last, full = outs[0]
+        # rows in list order: row 2's three positions, then row 0's four
+        np.testing.assert_allclose(full[2], last[2], atol=1e-6)
+        np.testing.assert_allclose(full[6], last[0], atol=1e-6)
+
+    def test_tables_built_and_uploaded_once_a_step(self, monkeypatch):
+        from paddle_tpu.incubate.nn import paged_cache
+        from paddle_tpu.inference import paged_llama
+
+        layers = 3
+        m = _fresh_model(seed=41, num_hidden_layers=layers)
+        ad = PagedLlamaAdapter(m, num_pages=48, page_size=PAGE,
+                               max_length=128)
+        for sid in "ab":
+            ad.alloc(sid)
+        ad.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], pad_to=8)
+        uploads = []
+        for mod in (paged_cache, paged_llama):
+            real = mod._upload
+
+            def counted(*arrays, _real=real, _mod=mod.__name__):
+                uploads.append((_mod.rsplit(".", 1)[1],
+                                [np.shape(a) for a in arrays]))
+                return _real(*arrays)
+
+            monkeypatch.setattr(mod, "_upload", counted)
+        spans = _spans_of(lambda: ad.prefill_chunk(
+            [[1, 2], [3]], ["a", "b"], pad_to=4))
+        # one upload of the adapter's operands (a token's id, position,
+        # mr, mc, mflat as one array; gm; the last rows) and one of the
+        # pool's (table | lens | q_lens as one array; the padded slot
+        # plan): each index operand crosses once
+        assert uploads == [("paged_llama", [(5, 4), (2, 2), (2,)]),
+                           ("paged_cache", [(2, 4), (2, 4)])]
+        names = [s.name for s in spans]
+        assert names.count("pool.table") == 1
+        assert names.count("pool.book") == layers
+        assert names.count("model.layer") == layers
+        assert names.count("kernel.ragged") == layers
+        assert [s.attrs["op"] for s in spans
+                if s.name == "pool.fused_step"] == ["layer_step"] * layers
+        # every pool booked before the first layer ran
+        order = [n for n in names if n in ("pool.book", "model.layer")]
+        assert order == ["pool.book"] * layers + ["model.layer"] * layers
+        # every layer's pool holds the same tables and lengths
+        first = ad.caches[0]
+        for pool in ad.caches[1:]:
+            for sid in "ab":
+                assert pool.seq_pages(sid) == first.seq_pages(sid)
+                assert pool.seq_len(sid) == first.seq_len(sid)
+        assert first.seq_len("a") == 5 and first.seq_len("b") == 2
+
+    def test_a_pool_that_differs_builds_its_own_tables(self):
+        # pools of one adapter are driven in lockstep; one that is not
+        # (another page chain for the same rows) is not handed the
+        # first pool's table
+        m = _fresh_model(seed=43, num_hidden_layers=2)
+        ad = PagedLlamaAdapter(m, num_pages=48, page_size=PAGE,
+                               max_length=128)
+        ref = PagedLlamaAdapter(m, num_pages=48, page_size=PAGE,
+                                max_length=128)
+        ad.caches[1].alloc("x")
+        ad.caches[1].append("x", np.zeros((2, 32), "float32"),
+                            np.zeros((2, 32), "float32"))
+        for a in (ad, ref):
+            for sid in "ab":
+                a.alloc(sid)
+        spans = _spans_of(lambda: ad.prefill_chunk(
+            [[5, 6, 7], [9]], ["a", "b"], pad_to=8))
+        assert [s.name for s in spans].count("pool.table") == 2
+        assert ad.caches[1].seq_pages("a") != ad.caches[0].seq_pages("a")
+        got = ad.prefill_chunk([[1], [2]], ["a", "b"], pad_to=2).numpy()
+        ref.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], pad_to=8)
+        want = ref.prefill_chunk([[1], [2]], ["a", "b"], pad_to=2).numpy()
+        np.testing.assert_allclose(got, want, atol=1e-6)
+
+    def test_sanitizer_checks_the_shared_table_in_every_pool(self):
+        m = _fresh_model(seed=47, num_hidden_layers=3)
+        ad = PagedLlamaAdapter(m, num_pages=48, page_size=PAGE,
+                               max_length=128, sanitizer="strict")
+        for sid in "ab":
+            ad.alloc(sid)
+        before = [c.sanitizer_stats["by_op"].get("page-table", 0)
+                  for c in ad.caches]
+        ad.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], pad_to=8)
+        for c, n in zip(ad.caches, before):
+            assert c.sanitizer_stats["by_op"]["page-table"] == n + 1
+            assert c.sanitizer_stats["violations"] == 0
+
+    def test_exhausted_pool_raises_before_any_page_array_is_replaced(
+            self):
+        m = _fresh_model(seed=53, num_hidden_layers=3)
+        ad = PagedLlamaAdapter(m, num_pages=4, page_size=PAGE,
+                               max_length=64)
+        for sid in "ab":
+            ad.alloc(sid)
+        ad.prefill_chunk([[5, 6, 7], [9]], ["a", "b"], pad_to=4)
+        # the LAST layer's pool has no page left for the rows' growth
+        last = ad.caches[-1]
+        last.alloc("hog")
+        for _ in range(2 * PAGE):
+            last.append("hog", np.zeros((2, 32), "float32"),
+                        np.zeros((2, 32), "float32"))
+        assert last.num_free_pages == 0
+        arrays = [(c.k_pages, c.v_pages) for c in ad.caches]
+        with pytest.raises(RuntimeError, match="exhausted"):
+            ad.prefill_chunk([[1, 2], [3, 4, 5, 6]], ["a", "b"],
+                             pad_to=8)
+        assert all(c.k_pages is k and c.v_pages is v
+                   for c, (k, v) in zip(ad.caches, arrays))
+        assert last.seq_len("a") == 3 and last.seq_len("b") == 1
+
+    def test_one_program_a_shape_serves_every_layer(self):
+        layers = 3
+        m = _fresh_model(seed=59, num_hidden_layers=layers,
+                         hidden_size=32, intermediate_size=96)
+        ad = PagedLlamaAdapter(m, num_pages=48, page_size=PAGE,
+                               max_length=128)
+        for sid in "ab":
+            ad.alloc(sid)
+
+        def step(a, b):
+            return lambda: ad.prefill_chunk([a, b], ["a", "b"], pad_to=8)
+
+        step([5, 6, 7], [9])()
+        # a new shape (rows of 4 tokens, tables 2 pages wide)
+        first = _builds_during(step([1, 2, 3], [4]))
+        traced = [fun for name, fun in first if name == "xla.trace"]
+        assert traced.count("ragged_layer_step") == 1     # not `layers`
+        # another step of the same buckets, other real token counts:
+        # nothing is traced, lowered or built, in any layer
+        assert _builds_during(step([1, 2], [3, 4, 5])) == []
+        assert ad.chunk_stats["layer_programs"] == 3 * layers
 
 
 class TestEndToEndGreedyIdentity:
@@ -821,11 +1113,11 @@ class TestEndToEndGreedyIdentity:
 
     def test_fused_program_count_includes_packed_bucket(self, model):
         # two packed buckets sharing (b_pad, t_pad, mp_pad) compile
-        # two REAL fused programs — the dense prologue/epilogue is
-        # bucket-shaped — and the accounting must not collapse them
+        # two REAL layer programs — the dense work is bucket-shaped —
+        # and the accounting must not collapse them
         # (review find: the cfg keys n_pad, the shape tuple must too)
         from paddle_tpu.ops.kernels.paged_attention import (
-            _jitted_fused_call,
+            _jitted_layer_step,
         )
 
         ad = PagedLlamaAdapter(model, num_pages=32, page_size=16,
@@ -837,12 +1129,12 @@ class TestEndToEndGreedyIdentity:
         def toks(n):
             return rng.randint(1, 400, n).tolist()
 
-        miss0 = _jitted_fused_call.cache_info().misses
+        miss0 = _jitted_layer_step.cache_info().misses
         ad.prefill_chunk([toks(5), toks(1), toks(1), toks(1)],
                          list("abcd"), [0, 0, 0, 0], pad_to=8)
         ad.prefill_chunk([toks(5), toks(2), toks(2), toks(2)],
                          list("abcd"), [5, 1, 1, 1], pad_to=16)
-        compiled = _jitted_fused_call.cache_info().misses - miss0
+        compiled = _jitted_layer_step.cache_info().misses - miss0
         assert ad.attend_program_count == compiled == 2, (
             ad.attend_program_count, compiled, ad._kernel_shapes)
         for s in "abcd":

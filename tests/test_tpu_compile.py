@@ -134,37 +134,90 @@ def test_ragged_int8_kv_2048_pages(one_chip, t):
     assert "tpu_custom_call" in text
 
 
+FFN = 14336
+
+
+def _layer_specs(n_pad, b_pad, t_pad, mp, npages):
+    i32, pool = jnp.int32, ((npages, PAGE, KVH, D), BF16)
+    return [pool, pool, ((n_pad, E), BF16), ((E,), BF16),
+            ((E, H * D), BF16), ((E, KVH * D), BF16),
+            ((E, KVH * D), BF16), ((H * D, E), BF16), ((E,), BF16),
+            ((E, FFN), BF16), ((E, FFN), BF16), ((FFN, E), BF16),
+            ((32768, D), jnp.float32), ((32768, D), jnp.float32),
+            ((5, n_pad), i32), ((b_pad, t_pad), i32), ((2, n_pad), i32),
+            ((b_pad, mp + 2), i32)]
+
+
+def _layer_cfg(n_pad, b_pad, t_pad, mp, npages):
+    return (n_pad, E, H, KVH, D, npages, PAGE, b_pad, t_pad, mp,
+            D ** -0.5, 4096, False, 1e-5, False)
+
+
+# mistral-7b-serve.decode-closed32's steps (32 rows, 4,096 pages, tables
+# 32 / 64 / 128 wide, 32 decode rows or 31 beside a 64-token prompt
+# chunk) and one serving bucket of 8 rows
 @pytest.mark.parametrize("n_pad,b_pad,t_pad,mp,npages", [
     (128, 8, 64, 128, 2048),
-    (32, 32, 1, 32, 4096), (32, 32, 1, 128, 4096), (128, 32, 64, 64, 4096),
+    (32, 32, 1, 32, 4096), (32, 32, 1, 64, 4096), (32, 32, 1, 128, 4096),
+    (128, 32, 64, 64, 4096),
 ])
-def test_fused_ragged_step(one_chip, n_pad, b_pad, t_pad, mp, npages):
-    """qkv + RoPE + page scatter + ragged attend + o_proj as ONE program
-    at one serving bucket (128 packed tokens, 8 rows x 64), and at the
-    Mistral serving cell's: 32 decode rows, and 31 with a 64-token
-    prompt chunk."""
-    from paddle_tpu.ops.kernels.paged_attention import _build_fused_call
+def test_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp,
+                       npages):
+    """A decoder layer of the packed step as ONE program, its pools
+    donated: norm (the Mosaic ``rms_norm``), qkv + RoPE + page scatter,
+    ONE ragged kernel call, o_proj, residual, norm, the gated MLP,
+    residual. Both pools come back in the buffers they came in (aliased
+    input to output, the page write in place) and nothing copies,
+    transposes or reshapes a pool for the kernel."""
+    import re
 
-    run = _build_fused_call(n_pad, E, H, KVH, D, npages, PAGE, b_pad,
-                            t_pad, mp, D ** -0.5, 4096, False, False)
-    i32 = jnp.int32
-    specs = [((n_pad, E), BF16), ((E, H * D), BF16),
-             ((E, KVH * D), BF16), ((E, KVH * D), BF16),
-             ((H * D, E), BF16),
-             ((32768, D), jnp.float32), ((32768, D), jnp.float32),
-             ((n_pad,), i32), ((n_pad,), i32), ((n_pad,), i32),
-             ((b_pad, t_pad), i32), ((n_pad,), i32), ((n_pad,), i32),
-             ((n_pad,), i32),
-             ((npages, PAGE, KVH, D), BF16),
-             ((npages, PAGE, KVH, D), BF16),
-             ((b_pad, mp), i32), ((b_pad,), i32), ((b_pad,), i32)]
-    text = _compile(run, one_chip, *specs)
-    assert "tpu_custom_call" in text
-    # the page write returns a pool (and, undonated, copies it first);
-    # nothing lays the pool out another way for the kernel
-    assert {(tuple(dims), layout) for dims, layout, _ in
-            _pool_sized(text, npages)} <= {
-                ((npages, PAGE, KVH, D), "{3,2,1,0")}
+    import paddle_tpu.ops.kernels as kernels
+    from paddle_tpu.ops.kernels.paged_attention import _build_layer_call
+
+    # the backend here is the CPU: take the chip's branch of the norm
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    run = _build_layer_call(*_layer_cfg(n_pad, b_pad, t_pad, mp, npages))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in _layer_specs(n_pad, b_pad, t_pad, mp, npages)]
+    text = jax.jit(run, donate_argnums=(0, 1)).lower(
+        *args).compile().as_text()
+    head = text.splitlines()[0]
+    # outputs 1 and 2 (the pools) alias parameters 0 and 1
+    assert re.search(r"\{1\}: \(0, \{\}", head), head[:300]
+    assert re.search(r"\{2\}: \(1, \{\}", head), head[:300]
+    pools = _pool_sized(text, npages)
+    assert pools and all(
+        tuple(dims) == (npages, PAGE, KVH, D) and layout == "{3,2,1,0"
+        for dims, layout, _ in pools)
+    for _, _, line in pools:
+        op = re.search(r"\} (\w[\w-]*)\(", line).group(1)
+        assert op in ("scatter", "fusion"), line      # the page write
+    assert text.count("tpu_custom_call") == 3    # two norms, one attend
+    assert len(re.findall(r"ragged_paged_attention/pallas_call",
+                          text)) == 1
+
+
+def test_layer_program_is_one_for_every_layer():
+    """The layers' weights are operands of the layer program: its jaxpr
+    closes over no array (a weight closed over would make the program a
+    layer's own) and the cache that holds it has no layer in its key, so
+    a second layer's call at the same shapes builds nothing."""
+    import importlib
+
+    pa = importlib.import_module("paddle_tpu.ops.kernels.paged_attention")
+    shape = (32, 32, 1, 64, 4096)
+    cfg = _layer_cfg(*shape)[:-1] + (True,)          # interpret: no chip
+    specs = [jax.ShapeDtypeStruct(s, d) for s, d in _layer_specs(*shape)]
+    closed = jax.make_jaxpr(pa._build_layer_call(*cfg))(*specs)
+    assert [c.shape for c in closed.consts if c.size > 1] == []
+    assert len(closed.jaxpr.invars) == len(specs)
+    first = pa._jitted_layer_step(cfg, False, None)
+    misses = pa._jitted_layer_step.cache_info().misses
+    assert pa._jitted_layer_step(cfg, False, None) is first
+    assert pa._jitted_layer_step.cache_info().misses == misses
+    # two layers' operands have one signature: one trace, one program
+    jaxprs = {str(jax.make_jaxpr(first)(*specs)) for _ in range(2)}
+    assert len(jaxprs) == 1
 
 
 @pytest.mark.parametrize("b,t,max_pages", [

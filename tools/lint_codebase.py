@@ -1029,7 +1029,7 @@ UNIFIED_ATTENTION_FILES = (
 )
 
 _UNIFIED_ATTEND_CALLS = frozenset({"attend_ragged",
-                                   "fused_ragged_step"})
+                                   "layer_step"})
 _PACKED_STEP_MARKERS = frozenset({"append_ragged"})
 
 
@@ -1086,7 +1086,7 @@ class _UnifiedAttentionVisitor(ast.NodeVisitor):
                     "%s:%d: function %r lands a ragged append "
                     "(append_ragged) without attending through the "
                     "unified pool API (attend_ragged/"
-                    "fused_ragged_step) in the same scope — the "
+                    "layer_step) in the same scope — the "
                     "packed step must compile ONE attend program per "
                     "config; fix it or waive with '%s(<reason>)'"
                     % (self.relpath, lineno, node.name, _WAIVER_MARK))
@@ -2633,7 +2633,7 @@ RULES = (
      "bucket_packed_tokens (bounded XLA compile count)"),
     ("unified-attention",
      "packed-step attention in serving.py/paged_llama.py routes "
-     "through the single attend_ragged/fused_ragged_step pool API "
+     "through the single attend_ragged/layer_step pool API "
      "(one attend program per packed config): a ragged append's "
      "function must attend unified in-scope"),
     ("serving-terminal-trace",
