@@ -1572,6 +1572,17 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("moe.expert_tokens_mean", "counter",
      "assignments / experts, summed over a step's expert-layer calls "
      "and rounded down a step (exact: assignments / (calls x E))"),
+    # window-and-summary pools (page_format="eva")
+    ("eva.windows_rolled", "counter",
+     "window chains released at a window's end (sequences x layer "
+     "pools)"),
+    ("eva.summaries_written", "counter",
+     "summary rows booked: one a page a window chain filled (summed "
+     "across layer pools)"),
+    ("eva.pages_window", "gauge",
+     "pages in window chains right now (all layer caches)"),
+    ("eva.pages_summary", "gauge",
+     "pages in summary chains right now (all layer caches)"),
     ("pool.total_pages", "gauge", "pool capacity (all layer caches)"),
     ("pool.free_pages", "gauge", "free pages right now"),
     ("pool.utilization", "gauge", "1 - free/total"),
@@ -1806,7 +1817,8 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "one to_static trace (program/variant/n_eqns/lint attrs)"),
     ("span:jit.call", "span", "the to_static entry call"),
     ("span:serving.pack", "span",
-     "_chunk_feeds and the bucket choice (rows/packed/pad_to attrs)"),
+     "_chunk_feeds and the bucket choice (rows/packed/pad_to attrs; "
+     "prefill = prompt tokens of the step)"),
     ("span:serving.logits_pull", "span",
      "np.asarray(logits): the wait for the device and the "
      "device->host copy"),
@@ -1856,14 +1868,19 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "attend_ragged / latent_ragged_step, whole (op attr)"),
     ("span:pool.book", "span",
      "_ragged_slots: capacity check, COW forks, slot plan "
-     "(slots/pages attrs)"),
+     "(slots/pages attrs; summary_slots on a window-and-summary pool)"),
+    ("span:pool.roll", "span",
+     "window chains of the rows at a window's end released, inside "
+     "pool.book (rows/pages attrs)"),
     ("span:pool.table", "span",
      "page table / slot plan built in numpy and uploaded in one "
      "call: once a programmed step, once a layer call elsewhere "
      "(rows/bytes attrs)"),
     ("span:kernel.ragged", "span",
      "the jitted ragged call: LRU lookup and dispatch "
-     "(rows/t/max_pages attrs; grid_steps = rows x page blocks)"),
+     "(rows/t/max_pages attrs; grid_steps = rows x page blocks; over "
+     "a window-and-summary pool the call's exact fed/pairs/kv_rows/"
+     "summaries_written from its table)"),
     ("span:xla.trace", "span",
      "jax.monitoring jaxpr_trace_duration under the open span "
      "(fun attr)"),
@@ -2009,7 +2026,7 @@ def write_prometheus(path: str,
 # watermarks, epochs, uptimes — takes the max by default.
 _GAUGE_MERGE_SUM = frozenset({
     "pool.total_pages", "pool.free_pages", "pool.shared_pages",
-    "pool.used_bytes",
+    "pool.used_bytes", "eva.pages_window", "eva.pages_summary",
     "serving.active_requests", "serving.queued_requests",
     "serving.retired_requests", "serving.swapped_requests",
     "serving.swap_used_bytes", "serving.slo_window_requests",

@@ -489,11 +489,32 @@ class PageSanitizer:
                 "use-after-free",
                 "truncate(%r): unknown or freed sequence" % (s,), ev)
             return
-        keep = -(-n // self.page_size) if n else 0
+        # ``keep``: a window-and-summary pool's chains are shorter than
+        # their tokens (the window's pages; a page of summary rows)
+        keep = ev.get("keep", -(-n // self.page_size) if n else 0)
         while len(chain) > keep:
             p, g = chain.pop()
             self._release(p, g, ev, "truncate(%r)" % (s,))
         self.lens[s] = n
+
+    def _ev_roll(self, ev, pool):
+        """A window-and-summary sequence passed a window's end: its
+        window chain is released whole; its length stays."""
+        s = ev["seq"]
+        chain = self.chains.get(s)
+        if chain is None:
+            self._violate(
+                "use-after-free",
+                "roll(%r): unknown or freed sequence" % (s,), ev)
+            return
+        if [p for p, _ in chain] != [int(p) for p in ev["pages"]]:
+            self._violate(
+                "stale-page-table",
+                "roll(%r) released %s but the tracked chain is %s"
+                % (s, ev["pages"], [p for p, _ in chain]), ev)
+        while chain:
+            p, g = chain.pop()
+            self._release(p, g, ev, "roll(%r)" % (s,))
 
     def _ev_fork(self, ev, pool):
         s, src, dst = ev["seq"], int(ev["src"]), int(ev["dst"])

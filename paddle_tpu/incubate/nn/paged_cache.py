@@ -34,7 +34,24 @@ value (:meth:`PagedKVCacheManager.latent_ragged_step`). Booking, page
 tables, reference counts, copy-on-write, prefix attach, the sanitizer and
 the ``pool.*`` spans are the same code for both; what moves K/V-shaped
 records (the append/attend family, host swap, the page-chain wire, int8
-calibration) refuses a latent pool by name.
+calibration) refuses a latent pool by name. ``"eva"`` (window and
+summary, ``window_tokens=``) keeps K and V arrays like ``"kv"`` and TWO
+chains a sequence in them: the window chain holds the tokens of the
+current aligned window of ``window_tokens`` and is released whole when
+the sequence passes a multiple of it (span ``pool.roll``); the summary
+chain, booked under a derived id (:meth:`PagedKVCacheManager.
+_summary_key`) so that refcounts, invariants and the sanitizer cover it
+with the same code, gains one row for every page the window chain fills
+(the model's chunk is the page), written by the layer program in the
+step that fills the page. The table a step hands the kernel is, for a row
+in window w, [the summary pages of windows 0..w-1 ; the window's pages],
+its length one a visible summary row and one a window token: causal
+attention over it is one softmax over the window's exact keys and every
+earlier chunk's pooled key. ``pages_for(n)`` is what admission reserves
+(one window's pages and n / page_size^2 summary pages at most, not n /
+page_size). Only :meth:`PagedKVCacheManager.layer_step` writes such a
+pool; prefix attach, host swap, the page-chain wire, the append/attend
+family and ``truncate`` across a roll refuse it by name.
 
 Quantized pages (``kv_dtype="int8"``): pages store int8 with a
 per-page, PER-HEAD float32 scale sidecar ``k_scales``/``v_scales``
@@ -273,7 +290,7 @@ class HostKVSwapSpace:
         if mp_shards < 1:
             raise ValueError("export_seq: mp_shards must be >= 1")
         for pool in pools:
-            pool._kv_only("export_seq")
+            pool._plain_kv("export_seq")
         if not pools:
             raise ValueError("export_seq: no pools given")
         recs = []
@@ -388,7 +405,7 @@ class HostKVSwapSpace:
         and the byte budget are all validated before any record is
         stored. Returns the host bytes stored."""
         for pool in pools:
-            pool._kv_only("import_seq")
+            pool._plain_kv("import_seq")
         parsed = sorted((self._parse_wire(p) for p in payloads),
                         key=lambda hp: hp[0]["shard"]["rank"])
         if not parsed:
@@ -559,6 +576,11 @@ class HostKVSwapSpace:
         return rec
 
 
+class _SummaryKey(str):
+    """The id a sequence's chain of summary rows is booked under
+    (:meth:`PagedKVCacheManager._summary_key`)."""
+
+
 class StepTables(tuple):
     """What a step's kernel reads beside the pages, on the device:
     ``(tbl, lens, q_lens)``, ``(tbl, lens, q_lens, pg, of)`` or, merged
@@ -568,7 +590,7 @@ class StepTables(tuple):
     checks) and, for a step shared between the pools of the layers,
     what it was ``booked`` from (:meth:`PagedKVCacheManager.book_step`)."""
 
-    host_tbl = host_lens = booked = None
+    host_tbl = host_lens = booked = counts = None
 
 
 class PagedKVCacheManager:
@@ -593,11 +615,12 @@ class PagedKVCacheManager:
         "float16": jnp.float16,
     }
 
-    PAGE_FORMATS = ("kv", "latent")
+    PAGE_FORMATS = ("kv", "latent", "eva")
 
     def __init__(self, num_pages, page_size, kv_heads, head_dim,
                  dtype=jnp.bfloat16, kv_dtype=None, sanitizer=None,
-                 mp_size=1, mp_rank=0, page_format="kv"):
+                 mp_size=1, mp_rank=0, page_format="kv",
+                 window_tokens=None):
         # the serving path's jax.jit programs persist like to_static's
         from ...jit.api import ensure_compilation_cache
 
@@ -610,6 +633,26 @@ class PagedKVCacheManager:
                 f"{page_format!r}")
         self.page_format = page_format
         self.latent = page_format == "latent"
+        self.eva = page_format == "eva"
+        if self.eva != (window_tokens is not None):
+            raise ValueError(
+                "window_tokens is the aligned window of page_format="
+                f"'eva' and of no other format (page_format="
+                f"{page_format!r}, window_tokens={window_tokens!r})")
+        if self.eva:
+            # a summary row is ONE finished page pooled over its slots,
+            # so the model's chunk is the page; whole pages of summary
+            # rows become visible a window at a time
+            self.window_tokens = int(window_tokens)
+            self.window_pages = self.window_tokens // self.page_size
+            if self.window_tokens % (self.page_size ** 2) \
+                    or int(mp_size) != 1 or kv_dtype == "int8":
+                raise ValueError(
+                    "page_format='eva': the window holds whole pages of "
+                    "summary rows (window_tokens a multiple of "
+                    f"page_size^2 = {self.page_size ** 2}), float pages, "
+                    f"mp_size=1; got window_tokens={window_tokens} "
+                    f"kv_dtype={kv_dtype!r} mp_size={mp_size}")
         if self.latent and (int(kv_heads) != 1 or int(mp_size) != 1):
             raise ValueError(
                 "page_format='latent': one latent row a token, shared "
@@ -713,14 +756,90 @@ class PagedKVCacheManager:
                 "no V array); latent pools are written and read by "
                 "latent_ragged_step")
 
+    def _one_chain(self, op):
+        """Refuse, by name, an operation that takes a sequence for ONE
+        page chain as long as its tokens on a window-and-summary pool."""
+        if self.eva:
+            raise ValueError(
+                f"{op}: not available for page_format='eva' (a sequence "
+                "holds a window chain, released at every window's end, "
+                "and a chain of summary rows; both are written by "
+                "layer_step alone)")
+
+    def _plain_kv(self, op):
+        """Refuse what moves K/V records of a chain as long as its
+        sequence: a latent pool has no such record, a window-and-summary
+        sequence no such chain."""
+        self._kv_only(op)
+        self._one_chain(op)
+
+    # -- window-and-summary geometry (page_format="eva") -------------------
+    @staticmethod
+    def _summary_key(seq_id):
+        """The id the summary chain of ``seq_id`` is booked under: a
+        chain of its own in ``_tables`` / ``_lens`` (length = summary
+        rows) and in the sanitizer's shadow heap, so that refcounts,
+        invariants and cross-checks cover both chains with one code."""
+        return _SummaryKey(f"{seq_id!r}/summary")
+
+    def pages_for(self, n) -> int:
+        """Pages a sequence of ``n`` tokens holds AT MOST over its life
+        (what admission reserves): one a ``page_size`` tokens; for
+        ``"eva"`` the current window's pages, at most one window's,
+        beside one summary page a ``page_size ** 2`` tokens."""
+        n = max(int(n), 0)
+        pages = -(-n // self.page_size)
+        if not self.eva:
+            return pages
+        return min(self.window_pages, pages) + -(-n // self.page_size ** 2)
+
+    def _window_of(self, n):
+        """(w, t, visible summary pages) of an ``"eva"`` sequence of
+        ``n`` >= 1 tokens: its newest token is the t-th (1..window) of
+        window w, and sees the summary pages of windows 0..w-1."""
+        w = (n - 1) // self.window_tokens
+        return (w, n - w * self.window_tokens,
+                w * (self.window_pages // self.page_size))
+
+    def pages_held(self, n) -> int:
+        """Pages a sequence holds WHEN it is ``n`` tokens long."""
+        n = max(int(n), 0)
+        if not self.eva or not n:
+            return -(-n // self.page_size)
+        return (-(-self._window_of(n)[1] // self.page_size)
+                + -(-(n // self.page_size) // self.page_size))
+
+    def table_pages(self, n) -> int:
+        """Width of the page-table row of a sequence of ``n`` tokens:
+        for ``"eva"`` the visible summary pages (every chunk of every
+        earlier window) and then the window's."""
+        n = max(int(n), 0)
+        if not self.eva or not n:
+            return -(-n // self.page_size)
+        _, t, vis = self._window_of(n)
+        return vis + -(-t // self.page_size)
+
+    def chunk_room(self, seq_id) -> int:
+        """Tokens one step may append to the sequence: for ``"eva"`` up
+        to the end of the window its next token falls in (a step's rows
+        never straddle a window boundary), else no bound (None)."""
+        if not self.eva:
+            return None
+        return self.window_tokens - self._lens[seq_id] % self.window_tokens
+
     # -- bookkeeping -------------------------------------------------------
     def alloc(self, seq_id):
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
-        if self._san is not None:
-            self._san.event("alloc", seq=seq_id)
-        self._tables[seq_id] = []
-        self._lens[seq_id] = 0
+        for key in self._chain_keys(seq_id):
+            if self._san is not None:
+                self._san.event("alloc", seq=key)
+            self._tables[key] = []
+            self._lens[key] = 0
+
+    def _chain_keys(self, seq_id):
+        return (seq_id, self._summary_key(seq_id)) if self.eva \
+            else (seq_id,)
 
     def attach(self, seq_id, pages, length, trace_ctx=None):
         """Register ``seq_id`` on an existing page chain covering its
@@ -730,6 +849,7 @@ class PagedKVCacheManager:
         into the (partial) last page, which forks it. ``trace_ctx``
         (a serialized TraceContext wire string) rides along so the
         chain's trace identity transfers with its ownership."""
+        self._one_chain("attach")
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
         need = -(-int(length) // self.page_size) if length else 0
@@ -779,6 +899,11 @@ class PagedKVCacheManager:
             self._refcnt[p] += 1
 
     def free(self, seq_id):
+        for key in self._chain_keys(seq_id):
+            self._free_chain(key)
+        self._trace_ctxs.pop(seq_id, None)
+
+    def _free_chain(self, seq_id):
         tbl = self._tables.get(seq_id)
         if self._san is not None:
             # emitted BEFORE the lookup raise: a double-free lands in
@@ -794,7 +919,6 @@ class PagedKVCacheManager:
         del self._tables[seq_id]
         self._drop_refs(tbl)
         self._lens.pop(seq_id)
-        self._trace_ctxs.pop(seq_id, None)
         if self._san is not None:
             self._san.verify_pages(tbl, self)
 
@@ -905,11 +1029,16 @@ class PagedKVCacheManager:
         """The sequence's physical page chain (copy)."""
         return list(self._tables[seq_id])
 
+    def seq_summary_pages(self, seq_id):
+        """The sequence's chain of summary pages (copy; ``"eva"``)."""
+        return list(self._tables[self._summary_key(seq_id)])
+
     def seq_page_count(self, seq_id) -> int:
-        """Pages the sequence holds, without materializing the chain
-        (victim scoring reads this for every active sequence on every
-        pick — ``len(seq_pages())`` would copy the table each time)."""
-        return len(self._tables[seq_id])
+        """Pages the sequence holds (both chains of an ``"eva"``
+        sequence), without materializing the chain (victim scoring
+        reads this for every active sequence on every pick —
+        ``len(seq_pages())`` would copy the table each time)."""
+        return sum(len(self._tables[k]) for k in self._chain_keys(seq_id))
 
     def pending_cow(self, seq_id) -> bool:
         """True if the sequence's next append must fork a shared page
@@ -928,11 +1057,27 @@ class PagedKVCacheManager:
         if n > cur:
             raise ValueError(
                 f"truncate({seq_id!r}, {n}): sequence has only {cur}")
-        keep = -(-n // self.page_size) if n else 0
+        start = 0
+        if self.eva and n < cur:
+            start = self._window_of(cur)[0] * self.window_tokens
+            if n <= start:
+                raise ValueError(
+                    f"truncate({seq_id!r}, {n}): not available across a "
+                    f"roll for page_format='eva' (the window that held "
+                    f"token {n} was released at {start}; only its "
+                    "summary rows are left)")
+            # the summary rows of the chunks that are whole no longer
+            rows = n // self.page_size
+            self._truncate_chain(self._summary_key(seq_id), rows,
+                                 -(-rows // self.page_size))
+        self._truncate_chain(
+            seq_id, n, -(-(n - start) // self.page_size) if n else 0)
+
+    def _truncate_chain(self, seq_id, n, keep):
         tbl = self._tables[seq_id]
         dropped = tbl[keep:]
         if self._san is not None:
-            self._san.event("truncate", seq=seq_id, n=int(n),
+            self._san.event("truncate", seq=seq_id, n=int(n), keep=keep,
                             dropped=[int(p) for p in dropped])
         while len(tbl) > keep:
             self._release_page(tbl.pop())
@@ -971,7 +1116,7 @@ class PagedKVCacheManager:
         any bookkeeping mutation, so a full space
         (:class:`SwapSpaceFull`) aborts with the pool untouched.
         Returns ``(pages_freed, nbytes_swapped)``."""
-        self._kv_only("swap_out")
+        self._plain_kv("swap_out")
         tbl = self._tables.get(seq_id)
         if tbl is None:
             raise KeyError(f"swap_out({seq_id!r}): unknown sequence")
@@ -1046,7 +1191,7 @@ class PagedKVCacheManager:
         private positions change; contents and order do not).
         Atomic: capacity is validated before any mutation. Returns
         the number of pages restored from host."""
-        self._kv_only("swap_in")
+        self._plain_kv("swap_in")
         if seq_id in self._tables:
             raise ValueError(
                 f"swap_in({seq_id!r}): sequence already allocated")
@@ -1203,8 +1348,24 @@ class PagedKVCacheManager:
         return self._san.stats()
 
     def _san_check_table(self, seq_ids, tbl, lens):
+        tbl, lens = np.asarray(tbl), np.asarray(lens)
+        if not self.eva:
+            self._san.check_table(seq_ids, tbl, lens)
+            return
+        # a row is [visible summary pages ; window pages] and its length
+        # counts summary rows and window tokens: each part against its
+        # own shadow chain (the window a sequence is in, and the summary
+        # pages that are not visible yet, from the pool's books)
+        wins = [self._window_of(max(self._lens[s], 1)) for s in seq_ids]
         self._san.check_table(
-            seq_ids, np.asarray(tbl), np.asarray(lens))
+            seq_ids, [tbl[i, vis:] for i, (_, _, vis) in enumerate(wins)],
+            [int(lens[i]) + w * (self.window_tokens - self.window_pages)
+             for i, (w, _, _) in enumerate(wins)])
+        keys = [self._summary_key(s) for s in seq_ids]
+        self._san.check_table(
+            keys, [list(tbl[i, :vis]) + self._tables[k][vis:]
+                   for i, ((_, _, vis), k) in enumerate(zip(wins, keys))],
+            [self._lens[k] for k in keys])
 
     def _needs_fork(self, page) -> bool:
         """A mid-page write must fork when the page is shared."""
@@ -1271,7 +1432,7 @@ class PagedKVCacheManager:
     def append(self, seq_id, k_tok, v_tok):
         """Write one token's K/V ((KVH, D) arrays or Tensors) into the
         sequence's next slot."""
-        self._kv_only("append")
+        self._plain_kv("append")
         page, off = self._next_slot(seq_id)
         k_tok = k_tok._data if isinstance(k_tok, Tensor) else k_tok
         v_tok = v_tok._data if isinstance(v_tok, Tensor) else v_tok
@@ -1300,7 +1461,7 @@ class PagedKVCacheManager:
         scatter per pages array (the hot serving path: B sequences x
         L layers must not issue B*L separate updates). k_toks/v_toks:
         (B, KVH, D) arrays or Tensors."""
-        self._kv_only("append_batch")
+        self._plain_kv("append_batch")
         k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
         v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
         # atomicity: validate capacity BEFORE any bookkeeping mutation,
@@ -1345,6 +1506,9 @@ class PagedKVCacheManager:
         current tail, plus one draw per sequence whose first write
         lands mid-page on a SHARED page (the copy-on-write fork) —
         the page-granular reservation a chunk boundary must respect."""
+        if self.eva:
+            return sum(sum(self._eva_growth(s, int(c))[:2])
+                       for s, c in zip(seq_ids, counts))
         need = 0
         for s, c in zip(seq_ids, counts):
             if not c:
@@ -1355,6 +1519,119 @@ class PagedKVCacheManager:
             if self.pending_cow(s):
                 need += 1
         return need
+
+    def _eva_growth(self, seq_id, c):
+        """(window pages drawn, summary pages drawn, window pages
+        released first) by appending ``c`` tokens to a window-and-summary
+        sequence: arithmetic alone. A sequence whose length is a
+        multiple of the window rolls: its whole window chain goes back
+        to the pool before the first new page is drawn."""
+        n, P, W = self._lens[seq_id], self.page_size, self.window_tokens
+        if not c:
+            return 0, 0, 0
+        start = n // W * W
+        if n + c > start + W:
+            raise ValueError(
+                f"page_format='eva': {c} tokens appended to {seq_id!r} at "
+                f"{n} would straddle the window boundary at {start + W} "
+                "(a step's rows end at a window's end: chunk_room)")
+        rolled = self.window_pages if n and n == start else 0
+        rows0, rows1 = n // P, (n + c) // P
+        return (-(-(n + c - start) // P) - -(-(n - start) // P),
+                -(-rows1 // P) - -(-rows0 // P), rolled)
+
+    def _eva_slots(self, seq_ids, counts):
+        """:meth:`_ragged_slots` of a window-and-summary pool: the same
+        contract (atomic capacity precheck, then slot assignment and
+        length advance, sanitizer events) over two chains. Rolls come
+        first (span ``pool.roll``): a row at a window's end releases its
+        window chain. Then every token gets its window slot, and every
+        token that FILLS its page books the next row of the summary
+        chain: the layer program pools that page into it in this step.
+        Returns (pages, offs, (src, dst_page, dst_off)): the K/V write
+        plan and the summary plan, row-major."""
+        with telemetry.span("pool.book") as sp:
+            grow = [self._eva_growth(s, c) for s, c in zip(seq_ids, counts)]
+            need = sum(g[0] + g[1] for g in grow)
+            released = sum(g[2] for g in grow)
+            if need > len(self._free) + released:
+                raise RuntimeError(
+                    f"KV page pool exhausted: ragged append needs "
+                    f"{need} new pages, {len(self._free)} free and "
+                    f"{released} released by window roll-over")
+            if released:
+                rolled = [s for s, g in zip(seq_ids, grow) if g[2]]
+                with telemetry.span("pool.roll", rows=len(rolled),
+                                    pages=released):
+                    for s in rolled:
+                        self._roll(s)
+            pages, offs, src, dpg, dof, filled = [], [], [], [], [], []
+            last = self.page_size - 1
+            for s, c in zip(seq_ids, counts):
+                key = self._summary_key(s)
+                filled.append(-len(src))
+                for _ in range(c):
+                    page, off = self._next_slot(s)
+                    self._lens[s] += 1
+                    pages.append(page)
+                    offs.append(off)
+                    if off == last:
+                        spg, sof = self._next_slot(key)
+                        self._lens[key] += 1
+                        src.append(page)
+                        dpg.append(spg)
+                        dof.append(sof)
+                filled[-1] += len(src)
+            if self._reg is not None:
+                self._reg.inc("eva.summaries_written", len(src))
+            if pages and self._san is not None:
+                self._san.event("append_ragged", seq_ids=list(seq_ids),
+                                counts=list(counts),
+                                pages=[int(p) for p in pages],
+                                offs=[int(o) for o in offs], pool=self)
+                if src:
+                    self._san.event(
+                        "append_ragged",
+                        seq_ids=[self._summary_key(s) for s in seq_ids],
+                        counts=filled,
+                        pages=[int(p) for p in dpg],
+                        offs=[int(o) for o in dof], pool=self)
+            if sp is not None:
+                sp.attrs.update(slots=len(pages), pages=need,
+                                summary_slots=len(src))
+            return pages, offs, (src, dpg, dof)
+
+    def _roll(self, seq_id):
+        """Release the window chain of a sequence at a window's end (its
+        last page was pooled in the step that filled it)."""
+        tbl = self._tables[seq_id]
+        if len(tbl) != self.window_pages:
+            raise AssertionError(
+                f"roll({seq_id!r}): the window chain holds {len(tbl)} "
+                f"pages at length {self._lens[seq_id]}, a full window is "
+                f"{self.window_pages}")
+        pages = list(tbl)
+        if self._san is not None:
+            self._san.event("roll", seq=seq_id,
+                            pages=[int(p) for p in pages])
+        tbl.clear()
+        self._drop_refs(pages)
+        if self._reg is not None:
+            self._reg.inc("eva.windows_rolled")
+        if self._san is not None:
+            self._san.verify_pages(pages, self)
+
+    @property
+    def pages_window(self) -> int:
+        """Pages in window chains right now (``"eva"``)."""
+        return sum(len(t) for k, t in self._tables.items()
+                   if not isinstance(k, _SummaryKey))
+
+    @property
+    def pages_summary(self) -> int:
+        """Pages in summary chains right now (``"eva"``)."""
+        return sum(len(t) for k, t in self._tables.items()
+                   if isinstance(k, _SummaryKey))
 
     def _ragged_slots(self, seq_ids, counts):
         """Bookkeeping half of a ragged append: atomic capacity
@@ -1395,7 +1672,7 @@ class PagedKVCacheManager:
         decode rows must not issue one update per token per layer).
         k_toks/v_toks: (sum(counts), KVH, D) arrays or Tensors, rows
         ordered sequence-major (seq_ids[0]'s tokens first)."""
-        self._kv_only("append_ragged")
+        self._plain_kv("append_ragged")
         with telemetry.span("pool.fused_step", op="append_ragged"):
             k_toks = k_toks._data if isinstance(k_toks, Tensor) else k_toks
             v_toks = v_toks._data if isinstance(v_toks, Tensor) else v_toks
@@ -1454,7 +1731,7 @@ class PagedKVCacheManager:
         Quantized pools pass their scale sidecars into the kernel
         (dequant fused after the page DMA). The T=1 shape of
         :meth:`attend_ragged`: every row's ``q_len`` is 1."""
-        self._kv_only("attend")
+        self._plain_kv("attend")
         out = self.attend_ragged(
             Tensor(_as_tensor(q)._data[:, None]), seq_ids,
             [1] * len(seq_ids), sm_scale=sm_scale, window=window)
@@ -1467,15 +1744,30 @@ class PagedKVCacheManager:
         exact zeros) — the shape-bucketing enabler for the chunked-
         prefill dispatch."""
         rows_pad = max(int(rows_pad or len(seq_ids)), len(seq_ids))
-        mp = max((len(self._tables[s]) for s in seq_ids), default=1)
+        chains = [self._table_row(s) for s in seq_ids]
+        mp = max((len(pages) for pages, _ in chains), default=1)
         mp = max(int(max_pages or mp), mp, 1)
         tbl = np.zeros((rows_pad, mp), np.int32)
         lens = np.zeros((rows_pad,), np.int32)
-        for i, s in enumerate(seq_ids):
-            pages = self._tables[s]
+        for i, (pages, n) in enumerate(chains):
             tbl[i, :len(pages)] = pages
-            lens[i] = self._lens[s]
+            lens[i] = n
         return tbl, lens
+
+    def _table_row(self, seq_id):
+        """(pages, length) of a sequence as its kernel sees it. ``"eva"``,
+        a sequence in window w: the summary pages of every earlier
+        window, always whole pages, then the window's pages; the length
+        counts one row a visible summary and one a window token. Every
+        summary lies before every query, so causal attention over this
+        row is ONE softmax over the window's exact keys and the earlier
+        windows' summaries."""
+        n = self._lens[seq_id]
+        if not self.eva or not n:
+            return self._tables[seq_id], n
+        w, t, vis = self._window_of(n)
+        return (self._tables[self._summary_key(seq_id)][:vis]
+                + self._tables[seq_id], w * self.window_pages + t)
 
     def _step_tables(self, seq_ids, q_lens, rows_pad, max_pages,
                      slots=None, n_pad=None, merged=False):
@@ -1503,7 +1795,7 @@ class PagedKVCacheManager:
             ql[:len(seq_ids)] = q_lens
             host = (tbl, lens, ql)
             if slots is not None:
-                pages, offs = slots
+                pages, offs, *sums = slots
                 plan = np.zeros((2, max(int(n_pad), len(pages))), np.int32)
                 plan[0, len(pages):] = self.num_pages
                 plan[0, :len(pages)] = pages
@@ -1512,8 +1804,27 @@ class PagedKVCacheManager:
             if merged:
                 host = (np.concatenate(
                     [tbl, lens[:, None], ql[:, None]], 1), plan)
+                if self.eva:
+                    # the summary plan: (page pooled, page and slot of
+                    # its row), one a page the step fills, padded like
+                    # the slot plan to what a step can fill at most
+                    src = sums[0][0]
+                    plan = np.zeros((3, plan.shape[1] // self.page_size
+                                     + tbl.shape[0]), np.int32)
+                    plan[1, len(src):] = self.num_pages
+                    plan[:, :len(src)] = sums[0]
+                    host += (plan,)
             out = StepTables(_upload(*host))
             out.host_tbl, out.host_lens = tbl, lens
+            if self.eva:
+                # what the kernel call of this step reads and computes,
+                # exact from the table: a fed token is paired with every
+                # row before it and itself
+                fed, seen = ql.astype(np.int64), lens.astype(np.int64)
+                out.counts = {
+                    "fed": int(fed.sum()), "kv_rows": int(seen.sum()),
+                    "pairs": int((fed * seen - fed * (fed - 1) // 2).sum()),
+                    "summaries_written": len(sums[0][0])}
             if sp is not None:
                 sp.attrs.update(rows=len(seq_ids), bytes=int(
                     sum(a.nbytes for a in host)))
@@ -1532,8 +1843,10 @@ class PagedKVCacheManager:
         shadow heap where a sanitizer is on, and nothing is built or
         uploaded; else this pool builds its own."""
         counts = [int(c) for c in counts]
-        slots = self._ragged_slots(seq_ids, counts)
-        chains = [self._tables[s] for s in seq_ids]
+        slots = (self._eva_slots if self.eva else self._ragged_slots)(
+            seq_ids, counts)
+        chains = [self._tables[k] for s in seq_ids
+                  for k in self._chain_keys(s)]
         lens = [self._lens[s] for s in seq_ids]
         if like is not None and like.booked == (slots, chains, lens):
             if self._san is not None:
@@ -1554,7 +1867,7 @@ class PagedKVCacheManager:
         Earlier rows and batch-padding rows return exact zeros. One
         ragged kernel call for the whole mixed batch: the single
         attend program per packed config."""
-        self._kv_only("attend_ragged")
+        self._plain_kv("attend_ragged")
         with telemetry.span("pool.fused_step", op="attend_ragged"):
             q = _as_tensor(q)
             tbl, lens, ql = self._step_tables(
@@ -1572,7 +1885,8 @@ class PagedKVCacheManager:
                             differentiable=False)
 
     def layer_step(self, x, weights, rope, plan, tables, eps,
-                   sm_scale=None, window=0):
+                   sm_scale=None, window=0, unit_offset=False,
+                   summary=None):
         """One decoder layer of a packed step as ONE compiled program
         over this pool's pages (ops/kernels/paged_attention.
         paged_ragged_layer_step): norm, qkv projection + RoPE + THIS
@@ -1597,15 +1911,24 @@ class PagedKVCacheManager:
         output stream (n_pad, E), a raw array. Float pools only — int8
         page calibration is a host-driven per-token wave replay the
         program cannot express (callers use append_ragged +
-        attend_ragged)."""
+        attend_ragged). ``unit_offset``: the norms' gains are stored
+        less one (x / rms(x) * (1 + g)). ``summary`` = (phi, mu), each
+        (heads, head_dim), of a ``page_format="eva"`` pool: the program
+        pools every page this step fills into the row of the summary
+        chain that :meth:`book_step` booked for it."""
         self._kv_only("layer_step")
         with telemetry.span("pool.fused_step", op="layer_step"):
             if self.quantized:
                 raise ValueError(
                     "layer_step: int8 KV pools calibrate per token on "
                     "the host — use append_ragged + attend_ragged")
+            if self.eva != (summary is not None):
+                raise ValueError(
+                    "layer_step: summary=(phi, mu) goes with "
+                    f"page_format='eva' and with no other (this pool "
+                    f"holds {self.page_format!r} pages)")
             tok, gm = plan
-            rows, slots = tables
+            rows, slots, *sums = tables
             n_pad = x.shape[0]
             if not tok.shape[1] == slots.shape[1] == n_pad:
                 raise ValueError(
@@ -1616,7 +1939,9 @@ class PagedKVCacheManager:
             y, self.k_pages, self.v_pages = _layer_step_fn(
                 self.k_pages, self.v_pages, x, weights, rope,
                 (tok, gm, slots, rows), eps, sm_scale=sm_scale,
-                window=window)
+                window=window, unit_offset=unit_offset,
+                summary=summary and (*summary, *sums),
+                counts=tables.counts)
             return y
 
     def latent_ragged_step(self, q, toks, seq_ids, counts, gather_map,
@@ -1665,7 +1990,7 @@ class PagedKVCacheManager:
         returns (page_table (B, MP), k (B, MP, P, KVH, D),
         v (...)) with k/v in compute dtype — the supported way to
         read quantized pages without touching the scale sidecars."""
-        self._kv_only("dense_kv")
+        self._plain_kv("dense_kv")
         tbl = self.page_table(seq_ids)
         kd = self.k_pages[tbl]
         vd = self.v_pages[tbl]

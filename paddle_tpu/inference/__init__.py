@@ -62,6 +62,11 @@ from .disagg import (  # noqa: E402
     apply_role_budgets,
     role_scheduler_kwargs,
 )
+# the serving adapters BatchScheduler drives (docs/SERVING.md, "Page
+# formats and the adapter contract"): PagedLlamaAdapter serves dense
+# Llama/Mistral models from "kv" pages and, read off the model's config,
+# a window-and-summary model (models.EvaByteForCausalLM) from "eva" pages;
+# PagedXing4Adapter serves Xing4 from "latent" pages
 from .paged_llama import PagedLlamaAdapter  # noqa: E402
 from .paged_xing4 import PagedXing4Adapter  # noqa: E402
 from .prefix_cache import RadixPrefixCache, PrefixMatch  # noqa: E402

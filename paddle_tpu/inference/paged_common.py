@@ -18,13 +18,16 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
+from ..framework.core import Tensor
 from ..ops.kernels.paged_attention import (
     packed_position_index as _packed_position_index,
 )
 
-__all__ = ["PagedAdapterBase", "PackedRows", "pow2",
+__all__ = ["PagedAdapterBase", "PackedRows", "StepLogits", "step_logits",
+           "pow2",
            "right_align_plan_np", "plan_packed_rows", "logits_epilogue"]
 
 
@@ -116,8 +119,7 @@ def plan_packed_rows(cache, token_ids, seq_ids, start_positions, pad_to,
     # every layer's cache shares one page size (adapter construction),
     # so the padded page-table width is loop-invariant
     r.mp_pad = pow2(max(
-        -(-(n + c) // cache.page_size)
-        for n, c in zip(lens0, counts)))
+        cache.table_pages(n + c) for n, c in zip(lens0, counts)))
     return r
 
 
@@ -139,6 +141,32 @@ def logits_epilogue(x, rows: PackedRows, head, logits_rows=None,
         return last
     vidx = _packed_position_index(rows.starts, rows.counts, logits_rows)
     return last, head(x[vidx])
+
+
+class StepLogits(Tensor):
+    """A step's logits as an adapter whose head runs on a bucketed row
+    count hands them back, with what rides their pull: ``numpy()`` cuts
+    the padding rows on the host and, where the step has some
+    (``extra``: Xing4's per-layer expert counts), fetches them in the
+    same transfer and hands them to ``note``."""
+
+    def numpy(self):
+        """The first ``rows`` rows (the head runs on a bucketed row
+        count; the padding is cut on the host, where it costs no
+        program)."""
+        extra, note = self._extra, self._note
+        if extra is None:
+            return np.asarray(jax.device_get(self._data))[:self._rows]
+        logits, counts = jax.device_get((self._data, extra))
+        self._extra = None
+        note(counts)
+        return np.asarray(logits)[:self._rows]
+
+
+def step_logits(data, rows, extra=None, note=None):
+    out = StepLogits(data)
+    out._rows, out._extra, out._note = rows, extra, note
+    return out
 
 
 class PagedAdapterBase:

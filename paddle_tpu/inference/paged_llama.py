@@ -19,6 +19,15 @@ raw arrays (``PagedKVCacheManager.layer_step``), with embed and head as
 programs too and every index operand of the step built in numpy and
 uploaded once; anything else (int8 pages or weights, routed experts,
 sharded or biased projections) runs the same plan op by op.
+
+A model that declares ``window_size`` / ``chunk_size`` (EvaByte,
+models/evabyte.py: exact keys for the current aligned window, one pooled
+row a finished chunk behind it) is served by the same adapter from a
+``page_format="eva"`` pool. Its dense layer is Llama's but for four
+things the adapter reads off the model: the norms' unit offset, MHA, the
+summary epilogue of the layer program, and the table a step hands the
+kernel (the pool's). It runs the programmed body only, offers ``warm``,
+and returns head 0's logits of the ``num_pred_heads`` it computes.
 """
 from __future__ import annotations
 
@@ -30,6 +39,7 @@ import jax.numpy as jnp
 from ..framework import telemetry
 from ..framework.core import Tensor, no_grad
 from ..incubate.nn import PagedKVCacheManager
+from ..incubate.nn.paged_cache import StepTables
 from ..nn.layer.norm import RMSNorm
 from ..ops.kernels.paged_attention import (
     packed_position_index_np as _position_index,
@@ -41,7 +51,7 @@ from ..ops.kernels.rope import apply_rotary_emb, build_rope_cache
 from ..tensor.manipulation import reshape
 from .paged_common import (
     PagedAdapterBase, logits_epilogue, plan_packed_rows,
-    pow2 as _pow2, right_align_plan_np as _right_align_plan,
+    pow2 as _pow2, right_align_plan_np as _right_align_plan, step_logits,
 )
 
 __all__ = ["PagedLlamaAdapter"]
@@ -86,6 +96,19 @@ class PagedLlamaAdapter(PagedAdapterBase):
         # Mistral-style sliding window rides through the paged decode
         # kernel's banded mask (out-of-window pages skipped)
         self._window = int(getattr(cfg, "sliding_window", 0) or 0)
+        # a window-and-summary model (EvaByte): aligned windows read
+        # exactly, one pooled row a finished chunk behind them; the
+        # chunk is the page, so the layer program pools whole pages
+        self._eva = int(getattr(cfg, "chunk_size", 0) or 0)
+        if self._eva and (self._eva != page_size or kv_cache_dtype
+                          or weight_dtype or page_pool_bytes):
+            raise ValueError(
+                f"a window-and-summary model (chunk_size={self._eva}) is "
+                "served from page_format='eva' pages of page_size = "
+                "chunk_size, float pages and weights, sized by num_pages "
+                f"(page_size={page_size} kv_cache_dtype={kv_cache_dtype!r}"
+                f" weight_dtype={weight_dtype!r} "
+                f"page_pool_bytes={page_pool_bytes!r})")
         self.weight_dtype = weight_dtype
         self.quant_report = None
         if weight_dtype is not None:
@@ -102,7 +125,9 @@ class PagedLlamaAdapter(PagedAdapterBase):
             return PagedKVCacheManager(
                 n, page_size, cfg.num_key_value_heads,
                 cfg.head_dim, dtype=dtype, kv_dtype=kv_cache_dtype,
-                sanitizer=sanitizer,
+                sanitizer=sanitizer, **(dict(
+                    page_format="eva", window_tokens=cfg.window_size)
+                    if self._eva else {}),
             )
 
         if page_pool_bytes is not None:
@@ -127,6 +152,18 @@ class PagedLlamaAdapter(PagedAdapterBase):
         self._init_dispatch_accounting()
         self._fused_ok = None
         self._programs = None
+        # the row shapes of a window-and-summary step: rows and a
+        # multi-token row's tokens are padded up to what ``warm`` was
+        # told, the table to a window's pages at least, so that the
+        # steady steps run the programs set-up built
+        self._rows_pad = self._chunk_pad = 1
+        self.pred_logits = None
+        if self._eva and not self._fusion_eligible():
+            raise ValueError(
+                "a window-and-summary model is served by the layer "
+                "program alone (page_format='eva' pages are written by "
+                "layer_step): every layer plain float linears, "
+                "RMSNorm gains of one kind, a plain embedding and head")
 
     def _fusion_eligible(self) -> bool:
         """The gate of the programmed body, computed once per adapter
@@ -168,17 +205,23 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     and (all(has) or not any(has))
                     and all(plain(p.bias, 1) for p in qkv if has[0])
                     and linear(att.o_proj)   # bias-free epilogue
-                    and type(mlp) is LlamaMLP
+                    and isinstance(mlp, LlamaMLP)
                     and all(linear(p) for p in (
                         mlp.gate_proj, mlp.up_proj, mlp.down_proj))):
                 return False
             norms += [layer.input_layernorm, layer.post_attention_layernorm]
         head = self.model.lm_head
-        return (all(type(n) is RMSNorm and plain(n.weight, 1)
+        return (all(isinstance(n, RMSNorm) and plain(n.weight, 1)
                     for n in norms)
-                and len({float(n._epsilon) for n in norms}) == 1
+                and len({(float(n._epsilon), self._unit_offset(n))
+                         for n in norms}) == 1
                 and plain(core.embed_tokens.weight)
                 and (head is None or linear(head)))
+
+    @staticmethod
+    def _unit_offset(norm) -> bool:
+        """The norm stores its gain less one (x / rms(x) * (1 + g))."""
+        return bool(getattr(norm, "unit_offset", False))
 
     def _step_programs(self):
         """(embed, head, eps) of the programmed body: the two programs
@@ -187,13 +230,27 @@ class PagedLlamaAdapter(PagedAdapterBase):
         if self._programs is None:
             eps = float(self.model.model.norm._epsilon)
             tied = self.model.lm_head is None
+            offset = self._unit_offset(self.model.model.norm)
+            f32 = bool(getattr(self.cfg, "fp32_logits", False))
+            heads = int(getattr(self.cfg, "num_pred_heads", 1))
 
             def embed(emb, tok):
                 return jnp.take(emb, tok[0], axis=0)
 
             def head(x, idx, norm_w, head_w):
+                if offset:
+                    norm_w = 1.0 + norm_w.astype(jnp.float32)
                 h = _rms_norm(x[idx], norm_w, eps)
-                return h @ head_w.T if tied else jnp.matmul(h, head_w)
+                if tied:
+                    return h @ head_w.T
+                lg = jnp.matmul(h, head_w, preferred_element_type=(
+                    jnp.float32 if f32 else None))
+                if heads == 1:
+                    return lg
+                # [head, vocabulary]: head 0, what a sampler sees,
+                # beside all of them
+                lg = lg.reshape(lg.shape[0], heads, -1)
+                return lg[:, 0], lg
 
             embed.__name__, head.__name__ = "llama_embed", "llama_head"
             self._programs = jax.jit(embed), jax.jit(head), eps
@@ -201,6 +258,9 @@ class PagedLlamaAdapter(PagedAdapterBase):
 
     def decode_token(self, token_ids, seq_ids):
         """One token per listed sequence; returns logits (B, vocab)."""
+        if self._eva:
+            return self.prefill_chunk([[int(t)] for t in token_ids],
+                                      seq_ids, pad_to=_pow2(len(seq_ids)))
         cfg = self.cfg
         b = len(seq_ids)
         nh, nkv, hd = (cfg.num_attention_heads,
@@ -298,6 +358,10 @@ class PagedLlamaAdapter(PagedAdapterBase):
             # program
             t_pad = _pow2(max(counts))
             b_pad = _pow2(b)
+            if self._eva:
+                b_pad = max(b_pad, self._rows_pad)
+                t_pad = 1 if t_pad == 1 else max(t_pad, self._chunk_pad)
+                rows.mp_pad = max(rows.mp_pad, self.caches[0].window_pages)
             gm, mr, mc, m_flat = _right_align_plan(
                 range(b), rows.starts, counts, t_pad, b_pad)
             fuse = self._fusion_eligible()
@@ -315,7 +379,10 @@ class PagedLlamaAdapter(PagedAdapterBase):
                                 _pad_plan(mr, pad_to, 0),
                                 _pad_plan(mc, pad_to, 0),
                                 _pad_plan(m_flat, pad_to, pad_to)])
-                host = [tok, gm, rows.last_idx]
+                # (the head of a window-and-summary model runs on the
+                # padded row count: slot 0 again, cut on the host)
+                host = [tok, gm, _pad_plan(rows.last_idx, b_pad, 0)
+                        if self._eva else rows.last_idx]
                 if logits_rows is not None:
                     host.append(_position_index(rows.starts, counts,
                                                 logits_rows))
@@ -331,6 +398,72 @@ class PagedLlamaAdapter(PagedAdapterBase):
             if fuse:
                 return self._run_programs(rows, seq_ids, up, b_pad)
             return self._run_eager(rows, seq_ids, up, b_pad, logits_rows)
+
+    def chunk_room(self, seq_id):
+        """Tokens one step may feed the sequence (the scheduler clamps a
+        prompt chunk to it): up to its window's end for a
+        window-and-summary model, else no bound (None)."""
+        return self.caches[0].chunk_room(seq_id)
+
+    def warm(self, rows, packed, chunk_tokens):
+        """Build, before the first request, the programs of the steady
+        steps of a window-and-summary model (``BatchScheduler.warm``
+        calls it with its batch size, packed widths and chunk size):
+        ``rows`` sequences decoding, alone or beside prompt chunks of at
+        most ``chunk_tokens`` tokens, at each packed width (the two it
+        is told of and the doublings between them) and each table width
+        up to ``max_length``. From here on every step pads
+        its rows to ``rows`` and a multi-token row to ``chunk_tokens``,
+        so the list is short and closed. Each program runs once on
+        zeros over the pools themselves: every slot and summary plan
+        entry is out of bounds (nothing is written) and every length 0.
+        A Llama/Mistral model returns at once: its steps' shapes follow
+        the traffic (ROADMAP Queue 1 #3)."""
+        if not self._eva:
+            return
+        self._rows_pad = b_pad = _pow2(rows)
+        self._chunk_pad = _pow2(max(2, int(chunk_tokens)))
+        embed, head, eps = self._step_programs()
+        core, pool = self.model.model, self.caches[0]
+        z, i32 = np.zeros, np.int32
+        mps, mp = [], pool.window_pages
+        while mp <= _pow2(pool.table_pages(self.max_length)):
+            mps.append(mp)
+            mp *= 2
+        layer = core.layers[0]
+        # a short last chunk packs to a width between the two it is
+        # told of: the doublings between them (the shipped buckets' and
+        # the usual ones'; another width compiles when it is first met)
+        packed = {int(p) for p in packed}
+        n = min(packed)
+        while n < max(packed):
+            n *= 2
+            packed.add(n)
+        packed = sorted(packed)
+        for n_pad in packed:
+            tok = z((5, n_pad), i32)
+            tok[4] = n_pad
+            slots = z((2, n_pad), i32)
+            slots[0] = pool.num_pages
+            sums = z((3, n_pad // pool.page_size + b_pad), i32)
+            sums[1] = pool.num_pages
+            for t_pad in (1, self._chunk_pad):
+                if t_pad == 1 and n_pad != min(packed):
+                    continue      # decode rows alone: the least width
+                for mp in mps:
+                    tok_d, gm, slots_d, rows_d, sums_d = _upload(
+                        tok, z((b_pad, t_pad), i32), slots,
+                        z((b_pad, mp + 2), i32), sums)
+                    x = embed(core.embed_tokens.weight._data, tok_d)
+                    x = pool.layer_step(
+                        x, self._layer_weights(layer),
+                        (self._cos, self._sin), (tok_d, gm),
+                        StepTables((rows_d, slots_d, sums_d)), eps,
+                        **self._layer_switches(layer))
+            out = head(x, jnp.zeros((b_pad,), jnp.int32),
+                       core.norm.weight._data,
+                       self.model.lm_head.weight._data)
+        jax.block_until_ready(out)
 
     def _run_programs(self, rows, seq_ids, up, b_pad):
         """The programmed body of a packed step: one dispatch for the
@@ -353,31 +486,52 @@ class PagedLlamaAdapter(PagedAdapterBase):
             x = embed(core.embed_tokens.weight._data, tok)      # (N, H)
         for li, layer in enumerate(core.layers):
             with span("model.layer", li=li, program=1):
-                att, mlp = layer.self_attn, layer.mlp
-                biases = None
-                if att.q_proj.bias is not None:
-                    biases = (att.q_proj.bias._data, att.k_proj.bias._data,
-                              att.v_proj.bias._data)
                 self.chunk_stats["attend_calls"] += 1
                 self.chunk_stats["layer_programs"] += 1
                 x = caches[li].layer_step(
-                    x, (layer.input_layernorm.weight._data,
-                        att.q_proj.weight._data, att.k_proj.weight._data,
-                        att.v_proj.weight._data, att.o_proj.weight._data,
-                        biases, layer.post_attention_layernorm.weight._data,
-                        mlp.gate_proj.weight._data, mlp.up_proj.weight._data,
-                        mlp.down_proj.weight._data),
-                    rope, plan, tables[li], eps, window=self._window)
+                    x, self._layer_weights(layer), rope, plan, tables[li],
+                    eps, window=self._window,
+                    **self._layer_switches(layer))
         with span("model.head"):
             head_w = (core.norm.weight._data,
                       (core.embed_tokens if self.model.lm_head is None
                        else self.model.lm_head).weight._data)
-            logits = Tensor(head(x, last, *head_w))
+            logits = head(x, last, *head_w)
+            if self._eva:
+                # every prediction head is computed; a sampler sees
+                # head 0 of the rows that are real (cut on the host)
+                logits, self.pred_logits = logits
+                return step_logits(logits, rows.b)
+            logits = Tensor(logits)
             if not verify:
                 return logits
             # multi-row sampling epilogue: per-position logits for
             # the listed (verify) rows, concatenated in list order
             return logits, Tensor(head(x, verify[0], *head_w))
+
+    @staticmethod
+    def _layer_weights(layer):
+        """A layer's raw arrays as the layer program takes them."""
+        att, mlp = layer.self_attn, layer.mlp
+        biases = None
+        if att.q_proj.bias is not None:
+            biases = (att.q_proj.bias._data, att.k_proj.bias._data,
+                      att.v_proj.bias._data)
+        return (layer.input_layernorm.weight._data,
+                att.q_proj.weight._data, att.k_proj.weight._data,
+                att.v_proj.weight._data, att.o_proj.weight._data,
+                biases, layer.post_attention_layernorm.weight._data,
+                mlp.gate_proj.weight._data, mlp.up_proj.weight._data,
+                mlp.down_proj.weight._data)
+
+    def _layer_switches(self, layer):
+        """The layer program's static switches, off for Llama/Mistral:
+        the norms' unit offset and the summary epilogue's (phi, mu)."""
+        if not self._eva:
+            return {}
+        att = layer.self_attn
+        return {"unit_offset": self._unit_offset(layer.input_layernorm),
+                "summary": (att.phi._data, att.mu._data)}
 
     def _run_eager(self, rows, seq_ids, up, b_pad, logits_rows):
         """The op-by-op body of a packed step (int8 pages or weights,

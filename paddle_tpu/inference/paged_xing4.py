@@ -41,8 +41,8 @@ from ..models import xing4 as _x
 from ..ops.kernels.paged_attention import latent_ragged_step as _latent_step
 from ..ops.kernels.paged_attention import pad_plan_i32 as _pad_plan
 from .paged_common import (
-    PagedAdapterBase, logits_epilogue, plan_packed_rows, pow2,
-    right_align_plan_np,
+    PagedAdapterBase, StepLogits, logits_epilogue, plan_packed_rows, pow2,
+    right_align_plan_np, step_logits as _step_logits,
 )
 
 __all__ = ["PagedXing4Adapter", "StepLogits"]
@@ -52,30 +52,6 @@ __all__ = ["PagedXing4Adapter", "StepLogits"]
 # are of two row widths (decode; chunk) and not one a power of two; the
 # kernel skips a row's padding
 CHUNK_ROW_PAD = 64
-
-
-class StepLogits(Tensor):
-    """A step's logits with what rides its pull: ``numpy()`` fetches the
-    logits and the step's per-layer expert counts in one transfer and
-    hands the counts to the adapter."""
-
-    def numpy(self):
-        """The first ``rows`` rows (the head runs on a bucketed row
-        count; the padding is cut on the host, where it costs no
-        program)."""
-        extra, note = self._extra, self._note
-        if extra is None:
-            return np.asarray(jax.device_get(self._data))[:self._rows]
-        logits, counts = jax.device_get((self._data, extra))
-        self._extra = None
-        note(counts)
-        return np.asarray(logits)[:self._rows]
-
-
-def _step_logits(data, rows, extra, note):
-    out = StepLogits(data)
-    out._rows, out._extra, out._note = rows, extra, note
-    return out
 
 
 class PagedXing4Adapter(PagedAdapterBase):

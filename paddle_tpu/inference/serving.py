@@ -153,6 +153,16 @@ __all__ = ["Request", "BatchScheduler", "RequestState",
 _SCHED_SEQ = [0]  # concurrency: single-writer
 
 
+def _pages_for(cache, n) -> int:
+    """Pages a sequence of ``n`` tokens holds at most over its life, as
+    its pool says (``PagedKVCacheManager.pages_for``: a window-and-summary
+    pool holds one window's pages and the summary pages, not one a
+    ``page_size`` tokens); a pool that does not say holds one a
+    ``page_size`` tokens."""
+    ask = getattr(cache, "pages_for", None)
+    return ask(n) if ask is not None else -(-int(n) // cache.page_size)
+
+
 class QueueFullError(RuntimeError):
     """submit() backpressure: the bounded queue
     (``FLAGS_serving_max_queue`` / ``max_queue=``) is at capacity —
@@ -357,6 +367,18 @@ class BatchScheduler:
         }
         # cross-request prefix KV cache (inference/prefix_cache.py):
         # True builds a RadixPrefixCache over the model's own caches;
+        # a window-and-summary pool (page_format="eva") keeps two chains
+        # a sequence and releases pages behind its window: nothing that
+        # takes a sequence for one chain as long as its tokens serves it
+        eva = any(getattr(c, "eva", False) for c in model.caches)
+        if eva and (prefix_cache or draft_model is not None or preempt):
+            raise ValueError(
+                "page_format='eva' pools serve no prefix cache (no reuse "
+                "across a roll), no speculative draft (truncate across a "
+                "roll) and no preemption (no swap record of a window and "
+                "its summaries): got prefix_cache="
+                f"{bool(prefix_cache)} draft_model="
+                f"{draft_model is not None} preempt={preempt}")
         # or pass a pre-built instance (shared across schedulers)
         if prefix_cache:
             if prefix_cache is True:
@@ -420,7 +442,7 @@ class BatchScheduler:
         self._plain_fifo = True
         self._deadline_seen = False
         preempt = bool(flag("serving_preempt")
-                       if preempt is None else preempt)
+                       if preempt is None else preempt) and not eva
         swap_bytes = int(flag("serving_swap_bytes")
                          if swap_bytes is None else swap_bytes)
         self.swap_space = None
@@ -609,7 +631,7 @@ class BatchScheduler:
         # length by up to draft_k+1 tokens before the rollback
         slack = (self.draft_k + 1) if self.draft is not None else 0
         for c in (model or self.model).caches:
-            n = -(-(req.total_tokens() + slack) // c.page_size)
+            n = _pages_for(c, req.total_tokens() + slack)
             # a prefix-cache hit shares its FULL pages; the hit's
             # partial tail page still costs one draw (the COW fork on
             # the first divergent write), so only full pages reduce
@@ -774,6 +796,10 @@ class BatchScheduler:
         for key in ("total_pages", "free_pages", "utilization",
                     "shared_pages", "used_bytes"):
             m.gauge("pool." + key, stats[key])
+        if any(getattr(c, "eva", False) for c in self.model.caches):
+            for chain in ("pages_window", "pages_summary"):
+                m.gauge("eva." + chain, sum(
+                    getattr(c, chain, 0) for c in self.model.caches))
         peak = sum(getattr(c, "peak_used_pages", 0)
                    for c in self.model.caches)
         m.gauge("pool.peak_utilization",
@@ -1644,8 +1670,9 @@ class BatchScheduler:
         slack = (self.draft_k + 1) if self.draft is not None else 0
         worst = req.total_tokens() + slack
         n = c.seq_len(req.req_id)
-        have = -(-n // c.page_size) if n else 0
-        rem = -(-worst // c.page_size) - have
+        held = getattr(c, "pages_held", None)
+        rem = _pages_for(c, worst) - (
+            held(n) if held is not None else _pages_for(c, n))
         pcow = getattr(c, "pending_cow", None)
         if pcow is not None and pcow(req.req_id):
             rem += 1
@@ -2254,6 +2281,9 @@ class BatchScheduler:
         budget = self.prefill_chunk_tokens
         rows, feeds, starts = [], [], []
         n_pre = n_dec = 0
+        # the one hook an adapter answers a chunk's length with: a
+        # window-and-summary row ends at its window's end
+        room = getattr(self.model, "chunk_room", None)
         for s in sids:
             req = self._active[s]
             if req.state == RequestState.DECODE:
@@ -2263,6 +2293,8 @@ class BatchScheduler:
                 n_dec += 1
             elif budget > 0:
                 take = min(len(req.prompt_ids) - req._pos, budget)
+                if room is not None:
+                    take = min(take, room(s) or take)
                 budget -= take
                 rows.append(s)
                 feeds.append(req.prompt_ids[req._pos:req._pos + take])
@@ -2317,7 +2349,7 @@ class BatchScheduler:
             pad_to = bucket_packed_tokens(packed, self.serving_buckets)
             if sp is not None:
                 sp.attrs.update(rows=len(rows), packed=packed,
-                                pad_to=pad_to)
+                                pad_to=pad_to, prefill=n_pre)
         t_exec = telemetry.clock() if self._metrics is not None \
             else 0.0
         with self._span("serving.prefill_chunk", rows=len(rows),

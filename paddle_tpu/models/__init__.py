@@ -9,6 +9,9 @@ this framework ships the acceptance-config model families in-tree:
 * :mod:`.gpt`    — GPT-3 (pre-LN, learned positions, gelu), DP/sharding
 * :mod:`.xing4`  — Xing4.0 (MLA, drop-free sigmoid-routed experts with a
   shared expert, mHC residual streams, MTP), served from latent pages
+* :mod:`.evabyte` — EvaByte (byte-level; EVA window-and-summary attention,
+  unit-offset norms, 8 next-byte heads), served from window-and-summary
+  pages by the Llama adapter
 * :mod:`.bert`   — BERT (bidirectional post-norm encoder, MLM +
   sequence-classification heads), non-causal flash path
 """
@@ -67,5 +70,12 @@ from .xing4 import (  # noqa: E402
     Xing4Model,
     xing4_29b_a4b,
     xing4_tiny,
+)
+from .evabyte import (  # noqa: E402
+    EvaByteConfig,
+    EvaByteForCausalLM,
+    EvaByteModel,
+    evabyte_6_5b,
+    evabyte_tiny,
 )
 from .generation import generate, speculative_generate  # noqa: E402

@@ -531,9 +531,22 @@ def _jitted_ragged_call(cfg):
     return jax.jit(_build_ragged_call(*cfg))
 
 
+def pool_pages(k, v, phi, mu, scale):
+    """One pooled (key, value) row a finished chunk: ``k``/``v`` (n, P,
+    H, D), a chunk's P rotated keys and its values; ``phi``/``mu`` (H, D)
+    learned a layer. a_j = softmax_j(scale * phi . k_j); the pooled key
+    is sum_j a_j k_j + mu, the pooled value sum_j a_j v_j. Float32,
+    elementwise (no matmul rounds the weights). Returns float32 (n, H,
+    D) twice."""
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    a = jax.nn.softmax(
+        jnp.sum(kf * phi.astype(jnp.float32), -1) * scale, axis=1)[..., None]
+    return (jnp.sum(a * kf, 1) + mu.astype(jnp.float32), jnp.sum(a * vf, 1))
+
+
 def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
                       t_pad, max_pages, scale, window, has_bias, eps,
-                      interpret):
+                      interpret, unit_offset=False, n_sum=0):
     """One decoder layer of the packed serving step as ONE program:
     ``rms_norm`` -> qkv projection + RoPE + this chunk's K/V page scatter
     -> the ragged kernel over the right-aligned rows -> scatter back +
@@ -561,7 +574,16 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
       per written token (PADDING entries carry an out-of-bounds page id
       and the scatter runs mode="drop", so they write nothing);
       ``rows`` (b_pad, max_pages + 2), a row's page table, then its
-      ``seq_len`` and ``q_len`` as in :func:`paged_ragged_attention`.
+      ``seq_len`` and ``q_len`` as in :func:`paged_ragged_attention`;
+    * two static switches, both off for a Llama/Mistral layer, whose
+      program they leave as it was. ``unit_offset``: the norms' gains
+      are stored less one (x / rms(x) * (1 + g), the sum in float32).
+      ``n_sum`` > 0, the summary epilogue of a window-and-summary pool
+      (three more operands: ``phi``/``mu`` (kvh, hd) and ``sums`` (3,
+      n_sum), for every page this step's scatter fills the page, then
+      the page and slot of its summary row; padding drops): the filled
+      pages are gathered, pooled (:func:`pool_pages`) and scattered
+      into the same pools before the kernel reads them.
 
     Returns ``(x_out (n_pad, e), new_k_pages, new_v_pages)`` — the caller
     (the pool, which owns page state) commits the returned pages. The
@@ -579,10 +601,12 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
         if has_bias:
             bq, bk, bv = rest[:3]
             rest = rest[3:]
-        ln2, wg, wu, wd, cos, sin, tok, gm, slots, rows = rest
+        ln2, wg, wu, wd, cos, sin, tok, gm, slots, rows, *eva = rest
         _, pos, mr, mc, mflat = tok
         (pg, of), tbl = slots, rows[:, :max_pages]
         lens, q_lens = rows[:, max_pages], rows[:, max_pages + 1]
+        if unit_offset:
+            ln1, ln2 = (1.0 + g.astype(jnp.float32) for g in (ln1, ln2))
         h = rms_norm(x, ln1, eps)
         xq = jnp.matmul(h, wq)
         xk = jnp.matmul(h, wk)
@@ -600,6 +624,11 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
             kh.astype(k_pages.dtype), mode="drop")
         vp = v_pages.at[pg, of].set(
             vh.astype(v_pages.dtype), mode="drop")
+        if n_sum:
+            phi, mu, (src, s_pg, s_of) = eva
+            kt, vt = pool_pages(kp[src], vp[src], phi, mu, scale)
+            kp = kp.at[s_pg, s_of].set(kt.astype(kp.dtype), mode="drop")
+            vp = vp.at[s_pg, s_of].set(vt.astype(vp.dtype), mode="drop")
         qm = qh[gm]                        # (b_pad, t_pad, nh, hd)
         out = attend(qm, kp, vp, tbl, lens, q_lens)
         # scatter back to the packed axis (padding entries target the
@@ -941,7 +970,8 @@ def upload_plan(*arrays):
 
 def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
                             eps, sm_scale=None, window=0,
-                            interpret=None):
+                            interpret=None, unit_offset=False,
+                            summary=None, counts=None):
     """One decoder layer of the packed serving step, one dispatch (see
     :func:`_build_layer_call` for the operand contract). ``weights`` =
     (ln1, wq, wk, wv, wo, biases, ln2, wg, wu, wd) with ``biases``
@@ -953,7 +983,11 @@ def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
 
     Returns ``(x_out, new_k_pages, new_v_pages)``; on the chip the pools
     handed in are DONATED: the page-pool owner holds the only reference
-    and commits the returned arrays.
+    and commits the returned arrays. ``unit_offset`` and ``summary`` =
+    (phi, mu, sums) are the program's two static switches; ``counts``
+    (a window-and-summary step's exact ``fed`` / ``pairs`` / ``kv_rows`` /
+    ``summaries_written``, from the pool's table) ride the
+    ``kernel.ragged`` span as attributes.
     """
     from ...distributed.mesh import global_mesh
 
@@ -973,11 +1007,13 @@ def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
     has_bias = biases is not None
     cfg = (n_pad, e, nh, kvh, hd, npages, page_size,
            b_pad, t_pad, max_pages, float(scale), int(window or 0),
-           has_bias, float(eps), bool(interpret))
+           has_bias, float(eps), bool(interpret), bool(unit_offset),
+           summary[2].shape[1] if summary else 0)
     with telemetry.span("kernel.ragged", rows=b_pad, t=t_pad,
                         max_pages=max_pages, fused=1,
-                        grid_steps=_ragged_grid_steps(b_pad, max_pages)):
+                        grid_steps=_ragged_grid_steps(b_pad, max_pages),
+                        **(counts or {})):
         return _jitted_layer_step(cfg, on_tpu(), global_mesh())(
             k_pages, v_pages, x, ln1, wq, wk, wv, wo,
             *(biases if has_bias else ()), ln2, wg, wu, wd, cos, sin,
-            *index)
+            *index, *(summary or ()))

@@ -220,6 +220,87 @@ def test_layer_program_is_one_for_every_layer():
     assert len(jaxprs) == 1
 
 
+# sha256 of the layer program's jaxpr at two of the Mistral cell's shapes,
+# taken from the parent of PR 34 (commit 4c5301f), before the program had
+# its two switches
+MISTRAL_BODY = {
+    (32, 32, 1, 64, 4096):
+        "6242de8a09a1409080944790e622b948a9c54cc1df179daf79291a45066ee8ee",
+    (128, 32, 64, 64, 4096):
+        "10a368f96885397a3b706bd721308c86082365c587796ad1c3ffc40c26124404",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(MISTRAL_BODY))
+def test_mistral_layer_program_keeps_its_body(shape):
+    """The layer program's two static switches (the norms' unit offset,
+    the summary epilogue) are off for a Llama/Mistral layer and leave its
+    program as it was: the jaxpr is, letter for letter, the one the
+    program had before it knew of them."""
+    import hashlib
+    import importlib
+
+    pa = importlib.import_module("paddle_tpu.ops.kernels.paged_attention")
+    cfg = _layer_cfg(*shape)[:-1] + (True,)          # interpret: no chip
+    specs = [jax.ShapeDtypeStruct(s, d) for s, d in _layer_specs(*shape)]
+    text = str(jax.make_jaxpr(pa._build_layer_call(*cfg))(*specs))
+    assert hashlib.sha256(text.encode()).hexdigest() == MISTRAL_BODY[shape]
+    off = str(jax.make_jaxpr(pa._build_layer_call(*cfg, False, 0))(*specs))
+    assert off == text
+
+
+# evabyte-6.5b-serve.docs-closed24's steps (EvaByte's widths: 32 KV heads,
+# group 1, feed-forward 11008; 4,096 pages; tables of 128 and 256 pages):
+# 24 decode rows in the 32 bucket, and beside a prompt chunk of up to 480
+# bytes (rows padded to 512 tokens) at a small and the largest bucket
+@pytest.mark.parametrize("n_pad,b_pad,t_pad,mp", [
+    (32, 32, 1, 128), (32, 32, 1, 256), (64, 32, 512, 128),
+    (512, 32, 512, 256),
+])
+def test_eva_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
+    """The layer program with both switches on at EvaByte's widths: the
+    kernel's landing buffers are four times the Mistral block (32 KV
+    heads a page) and fit; the unit-offset norms, the K/V scatter, the
+    summary epilogue (gather the filled pages, pool, scatter the rows)
+    and ONE ragged kernel call are one program whose pools come back in
+    the buffers they came in: four in-place page writes, no pool copied."""
+    import re
+
+    import paddle_tpu.ops.kernels as kernels
+    from paddle_tpu.ops.kernels.paged_attention import _build_layer_call
+
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    kvh, ffn, npages = 32, 11008, 4096
+    n_sum = n_pad // PAGE + b_pad
+    run = _build_layer_call(n_pad, E, H, kvh, D, npages, PAGE, b_pad, t_pad,
+                            mp, D ** -0.5, 0, False, 1e-5, False, True, n_sum)
+    i32, pool = jnp.int32, ((npages, PAGE, kvh, D), BF16)
+    specs = [pool, pool, ((n_pad, E), BF16), ((E,), BF16),
+             ((E, H * D), BF16), ((E, kvh * D), BF16), ((E, kvh * D), BF16),
+             ((H * D, E), BF16), ((E,), BF16), ((E, ffn), BF16),
+             ((E, ffn), BF16), ((ffn, E), BF16),
+             ((32768, D), jnp.float32), ((32768, D), jnp.float32),
+             ((5, n_pad), i32), ((b_pad, t_pad), i32), ((2, n_pad), i32),
+             ((b_pad, mp + 2), i32), ((kvh, D), BF16), ((kvh, D), BF16),
+             ((3, n_sum), i32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    text = jax.jit(run, donate_argnums=(0, 1)).lower(
+        *args).compile().as_text()
+    head = text.splitlines()[0]
+    assert re.search(r"\{1\}: \(0, \{\}", head), head[:300]
+    assert re.search(r"\{2\}: \(1, \{\}", head), head[:300]
+    pools = [ln for ln in text.splitlines()
+             if re.search(r"= bf16\[%d,%d,%d,%d\]" % (npages, PAGE, kvh, D),
+                          ln) and " parameter(" not in ln]
+    assert pools
+    for line in pools:
+        op = re.search(r"\} (\w[\w-]*)\(", line).group(1)
+        assert op in ("scatter", "fusion"), line[:160]
+    assert text.count("tpu_custom_call") == 3    # two norms, one attend
+    assert len(re.findall(r"ragged_paged_attention/pallas_call",
+                          text)) == 1
+
+
 @pytest.mark.parametrize("b,t,max_pages", [
     (64, 1, 256), (64, 64, 256), (64, 16, 64), (8, 1, 1),
 ])
