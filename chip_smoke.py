@@ -47,6 +47,9 @@ FULL = dict(
               "phase follows in the same 16 GB",
     prompt_len=(200, 1500), new_tokens=(32, 64), dense_pad=512,
     kernel_check=dict(b=4, t=16, lens=(5000, 4100, 1500, 16), window=4096),
+    # decode rows at as many KV heads as heads (group 1): the kernel's
+    # few-row form, the pages multiplied in the pool's own layout
+    kernel_check_mha=dict(b=4, t=1, lens=(2100, 1040, 300, 16), window=0),
     # train depth: AdamW multi-precision keeps 14 B/param (bf16 param, fp32
     # master, m, v) + 2 B/param of grads; embed + head alone are 262 M
     # params (4.2 GB). 2 layers = 698 M params = 11.2 GB, the most 16 GB holds
@@ -64,6 +67,7 @@ TINY = dict(
     serve_layers=2, num_pages=128, n_requests=4,
     prompt_len=(20, 90), new_tokens=(4, 8), dense_pad=128,
     kernel_check=dict(b=2, t=8, lens=(100, 24), window=64),
+    kernel_check_mha=dict(b=2, t=1, lens=(100, 24), window=0),
     train_layers=1, train_seq=128, train_steps=4,
     serve_why="rehearsal", train_why="rehearsal",
     tp_seq=64,
@@ -123,15 +127,16 @@ def no_fallback(stats, what):
 # ---------------------------------------------------------------------------
 # serve
 # ---------------------------------------------------------------------------
-def check_kernel_vs_reference(S, cfg):
+def check_kernel_vs_reference(S, cfg, check="kernel_check", kvh=None):
     """One paged_ragged_attention call at these widths against the dense
-    f32 reference, on this device (sequence lengths past the window)."""
+    f32 reference, on this device (sequence lengths past the window).
+    ``kvh``: KV heads other than the model's."""
     from paddle_tpu.ops.kernels import (
         paged_ragged_attention, paged_ragged_attention_reference)
 
-    kc = S["kernel_check"]
-    h, kvh, d, page = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                       cfg.head_dim, 16)
+    kc = S[check]
+    h, kvh, d, page = (cfg.num_attention_heads,
+                       kvh or cfg.num_key_value_heads, cfg.head_dim, 16)
     b, t, lens = kc["b"], kc["t"], list(kc["lens"])
     max_pages = -(-max(lens) // page)
     npages = b * max_pages
@@ -273,6 +278,8 @@ def phase_serve(S, watch):
     dense = [check_greedy_vs_dense(S, model, r)
              for r in (reqs[0], reqs[1], ereqs[0], ereqs[1])]
     kernel_err = check_kernel_vs_reference(S, cfg)
+    few_row_err = check_kernel_vs_reference(
+        S, cfg, "kernel_check_mha", cfg.num_attention_heads)
     t_chk = time.perf_counter() - t_chk
     dense_stats = kernel_dispatch_stats(reset=True)
     no_fallback(dense_stats, "dense reference")
@@ -298,6 +305,7 @@ def phase_serve(S, watch):
                      checks=round(t_chk, 2)),
          greedy_vs_dense=dense, greedy_tol=GREEDY_TOL,
          kernel_vs_reference_rel_err=round(kernel_err, 5),
+         kernel_few_row_rel_err=round(few_row_err, 5),
          kernel_tol=KERNEL_TOL, dispatch=served_stats,
          dispatch_dense=dense_stats, peak_bytes_in_use=device_bytes(),
          compile=watch.snap())

@@ -581,6 +581,13 @@ class _SummaryKey(str):
     (:meth:`PagedKVCacheManager._summary_key`)."""
 
 
+def _few_counts(counts, few_row_rows):
+    """A step's span attributes with the caller's ``few_row_rows``."""
+    if few_row_rows is None:
+        return counts
+    return {**(counts or {}), "few_row_rows": few_row_rows}
+
+
 class StepTables(tuple):
     """What a step's kernel reads beside the pages, on the device:
     ``(tbl, lens, q_lens)``, ``(tbl, lens, q_lens, pg, of)`` or, merged
@@ -1859,14 +1866,18 @@ class PagedKVCacheManager:
         return out
 
     def attend_ragged(self, q, seq_ids, q_lens, rows_pad=None,
-                      max_pages=None, sm_scale=None, window=0):
+                      max_pages=None, sm_scale=None, window=0,
+                      few_row_rows=None):
         """THE unified packed-step attend (ROADMAP item 2): ``q`` is
         (rows_pad, T, H, D) with row i's last ``q_lens[i]`` rows the
         newest tokens of seq_ids[i] — 1 for decode rows, n for
         prefill chunks (K/V already appended; seq_len counts them).
         Earlier rows and batch-padding rows return exact zeros. One
         ragged kernel call for the whole mixed batch: the single
-        attend program per packed config."""
+        attend program per packed config. ``few_row_rows``: the rows
+        the caller counted for the kernel's few-row form
+        (``ragged_few_rows``), an attribute of the ``kernel.ragged``
+        span."""
         self._plain_kv("attend_ragged")
         with telemetry.span("pool.fused_step", op="attend_ragged"):
             q = _as_tensor(q)
@@ -1879,14 +1890,15 @@ class PagedKVCacheManager:
             def f(qr):
                 return _ragged_kernel_fn(
                     qr, kp, vp, tbl, lens, q_lens=ql, sm_scale=sm_scale,
-                    window=window, k_scales=ks, v_scales=vs)
+                    window=window, k_scales=ks, v_scales=vs,
+                    counts=_few_counts(None, few_row_rows))
 
             return apply_op("paged_ragged_attend", f, q,
                             differentiable=False)
 
     def layer_step(self, x, weights, rope, plan, tables, eps,
                    sm_scale=None, window=0, unit_offset=False,
-                   summary=None):
+                   summary=None, few_row_rows=None):
         """One decoder layer of a packed step as ONE compiled program
         over this pool's pages (ops/kernels/paged_attention.
         paged_ragged_layer_step): norm, qkv projection + RoPE + THIS
@@ -1915,7 +1927,8 @@ class PagedKVCacheManager:
         less one (x / rms(x) * (1 + g)). ``summary`` = (phi, mu), each
         (heads, head_dim), of a ``page_format="eva"`` pool: the program
         pools every page this step fills into the row of the summary
-        chain that :meth:`book_step` booked for it."""
+        chain that :meth:`book_step` booked for it. ``few_row_rows``: as
+        in :meth:`attend_ragged`."""
         self._kv_only("layer_step")
         with telemetry.span("pool.fused_step", op="layer_step"):
             if self.quantized:
@@ -1941,7 +1954,7 @@ class PagedKVCacheManager:
                 (tok, gm, slots, rows), eps, sm_scale=sm_scale,
                 window=window, unit_offset=unit_offset,
                 summary=summary and (*summary, *sums),
-                counts=tables.counts)
+                counts=_few_counts(tables.counts, few_row_rows))
             return y
 
     def latent_ragged_step(self, q, toks, seq_ids, counts, gather_map,

@@ -185,10 +185,12 @@ class PagedAdapterBase:
         self._kernel_shapes = set()
         self._bucket_programs = {}   # pad_to -> set of kernel shapes
         # attend_calls counts a step's per-layer attend dispatches,
-        # layer_programs those that ran the whole layer as one program
+        # layer_programs those that ran the whole layer as one program,
+        # few_row_rows the rows of those calls that the ragged kernel
+        # attended in its few-row form
         self.chunk_stats = {"calls": 0, "packed_tokens": 0,
                             "padded_tokens": 0, "attend_calls": 0,
-                            "layer_programs": 0}
+                            "layer_programs": 0, "few_row_rows": 0}
 
     def _count_packed_step(self, rows: PackedRows):
         self._dispatch_shapes.add(rows.pad_to)

@@ -44,6 +44,7 @@ from ..nn.layer.norm import RMSNorm
 from ..ops.kernels.paged_attention import (
     packed_position_index_np as _position_index,
     pad_plan_np as _pad_plan,
+    ragged_few_rows,
     upload_plan as _upload,
 )
 from ..ops.kernels.rms_norm import rms_norm as _rms_norm
@@ -465,6 +466,16 @@ class PagedLlamaAdapter(PagedAdapterBase):
                        self.model.lm_head.weight._data)
         jax.block_until_ready(out)
 
+    def _few_row_rows(self, rows, t_pad):
+        """The rows of this step that the ragged kernel attends in its
+        few-row form, a layer's call: from the step's own counts and
+        the static shapes, nothing read back."""
+        cfg = self.cfg
+        return ragged_few_rows(
+            rows.counts, t_pad,
+            cfg.num_attention_heads // cfg.num_key_value_heads,
+            self.caches[0].quantized)
+
     def _run_programs(self, rows, seq_ids, up, b_pad):
         """The programmed body of a packed step: one dispatch for the
         embedding, one a layer, one for the head (two with verify rows,
@@ -482,15 +493,17 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 seq_ids, rows.counts, b_pad, rows.mp_pad, rows.pad_to,
                 like=tables[-1] if tables else None))
         plan, rope = (tok, gm), (self._cos, self._sin)
+        few = self._few_row_rows(rows, gm.shape[1])
         with span("model.embed"):
             x = embed(core.embed_tokens.weight._data, tok)      # (N, H)
         for li, layer in enumerate(core.layers):
             with span("model.layer", li=li, program=1):
                 self.chunk_stats["attend_calls"] += 1
                 self.chunk_stats["layer_programs"] += 1
+                self.chunk_stats["few_row_rows"] += few
                 x = caches[li].layer_step(
                     x, self._layer_weights(layer), rope, plan, tables[li],
-                    eps, window=self._window,
+                    eps, window=self._window, few_row_rows=few,
                     **self._layer_switches(layer))
         with span("model.head"):
             head_w = (core.norm.weight._data,
@@ -542,6 +555,7 @@ class PagedLlamaAdapter(PagedAdapterBase):
                        cfg.head_dim)
         ids, pos, gm, mr, mc, m_flat = up
         ids, pos = Tensor(ids), pos[None, :]                       # (1, N)
+        few = self._few_row_rows(rows, gm.shape[1])
         with span("model.embed"):
             x = self.model.model.embed_tokens(ids)[:, 0]     # (N, H)
         for li, layer in enumerate(self.model.model.layers):
@@ -564,10 +578,11 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     seq_ids, counts, kh[:n_real], vh[:n_real])
                 qm = qh[gm]              # (b_pad, t_pad, nh, hd)
                 self.chunk_stats["attend_calls"] += 1
+                self.chunk_stats["few_row_rows"] += few
                 out = cache.attend_ragged(
                     Tensor(qm), seq_ids, counts,
                     rows_pad=b_pad, max_pages=rows.mp_pad,
-                    window=self._window)
+                    window=self._window, few_row_rows=few)
                 attn = jnp.zeros((pad_to, nh, hd), qh.dtype)
                 attn = attn.at[m_flat].set(out._data[mr, mc])
                 attn_flat = Tensor(attn.reshape(pad_to, nh * hd))

@@ -27,7 +27,12 @@ TPU paged-attention recipe ("Ragged Paged Attention" — see PAPERS.md):
   so a right-aligned row's leading padding is skipped by tile — with
   online softmax (m, l, acc) in VMEM scratch across the page axis
   (row i's last q_lens[i] rows are its newest tokens; padded leading
-  rows return exact zeros).
+  rows return exact zeros);
+* a decode row whose KV heads have one query row each (group 1: MHA)
+  is not fed to the MXU head by head: its token is multiplied with the
+  pages where they landed, (slot, kv head, dim), on the vector unit,
+  chosen by ``_ragged_tiling`` from the static shapes and, beside a
+  prompt chunk, by the kernel from ``q_lens``.
 
 GQA never replicates KV in HBM or in VMEM. Int8 pages dequantize in
 VMEM right after the page DMA (per-page per-head scale sidecars ride
@@ -192,14 +197,29 @@ def paged_ragged_attention_reference(q, k_pages, v_pages, page_table,
 
 RAGGED_PAGES_PER_STEP = 16       # K and V pages DMA'd a grid step
 RAGGED_ROW_TILE = 64             # (token, head-in-group) rows a matmul
+RAGGED_FEW_ROWS = 1              # query rows a KV head of the few-row form
+RAGGED_FEW_PAGES = 4             # pages a turn of the few-row form's loop
 
 
-def _ragged_tiling(t, group, max_pages):
-    """(pages a step, page blocks a row, query row tile) of the unified
-    kernel, from the static shapes alone. A KV head's query rows are
-    ``t * group`` (token, head-in-group) pairs; they are multiplied in
+def _ragged_tiling(t, group, max_pages, quant=False):
+    """(pages a step, page blocks a row, query row tile, few) of the
+    unified kernel, from the static shapes alone. A KV head's query rows
+    are ``t * group`` (token, head-in-group) pairs; they are multiplied in
     tiles so that a right-aligned row's leading padding is skipped by
-    tile. A row count the tile does not divide is one whole tile."""
+    tile. A row count the tile does not divide is one whole tile.
+
+    ``few``: whether a decode row (``q_len`` 1) is attended in the
+    pool's own layout instead (:func:`_few_row_pages`): where it has at
+    most ``RAGGED_FEW_ROWS`` query rows a KV head. Such a row feeds the
+    MXU's stationary side a head's keys for one row pushed through them
+    and pays by KV head, not by byte: on the vector unit a live step
+    takes 5.9 us against 25.5 at 32 KV heads x group 1 (v5e; PERF.md
+    section 6, PR 35). At 8 x 4 the form is faster too (4.9 us against
+    6.2) and stays off: a program that holds both forms costs the host
+    more to trace, and the Mistral cell, which builds a dozen programs
+    inside its window, lost a fifth of its tokens a second to that. Int8
+    pages dequantise on the way to the head-major copy and keep the
+    tiles."""
     ppb = min(RAGGED_PAGES_PER_STEP, max_pages)
     m = t * group
     tm = m
@@ -209,7 +229,8 @@ def _ragged_tiling(t, group, max_pages):
             if m % c == 0:
                 tm = c
                 break
-    return ppb, -(-max_pages // ppb), tm
+    few = not quant and group <= RAGGED_FEW_ROWS
+    return ppb, -(-max_pages // ppb), tm, few
 
 
 def _ragged_grid_steps(b, max_pages):
@@ -218,36 +239,62 @@ def _ragged_grid_steps(b, max_pages):
     return b * _ragged_tiling(1, 1, max_pages)[1]
 
 
+def ragged_few_rows(q_lens, t, group, quant=False):
+    """Rows of one call that take the few-row form, from the host's
+    ``q_lens`` (a step's real rows; padding rows carry 0) and the static
+    shapes: the ``kernel.ragged`` span's ``few_row_rows``."""
+    few = _ragged_tiling(t, group, 1, quant)[3]
+    return sum(n == 1 for n in q_lens) if few else 0
+
+
 def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
-                   tm, window, quant, tbl_ref, lens_ref, qlens_ref, *refs):
+                   tm, window, quant, few, tbl_ref, lens_ref, qlens_ref,
+                   *refs):
     """THE unified kernel, grid (row b, page block p), steps in order. A
     live step holds the block's K pages and V pages — at most ``ppb``,
     only those the row has — in VMEM in the pool's own layout
     (page_size, kv heads, head_dim), each copied from HBM once for every
     head while the block before it was computed (the copies of a step
-    are issued by the live step before it, across rows too), and lays
-    them head-major. Then, KV head by KV head, the block's keys
-    (ppb * page_size, head_dim) are multiplied with the ``t * group``
-    (token, head-in-group) query rows of the head's group in tiles of
-    ``tm``, from the tile that holds the row's first real token: the
-    last q_lens[b] tokens are real, the rest is the right-alignment's
-    padding and returns exact zeros. Causal; ``window`` > 0 bands the
-    mask (0 <= qpos - kpos < window). ``quant``: int8 pages dequantised
-    on the way by the scalar-prefetched per-page, per-head scale
-    sidecars. Online softmax state (m, l, acc) stays in VMEM across the
-    page axis, float32. Blocks beyond the row's length, below every real
-    token's window, or of a padding row copy and multiply nothing."""
+    are issued by the live step before it, across rows too). The pages
+    are multiplied in one of two forms, by what the row is:
+
+    * tiles: the pages are laid head-major and, KV head by KV head, the
+      block's keys (ppb * page_size, head_dim) are multiplied with the
+      ``t * group`` (token, head-in-group) query rows of the head's
+      group in tiles of ``tm``, from the tile that holds the row's first
+      real token: the last q_lens[b] tokens are real, the rest is the
+      right-alignment's padding and returns exact zeros;
+    * few rows (``few`` and ``q_lens[b] <= 1``: a decode row at a small
+      group): the row's one real token is attended where the pages
+      landed, every KV head at once on the vector unit
+      (:func:`_few_row_pages`); at ``t`` = 1 every row is such a row and
+      the kernel holds no head-major buffer.
+
+    Causal; ``window`` > 0 bands the mask (0 <= qpos - kpos < window).
+    ``quant``: int8 pages dequantised on the way by the scalar-prefetched
+    per-page, per-head scale sidecars. Online softmax state (m, l, acc)
+    stays in VMEM across the page axis, float32. Blocks beyond the row's
+    length, below every real token's window, or of a padding row copy
+    and multiply nothing."""
     refs = list(refs)
     if quant:
         k_scale_ref = refs.pop(0)
         v_scale_ref = refs.pop(0)
-    (q_ref, k_hbm, v_hbm, o_ref, k_in, v_in, sems, slot_ref, k_buf, v_buf,
-     m_ref, l_ref, acc_ref) = refs
+    all_few = few and t == 1
+    q_ref = None if all_few else refs.pop(0)
+    qf_ref = refs.pop(0) if few else None
+    k_hbm, v_hbm, o_ref, k_in, v_in, sems, slot_ref = refs[:7]
+    refs = refs[7:]
+    if not all_few:
+        k_buf, v_buf, m_ref, l_ref, acc_ref = refs[:5]
+        refs = refs[5:]
+    few_state = refs                       # (m, l, acc) of the few rows
     b = pl.program_id(0)
     p = pl.program_id(1)
     seq_len = lens_ref[b]
     q_len = qlens_ref[b]
-    n_tiles = (t * group) // tm
+    m_rows = t * group
+    n_tiles = m_rows // tm
     span = ppb * page_size
     log_g = group.bit_length() - 1 if group & (group - 1) == 0 else None
 
@@ -259,6 +306,15 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
     def token_of(row):
         return jax.lax.shift_right_logical(row, log_g) \
             if log_g is not None else div(row, group)
+
+    def either(tiles, few_rows):
+        """Run the form the row takes."""
+        if all_few:
+            return few_rows()
+        if few:
+            pl.when(q_len <= 1)(few_rows)
+            return pl.when(q_len > 1)(tiles)
+        return tiles()
 
     def blocks(row):
         """(first, last) live block of a row that holds tokens."""
@@ -278,16 +334,17 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
                 pltpu.make_async_copy(v_hbm.at[pg], v_in.at[slot, j],
                                       sems.at[slot, 1]))
 
-    def each_page(row, blk, slot, act):
+    def pages_here(row, blk):
         # the pages of the block the row has tokens in
-        n = jnp.minimum(
+        return jnp.minimum(
             ppb, div(lens_ref[row] + page_size - 1, page_size) - blk * ppb)
 
+    def each_page(row, blk, slot, act):
         def body(j, c):
             for cp in copies(row, blk, slot, j):
                 act(cp)
             return c
-        jax.lax.fori_loop(0, n, body, 0)
+        jax.lax.fori_loop(0, pages_here(row, blk), body, 0)
 
     def start(row, blk, slot):
         each_page(row, blk, slot, lambda cp: cp.start())
@@ -296,15 +353,22 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
     def _():
         slot_ref[0] = 0
         # a page a block does not copy keeps what the buffer held:
-        # masked, but it goes through the matmul (0 x NaN)
+        # masked, but it goes through the multiply (0 x NaN)
         k_in[...] = jnp.zeros(k_in.shape, k_in.dtype)
         v_in[...] = jnp.zeros(v_in.shape, v_in.dtype)
 
-    @pl.when(p == 0)
-    def _():
+    def init_tiles():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def init_few():
+        for ref, x in zip(few_state, (NEG_INF, 0.0, 0.0)):
+            ref[...] = jnp.full(ref.shape, x, jnp.float32)
+
+    @pl.when(p == 0)
+    def _():
+        either(init_tiles, init_few)
 
     first, last = blocks(b)
 
@@ -333,7 +397,9 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
         slot_ref[0] = 1 - slot
 
         def gather(pages_in, buf, scale_ref):
-            x = pages_in[slot]                       # (ppb, P, KVH, D)
+            # (ppb, P, KVH, D); beside the few-row form the landing
+            # buffers may hold a last turn's pages more
+            x = pages_in[slot, :ppb] if few else pages_in[slot]
             if not quant:
                 x = x.reshape(span, kvh, x.shape[-1])
                 for g in range(kvh):
@@ -346,9 +412,6 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
                 for g in range(kvh):
                     buf[g, j * page_size:(j + 1) * page_size, :] = \
                         x[j, :, g, :] * scale_ref[pg * kvh + g]
-
-        gather(k_in, k_buf, k_scale_ref if quant else None)
-        gather(v_in, v_buf, v_scale_ref if quant else None)
 
         def head(g, c):
             k = k_buf[g]                                 # (span, D)
@@ -396,19 +459,111 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
             return jax.lax.fori_loop(
                 div((t - q_len) * group, tm), n_tiles, tile, c)
 
-        jax.lax.fori_loop(0, kvh, head, 0)
+        def tiles():
+            gather(k_in, k_buf, k_scale_ref if quant else None)
+            gather(v_in, v_buf, v_scale_ref if quant else None)
+            jax.lax.fori_loop(0, kvh, head, 0)
+
+        def few_rows():
+            # the row's one token sits at seq_len - 1: the block's pages
+            # from its window's floor to the row's last
+            floor = div(jnp.maximum(seq_len - window, 0), page_size) \
+                - p * ppb if window else 0
+            _few_row_pages(
+                scale, window, seq_len, p * span, jnp.maximum(floor, 0),
+                pages_here(b, p), qf_ref, k_in.at[slot], v_in.at[slot],
+                few_state)
+
+        either(tiles, few_rows)
 
     @pl.when(p == n_steps - 1)
     def _():
+        def few_out():
+            _, l_few, acc_few = few_state
+            return jnp.where(
+                q_len > 0,
+                acc_few[...] / jnp.maximum(l_few[...], 1e-30), 0.0)
+
+        if all_few:
+            o_ref[0] = few_out().astype(o_ref.dtype)
+            return
+        if few:
+            # the few rows' token joins the tiles' state, head by head,
+            # at the row block's end, and leaves by the tiles' way out
+            @pl.when(q_len <= 1)
+            def _():
+                out = few_out()                  # (group, KVH, D)
+                for g in range(kvh):
+                    acc_ref[g, m_rows - group:, :] = out[:, g, :]
+                l_ref[:, m_rows - group:, :] = jnp.ones(
+                    (kvh, group, l_ref.shape[-1]), jnp.float32)
+
         out = acc_ref[...] / jnp.maximum(l_ref[...][:, :, :1], 1e-30)
         tok = token_of(jax.lax.broadcasted_iota(jnp.int32, out.shape, 1))
         o_ref[0] = jnp.where(tok >= t - q_len, out, 0.0).astype(
             o_ref.dtype)
 
 
+def _few_row_pages(scale, window, seq_len, base, first, pages, q_ref, k_in,
+                   v_in, state):
+    """The few-row form of a live step: the ONE real token of the row
+    (position ``seq_len - 1``, ``group`` query rows a KV head) against
+    the block's pages where they landed, ``k_in`` / ``v_in`` (pages, P,
+    KVH, D): pages ``first`` up to ``pages``, in whole turns of
+    ``RAGGED_FEW_PAGES`` counted from the block's start (what a turn
+    holds beside them is masked). Nothing is
+    laid head-major and nothing is fed to the MXU head by head: the
+    query (group, KVH, D) has the layout of one slot of a page, so
+    ``k * q`` is elementwise with the slots on the leading axis; the
+    products (exact in float32 for bf16 operands) are summed over the
+    lanes in float32, the score comes back along the lanes, which is the
+    form the weighted sum over ``v`` wants, and softmax state ``state`` =
+    (m, l, acc), each (group, KVH, D) float32 with m and l along the
+    lanes, runs over the slots on the leading axis: vector adds. The
+    probabilities are cast to the values' dtype before the weighted sum,
+    as the tiles' are. ``base``: the block's first position."""
+    m_ref, l_ref, acc_ref = state
+    group, kvh, d = acc_ref.shape
+    page_size = k_in.shape[1]
+    pc = RAGGED_FEW_PAGES
+    n = pc * page_size
+    q = q_ref[0].astype(jnp.float32)                     # (group, KVH, D)
+
+    def turn(c, carry):
+        pages = pl.ds(c * pc, pc)
+        k = k_in[pages].astype(jnp.float32).reshape(n, kvh, d)
+        v = v_in[pages].reshape(n, kvh, d)
+        kpos = base + c * n + jax.lax.broadcasted_iota(
+            jnp.int32, (n, kvh, d), 0)
+        keep = kpos < seq_len
+        if window:
+            keep = keep & (kpos >= seq_len - window)
+        out = []
+        for gi, (m_prev, l_prev, acc) in enumerate(carry):
+            s = jnp.sum(k * q[gi], -1, keepdims=True) * scale
+            s = jnp.where(keep, jnp.broadcast_to(s, (n, kvh, d)), NEG_INF)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, 0))
+            corr = jnp.exp(m_prev - m_cur)
+            pv = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
+            out.append((
+                m_cur, corr * l_prev + jnp.sum(pv, 0),
+                acc * corr + jnp.sum(
+                    pv.astype(v.dtype).astype(jnp.float32)
+                    * v.astype(jnp.float32), 0)))
+        return tuple(out)
+
+    done = jax.lax.fori_loop(
+        jax.lax.div(first, jnp.int32(pc)),
+        jax.lax.div(pages + (pc - 1), jnp.int32(pc)), turn,
+        tuple((m_ref[gi], l_ref[gi], acc_ref[gi]) for gi in range(group)))
+    for gi, (m_cur, l_cur, acc) in enumerate(done):
+        m_ref[gi], l_ref[gi], acc_ref[gi] = m_cur, l_cur, acc
+
+
 def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
                            q_lens=None, sm_scale=None, interpret=None,
-                           window=0, k_scales=None, v_scales=None):
+                           window=0, k_scales=None, v_scales=None,
+                           counts=None):
     """The unified ragged paged-attention entry (PAPERS.md: Ragged
     Paged Attention) — ONE kernel for decode rows and prefill chunks.
 
@@ -420,7 +575,9 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
     every row is treated as real (positions follow seq_len) and short
     rows must be masked by the caller. Returns (B, T, H, D). Int8
     pages: pass k_scales/v_scales (NP, KVH) as in
-    :func:`paged_attention`.
+    :func:`paged_attention`. ``counts``: what the caller knows of the
+    call on the host (``few_row_rows``), attributes of the
+    ``kernel.ragged`` span.
     """
     b, t, h, d = q.shape
     npages, page_size, kvh, _ = k_pages.shape
@@ -451,7 +608,8 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
         return _build_ragged_call(*cfg)(*args)
     with telemetry.span("kernel.ragged", rows=b, t=t,
                         max_pages=max_pages,
-                        grid_steps=_ragged_grid_steps(b, max_pages)):
+                        grid_steps=_ragged_grid_steps(b, max_pages),
+                        **(counts or {})):
         return _jitted_ragged_call(cfg)(*args)
 
 
@@ -470,7 +628,8 @@ def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
     call a tenth of a second of tracing and lowering more)."""
     group = h // kvh
     m = t * group
-    ppb, n_steps, tm = _ragged_tiling(t, group, max_pages)
+    ppb, n_steps, tm, few = _ragged_tiling(t, group, max_pages, quant)
+    all_few = few and t == 1
     span = ppb * page_size
 
     def q_map(b_, p_, *pref):
@@ -481,41 +640,57 @@ def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
             scalar_args = (jnp.full((b,), t, jnp.int32), *scalar_args)
         kv_dtype = jnp.float32 if quant else k_pages.dtype
         pool = pl.BlockSpec(memory_space=pl.ANY)
-        landing = pltpu.VMEM((2, ppb, page_size, kvh, d), k_pages.dtype)
+        # the few-row form takes its pages RAGGED_FEW_PAGES a turn,
+        # whatever the table's width: a row's sums have one order
+        pages = -(-ppb // RAGGED_FEW_PAGES) * RAGGED_FEW_PAGES \
+            if few else ppb
+        landing = pltpu.VMEM((2, pages, page_size, kvh, d), k_pages.dtype)
+        rows, few_row = (kvh, m, d), (group, kvh, d)
+        # (B, T, H, D) -> (B, KVH, T * group, D): a KV head's query
+        # rows together, ordered (token, head-in-group); the few-row
+        # form reads a row's last token as (B, group, KVH, D), a slot
+        # of a page a query row
+        qs = [] if all_few else [jnp.transpose(
+            q.reshape(b, t, kvh, group, d),
+            (0, 2, 1, 3, 4)).reshape(b, *rows)]
+        if few:
+            qs.append(jnp.transpose(
+                q[:, t - 1].reshape(b, kvh, group, d), (0, 2, 1, 3)))
+        shape_out = few_row if all_few else rows
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 + (2 if quant else 0),
             grid=(b, n_steps),
-            in_specs=[pl.BlockSpec((1, kvh, m, d), q_map), pool, pool],
-            out_specs=pl.BlockSpec((1, kvh, m, d), q_map),
+            in_specs=[pl.BlockSpec((1, *x.shape[1:]), q_map) for x in qs]
+            + [pool, pool],
+            out_specs=pl.BlockSpec((1, *shape_out), q_map),
             scratch_shapes=[
                 landing, landing,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
+            ] + ([] if all_few else [
                 pltpu.VMEM((kvh, span, d), kv_dtype),
                 pltpu.VMEM((kvh, span, d), kv_dtype),
                 pltpu.VMEM((kvh, m, 128), jnp.float32),
                 pltpu.VMEM((kvh, m, 128), jnp.float32),
                 pltpu.VMEM((kvh, m, d), jnp.float32),
-            ],
+            ]) + [pltpu.VMEM(few_row, jnp.float32)] * (3 if few else 0),
         )
-        # (B, T, H, D) -> (B, KVH, T * group, D): a KV head's query
-        # rows together, ordered (token, head-in-group)
-        qg = jnp.transpose(q.reshape(b, t, kvh, group, d),
-                           (0, 2, 1, 3, 4)).reshape(b, kvh, m, d)
         out = pl.pallas_call(
             functools.partial(
                 _ragged_kernel, scale, page_size, ppb, n_steps, b, t,
-                group, kvh, tm, window, quant),
+                group, kvh, tm, window, quant, few),
             name="ragged_paged_attention",
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, kvh, m, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, *shape_out), q.dtype),
             interpret=interpret,
             # in order: a step issues the next live step's page copies
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=96 * 1024 * 1024,
             ) if not interpret else None,
-        )(tbl, lens, *scalar_args, qg, k_pages, v_pages)
+        )(tbl, lens, *scalar_args, *qs, k_pages, v_pages)
+        if all_few:
+            return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h, d)
         return jnp.transpose(out.reshape(b, kvh, t, group, d),
                              (0, 2, 1, 3, 4)).reshape(b, t, h, d)
 
@@ -986,8 +1161,8 @@ def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
     and commits the returned arrays. ``unit_offset`` and ``summary`` =
     (phi, mu, sums) are the program's two static switches; ``counts``
     (a window-and-summary step's exact ``fed`` / ``pairs`` / ``kv_rows`` /
-    ``summaries_written``, from the pool's table) ride the
-    ``kernel.ragged`` span as attributes.
+    ``summaries_written``, from the pool's table, and the step's
+    ``few_row_rows``) ride the ``kernel.ragged`` span as attributes.
     """
     from ...distributed.mesh import global_mesh
 

@@ -193,9 +193,142 @@ class TestUnifiedKernelParity:
         from paddle_tpu.ops.kernels.paged_attention import \
             _ragged_tiling
 
-        ppb, blocks, tm = _ragged_tiling(t, group, 24)
+        ppb, blocks, tm, _ = _ragged_tiling(t, group, 24)
         assert (ppb, blocks, tm) == (16, 2, want)
         assert (t * group) % tm == 0
+
+
+class TestFewRowForm:
+    """ISSUE 35: a decode row at group 1 is attended in the pool's own
+    (slot, kv head, dim) layout, no head-major copy and no matmul a head;
+    at t = 1 every row, beside a prompt chunk the rows the kernel finds
+    with ``q_len`` 1. Against the dense reference, as the tiles are."""
+    _run = TestUnifiedKernelParity._run
+
+    def _few(self, lens, q_lens, T, KVH=4, group=1, **kw):
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _ragged_tiling
+
+        assert _ragged_tiling(T, group, kw.get("MAXP") or 1)[3]
+        return self._run(lens=lens, q_lens=q_lens, T=T, H=KVH * group,
+                         KVH=KVH, **kw)
+
+    @pytest.fixture
+    def few_rows_4(self, monkeypatch):
+        """The form at a group of up to 4, as the chip probe of PR 35 ran
+        it (faster a step at 8 x 4 too, and off for what a program that
+        holds both forms costs to trace: PERF.md section 6): one constant
+        away, so it stays tested."""
+        import importlib
+
+        pa = importlib.import_module(
+            "paddle_tpu.ops.kernels.paged_attention")
+        monkeypatch.setattr(pa, "RAGGED_FEW_ROWS", 4)
+        pa._jitted_ragged_call.cache_clear()
+        yield
+        pa._jitted_ragged_call.cache_clear()
+
+    @pytest.mark.parametrize("window", [0, 70])
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                           (jnp.bfloat16, 2e-2)])
+    @pytest.mark.parametrize("kvh", [4, 32])
+    def test_decode_rows_at_group_1(self, kvh, dtype, tol, window):
+        # rows that end on a block's edge (64 tokens), one token past
+        # it, inside the third block and at their first token; a band of
+        # 70 floors inside a page of the block before the row's last
+        self._few(lens=(130, 65, 1, 64, 200), q_lens=(1,) * 5, T=1,
+                  KVH=kvh, window=window, MAXP=50, dtype=dtype, tol=tol)
+
+    @pytest.mark.parametrize("maxp", [1, 2, 24, 32])
+    def test_table_widths_around_the_pages_a_step(self, maxp):
+        full = maxp * PAGE
+        # under four pages the landing buffers hold four (a turn of the
+        # loop), the table's width aside
+        self._few(lens=(full, max(full // 2, 1), 1), q_lens=(1, 1, 1),
+                  T=1, MAXP=maxp)
+
+    @pytest.mark.parametrize("tail", [1, PAGE - 1, PAGE + 1])
+    def test_row_ends_inside_a_blocks_first_pages(self, tail):
+        self._few(lens=(64 + tail, 64, 128 + tail), q_lens=(1, 1, 1),
+                  T=1, MAXP=40)
+
+    @pytest.mark.parametrize("window", [0, 50])
+    def test_padding_rows_between_real_ones(self, window):
+        # a padding row carries length 0; a row of length 0 that claims
+        # a token, and a row that holds tokens and claims none
+        out = self._few(lens=(70, 0, 9, 0, 33, 131),
+                        q_lens=(1, 0, 1, 1, 0, 1), T=1, window=window,
+                        MAXP=33)
+        for r in (1, 3, 4):
+            np.testing.assert_array_equal(out[r], 0.0)
+
+    @pytest.mark.parametrize("group", [2, 4])
+    @pytest.mark.parametrize("window", [0, 70])
+    def test_decode_rows_of_a_small_group(self, few_rows_4, group, window):
+        # a KV head's few query rows, one after the other against the
+        # same pages: Mistral's 8 x 4 at the test's widths
+        self._few(lens=(130, 65, 1, 64, 200), q_lens=(1,) * 5, T=1,
+                  KVH=2, group=group, window=window, MAXP=50)
+
+    @pytest.mark.parametrize("group", [1, 4])
+    @pytest.mark.parametrize("window", [0, 70])
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                           (jnp.bfloat16, 2e-2)])
+    def test_decode_rows_beside_a_chunk_row(self, few_rows_4, dtype, tol,
+                                            window, group):
+        # t = 64: both forms in one call, the chunk's row between decode
+        # rows, so a few-row step issues a tiled step's copies and the
+        # other way round
+        out = self._few(lens=(130, 19, 70, 0, 200, 5),
+                        q_lens=(1, 1, 64, 0, 1, 3), T=64, window=window,
+                        group=group, MAXP=50, dtype=dtype, tol=tol)
+        for r in (0, 1, 4):
+            np.testing.assert_array_equal(out[r, :63], 0.0)
+            assert np.abs(out[r, 63]).max() > 0
+        np.testing.assert_array_equal(out[3], 0.0)
+
+    @pytest.mark.parametrize("t,group,quant,want", [
+        (1, 1, False, True),        # EvaByte's decode step: 32 x 1
+        (512, 1, False, True),      # its prompt step: chosen a row
+        (1, 4, False, False),       # Mistral's 8 x 4: the tiles (faster
+        (64, 4, False, False),      # a step in PR 35's probe, off for
+        (1, 2, False, False),       # what two forms cost to trace)
+        (1, 8, False, False),
+        (1, 1, True, False), (64, 1, True, False)])     # int8: never
+    def test_the_form_follows_the_static_shapes(self, t, group, quant,
+                                                want):
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _ragged_tiling
+
+        for max_pages in (1, 128, 256):
+            assert _ragged_tiling(t, group, max_pages, quant)[3] == want
+
+    @pytest.mark.parametrize("t", [1, 32])
+    def test_t1_holds_no_head_major_scratch(self, t):
+        # the kernel's operands: at t = 1 the landing buffers and the
+        # few rows' state alone; beside a chunk both forms' state
+        from paddle_tpu.ops.kernels.paged_attention import \
+            _build_ragged_call
+
+        b, kvh, d, npg, mp = 3, 4, 32, 64, 40
+        S = jax.ShapeDtypeStruct
+        run = _build_ragged_call(b, t, kvh, d, npg, PAGE, kvh, mp,
+                                 d ** -0.5, 0, False, True, True)
+        pool = S((npg, PAGE, kvh, d), jnp.float32)
+        jaxpr = jax.make_jaxpr(run)(
+            S((b, t, kvh, d), jnp.float32), pool, pool,
+            S((b, mp), jnp.int32), S((b,), jnp.int32),
+            S((b,), jnp.int32)).jaxpr
+        (call,) = [e for e in _eqns(jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        shapes = [tuple(v.aval.shape) for v in call.params["jaxpr"].invars]
+        head_major = (kvh, 16 * PAGE, d)
+        assert shapes.count((2, 16, PAGE, kvh, d)) == 2
+        assert shapes.count((1, kvh, d)) == 3              # m, l, acc
+        # a row's last token, a slot of a page a query row: in, and at
+        # t = 1 out
+        assert shapes.count((1, 1, kvh, d)) == 1 + (t == 1)
+        assert shapes.count(head_major) == (0 if t == 1 else 2)
 
 
 def _eqns(jaxpr):
@@ -848,6 +981,37 @@ class TestFusionChoice:
         eager_only = {s.name for s in spans} & {"model.norm", "model.mlp"}
         assert eager_only == (set() if program
                               else {"model.norm", "model.mlp"})
+
+
+    @pytest.mark.parametrize("case,kw,adkw,want", [
+        ("group_1", {}, {}, [0, 2, 1]),
+        ("group_2", {"num_attention_heads": 4}, {}, [0, 0, 0]),
+        ("int8_pages", {}, {"kv_cache_dtype": "int8"}, [0, 0, 0])],
+        ids=["group_1", "group_2", "int8_pages"])
+    def test_few_row_rows_ride_the_span_and_the_stats(self, case, kw,
+                                                      adkw, want):
+        # how often the kernel's few-row form engages, from the step's
+        # own counts and the static shapes: a chunk step, a decode step,
+        # a chunk beside a decode row; both bodies say it
+        m = _fresh_model(num_hidden_layers=2, **kw)
+        ad = PagedLlamaAdapter(m, num_pages=16, page_size=PAGE,
+                               max_length=64, **adkw)
+        for sid in "ab":
+            ad.alloc(sid)
+        feeds = [([[5, 6, 7], [9, 3]], 8), ([[4], [8]], 2),
+                 ([[4, 5], [8]], 4)]
+
+        def run():
+            for toks, pad_to in feeds:
+                ad.prefill_chunk(
+                    toks, ["a", "b"],
+                    [ad.caches[0].seq_len(s) for s in "ab"], pad_to=pad_to)
+
+        calls = [s.attrs["few_row_rows"] for s in _spans_of(run)
+                 if s.name == "kernel.ragged"]
+        assert calls == [n for n in want for _ in range(2)]  # two layers
+        assert ad.chunk_stats["few_row_rows"] == 2 * sum(want)
+        assert ad.chunk_stats["attend_calls"] == 2 * len(feeds)
 
 
 def _three(model, **kw):

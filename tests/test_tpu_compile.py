@@ -62,10 +62,26 @@ def _compile(fn, one_chip, *specs):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _ragged_specs(b, t, npages, max_pages, kv_dtype):
+def _pallas_calls(jaxpr, name):
+    """The pallas calls of that name in a program, nested programs
+    opened."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            if e.params["name"] == name:
+                found.append(e)
+            continue
+        for v in e.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                found += _pallas_calls(sub, name)
+    return found
+
+
+def _ragged_specs(b, t, npages, max_pages, kv_dtype, kvh=KVH):
     specs = [((b, t, H, D), BF16),
-             ((npages, PAGE, KVH, D), kv_dtype),
-             ((npages, PAGE, KVH, D), kv_dtype),
+             ((npages, PAGE, kvh, D), kv_dtype),
+             ((npages, PAGE, kvh, D), kv_dtype),
              ((b, max_pages), jnp.int32), ((b,), jnp.int32),
              ((b,), jnp.int32)]
     if kv_dtype == jnp.int8:
@@ -73,7 +89,7 @@ def _ragged_specs(b, t, npages, max_pages, kv_dtype):
     return specs
 
 
-def _pool_sized(text, npages):
+def _pool_sized(text, npages, kvh=KVH):
     """The operations of a compiled program whose result has the pool's
     element count, parameters aside: [(shape, layout, line)]."""
     import re
@@ -84,20 +100,25 @@ def _pool_sized(text, npages):
         if not m or " parameter(" in line:
             continue
         dims = [int(x) for x in m.group(1).split(",")]
-        if math.prod(dims) == npages * PAGE * KVH * D:
+        if math.prod(dims) == npages * PAGE * kvh * D:
             found.append((dims, m.group(2), line.strip()[:160]))
     return found
 
 
-# the last three: mistral-7b-serve.decode-closed32's own calls (32 rows,
-# 4,096 pages, tables 32-128 wide, a step with a 64-token prompt chunk)
-@pytest.mark.parametrize("b,t,max_pages,window", [
-    (8, 1, 128, 0), (8, 1, 128, 4096), (8, 16, 128, 4096),
-    (8, 64, 128, 0), (8, 64, 128, 4096), (1, 64, 16, 4096),
-    (64, 1, 256, 4096),
-    (32, 1, 32, 4096), (32, 1, 128, 4096), (32, 64, 64, 4096),
+# at 8 KV heads, the last three: mistral-7b-serve.decode-closed32's own
+# calls (32 rows, 4,096 pages, tables 32-128 wide, a step with a 64-token
+# prompt chunk); at 32 KV heads and group 1 (the kernel's few-row form):
+# evabyte-6.5b-serve.docs-closed24's, decode rows alone and beside a
+# prompt chunk padded to 512 tokens, where the kernel holds both forms
+@pytest.mark.parametrize("b,t,max_pages,window,kvh", [
+    (8, 1, 128, 0, KVH), (8, 1, 128, 4096, KVH), (8, 16, 128, 4096, KVH),
+    (8, 64, 128, 0, KVH), (8, 64, 128, 4096, KVH), (1, 64, 16, 4096, KVH),
+    (64, 1, 256, 4096, KVH),
+    (32, 1, 32, 4096, KVH), (32, 1, 128, 4096, KVH),
+    (32, 64, 64, 4096, KVH),
+    (32, 1, 128, 0, 32), (32, 1, 256, 0, 32), (32, 512, 256, 0, 32),
 ])
-def test_ragged_bf16(one_chip, b, t, max_pages, window):
+def test_ragged_bf16(one_chip, b, t, max_pages, window, kvh):
     from paddle_tpu.ops.kernels.paged_attention import \
         paged_ragged_attention
 
@@ -108,12 +129,12 @@ def test_ragged_bf16(one_chip, b, t, max_pages, window):
                                       window=window, interpret=False)
 
     text = _compile(f, one_chip,
-                    *_ragged_specs(b, t, npages, max_pages, BF16))
+                    *_ragged_specs(b, t, npages, max_pages, BF16, kvh))
     assert "tpu_custom_call" in text
     # the pages reach the kernel as the pool holds them: under the
     # chip's tiling a transpose of the pool, and the lane-merged view
     # (pages, 16, kv heads * head_dim) too, is a copy of all of it
-    assert _pool_sized(text, npages) == []
+    assert _pool_sized(text, npages, kvh) == []
 
 
 @pytest.mark.parametrize("t", [1, 16])
@@ -222,7 +243,8 @@ def test_layer_program_is_one_for_every_layer():
 
 # sha256 of the layer program's jaxpr at two of the Mistral cell's shapes,
 # taken from the parent of PR 34 (commit 4c5301f), before the program had
-# its two switches
+# its two switches; PR 35's few-row form of the kernel inside is off at
+# Mistral's group of 4 and leaves them as they were
 MISTRAL_BODY = {
     (32, 32, 1, 64, 4096):
         "6242de8a09a1409080944790e622b948a9c54cc1df179daf79291a45066ee8ee",
@@ -284,6 +306,13 @@ def test_eva_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
              ((b_pad, mp + 2), i32), ((kvh, D), BF16), ((kvh, D), BF16),
              ((3, n_sum), i32)]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    # decode rows alone: every row takes the kernel's few-row form, and
+    # the kernel holds no head-major (kv heads, block, head_dim) copy of
+    # a block's pages; beside a prompt chunk it holds one for K and V
+    (call,) = _pallas_calls(jax.make_jaxpr(run)(*args).jaxpr,
+                            "ragged_paged_attention")
+    scratch = [tuple(v.aval.shape) for v in call.params["jaxpr"].invars]
+    assert scratch.count((kvh, 16 * PAGE, D)) == (0 if t_pad == 1 else 2)
     text = jax.jit(run, donate_argnums=(0, 1)).lower(
         *args).compile().as_text()
     head = text.splitlines()[0]
