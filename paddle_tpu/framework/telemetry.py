@@ -1573,6 +1573,15 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "assignments / experts, summed over a step's expert-layer calls "
      "and rounded down a step (exact: assignments / (calls x E))"),
     # window-and-summary pools (page_format="eva")
+    ("diffusion.denoise_passes", "counter",
+     "row-passes of a block-diffusion step that committed nothing "
+     "(BatchScheduler._step_block; the pool rolled back)"),
+    ("diffusion.commit_passes", "counter",
+     "row-passes that committed a finished block's K/V"),
+    ("diffusion.tokens_unmasked", "counter",
+     "positions fixed by denoising passes (the remasking rule's picks)"),
+    ("diffusion.blocks_committed", "counter",
+     "blocks whose tokens were delivered to their requests"),
     ("eva.windows_rolled", "counter",
      "window chains released at a window's end (sequences x layer "
      "pools)"),

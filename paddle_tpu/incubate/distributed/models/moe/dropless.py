@@ -9,11 +9,14 @@ grouped matmul runs over the stacked expert weights with the group sizes
 as DEVICE data, so no shape depends on the routing and nothing crosses to
 the host inside the layer:
 
-* router in float32: ``s = sigmoid(x W_r)``; the top ``k`` of ``s + bias``
-  (``e_score_correction_bias``, the ``noaux_tc`` selection bias: it picks,
-  it does not weigh); weights ``s`` of the picked, divided by their sum
-  when ``norm_topk_prob``, times ``routed_scaling_factor``;
-* ``y = sum_i w_i E_i(x) + E_shared(x)``, every expert a SwiGLU.
+* router in float32, two scores: ``s = sigmoid(x W_r)`` and the top ``k``
+  of ``s + bias`` (``e_score_correction_bias``, the ``noaux_tc`` selection
+  bias: it picks, it does not weigh), or ``s = softmax(x W_r)`` over all
+  experts and its top ``k`` (the Qwen3-MoE family's router, no bias);
+  weights ``s`` of the picked, divided by their sum when
+  ``norm_topk_prob``, times ``routed_scaling_factor``;
+* ``y = sum_i w_i E_i(x) + E_shared(x)``, every expert a SwiGLU; the
+  shared expert is there or not (``num_shared`` 0).
 
 The pure functions (``route``, ``sort_by_expert``, ``experts_ffn``,
 ``combine``) are what the serving adapter compiles one program each of;
@@ -32,13 +35,20 @@ __all__ = ["DroplessMoE", "route", "sort_by_expert", "grouped_matmul",
            "experts_ffn", "combine", "swiglu", "dropless_moe"]
 
 
-def route(x, w_router, bias, top_k, scale=1.0, norm_topk=True):
+SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+
+
+def route(x, w_router, bias, top_k, scale=1.0, norm_topk=True,
+          scoring="sigmoid"):
     """x [N, C] -> (expert ids [N, k] int32, weights [N, k] float32).
-    Sigmoid scores in float32; ``bias`` only enters the selection."""
-    s = jax.nn.sigmoid(jnp.matmul(
+    Scores in float32, ``scoring`` of ``SCORES``: each expert's sigmoid,
+    or the softmax over all experts; ``bias`` (None: none) only enters the
+    selection."""
+    s = SCORES[scoring](jnp.matmul(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, idx, -1)
     if norm_topk:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
@@ -92,11 +102,11 @@ def combine(ys, order, weights, n_tokens):
 
 
 def dropless_moe(x, w_router, bias, w_gate, w_up, w_down, shared, top_k,
-                 scale=1.0, norm_topk=True, valid=None):
+                 scale=1.0, norm_topk=True, valid=None, scoring="sigmoid"):
     """The whole layer on [N, C]; ``shared`` is None or the shared
     expert's (gate, up, down). Returns (y [N, C], group_sizes [E])."""
     n = x.shape[0]
-    idx, w = route(x, w_router, bias, top_k, scale, norm_topk)
+    idx, w = route(x, w_router, bias, top_k, scale, norm_topk, scoring)
     order, sizes = sort_by_expert(idx, w_gate.shape[0], valid)
     xs = x[order // top_k]
     ys = experts_ffn(xs, sizes, w_gate, w_up, w_down)
@@ -108,16 +118,22 @@ def dropless_moe(x, w_router, bias, w_gate, w_up, w_down, shared, top_k,
 
 class DroplessMoE(Layer):
     """``num_experts`` routed SwiGLU experts of width ``d_hidden`` as three
-    stacked parameters, a sigmoid router with its selection bias, and
-    ``num_shared`` shared experts (one SwiGLU of width ``num_shared *
-    d_hidden``). No capacity: no token is dropped at any imbalance.
+    stacked parameters, a router (``scoring`` "sigmoid" with its selection
+    bias, or "softmax" over all experts with none), and ``num_shared``
+    shared experts (one SwiGLU of width ``num_shared * d_hidden``; 0:
+    none). No capacity: no token is dropped at any imbalance.
     ``forward`` takes [..., d_model]; ``last_group_sizes`` holds the
     per-expert assignment counts of the last call (a device array)."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  num_shared=1, routed_scaling_factor=1.0,
-                 norm_topk_prob=True, init_std=0.02, dtype="float32"):
+                 norm_topk_prob=True, init_std=0.02, dtype="float32",
+                 scoring="sigmoid"):
         super().__init__(dtype=dtype)
+        if scoring not in SCORES:
+            raise ValueError(f"DroplessMoE: scoring={scoring!r} is none of "
+                             f"{sorted(SCORES)}")
+        self.scoring = scoring
         self.top_k = int(top_k)
         self.num_experts = int(num_experts)
         self.routed_scaling_factor = float(routed_scaling_factor)
@@ -126,8 +142,10 @@ class DroplessMoE(Layer):
         mk = lambda shape: self.create_parameter(  # noqa: E731
             shape, default_initializer=init)
         self.gate_weight = mk([d_model, num_experts])
+        # the selection bias is the sigmoid (noaux_tc) router's
         self.e_score_correction_bias = self.create_parameter(
-            [num_experts], default_initializer=I.Constant(0.0))
+            [num_experts], default_initializer=I.Constant(0.0)) \
+            if scoring == "sigmoid" else None
         self.experts_gate = mk([num_experts, d_model, d_hidden])
         self.experts_up = mk([num_experts, d_model, d_hidden])
         self.experts_down = mk([num_experts, d_hidden, d_model])
@@ -140,7 +158,9 @@ class DroplessMoE(Layer):
         self.last_group_sizes = None
 
     def routed_params(self):
-        return (self.gate_weight, self.e_score_correction_bias,
+        """(router, [selection bias,] the three expert stacks)."""
+        bias = self.e_score_correction_bias
+        return (self.gate_weight, *(() if bias is None else (bias,)),
                 self.experts_gate, self.experts_up, self.experts_down)
 
     def shared_params(self):
@@ -150,12 +170,16 @@ class DroplessMoE(Layer):
     def forward(self, x):
         k, scale, norm = (self.top_k, self.routed_scaling_factor,
                           self.norm_topk_prob)
-        has_shared = self.has_shared
+        has_shared, scoring = self.has_shared, self.scoring
+        has_bias = self.e_score_correction_bias is not None
 
-        def f(xr, wr, b, wg, wu, wd, *sh):
+        def f(xr, wr, *rest):
+            b, (wg, wu, wd, *sh) = (rest[0], rest[1:]) if has_bias \
+                else (None, rest)
             y, sizes = dropless_moe(
                 xr.reshape(-1, xr.shape[-1]), wr, b, wg, wu, wd,
-                sh if has_shared else None, k, scale, norm)
+                sh if has_shared else None, k, scale, norm,
+                scoring=scoring)
             # the counts leave the op as float32: the tape's cotangents
             # are of the outputs' types
             return y.reshape(xr.shape), sizes.astype(jnp.float32)

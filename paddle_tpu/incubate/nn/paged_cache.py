@@ -1898,7 +1898,8 @@ class PagedKVCacheManager:
 
     def layer_step(self, x, weights, rope, plan, tables, eps,
                    sm_scale=None, window=0, unit_offset=False,
-                   summary=None, few_row_rows=None):
+                   summary=None, few_row_rows=None, block=0, qk_norm=None,
+                   router=None):
         """One decoder layer of a packed step as ONE compiled program
         over this pool's pages (ops/kernels/paged_attention.
         paged_ragged_layer_step): norm, qkv projection + RoPE + THIS
@@ -1928,7 +1929,12 @@ class PagedKVCacheManager:
         (heads, head_dim), of a ``page_format="eva"`` pool: the program
         pools every page this step fills into the row of the summary
         chain that :meth:`book_step` booked for it. ``few_row_rows``: as
-        in :meth:`attend_ragged`."""
+        in :meth:`attend_ragged`. ``block``, ``qk_norm`` = (gq, gk) and
+        ``router`` = (wr, top_k, norm_topk, scoring) are the program's
+        other switches (the block-causal mask, per-head q/k norms, a
+        routed feed-forward over ``weights``' expert stacks); with
+        ``router`` the per-expert assignment counts come back beside the
+        stream: ``(y, counts)``."""
         self._kv_only("layer_step")
         with telemetry.span("pool.fused_step", op="layer_step"):
             if self.quantized:
@@ -1949,13 +1955,14 @@ class PagedKVCacheManager:
                     f"rows, its plan {tok.shape[1]} and {slots.shape[1]}"
                     " (every plan operand is padded to the packed "
                     "length)")
-            y, self.k_pages, self.v_pages = _layer_step_fn(
+            y, self.k_pages, self.v_pages, *sizes = _layer_step_fn(
                 self.k_pages, self.v_pages, x, weights, rope,
                 (tok, gm, slots, rows), eps, sm_scale=sm_scale,
                 window=window, unit_offset=unit_offset,
                 summary=summary and (*summary, *sums),
-                counts=_few_counts(tables.counts, few_row_rows))
-            return y
+                counts=_few_counts(tables.counts, few_row_rows),
+                block=block, qk_norm=qk_norm, router=router)
+            return (y, *sizes) if sizes else y
 
     def latent_ragged_step(self, q, toks, seq_ids, counts, gather_map,
                            value_dim, rows_pad=None, max_pages=None,

@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..framework import telemetry
 from ..framework.core import Tensor
 from ..ops.kernels.paged_attention import (
     packed_position_index as _packed_position_index,
@@ -191,6 +192,7 @@ class PagedAdapterBase:
         self.chunk_stats = {"calls": 0, "packed_tokens": 0,
                             "padded_tokens": 0, "attend_calls": 0,
                             "layer_programs": 0, "few_row_rows": 0}
+        self._reg = telemetry.registry()
 
     def _count_packed_step(self, rows: PackedRows):
         self._dispatch_shapes.add(rows.pad_to)
@@ -224,6 +226,29 @@ class PagedAdapterBase:
         program per bucket."""
         return {b: sorted({k for k, *_ in shapes})
                 for b, shapes in self._bucket_programs.items()}
+
+    # -- expert counts -----------------------------------------------------
+    def _note_counts(self, counts):
+        """counts [expert layers, E] of one step, on the host."""
+        counts = np.asarray(counts)
+        if counts.size == 0:
+            return
+        nums = {
+            "calls": int(counts.shape[0]),
+            "assignments": int(counts.sum()),
+            "experts_touched": int((counts > 0).sum()),
+            "expert_tokens_max": int(counts.max(-1).sum()),
+            "expert_tokens_mean": float(counts.mean(-1).sum()),
+        }
+        reg = self._reg
+        if reg is not None:
+            reg.inc("moe.calls", nums["calls"])
+            reg.inc("moe.assignments", nums["assignments"])
+            reg.inc("moe.experts_touched", nums["experts_touched"])
+            reg.inc("moe.expert_tokens_max", nums["expert_tokens_max"])
+            reg.inc("moe.expert_tokens_mean", nums["expert_tokens_mean"])
+        with telemetry.span("moe.counts", **nums):
+            pass
 
     # -- scheduler protocol ------------------------------------------------
     def alloc(self, seq_id):

@@ -28,6 +28,18 @@ things the adapter reads off the model: the norms' unit offset, MHA, the
 summary epilogue of the layer program, and the table a step hands the
 kernel (the pool's). It runs the programmed body only, offers ``warm``,
 and returns head 0's logits of the ``num_pred_heads`` it computes.
+
+A model that declares ``block_length`` / ``mask_token_id`` (SDAR-MoE,
+models/sdar.py: generation by diffusion over blocks) is served from plain
+``"kv"`` pages by the same programs with three more things read off the
+model: per-head q/k norm gains, a routed feed-forward (``DroplessMoE``:
+the layer program's second static body; the per-expert counts come back
+beside the stream and cross with the step's pull) and the block length,
+handed to the kernel as its block-causal mask. ``prefill_chunk(...,
+choose_rows=)`` runs the head over every position of the listed rows ON
+THE DEVICE and returns, a position, (best token, its logit, logsumexp):
+the MASK id's logit is left out, and 3 numbers cross where 151,936 would.
+The programmed body only; ``warm`` as above.
 """
 from __future__ import annotations
 
@@ -101,6 +113,19 @@ class PagedLlamaAdapter(PagedAdapterBase):
         # exactly, one pooled row a finished chunk behind them; the
         # chunk is the page, so the layer program pools whole pages
         self._eva = int(getattr(cfg, "chunk_size", 0) or 0)
+        # a model that generates by diffusion over blocks (SDAR): a row
+        # feeds a whole block a pass, so every length the pool holds is
+        # whole blocks and the kernel's mask is block-causal
+        self.block_length = int(getattr(cfg, "block_length", 0) or 0)
+        self.mask_token_id = getattr(cfg, "mask_token_id", None)
+        if self.block_length and (page_size % self.block_length
+                                  or kv_cache_dtype or weight_dtype):
+            raise ValueError(
+                f"a block-diffusion model (block_length="
+                f"{self.block_length}) is served from float pages that "
+                f"hold whole blocks, with float weights (page_size="
+                f"{page_size} kv_cache_dtype={kv_cache_dtype!r} "
+                f"weight_dtype={weight_dtype!r})")
         if self._eva and (self._eva != page_size or kv_cache_dtype
                           or weight_dtype or page_pool_bytes):
             raise ValueError(
@@ -157,8 +182,16 @@ class PagedLlamaAdapter(PagedAdapterBase):
         # multi-token row's tokens are padded up to what ``warm`` was
         # told, the table to a window's pages at least, so that the
         # steady steps run the programs set-up built
-        self._rows_pad = self._chunk_pad = 1
+        self._rows_pad = self._chunk_pad = self._table_pad = 1
         self.pred_logits = None
+        if self.block_length and not self._fusion_eligible():
+            raise ValueError(
+                "a block-diffusion model is served by the layer program "
+                "alone (the op-by-op body has no q/k norms, no block mask "
+                "and no device-side choice): every layer plain float "
+                "linears, RMSNorm gains of one epsilon, a DroplessMoE "
+                "without a shared expert or a selection bias, a plain "
+                "embedding and head")
         if self._eva and not self._fusion_eligible():
             raise ValueError(
                 "a window-and-summary model is served by the layer "
@@ -171,18 +204,22 @@ class PagedLlamaAdapter(PagedAdapterBase):
         from what it can observe: the layer program takes raw [in, out]
         float weights as operands and writes float pages, so the KV
         pool must be float (int8 page calibration is a host-driven
-        per-token wave replay) and every layer a dense one of plain
-        parts — q/k/v/o and the gate/up/down of a ``LlamaMLP`` plain
-        (non-distributed, non-weight-quantized) linears, bias-free but
-        for an all-or-none q/k/v bias, ``RMSNorm`` norms of one epsilon
-        — with a plain embedding and head. Anything else keeps the same
-        plan, op by op."""
+        per-token wave replay) and every layer one of plain parts —
+        q/k/v/o plain (non-distributed, non-weight-quantized) linears,
+        bias-free but for an all-or-none q/k/v bias, a feed-forward that
+        is a ``LlamaMLP`` of such linears or a ``DroplessMoE`` of plain
+        float stacks with no shared expert and no selection bias (the
+        program's second static body), ``RMSNorm`` norms of one epsilon,
+        per-head q/k norms in every layer or in none — with a plain
+        embedding and head. Anything else keeps the same plan, op by
+        op."""
         if self._fused_ok is None:
             self._fused_ok = not self.caches[0].quantized \
                 and self.weight_dtype is None and self._plain_dense()
         return self._fused_ok
 
     def _plain_dense(self) -> bool:
+        from ..incubate.distributed.models.moe import DroplessMoE
         from ..models.llama import LlamaMLP
 
         def plain(w, ndim=2):
@@ -196,8 +233,18 @@ class PagedLlamaAdapter(PagedAdapterBase):
             return plain(getattr(proj, "weight", None)) and (
                 bias_ok or getattr(proj, "bias", None) is None)
 
+        def feed_forward(mlp):
+            if isinstance(mlp, LlamaMLP):
+                return all(linear(p) for p in (
+                    mlp.gate_proj, mlp.up_proj, mlp.down_proj))
+            return (isinstance(mlp, DroplessMoE) and not mlp.has_shared
+                    and mlp.e_score_correction_bias is None
+                    and mlp.routed_scaling_factor == 1.0
+                    and plain(mlp.gate_weight) and all(
+                        plain(w, 3) for w in mlp.routed_params()[1:]))
+
         core = self.model.model
-        norms = [core.norm]
+        norms, head_norms = [core.norm], []
         for layer in core.layers:
             att, mlp = layer.self_attn, layer.mlp
             qkv = (att.q_proj, att.k_proj, att.v_proj)
@@ -206,11 +253,15 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     and (all(has) or not any(has))
                     and all(plain(p.bias, 1) for p in qkv if has[0])
                     and linear(att.o_proj)   # bias-free epilogue
-                    and isinstance(mlp, LlamaMLP)
-                    and all(linear(p) for p in (
-                        mlp.gate_proj, mlp.up_proj, mlp.down_proj))):
+                    and feed_forward(mlp)):
                 return False
             norms += [layer.input_layernorm, layer.post_attention_layernorm]
+            head_norms += [n for n in (getattr(att, "q_norm", None),
+                                       getattr(att, "k_norm", None))
+                           if n is not None]
+        if len(head_norms) not in (0, 2 * len(core.layers)):
+            return False
+        norms += head_norms
         head = self.model.lm_head
         return (all(isinstance(n, RMSNorm) and plain(n.weight, 1)
                     for n in norms)
@@ -253,8 +304,23 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 lg = lg.reshape(lg.shape[0], heads, -1)
                 return lg[:, 0], lg
 
+            mask_id = self.mask_token_id
+
+            def choose(x, idx, norm_w, head_w):
+                """A position: (best token, its logit, logsumexp) of
+                the float32 logits with the MASK id's left out."""
+                lg = jnp.matmul(_rms_norm(x[idx], norm_w, eps), head_w,
+                                preferred_element_type=jnp.float32)
+                lg = lg.at[:, mask_id].set(-jnp.inf)
+                return jnp.stack([
+                    jnp.argmax(lg, -1).astype(jnp.float32), jnp.max(lg, -1),
+                    jax.nn.logsumexp(lg, -1)], -1)
+
             embed.__name__, head.__name__ = "llama_embed", "llama_head"
+            choose.__name__ = "llama_choose"
             self._programs = jax.jit(embed), jax.jit(head), eps
+            if self.block_length:
+                self._programs += (jax.jit(choose),)
         return self._programs
 
     def decode_token(self, token_ids, seq_ids):
@@ -306,7 +372,7 @@ class PagedLlamaAdapter(PagedAdapterBase):
             return self.model._head(h)
 
     def prefill_chunk(self, token_ids, seq_ids, start_positions=None,
-                      pad_to=None, logits_rows=None):
+                      pad_to=None, logits_rows=None, choose_rows=None):
         """One ragged mixed prefill/decode step (the Ragged Paged
         Attention shape — see PAPERS.md): row i appends the
         ``len(token_ids[i])`` tokens of ``token_ids[i]`` to sequence
@@ -328,6 +394,14 @@ class PagedLlamaAdapter(PagedAdapterBase):
         lm-head over the packed activations the step already computed,
         so verify rows add NO compiled attend program beyond the existing
         bucketed ragged family.
+
+        ``choose_rows`` (a block-diffusion model only): a list of row
+        indices, each a row of ``block_length`` tokens, whose every
+        position is chosen ON THE DEVICE. The call then returns one
+        float32 array [len(choose_rows) * block_length, 3], a position
+        (best token, its logit, logsumexp), the MASK id's logit left out,
+        in place of any logits; what else rides the step's pull (the
+        routed layers' per-expert counts) comes with it.
 
         All dense compute (embed / qkv / o_proj / mlp / norms) runs over
         ONE flat packed token axis padded to ``pad_to`` (the scheduler
@@ -363,6 +437,12 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 b_pad = max(b_pad, self._rows_pad)
                 t_pad = 1 if t_pad == 1 else max(t_pad, self._chunk_pad)
                 rows.mp_pad = max(rows.mp_pad, self.caches[0].window_pages)
+            blk = self.block_length
+            if blk:
+                self._check_blocks(rows, seq_ids, choose_rows)
+                b_pad = max(b_pad, self._rows_pad)
+                t_pad = blk if t_pad <= blk else max(t_pad, self._chunk_pad)
+                rows.mp_pad = max(rows.mp_pad, self._table_pad)
             gm, mr, mc, m_flat = _right_align_plan(
                 range(b), rows.starts, counts, t_pad, b_pad)
             fuse = self._fusion_eligible()
@@ -383,7 +463,13 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 # (the head of a window-and-summary model runs on the
                 # padded row count: slot 0 again, cut on the host)
                 host = [tok, gm, _pad_plan(rows.last_idx, b_pad, 0)
-                        if self._eva else rows.last_idx]
+                        if self._eva or blk else rows.last_idx]
+                if choose_rows is not None:
+                    # the head runs over rows x block positions whatever
+                    # the step holds (slot 0 again, cut on the host)
+                    host[2] = _pad_plan(_position_index(
+                        rows.starts, counts, choose_rows) if choose_rows
+                        else (), b_pad * blk, 0)
                 if logits_rows is not None:
                     host.append(_position_index(rows.starts, counts,
                                                 logits_rows))
@@ -397,8 +483,26 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     bytes=sum(int(a.nbytes) for a in up))
         with no_grad():
             if fuse:
-                return self._run_programs(rows, seq_ids, up, b_pad)
+                return self._run_programs(rows, seq_ids, up, b_pad,
+                                          choose_rows)
             return self._run_eager(rows, seq_ids, up, b_pad, logits_rows)
+
+    def _check_blocks(self, rows, seq_ids, choose_rows):
+        """A block-diffusion step's rows: every row resumes on a block
+        boundary (a prompt chunk ends on one, a generated block is fed
+        whole), and a row whose positions are chosen on the device is one
+        block."""
+        blk = self.block_length
+        off = [s for s, n in zip(seq_ids, rows.lens0) if n % blk]
+        if off:
+            raise ValueError(
+                f"prefill_chunk: sequences {off} do not resume on a "
+                f"boundary of block_length={blk}")
+        bad = [i for i in choose_rows or () if rows.counts[i] != blk]
+        if bad:
+            raise ValueError(
+                f"prefill_chunk: choose_rows {bad} are not rows of "
+                f"block_length={blk} tokens")
 
     def chunk_room(self, seq_id):
         """Tokens one step may feed the sequence (the scheduler clamps a
@@ -408,27 +512,35 @@ class PagedLlamaAdapter(PagedAdapterBase):
 
     def warm(self, rows, packed, chunk_tokens):
         """Build, before the first request, the programs of the steady
-        steps of a window-and-summary model (``BatchScheduler.warm``
-        calls it with its batch size, packed widths and chunk size):
-        ``rows`` sequences decoding, alone or beside prompt chunks of at
-        most ``chunk_tokens`` tokens, at each packed width (the two it
-        is told of and the doublings between them) and each table width
-        up to ``max_length``. From here on every step pads
+        steps of a window-and-summary or a block-diffusion model
+        (``BatchScheduler.warm`` calls it with its batch size, packed
+        widths and chunk size): ``rows`` sequences decoding (a token a
+        row, or a block), alone or beside prompt chunks of at most
+        ``chunk_tokens`` tokens, at each packed width (the two it is
+        told of and the doublings between them) and each table width up
+        to ``max_length``. From here on every step pads
         its rows to ``rows`` and a multi-token row to ``chunk_tokens``,
         so the list is short and closed. Each program runs once on
         zeros over the pools themselves: every slot and summary plan
-        entry is out of bounds (nothing is written) and every length 0.
+        entry is out of bounds (nothing is written, no token is routed)
+        and every length 0.
         A Llama/Mistral model returns at once: its steps' shapes follow
         the traffic (ROADMAP Queue 1 #3)."""
-        if not self._eva:
+        blk = self.block_length
+        if not (self._eva or blk):
             return
         self._rows_pad = b_pad = _pow2(rows)
         self._chunk_pad = _pow2(max(2, int(chunk_tokens)))
-        embed, head, eps = self._step_programs()
+        embed, head, eps, *choose = self._step_programs()
         core, pool = self.model.model, self.caches[0]
         z, i32 = np.zeros, np.int32
-        mps, mp = [], pool.window_pages
-        while mp <= _pow2(pool.table_pages(self.max_length)):
+        top = _pow2(pool.table_pages(self.max_length))
+        if blk:
+            # tables of 32 pages (512 tokens) at least: two widths serve
+            # rows of up to 1,024 tokens
+            self._table_pad = min(32, top)
+        mps, mp = [], pool.window_pages if self._eva else self._table_pad
+        while mp <= top:
             mps.append(mp)
             mp *= 2
         layer = core.layers[0]
@@ -441,6 +553,7 @@ class PagedLlamaAdapter(PagedAdapterBase):
             n *= 2
             packed.add(n)
         packed = sorted(packed)
+        one = blk or 1                      # a decode row's tokens
         for n_pad in packed:
             tok = z((5, n_pad), i32)
             tok[4] = n_pad
@@ -448,8 +561,8 @@ class PagedLlamaAdapter(PagedAdapterBase):
             slots[0] = pool.num_pages
             sums = z((3, n_pad // pool.page_size + b_pad), i32)
             sums[1] = pool.num_pages
-            for t_pad in (1, self._chunk_pad):
-                if t_pad == 1 and n_pad != min(packed):
+            for t_pad in (one, self._chunk_pad):
+                if t_pad == one and n_pad != min(packed):
                     continue      # decode rows alone: the least width
                 for mp in mps:
                     tok_d, gm, slots_d, rows_d, sums_d = _upload(
@@ -459,11 +572,15 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     x = pool.layer_step(
                         x, self._layer_weights(layer),
                         (self._cos, self._sin), (tok_d, gm),
-                        StepTables((rows_d, slots_d, sums_d)), eps,
+                        StepTables((rows_d, slots_d, sums_d) if self._eva
+                                   else (rows_d, slots_d)), eps,
                         **self._layer_switches(layer))
-            out = head(x, jnp.zeros((b_pad,), jnp.int32),
-                       core.norm.weight._data,
-                       self.model.lm_head.weight._data)
+                    if isinstance(x, tuple):
+                        x = x[0]
+            head_w = (core.norm.weight._data, self.model.lm_head.weight._data)
+            out = choose[0](x, jnp.zeros((b_pad * blk,), jnp.int32),
+                            *head_w) if blk else \
+                head(x, jnp.zeros((b_pad,), jnp.int32), *head_w)
         jax.block_until_ready(out)
 
     def _few_row_rows(self, rows, t_pad):
@@ -476,12 +593,12 @@ class PagedLlamaAdapter(PagedAdapterBase):
             cfg.num_attention_heads // cfg.num_key_value_heads,
             self.caches[0].quantized)
 
-    def _run_programs(self, rows, seq_ids, up, b_pad):
+    def _run_programs(self, rows, seq_ids, up, b_pad, choose_rows=None):
         """The programmed body of a packed step: one dispatch for the
         embedding, one a layer, one for the head (two with verify rows,
         whose positions are the fourth operand of ``up``)."""
         span = telemetry.span
-        embed, head, eps = self._step_programs()
+        embed, head, eps, *choose = self._step_programs()
         core, caches = self.model.model, self.caches
         tok, gm, last, *verify = up
         # every layer's pool books its own slots BEFORE the first layer
@@ -492,8 +609,11 @@ class PagedLlamaAdapter(PagedAdapterBase):
             tables.append(cache.book_step(
                 seq_ids, rows.counts, b_pad, rows.mp_pad, rows.pad_to,
                 like=tables[-1] if tables else None))
+        if self.block_length:
+            self._note_block_counts(tables, rows)
         plan, rope = (tok, gm), (self._cos, self._sin)
         few = self._few_row_rows(rows, gm.shape[1])
+        sizes = []                 # a routed layer's per-expert counts
         with span("model.embed"):
             x = embed(core.embed_tokens.weight._data, tok)      # (N, H)
         for li, layer in enumerate(core.layers):
@@ -505,11 +625,25 @@ class PagedLlamaAdapter(PagedAdapterBase):
                     x, self._layer_weights(layer), rope, plan, tables[li],
                     eps, window=self._window, few_row_rows=few,
                     **self._layer_switches(layer))
+                if isinstance(x, tuple):
+                    x, n = x
+                    sizes.append(n)
         with span("model.head"):
             head_w = (core.norm.weight._data,
                       (core.embed_tokens if self.model.lm_head is None
                        else self.model.lm_head).weight._data)
+            extra = (jnp.stack(sizes), self._note_counts) if sizes \
+                else (None, None)
+            if choose_rows is not None:
+                # the choice of every position of the listed rows, made
+                # on the device: 3 numbers a position cross, not a row
+                # of the vocabulary
+                return step_logits(
+                    choose[0](x, last, *head_w),
+                    len(choose_rows) * self.block_length, *extra)
             logits = head(x, last, *head_w)
+            if self.block_length:
+                return step_logits(logits, rows.b, *extra)
             if self._eva:
                 # every prediction head is computed; a sampler sees
                 # head 0 of the rows that are real (cut on the host)
@@ -522,29 +656,62 @@ class PagedLlamaAdapter(PagedAdapterBase):
             # the listed (verify) rows, concatenated in list order
             return logits, Tensor(head(x, verify[0], *head_w))
 
+    def _note_block_counts(self, tables, rows):
+        """What a block-diffusion step's kernel call reads and computes,
+        exact from the step's table (the ``kernel.ragged`` span's ``fed``
+        / ``pairs`` / ``kv_rows``): a fed token is paired with every row
+        up to the end of its own block."""
+        blk = self.block_length
+        fed = np.asarray(rows.counts, np.int64)
+        seen = fed + np.asarray(rows.lens0, np.int64)
+        # a row of q tokens ending at L (both whole blocks): each of its
+        # q / blk blocks pairs blk tokens with the rows up to its end
+        n = fed // blk
+        pairs = blk * (n * (seen - fed) + blk * n * (n + 1) // 2)
+        # a last partial chunk (a prompt scored, never continued) sees
+        # up to the row's end
+        part = fed % blk
+        pairs += part * seen
+        tables[0].counts = {"fed": int(fed.sum()), "pairs": int(pairs.sum()),
+                            "kv_rows": int(seen.sum())}
+
     @staticmethod
     def _layer_weights(layer):
-        """A layer's raw arrays as the layer program takes them."""
+        """A layer's raw arrays as the layer program takes them; the
+        feed-forward's three are a ``LlamaMLP``'s matrices or a routed
+        layer's expert stacks."""
         att, mlp = layer.self_attn, layer.mlp
         biases = None
         if att.q_proj.bias is not None:
             biases = (att.q_proj.bias._data, att.k_proj.bias._data,
                       att.v_proj.bias._data)
+        ffn = (mlp.gate_proj, mlp.up_proj, mlp.down_proj) \
+            if hasattr(mlp, "gate_proj") else None
         return (layer.input_layernorm.weight._data,
                 att.q_proj.weight._data, att.k_proj.weight._data,
                 att.v_proj.weight._data, att.o_proj.weight._data,
                 biases, layer.post_attention_layernorm.weight._data,
-                mlp.gate_proj.weight._data, mlp.up_proj.weight._data,
-                mlp.down_proj.weight._data)
+                *((p.weight._data for p in ffn) if ffn else
+                  (w._data for w in mlp.routed_params()[1:])))
 
     def _layer_switches(self, layer):
         """The layer program's static switches, off for Llama/Mistral:
-        the norms' unit offset and the summary epilogue's (phi, mu)."""
-        if not self._eva:
-            return {}
-        att = layer.self_attn
-        return {"unit_offset": self._unit_offset(layer.input_layernorm),
-                "summary": (att.phi._data, att.mu._data)}
+        the norms' unit offset and the summary epilogue's (phi, mu); the
+        kernel's block-causal mask, the per-head q/k norm gains, the
+        routed feed-forward's router."""
+        att, mlp, out = layer.self_attn, layer.mlp, {}
+        if self._eva:
+            out.update(unit_offset=self._unit_offset(layer.input_layernorm),
+                       summary=(att.phi._data, att.mu._data))
+        if self.block_length:
+            out["block"] = self.block_length
+        if getattr(att, "q_norm", None) is not None:
+            out["qk_norm"] = (att.q_norm.weight._data,
+                              att.k_norm.weight._data)
+        if not hasattr(mlp, "gate_proj"):
+            out["router"] = (mlp.gate_weight._data, mlp.top_k,
+                             mlp.norm_topk_prob, mlp.scoring)
+        return out
 
     def _run_eager(self, rows, seq_ids, up, b_pad, logits_rows):
         """The op-by-op body of a packed step (int8 pages or weights,

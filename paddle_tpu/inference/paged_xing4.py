@@ -77,7 +77,6 @@ class PagedXing4Adapter(PagedAdapterBase):
             for _ in range(cfg.num_hidden_layers)]
         self._cos, self._sin = _x.yarn_rope_tables(cfg, self.max_length)
         self._scale = _x.mla_softmax_scale(cfg)
-        self._reg = telemetry.registry()
         self._init_dispatch_accounting()
         self._programs = self._build_programs()
 
@@ -133,29 +132,6 @@ class PagedXing4Adapter(PagedAdapterBase):
             fn.__name__ = "xing4_" + name       # jit(<name>) in the traces
             out[name] = jax.jit(fn)
         return out
-
-    # -- expert counts -----------------------------------------------------
-    def _note_counts(self, counts):
-        """counts [expert layers, E] of one step, on the host."""
-        counts = np.asarray(counts)
-        if counts.size == 0:
-            return
-        nums = {
-            "calls": int(counts.shape[0]),
-            "assignments": int(counts.sum()),
-            "experts_touched": int((counts > 0).sum()),
-            "expert_tokens_max": int(counts.max(-1).sum()),
-            "expert_tokens_mean": float(counts.mean(-1).sum()),
-        }
-        reg = self._reg
-        if reg is not None:
-            reg.inc("moe.calls", nums["calls"])
-            reg.inc("moe.assignments", nums["assignments"])
-            reg.inc("moe.experts_touched", nums["experts_touched"])
-            reg.inc("moe.expert_tokens_max", nums["expert_tokens_max"])
-            reg.inc("moe.expert_tokens_mean", nums["expert_tokens_mean"])
-        with telemetry.span("moe.counts", **nums):
-            pass
 
     @staticmethod
     def _row_pad(longest):

@@ -26,6 +26,16 @@ most len(buckets) ragged programs. Decode rows keep advancing one
 token per step (latency stays flat) while prefill saturates the chip;
 a 432-token prompt costs ceil(432/budget) steps instead of 432.
 
+Generation by diffusion over blocks (a model that declares
+``block_length``; models/sdar.py, docs/SERVING.md): a DECODE row feeds
+its open block's B ids every step — MASK at the positions not fixed yet —
+and gets back, a position, the best token and its probability, chosen on
+the device. A denoising pass commits nothing (the pool is rolled back)
+and fixes positions by the ``remasking`` rule; once no MASK is left one
+clean pass commits the block's K/V and its B tokens are delivered
+together. So a row receives B tokens every ``denoising_steps`` + 1
+passes at most (:meth:`BatchScheduler._step_block`).
+
 Admission control: a request is admitted only while (a) the active
 batch is below ``max_batch_size`` and (b) the page pool would stay
 under the high watermark after reserving the request's worst-case page
@@ -278,6 +288,8 @@ class Request:
     _ttft: Optional[float] = None
     _qwait: Optional[float] = None
     _gaps: Optional[List[float]] = None
+    # the open block of a DECODE row over a block-diffusion model
+    _block: Optional["_Block"] = None
 
     @property
     def finished(self) -> bool:
@@ -292,6 +304,24 @@ class Request:
 
     def total_tokens(self) -> int:
         return len(self.prompt_ids) + self.max_new_tokens
+
+
+class _Block:
+    """A DECODE row's open block over a block-diffusion model: its B
+    current ``ids`` (MASK where ``masked``), how many leading positions
+    are the prompt's tail (never masked: the row's own state, a prompt or
+    chosen token may equal the MASK id), and the denoising passes made."""
+
+    __slots__ = ("ids", "masked", "n_prompt", "passes")
+
+    def __init__(self, tail, size, mask_id):
+        self.n_prompt = len(tail)
+        self.ids = list(tail) + [mask_id] * (size - len(tail))
+        self.masked = [False] * len(tail) + [True] * (size - len(tail))
+        self.passes = 0
+
+
+REMASKING = ("low_confidence_dynamic", "low_confidence_static", "sequential")
 
 
 class BatchScheduler:
@@ -311,7 +341,9 @@ class BatchScheduler:
                  prefill_chunk_tokens=None, serving_buckets=None,
                  prefix_align=1, slo=None, watchdog=None,
                  max_queue=None, max_inflight_per_tenant=None,
-                 preempt=None, swap_bytes=None, fault_injector=None):
+                 preempt=None, swap_bytes=None, fault_injector=None,
+                 denoising_steps=4, remasking="low_confidence_dynamic",
+                 confidence_threshold=0.9):
         self.model = model
         self.max_batch_size = int(max_batch_size)
         self.page_watermark = float(page_watermark)
@@ -370,6 +402,14 @@ class BatchScheduler:
         # a window-and-summary pool (page_format="eva") keeps two chains
         # a sequence and releases pages behind its window: nothing that
         # takes a sequence for one chain as long as its tokens serves it
+        # generation by diffusion over blocks (module docstring): the
+        # block length and the MASK id are the model's, the three knobs
+        # the model card's command's (its defaults)
+        self.block_length = int(getattr(model, "block_length", 0) or 0)
+        if self.block_length:
+            self._init_block(denoising_steps, remasking,
+                             confidence_threshold, prefix_cache,
+                             draft_model, preempt)
         eva = any(getattr(c, "eva", False) for c in model.caches)
         if eva and (prefix_cache or draft_model is not None or preempt):
             raise ValueError(
@@ -442,7 +482,8 @@ class BatchScheduler:
         self._plain_fifo = True
         self._deadline_seen = False
         preempt = bool(flag("serving_preempt")
-                       if preempt is None else preempt) and not eva
+                       if preempt is None else preempt) \
+            and not eva and not self.block_length
         swap_bytes = int(flag("serving_swap_bytes")
                          if swap_bytes is None else swap_bytes)
         self.swap_space = None
@@ -601,6 +642,41 @@ class BatchScheduler:
                         "scheduler." + self._sched_uid,
                         self._statusz_info)
 
+    def _init_block(self, denoising_steps, remasking, confidence_threshold,
+                    prefix_cache, draft_model, preempt):
+        """The block step's settings, checked: what cannot serve a row
+        with an open block is refused by name."""
+        if prefix_cache or draft_model is not None or preempt:
+            raise ValueError(
+                "a block-diffusion model (block_length="
+                f"{self.block_length}) serves no prefix cache (a cached "
+                "prefix would have to end on a block boundary), no "
+                "speculative draft (a row already commits a block a round) "
+                "and no preemption (no swap record of an open block): got "
+                f"prefix_cache={bool(prefix_cache)} draft_model="
+                f"{draft_model is not None} preempt={preempt}")
+        if not self.chunked_prefill:
+            raise ValueError(
+                "a block-diffusion model is served by the packed step "
+                "(prefill_chunk) alone: chunked_prefill=False was given")
+        if remasking not in REMASKING:
+            raise ValueError(f"remasking={remasking!r} is none of "
+                             f"{list(REMASKING)}")
+        b, t = self.block_length, int(denoising_steps)
+        if not 1 <= t <= b:
+            raise ValueError(f"denoising_steps={t} is not in 1.."
+                             f"block_length={b}")
+        if not 0.0 < float(confidence_threshold) <= 1.0:
+            raise ValueError(
+                f"confidence_threshold={confidence_threshold} is no "
+                "probability")
+        self.denoising_steps, self.remasking = t, remasking
+        self.confidence_threshold = float(confidence_threshold)
+        # a pass's share of the block: its ceil-split over the passes
+        self._shares = [b // t + (s < b % t) for s in range(t)]
+        self.block_stats = {"denoise_passes": 0, "commit_passes": 0,
+                            "tokens_unmasked": 0, "blocks_committed": 0}
+
     # -- set-up ------------------------------------------------------------
     def warm(self):
         """Build, before the first request, the programs of the steady
@@ -614,8 +690,9 @@ class BatchScheduler:
         if warm is None:
             return
         rows, chunk = self.max_batch_size, self.prefill_chunk_tokens
+        fed = rows * (self.block_length or 1)   # a decode row feeds a block
         warm(rows, {bucket_packed_tokens(n, self.serving_buckets)
-                    for n in (rows, rows + chunk)}, chunk)
+                    for n in (fed, fed + chunk)}, chunk)
 
     # -- pool accounting ---------------------------------------------------
     def _pool(self, model=None):
@@ -699,7 +776,11 @@ class BatchScheduler:
         * ``compile`` / ``collective`` — whatever the compile path and
           the collective-matmul dispatch recorded in this process;
         * ``sanitizer`` — event/violation counters when a sanitizer
-          is live.
+          is live;
+        * ``sampler`` — over a block-diffusion model only: the note that
+          ``sampler=`` is not called (a host callable over one row's
+          logits cannot choose among a block's positions; the tokens are
+          chosen on the device).
 
         Plus, since PR 8: self-describing ``serving`` gauges (uptime,
         steps/sec, active/queued/retired request counts), SLO/goodput
@@ -710,11 +791,14 @@ class BatchScheduler:
 
         Returns ``{"telemetry": "off"}`` when FLAGS_telemetry was off
         at scheduler construction (nothing was ever recorded)."""
+        said = {"sampler": "not called: the tokens of a block-diffusion "
+                "model are chosen on the device"} if self.block_length else {}
         if self._metrics is None:
-            return {"telemetry": "off"}
+            return {"telemetry": "off", **said}
         m = self._metrics
         stats = self._publish_gauges()
         snap = m.snapshot()
+        snap.update(said)
         snap["telemetry"] = ("trace" if self._tracer is not None
                              else "metrics")
         if "sanitizer" in stats:
@@ -1714,9 +1798,12 @@ class BatchScheduler:
         """A model call's logits as a host array, under its own span:
         the wait for the device and the device->host copy, apart from
         the dispatch that ``serving.prefill_chunk`` covers."""
-        with self._span("serving.logits_pull"):
-            return np.asarray(
+        with self._span("serving.logits_pull") as sp:
+            out = np.asarray(
                 logits.numpy() if hasattr(logits, "numpy") else logits)
+            if sp is not None:
+                sp.attrs["bytes"] = int(out.nbytes)
+            return out
 
     def _req_span(self, name, request, **attrs):
         """Request-scoped span: recorded under the request's
@@ -2189,6 +2276,8 @@ class BatchScheduler:
                     "prefix_hit_tokens": hit_tokens,
                     "prefill_tokens": 0, "decode_tokens": 0}
 
+        if self.block_length:
+            return self._step_block(admitted, hit_tokens)
         if self.draft is not None:
             return self._step_spec_ragged(admitted, hit_tokens)
         if self.chunked_prefill:
@@ -2302,6 +2391,17 @@ class BatchScheduler:
                 n_pre += take
         return rows, feeds, starts, n_pre, n_dec
 
+    def _stream_prompt_chunk(self, req, toks):
+        """A chunk of prompt tokens is in the pool: count and stream it."""
+        req._pos += len(toks)
+        if self._traces is not None:
+            self._traces.event(
+                req.req_id, "prefill_chunk", telemetry.clock(),
+                self._step_epoch, tokens=len(toks), pos=req._pos)
+        if req.on_token is not None:
+            for t in toks:
+                req.on_token(req, t, True)
+
     def _advance_prefill_row(self, req, toks, logits_row) -> int:
         """Commit one chunk of prompt tokens for a PREFILL row:
         stream them, and when the chunk finishes the prompt either
@@ -2311,14 +2411,7 @@ class BatchScheduler:
         (in spec mode ``self.sampler`` is the greedy argmax default:
         a custom sampler is rejected at construction). Returns 1 if
         the request retired."""
-        req._pos += len(toks)
-        if self._traces is not None:
-            self._traces.event(
-                req.req_id, "prefill_chunk", telemetry.clock(),
-                self._step_epoch, tokens=len(toks), pos=req._pos)
-        if req.on_token is not None:
-            for t in toks:
-                req.on_token(req, t, True)
+        self._stream_prompt_chunk(req, toks)
         if req._pos < len(req.prompt_ids):
             return 0
         if req.max_new_tokens == 0:
@@ -2405,6 +2498,204 @@ class BatchScheduler:
             "attend_programs": getattr(
                 self.model, "attend_program_count", None),
         }
+
+    # -- generation by diffusion over blocks ------------------------------
+    def _prompt_end(self, req) -> int:
+        """The prompt tokens fed by clean passes: its whole blocks (the
+        tail shares the first generated block), all of it where nothing
+        is generated (a prompt scored)."""
+        n = len(req.prompt_ids)
+        return n if req.max_new_tokens == 0 else n - n % self.block_length
+
+    def _open_block(self, req):
+        """The block at the row's committed length: the prompt's tail, if
+        the prompt ends inside it, and MASK behind."""
+        base = self.model.caches[0].seq_len(req.req_id)
+        req._block = _Block(req.prompt_ids[base:], self.block_length,
+                            self.model.mask_token_id)
+        req.state = RequestState.DECODE
+
+    def _block_feeds(self, sids):
+        """Pack one block step: EVERY decode row's open block (B ids at
+        its committed length) plus up to ``prefill_chunk_tokens`` pending
+        prompt tokens in whole blocks. Returns (rows, feeds, starts,
+        prefill_tokens, indices of the decode rows)."""
+        b = self.block_length
+        # prompt chunks end on block boundaries: whole blocks a step
+        budget = max(b, self.prefill_chunk_tokens
+                     - self.prefill_chunk_tokens % b)
+        rows, feeds, starts, dec = [], [], [], []
+        n_pre = 0
+        for s in sids:
+            req = self._active[s]
+            if req.state == RequestState.PREFILL:
+                end = self._prompt_end(req)
+                if req._pos < end:
+                    if budget <= 0:
+                        continue
+                    take = min(end - req._pos, budget)
+                    budget -= -(-take // b) * b
+                    rows.append(s)
+                    feeds.append(req.prompt_ids[req._pos:req._pos + take])
+                    starts.append(req._pos)
+                    n_pre += take
+                    continue
+                self._open_block(req)   # a prompt shorter than a block
+            dec.append(len(rows))
+            rows.append(s)
+            feeds.append(list(req._block.ids))
+            starts.append(self.model.caches[0].seq_len(s))
+        return rows, feeds, starts, n_pre, dec
+
+    def _unmask(self, blk, choice) -> int:
+        """One denoising pass's outcome for one row: fix this pass's
+        positions by the ``remasking`` rule with the device's choice
+        (``choice`` [B, 3]: best token, its logit, logsumexp). Returns how
+        many positions were fixed."""
+        live = [p for p in range(self.block_length) if blk.masked[p]]
+        n = min(self._shares[blk.passes], len(live))
+        logp = choice[:, 1] - choice[:, 2]
+        if self.remasking == "sequential":
+            picks = live[:n]
+        else:
+            picks = sorted(live, key=lambda p: -logp[p])[:n]
+            if self.remasking == "low_confidence_dynamic":
+                sure = [p for p in live
+                        if logp[p] > np.log(self.confidence_threshold)]
+                if len(sure) >= n:
+                    picks = sure
+        for p in picks:
+            blk.ids[p] = int(choice[p, 0])
+            blk.masked[p] = False
+        blk.passes += 1
+        return len(picks)
+
+    def _roll_back(self, req, n):
+        """A denoising pass commits nothing: the row's pools go back to
+        its ``n`` committed tokens (the next pass writes the slots
+        again)."""
+        for c in self.model.caches:
+            c.truncate(req.req_id, n)
+
+    def _deliver_block(self, req) -> tuple:
+        """A commit pass's outcome: the block's tokens reach the request
+        in position order (the prompt's tail as prompt tokens), up to the
+        token that ends it. Returns (tokens delivered, 1 if it retired)."""
+        blk, n = req._block, 0
+        for t in blk.ids[:blk.n_prompt]:
+            req._pos += 1
+            if req.on_token is not None:
+                req.on_token(req, t, True)
+        for t in blk.ids[blk.n_prompt:]:
+            req.generated_ids.append(t)
+            self._note_gen_token(req)
+            n += 1
+            if req.on_token is not None:
+                req.on_token(req, t, False)
+            if self._done(req, t):
+                self._retire(req)
+                return n, 1
+        self._open_block(req)
+        return n, 0
+
+    def _step_block(self, admitted, hit_tokens) -> dict:
+        """The block-diffusion scheduler step (module docstring): one
+        ragged ``prefill_chunk`` call feeds every decode row's open block
+        and the step's prompt chunks (whole blocks), and returns the
+        device's choice for every position of the decode rows. A row with
+        MASK left made a denoising pass: its slots are rolled back
+        (``truncate``) and this pass's positions are fixed; a row with
+        none made its commit pass: the pool keeps the block and its
+        tokens are delivered. ``sampler`` is not called."""
+        b = self.block_length
+        with self._span("serving.pack") as sp:
+            sids = sorted(self._active)
+            rows, feeds, starts, n_pre, dec = self._block_feeds(sids)
+            packed = sum(len(f) for f in feeds)
+            pad_to = bucket_packed_tokens(packed, self.serving_buckets)
+            if sp is not None:
+                sp.attrs.update(rows=len(rows), packed=packed,
+                                pad_to=pad_to, prefill=n_pre)
+        t_exec = telemetry.clock() if self._metrics is not None \
+            else 0.0
+        with self._span("serving.prefill_chunk", rows=len(rows),
+                        packed=packed, pad_to=pad_to, prefill=n_pre,
+                        decode=len(dec) * b):
+            out = self.model.prefill_chunk(
+                feeds, rows, starts, pad_to=pad_to, choose_rows=dec)
+        choice = self._pull(out).reshape(len(dec), b, 3)
+        if self._metrics is not None:
+            self._metrics.observe("exec.wall_s.prefill_chunk",
+                                  telemetry.clock() - t_exec)
+            self._metrics.inc("exec.count.prefill_chunk")
+
+        finished = denoised = unmasked = delivered = 0
+        with self._span("serving.block") as sp:
+            for k, bi in enumerate(dec):
+                req = self._active[rows[bi]]
+                if any(req._block.masked):
+                    unmasked += self._unmask(req._block, choice[k])
+                    self._roll_back(req, starts[bi])
+                    denoised += 1
+                else:
+                    n, done = self._deliver_block(req)
+                    delivered += n
+                    finished += done
+            for bi, s in enumerate(rows):
+                req = self._active.get(s)
+                if req is None or req.state != RequestState.PREFILL:
+                    continue
+                finished += self._advance_block_prefill(req, feeds[bi])
+            if sp is not None:
+                sp.attrs.update(
+                    rows=len(rows), denoise_rows=denoised,
+                    commit_rows=len(dec) - denoised, unmasked=unmasked,
+                    delivered=delivered)
+
+        for key, n in (("denoise_passes", denoised),
+                       ("commit_passes", len(dec) - denoised),
+                       ("tokens_unmasked", unmasked),
+                       ("blocks_committed", len(dec) - denoised)):
+            self.block_stats[key] += n
+            if self._metrics is not None:
+                self._metrics.inc("diffusion." + key, n)
+        cs = self.chunk_stats
+        cs["steps"] += 1
+        cs["chunk_calls"] += 1
+        cs["prefill_tokens"] += n_pre
+        cs["decode_tokens"] += len(dec) * b
+        cs["packed_tokens"] += packed
+        cs["padded_tokens"] += pad_to - packed
+        return {
+            "admitted": admitted,
+            "advanced": len(rows),
+            "finished": finished,
+            "prefix_hit_tokens": hit_tokens,
+            "prefill_tokens": n_pre,
+            # the tokens the decode rows FED (a block a row); what
+            # reached the requests is delivered_tokens
+            "decode_tokens": len(dec) * b,
+            "delivered_tokens": delivered,
+            "chunk_utilization": round(packed / pad_to, 4),
+            "compile_count": getattr(self.model, "compile_count",
+                                     None),
+            "attend_programs": getattr(
+                self.model, "attend_program_count", None),
+        }
+
+    def _advance_block_prefill(self, req, toks) -> int:
+        """Commit one chunk of prompt tokens of a block-diffusion row:
+        stream them, and when the prompt's whole blocks are in, open the
+        first block (or retire a prompt that was only scored). Returns 1
+        if the request retired."""
+        self._stream_prompt_chunk(req, toks)
+        if req._pos < self._prompt_end(req):
+            return 0
+        if req.max_new_tokens == 0:
+            self._retire(req)
+            return 1
+        self._open_block(req)
+        return 0
 
     def _commit_spec_row(self, s, props_i, preds_i, base_t, base_d):
         """Greedy acceptance for ONE spec-active decode row: commit

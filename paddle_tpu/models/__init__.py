@@ -12,6 +12,9 @@ this framework ships the acceptance-config model families in-tree:
 * :mod:`.evabyte` — EvaByte (byte-level; EVA window-and-summary attention,
   unit-offset norms, 8 next-byte heads), served from window-and-summary
   pages by the Llama adapter
+* :mod:`.sdar`   — SDAR-MoE (Qwen3-MoE's layer: per-head q/k norms,
+  softmax-routed drop-free experts; generation by diffusion over blocks),
+  served from K/V pages by the Llama adapter, a block of tokens a row
 * :mod:`.bert`   — BERT (bidirectional post-norm encoder, MLM +
   sequence-classification heads), non-causal flash path
 """
@@ -70,6 +73,13 @@ from .xing4 import (  # noqa: E402
     Xing4Model,
     xing4_29b_a4b,
     xing4_tiny,
+)
+from .sdar import (  # noqa: E402
+    SDARMoeConfig,
+    SDARMoeForCausalLM,
+    SDARMoeModel,
+    sdar_30b_a3b,
+    sdar_tiny,
 )
 from .evabyte import (  # noqa: E402
     EvaByteConfig,

@@ -498,8 +498,11 @@ class LlamaSparseMoeBlock(Layer):
     layer that drops nothing at any imbalance is
     ``incubate.distributed.models.moe.DroplessMoE`` (tokens sorted by
     expert, a grouped matmul with the group sizes as device data; the
-    feed-forward of ``models/xing4.py``): its router is the sigmoid
-    ``noaux_tc`` one, so this block does not use it yet."""
+    feed-forward of ``models/xing4.py`` and ``models/sdar.py``). It has
+    two routers, the sigmoid ``noaux_tc`` one and the softmax-over-all-
+    experts top-k one that Mixtral's is; this block keeps ``MoELayer``
+    for its expert-parallel dispatch and its aux loss, which
+    ``DroplessMoE`` does not have yet."""
 
     def __init__(self, config: LlamaConfig):
         super().__init__()

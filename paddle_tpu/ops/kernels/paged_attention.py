@@ -152,11 +152,13 @@ def paged_attention_reference(q, k_pages, v_pages, page_table,
 def paged_ragged_attention_reference(q, k_pages, v_pages, page_table,
                                      seq_lens, q_lens=None,
                                      sm_scale=None, window=0,
-                                     k_scales=None, v_scales=None):
+                                     k_scales=None, v_scales=None, block=0):
     """Dense float32 reference for the unified ragged kernel: q is
     (B, T, H, D) right-aligned (row i's last q_lens[i] rows are real;
     padded leading rows return exact zeros). ``q_lens=None`` treats
-    every row as real. Returns (B, T, H, D) float32."""
+    every row as real. ``block`` > 0: a query sees its own aligned block
+    of ``block`` positions whole (as far as the sequence goes) and every
+    block before it. Returns (B, T, H, D) float32."""
     import numpy as np
 
     b, t, h, d = q.shape
@@ -185,9 +187,10 @@ def paged_ragged_attention_reference(q, k_pages, v_pages, page_table,
         for r in range(t - int(ql[i]), t):
             qpos = L - t + r
             lo = max(0, qpos - window + 1) if window else 0
+            hi = min(qpos | (block - 1), L - 1) if block else qpos
             for j in range(h):
-                kj = ks[lo:qpos + 1, j // group]
-                vj = vs[lo:qpos + 1, j // group]
+                kj = ks[lo:hi + 1, j // group]
+                vj = vs[lo:hi + 1, j // group]
                 s = kj @ qn[i, r, j] * scale
                 p = np.exp(s - s.max())
                 p /= p.sum()
@@ -248,8 +251,8 @@ def ragged_few_rows(q_lens, t, group, quant=False):
 
 
 def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
-                   tm, window, quant, few, tbl_ref, lens_ref, qlens_ref,
-                   *refs):
+                   tm, window, quant, few, block, tbl_ref, lens_ref,
+                   qlens_ref, *refs):
     """THE unified kernel, grid (row b, page block p), steps in order. A
     live step holds the block's K pages and V pages — at most ``ppb``,
     only those the row has — in VMEM in the pool's own layout
@@ -270,7 +273,11 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
       (:func:`_few_row_pages`); at ``t`` = 1 every row is such a row and
       the kernel holds no head-major buffer.
 
-    Causal; ``window`` > 0 bands the mask (0 <= qpos - kpos < window).
+    Causal; ``window`` > 0 bands the mask (0 <= qpos - kpos < window);
+    ``block`` > 0 (a power of two) lets a query see its own aligned
+    block of positions whole, as far as the row's length: causal across
+    blocks, bidirectional inside one (the few-row form's one token is
+    the row's last, and sees every key either way).
     ``quant``: int8 pages dequantised on the way by the scalar-prefetched
     per-page, per-head scale sidecars. Online softmax state (m, l, acc)
     stays in VMEM across the page axis, float32. Blocks beyond the row's
@@ -432,7 +439,11 @@ def _ragged_kernel(scale, page_size, ppb, n_steps, n_rows, t, group, kvh,
                     jnp.int32, s.shape, 0))
                 # token r is absolute position seq_len - t + r
                 qpos = seq_len - t + tok
-                keep = (kpos <= qpos) & (tok >= t - q_len)
+                if block:
+                    keep = (kpos <= (qpos | (block - 1))) \
+                        & (kpos < seq_len) & (tok >= t - q_len)
+                else:
+                    keep = (kpos <= qpos) & (tok >= t - q_len)
                 if window:
                     keep = keep & (qpos - kpos < window)
                 s = jnp.where(keep, s, NEG_INF)
@@ -563,7 +574,7 @@ def _few_row_pages(scale, window, seq_len, base, first, pages, q_ref, k_in,
 def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
                            q_lens=None, sm_scale=None, interpret=None,
                            window=0, k_scales=None, v_scales=None,
-                           counts=None):
+                           counts=None, block=0):
     """The unified ragged paged-attention entry (PAPERS.md: Ragged
     Paged Attention) — ONE kernel for decode rows and prefill chunks.
 
@@ -577,7 +588,9 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
     pages: pass k_scales/v_scales (NP, KVH) as in
     :func:`paged_attention`. ``counts``: what the caller knows of the
     call on the host (``few_row_rows``), attributes of the
-    ``kernel.ragged`` span.
+    ``kernel.ragged`` span. ``block`` (static, 0 = causal): a power of
+    two; a query sees its own aligned block of that many positions whole
+    and every block before it (generation by diffusion over blocks).
     """
     b, t, h, d = q.shape
     npages, page_size, kvh, _ = k_pages.shape
@@ -602,7 +615,7 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
                         v_scales.astype(jnp.float32).reshape(-1)]
     cfg = (b, t, h, d, npages, page_size, kvh, max_pages,
            float(scale), int(window or 0), quant, ragged,
-           bool(interpret))
+           bool(interpret), _block_of(block))
     args = (q, k_pages, v_pages, *scalar_args)
     if any(isinstance(x, jax.core.Tracer) for x in args):
         return _build_ragged_call(*cfg)(*args)
@@ -613,8 +626,18 @@ def paged_ragged_attention(q, k_pages, v_pages, page_table, seq_lens,
         return _jitted_ragged_call(cfg)(*args)
 
 
+def _block_of(block):
+    """``block`` as the kernel takes it: 0, or a power of two."""
+    block = int(block or 0)
+    if block & (block - 1):
+        raise ValueError(
+            f"ragged attention: block={block} has to be a power of two "
+            "(the mask is kpos <= qpos | (block - 1))")
+    return block
+
+
 def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
-                       scale, window, quant, ragged, interpret):
+                       scale, window, quant, ragged, interpret, block=0):
     """The unified ragged pallas dispatch as a pure function of the
     static config: returns ``run(q, k_pages, v_pages, *scalar_args)``.
     Traced callers inline it; eager callers go through
@@ -678,7 +701,7 @@ def _build_ragged_call(b, t, h, d, npages, page_size, kvh, max_pages,
         out = pl.pallas_call(
             functools.partial(
                 _ragged_kernel, scale, page_size, ppb, n_steps, b, t,
-                group, kvh, tm, window, quant, few),
+                group, kvh, tm, window, quant, few, block),
             name="ragged_paged_attention",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, *shape_out), q.dtype),
@@ -719,9 +742,18 @@ def pool_pages(k, v, phi, mu, scale):
     return (jnp.sum(a * kf, 1) + mu.astype(jnp.float32), jnp.sum(a * vf, 1))
 
 
+def head_rms(x, gain, eps):
+    """RMSNorm over a head's numbers (x [..., heads, head_dim], one gain
+    vector a layer), in float32, rounded once to the stream's type."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
 def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
                       t_pad, max_pages, scale, window, has_bias, eps,
-                      interpret, unit_offset=False, n_sum=0):
+                      interpret, unit_offset=False, n_sum=0, block=0,
+                      qk_norm=False, router=None):
     """One decoder layer of the packed serving step as ONE program:
     ``rms_norm`` -> qkv projection + RoPE + this chunk's K/V page scatter
     -> the ragged kernel over the right-aligned rows -> scatter back +
@@ -758,7 +790,17 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
       n_sum), for every page this step's scatter fills the page, then
       the page and slot of its summary row; padding drops): the filled
       pages are gathered, pooled (:func:`pool_pages`) and scattered
-      into the same pools before the kernel reads them.
+      into the same pools before the kernel reads them;
+    * three more, off likewise. ``block``: the kernel's block-causal
+      mask (:func:`paged_ragged_attention`). ``qk_norm`` (two more
+      operands, ``gq``/``gk`` (hd,)): an RMSNorm over each query and key
+      head's numbers before RoPE. ``router`` = (top_k, norm_topk,
+      scoring): the second static body of the feed-forward, the
+      drop-free routed experts (``dropless_moe``, a pure function of
+      arrays): ``wg/wu`` are (E, e, f) and ``wd`` (E, f, e) stacks, one
+      more operand ``wr`` (e, E) is the router, the packed axis's padding
+      (``mflat`` out of bounds) is routed nowhere, and the per-expert
+      assignment counts (E,) come back beside ``x``.
 
     Returns ``(x_out (n_pad, e), new_k_pages, new_v_pages)`` — the caller
     (the pool, which owns page state) commits the returned pages. The
@@ -768,7 +810,9 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
     """
     attend = _build_ragged_call(
         b_pad, t_pad, nh, hd, npages, page_size, kvh, max_pages,
-        scale, window, False, True, interpret)
+        scale, window, False, True, interpret, block)
+    if router is not None:
+        from ...incubate.distributed.models.moe.dropless import dropless_moe
 
     def run(k_pages, v_pages, x, ln1, wq, wk, wv, wo, *rest):
         rest = list(rest)
@@ -776,7 +820,9 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
         if has_bias:
             bq, bk, bv = rest[:3]
             rest = rest[3:]
-        ln2, wg, wu, wd, cos, sin, tok, gm, slots, rows, *eva = rest
+        ln2, wg, wu, wd, cos, sin, tok, gm, slots, rows, *rest = rest
+        eva = [rest.pop(0) for _ in range(3 if n_sum else 0)]
+        gq, gk = (rest.pop(0), rest.pop(0)) if qk_norm else (None, None)
         _, pos, mr, mc, mflat = tok
         (pg, of), tbl = slots, rows[:, :max_pages]
         lens, q_lens = rows[:, max_pages], rows[:, max_pages + 1]
@@ -791,6 +837,8 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
         qh = xq.reshape(1, n_pad, nh, hd)
         kh = xk.reshape(1, n_pad, kvh, hd)
         vh = xv.reshape(n_pad, kvh, hd)
+        if qk_norm:
+            qh, kh = head_rms(qh, gq, eps), head_rms(kh, gk, eps)
         qh = apply_rotary_emb(qh, cos, sin, position_ids=pos)[0]
         kh = apply_rotary_emb(kh, cos, sin, position_ids=pos)[0]
         # land this chunk's K/V in the pages (the pool computed the
@@ -812,6 +860,12 @@ def _build_layer_call(n_pad, e, nh, kvh, hd, npages, page_size, b_pad,
         attn = attn.at[mflat].set(out[mr, mc], mode="drop")
         x = x + jnp.matmul(attn.reshape(n_pad, nh * hd), wo)
         h2 = rms_norm(x, ln2, eps)
+        if router is not None:
+            top_k, norm_topk, scoring = router
+            y, sizes = dropless_moe(
+                h2, rest[0], None, wg, wu, wd, None, top_k,
+                norm_topk=norm_topk, valid=mflat < n_pad, scoring=scoring)
+            return x + y, kp, vp, sizes
         y = jnp.matmul(
             jax.nn.silu(jnp.matmul(h2, wg)) * jnp.matmul(h2, wu), wd)
         return x + y, kp, vp
@@ -1146,7 +1200,8 @@ def upload_plan(*arrays):
 def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
                             eps, sm_scale=None, window=0,
                             interpret=None, unit_offset=False,
-                            summary=None, counts=None):
+                            summary=None, counts=None, block=0,
+                            qk_norm=None, router=None):
     """One decoder layer of the packed serving step, one dispatch (see
     :func:`_build_layer_call` for the operand contract). ``weights`` =
     (ln1, wq, wk, wv, wo, biases, ln2, wg, wu, wd) with ``biases``
@@ -1163,6 +1218,11 @@ def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
     (a window-and-summary step's exact ``fed`` / ``pairs`` / ``kv_rows`` /
     ``summaries_written``, from the pool's table, and the step's
     ``few_row_rows``) ride the ``kernel.ragged`` span as attributes.
+    Three more switches, each off unless given: ``block`` (the kernel's
+    block-causal mask), ``qk_norm`` = (gq, gk), the per-head q/k norm
+    gains, and ``router`` = (wr, top_k, norm_topk, scoring) of a routed
+    feed-forward whose ``wg/wu/wd`` are the experts' stacks; with it the
+    per-expert assignment counts come back as a fourth result.
     """
     from ...distributed.mesh import global_mesh
 
@@ -1183,12 +1243,15 @@ def paged_ragged_layer_step(k_pages, v_pages, x, weights, rope, index,
     cfg = (n_pad, e, nh, kvh, hd, npages, page_size,
            b_pad, t_pad, max_pages, float(scale), int(window or 0),
            has_bias, float(eps), bool(interpret), bool(unit_offset),
-           summary[2].shape[1] if summary else 0)
+           summary[2].shape[1] if summary else 0, _block_of(block),
+           qk_norm is not None, tuple(router[1:]) if router else None)
     with telemetry.span("kernel.ragged", rows=b_pad, t=t_pad,
                         max_pages=max_pages, fused=1,
                         grid_steps=_ragged_grid_steps(b_pad, max_pages),
+                        **({"block": cfg[-3]} if block else {}),
                         **(counts or {})):
         return _jitted_layer_step(cfg, on_tpu(), global_mesh())(
             k_pages, v_pages, x, ln1, wq, wk, wv, wo,
             *(biases if has_bias else ()), ln2, wg, wu, wd, cos, sin,
-            *index, *(summary or ()))
+            *index, *(summary or ()), *(qk_norm or ()),
+            *(router[:1] if router else ()))

@@ -1,8 +1,10 @@
 """Tier-1's view of the seam between the benchmark's harness and an
 architecture: the pin and seam tests of ``benchmarks/tests/
 test_families.py`` (not collected from there by tier-1), and the CPU
-rehearsal of ``benchmarks/run.py``'s serving driver with a tiny ``xing4``
-and a tiny ``evabyte`` configuration through to ``correct: true``; the
+rehearsal of ``benchmarks/run.py``'s serving driver with a tiny ``xing4``,
+a tiny ``evabyte`` and a tiny ``sdar`` configuration through to
+``correct: true`` (and, for ``sdar``, two planted faults through to
+``correct: false``); the
 ``evabyte`` family's own seam, traffic and reader tests
 (``benchmarks/tests/test_evabyte_family.py``) are collected here too."""
 import os
@@ -29,6 +31,7 @@ from benchmarks.tests import tiny  # noqa: E402
 paddle.set_flags(_PREV)
 
 from evabyte_tiny_config import tiny_config as evabyte_tiny  # noqa: E402
+from sdar_tiny_config import tiny_config as sdar_tiny  # noqa: E402
 from xing4_tiny_config import tiny_config  # noqa: E402
 
 
@@ -127,3 +130,106 @@ def test_the_rehearsal_of_a_tiny_evabyte_cell_is_correct(tmp_path):
     assert share is not None and 0 < share < 100
     # the CPU's trace holds no Mosaic kernel: the reader reads nothing
     assert common.read_metric("eva_attention_roofline.serve", ctx) is None
+
+
+def _sdar_rehearsal(tmp_path, seconds=2.0, trace=True):
+    from benchmarks.lib import common, serve
+    from paddle_tpu.framework import telemetry
+
+    if telemetry.peek_tracer() is not None:
+        telemetry.peek_tracer().clear()
+    config = sdar_tiny()
+    config["assumed"]["order_margin"] = 0.05
+    bench = common.load_benchmark()
+    cell = {w["name"]: w for w in bench["workloads"]}[
+        "sdar-30b-a3b-serve.blocks-closed64"]
+    fam = common.load_family(config)
+    # (the CPU makes a hundred steps a second here: enough rounds to
+    # outlast the window, or the pump idles and it never closes)
+    mix = dict(tiny.tiny_serve_mix(), clients=4, rounds=80,
+               prompt_len={"dist": "fixed", "value": 24},
+               output_len={"dist": "fixed", "value": 16})
+    out = serve.run(bench, cell, config, fam, mix, 2**31 + 77, seconds,
+                    trace, time.perf_counter(), tiny.CPU_DEVICE,
+                    tiny.CPU_PEAKS, trace_dir=str(tmp_path),
+                    limits={"served_gap": 1.75})
+    return out, cell, config, fam, mix
+
+
+def test_the_rehearsal_of_a_tiny_sdar_cell_is_correct(tmp_path):
+    """The serving driver end to end on the CPU over a block-diffusion
+    model: the family's build under LazyGuard, the seed's leaves,
+    BatchScheduler (warmed: no program is built inside the window) over
+    the Llama adapter with the routed layer program behind ServingEngine,
+    prompts of 24 and answers of 16 in blocks of 4 at two passes a block,
+    a traced window whose spans the new readers read, the reference's
+    replay of the sampled requests with the served tokens forced. The
+    seed's leaves are bfloat16, so the program computes in bfloat16
+    against the float32 reference: its logits lie 0.01-0.03 from the
+    reference's and a served token up to 0.06 under the reference's best
+    where two logits nearly tie; a block whose unmasking order was a near
+    tie (``order_margin`` 0.05) is not judged: at this size a position
+    fixed in another pass reads another context. ``served_gap`` is the
+    family's sum (``loss_share``: the served tokens' summed loss over the
+    loss of a program blind to ties under ``tie_margin``, the denominator
+    at least ``tie_floor``): 0.08 here, 0.00-0.08 over five seeds, and
+    the two planted faults below 2.7 and 3.7 (measured here, PR 36).
+    Limit 1.75, the cell's own; the harness's ``top_logit`` stays None (the
+    sampler is never called), so ``logit_err`` is not formed."""
+    from benchmarks.lib import common
+
+    out, cell, config, fam, mix = _sdar_rehearsal(tmp_path)
+    assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
+    assert set(out["compared"]) == {"served_gap"}
+    assert out["counters"]["builds_in_window"] == 0
+    red, _ = common.reduce_trace(str(tmp_path), out["sync_ns"],
+                                 out["window_ns"], out["build_spans"])
+    ctx = {"cell": cell, "config": config, "traffic": mix,
+           "peaks": tiny.CPU_PEAKS, "window_s": out["window_s"],
+           "counters": out["counters"], "trace": red, "flops": fam,
+           "chips": 1}
+    ratio = common.read_metric("block_passes_per_token.serve", ctx)
+    if ratio is not None:           # read only where the spans could be laid
+        assert 0.6 <= ratio <= 0.9  # (T + 1) / B = 0.75 but for open blocks
+    spread = common.read_metric("expert_tokens_max_over_mean.serve", ctx)
+    if spread is not None:
+        assert 1.0 <= spread <= config["num_experts"]
+    # the CPU's trace holds no Mosaic kernel and no grouped matmul by name
+    assert common.read_metric("block_attention_roofline.serve", ctx) is None
+    assert common.read_metric("moe_matmul_roofline.serve", ctx) is None
+    assert common.read_metric("step_mfu.serve", ctx) > 0
+
+
+@pytest.mark.parametrize("fault", ("causal_inside_the_block",
+                                   "no_commit_pass"))
+def test_a_faulty_block_program_is_not_correct(tmp_path, monkeypatch, fault):
+    """Two faults a block-diffusion server can have and still emit
+    tokens, planted in the program for one rehearsal: a mask that is
+    causal inside the block (the kernel handed ``block`` 0), and a
+    scheduler that skips the commit pass (delivers a block straight from
+    its last denoising pass and keeps that pass's K/V, written while some
+    of the block was still MASK: later blocks read them). Both read
+    ``correct: false`` by ``served_gap`` (2.7 and 3.7 against the sound
+    program's 0.08 and the limit of 1.75, measured here, PR 36)."""
+    from paddle_tpu.inference import serving
+    from paddle_tpu.inference.paged_llama import PagedLlamaAdapter
+
+    if fault == "causal_inside_the_block":
+        inner = PagedLlamaAdapter._layer_switches
+
+        def switches(self, layer):
+            return dict(inner(self, layer), block=0)
+
+        monkeypatch.setattr(PagedLlamaAdapter, "_layer_switches", switches)
+    else:
+        inner = serving.BatchScheduler._roll_back
+
+        def roll_back(self, req, n):
+            if any(req._block.masked):
+                return inner(self, req, n)
+            self._deliver_block(req)     # the last denoising pass's K/V stay
+
+        monkeypatch.setattr(serving.BatchScheduler, "_roll_back", roll_back)
+    out, *_ = _sdar_rehearsal(tmp_path, seconds=1.0, trace=False)
+    assert not out["correct"]
+    assert not out["compared"]["served_gap"]["ok"], out["compared"]

@@ -330,6 +330,84 @@ def test_eva_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
                           text)) == 1
 
 
+# sdar-30b-a3b-serve.blocks-closed64's steps (SDAR-30B-A3B's widths:
+# hidden 2048, 32 query / 4 KV heads of 128, 128 experts of 768 top-8;
+# 5,120 pages; tables of 32 and 64 pages): 64 rows of one block of 4
+# tokens in the 256 bucket, and beside a 256-token prompt chunk in the 512
+@pytest.mark.parametrize("n_pad,b_pad,t_pad,mp", [
+    (256, 64, 4, 32), (256, 64, 4, 64), (256, 64, 256, 64),
+    (512, 64, 256, 64),
+])
+def test_sdar_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
+    """The layer program with its three other switches on at SDAR's
+    widths: the kernel under the block-causal mask at group 8 with rows
+    of 4 tokens (32 query rows a KV head: one tile), the per-head q/k
+    norms, and the routed feed-forward as the program's second body (the
+    router in float32, the sort by expert, three grouped matmuls over the
+    [128, 2048, 768] stacks, the combine) with the per-expert counts as a
+    fourth result. Both pools come back in the buffers they came in."""
+    import re
+
+    import paddle_tpu.ops.kernels as kernels
+    from paddle_tpu.ops.kernels.paged_attention import _build_layer_call
+
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    e, kvh, ne, f, npages = 2048, 4, 128, 768, 5120
+    run = _build_layer_call(
+        n_pad, e, H, kvh, D, npages, PAGE, b_pad, t_pad, mp, D ** -0.5, 0,
+        False, 1e-6, False, False, 0, 4, True, (8, True, "softmax"))
+    i32, pool = jnp.int32, ((npages, PAGE, kvh, D), BF16)
+    specs = [pool, pool, ((n_pad, e), BF16), ((e,), BF16),
+             ((e, H * D), BF16), ((e, kvh * D), BF16), ((e, kvh * D), BF16),
+             ((H * D, e), BF16), ((e,), BF16), ((ne, e, f), BF16),
+             ((ne, e, f), BF16), ((ne, f, e), BF16),
+             ((1024, D), jnp.float32), ((1024, D), jnp.float32),
+             ((5, n_pad), i32), ((b_pad, t_pad), i32), ((2, n_pad), i32),
+             ((b_pad, mp + 2), i32), ((D,), BF16), ((D,), BF16),
+             ((e, ne), BF16)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    compiled = jax.jit(run, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    head = text.splitlines()[0]
+    assert re.search(r"\{1\}: \(0, \{\}", head), head[:300]
+    assert re.search(r"\{2\}: \(1, \{\}", head), head[:300]
+    assert len(re.findall(r"ragged_paged_attention/pallas_call",
+                          text)) == 1
+    # no expert stack is copied or cast whole: the grouped matmuls read
+    # the operands where they are
+    stacks = [ln for ln in text.splitlines()
+              if re.search(r"= \w+\[%d,(%d,%d|%d,%d)\]" % (ne, e, f, f, e),
+                           ln) and " parameter(" not in ln]
+    assert stacks == [], stacks[:3]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+
+
+def test_sdar_choose_program(one_chip, monkeypatch):
+    """The device-side choice at SDAR's widths: 64 rows x 4 positions
+    through the final norm and the 151,936-wide head in float32, the MASK
+    id's logit left out, (token, its logit, logsumexp) a position."""
+    import types
+
+    import paddle_tpu.ops.kernels as kernels
+    from paddle_tpu.inference.paged_llama import PagedLlamaAdapter
+
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    norm = types.SimpleNamespace(_epsilon=1e-6)
+    ad = types.SimpleNamespace(
+        _programs=None, block_length=4, mask_token_id=151669,
+        cfg=types.SimpleNamespace(),
+        model=types.SimpleNamespace(
+            lm_head=object(), model=types.SimpleNamespace(norm=norm)),
+        _unit_offset=lambda n: False)
+    choose = PagedLlamaAdapter._step_programs(ad)[3]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((512, 2048), BF16), ((256,), jnp.int32), ((2048,), BF16),
+        ((2048, 151936), BF16))]
+    compiled = choose.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+
+
 @pytest.mark.parametrize("b,t,max_pages", [
     (64, 1, 256), (64, 64, 256), (64, 16, 64), (8, 1, 1),
 ])
