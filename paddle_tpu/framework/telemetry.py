@@ -1574,10 +1574,14 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "and rounded down a step (exact: assignments / (calls x E))"),
     # window-and-summary pools (page_format="eva")
     ("diffusion.denoise_passes", "counter",
-     "row-passes of a block-diffusion step that committed nothing "
-     "(BatchScheduler._step_block; the pool rolled back)"),
+     "row-passes of a block-diffusion step (BatchScheduler._step_block; "
+     "the open block's slots rolled back), whatever they carried"),
     ("diffusion.commit_passes", "counter",
-     "row-passes that committed a finished block's K/V"),
+     "row-passes that fed a finished block alone to write its K/V: none, "
+     "a block's commit rides the next block's first pass"),
+    ("diffusion.commits_carried", "counter",
+     "row-passes that also wrote the K/V of the finished block behind "
+     "the open one (a row of two blocks)"),
     ("diffusion.tokens_unmasked", "counter",
      "positions fixed by denoising passes (the remasking rule's picks)"),
     ("diffusion.blocks_committed", "counter",

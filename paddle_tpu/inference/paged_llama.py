@@ -36,10 +36,12 @@ model: per-head q/k norm gains, a routed feed-forward (``DroplessMoE``:
 the layer program's second static body; the per-expert counts come back
 beside the stream and cross with the step's pull) and the block length,
 handed to the kernel as its block-causal mask. ``prefill_chunk(...,
-choose_rows=)`` runs the head over every position of the listed rows ON
-THE DEVICE and returns, a position, (best token, its logit, logsumexp):
-the MASK id's logit is left out, and 3 numbers cross where 151,936 would.
-The programmed body only; ``warm`` as above.
+choose_rows=)`` lists rows of one block, or of two (a finished block whose
+K/V the pass writes, and the open one behind it), runs the head over
+every position of each listed row's LAST block ON THE DEVICE and returns,
+a position, (best token, its logit, logsumexp): the MASK id's logit is
+left out, and 3 numbers cross where 151,936 would. The programmed body
+only; ``warm`` as above.
 """
 from __future__ import annotations
 
@@ -396,12 +398,14 @@ class PagedLlamaAdapter(PagedAdapterBase):
         bucketed ragged family.
 
         ``choose_rows`` (a block-diffusion model only): a list of row
-        indices, each a row of ``block_length`` tokens, whose every
-        position is chosen ON THE DEVICE. The call then returns one
-        float32 array [len(choose_rows) * block_length, 3], a position
-        (best token, its logit, logsumexp), the MASK id's logit left out,
-        in place of any logits; what else rides the step's pull (the
-        routed layers' per-expert counts) comes with it.
+        indices, each a row of one or two blocks of ``block_length``
+        tokens; every position of a listed row's LAST block is chosen ON
+        THE DEVICE (a first block is a finished one, fed for its K/V).
+        The call then returns one float32 array [len(choose_rows) *
+        block_length, 3], a position (best token, its logit, logsumexp),
+        the MASK id's logit left out, in place of any logits; what else
+        rides the step's pull (the routed layers' per-expert counts)
+        comes with it.
 
         All dense compute (embed / qkv / o_proj / mlp / norms) runs over
         ONE flat packed token axis padded to ``pad_to`` (the scheduler
@@ -441,7 +445,10 @@ class PagedLlamaAdapter(PagedAdapterBase):
             if blk:
                 self._check_blocks(rows, seq_ids, choose_rows)
                 b_pad = max(b_pad, self._rows_pad)
-                t_pad = blk if t_pad <= blk else max(t_pad, self._chunk_pad)
+                # a closed list: a block where every row feeds one, two
+                # where some row feeds two, the chunk's pad beyond
+                t_pad = max(t_pad, blk) if t_pad <= 2 * blk \
+                    else max(t_pad, self._chunk_pad)
                 rows.mp_pad = max(rows.mp_pad, self._table_pad)
             gm, mr, mc, m_flat = _right_align_plan(
                 range(b), rows.starts, counts, t_pad, b_pad)
@@ -465,11 +472,12 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 host = [tok, gm, _pad_plan(rows.last_idx, b_pad, 0)
                         if self._eva or blk else rows.last_idx]
                 if choose_rows is not None:
-                    # the head runs over rows x block positions whatever
-                    # the step holds (slot 0 again, cut on the host)
+                    # the head runs over rows x the positions of a row's
+                    # last block whatever the step holds (slot 0 again,
+                    # cut on the host)
                     host[2] = _pad_plan(_position_index(
-                        rows.starts, counts, choose_rows) if choose_rows
-                        else (), b_pad * blk, 0)
+                        rows.last_idx + 1 - blk, [blk] * b, choose_rows)
+                        if choose_rows else (), b_pad * blk, 0)
                 if logits_rows is not None:
                     host.append(_position_index(rows.starts, counts,
                                                 logits_rows))
@@ -491,18 +499,20 @@ class PagedLlamaAdapter(PagedAdapterBase):
         """A block-diffusion step's rows: every row resumes on a block
         boundary (a prompt chunk ends on one, a generated block is fed
         whole), and a row whose positions are chosen on the device is one
-        block."""
+        block, or two: a finished block and the open one."""
         blk = self.block_length
         off = [s for s, n in zip(seq_ids, rows.lens0) if n % blk]
         if off:
             raise ValueError(
                 f"prefill_chunk: sequences {off} do not resume on a "
                 f"boundary of block_length={blk}")
-        bad = [i for i in choose_rows or () if rows.counts[i] != blk]
+        bad = [i for i in choose_rows or ()
+               if rows.counts[i] not in (blk, 2 * blk)]
         if bad:
             raise ValueError(
-                f"prefill_chunk: choose_rows {bad} are not rows of "
-                f"block_length={blk} tokens")
+                f"prefill_chunk: choose_rows {bad} are not rows of one or "
+                f"two blocks of block_length={blk} tokens (counts "
+                f"{[rows.counts[i] for i in bad]})")
 
     def chunk_room(self, seq_id):
         """Tokens one step may feed the sequence (the scheduler clamps a
@@ -515,10 +525,11 @@ class PagedLlamaAdapter(PagedAdapterBase):
         steps of a window-and-summary or a block-diffusion model
         (``BatchScheduler.warm`` calls it with its batch size, packed
         widths and chunk size): ``rows`` sequences decoding (a token a
-        row, or a block), alone or beside prompt chunks of at most
-        ``chunk_tokens`` tokens, at each packed width (the two it is
-        told of and the doublings between them) and each table width up
-        to ``max_length``. From here on every step pads
+        row; a block, or two in the pass that carries a finished one),
+        alone or beside prompt chunks of at most ``chunk_tokens`` tokens,
+        at each packed width (those it is told of and the doublings
+        between them) that rows of so many tokens can fill, and each
+        table width up to ``max_length``. From here on every step pads
         its rows to ``rows`` and a multi-token row to ``chunk_tokens``,
         so the list is short and closed. Each program runs once on
         zeros over the pools themselves: every slot and summary plan
@@ -536,9 +547,11 @@ class PagedLlamaAdapter(PagedAdapterBase):
         z, i32 = np.zeros, np.int32
         top = _pow2(pool.table_pages(self.max_length))
         if blk:
-            # tables of 32 pages (512 tokens) at least: two widths serve
-            # rows of up to 1,024 tokens
-            self._table_pad = min(32, top)
+            # tables of 64 pages (1,024 tokens) at least: a width costs
+            # set-up a program for every (packed width, pad) below, a
+            # shorter row's dead block of pages the kernel a skipped grid
+            # step
+            self._table_pad = min(64, top)
         mps, mp = [], pool.window_pages if self._eva else self._table_pad
         while mp <= top:
             mps.append(mp)
@@ -553,18 +566,22 @@ class PagedLlamaAdapter(PagedAdapterBase):
             n *= 2
             packed.add(n)
         packed = sorted(packed)
-        one = blk or 1                      # a decode row's tokens
-        for n_pad in packed:
+        # a decode row's tokens: one, a block, or two blocks
+        t_pads = sorted({blk or 1, 2 * blk or 1, self._chunk_pad})
+        for below, n_pad in zip([0] + packed, packed):
+            self._dispatch_shapes.add(n_pad)
             tok = z((5, n_pad), i32)
             tok[4] = n_pad
             slots = z((2, n_pad), i32)
             slots[0] = pool.num_pages
             sums = z((3, n_pad // pool.page_size + b_pad), i32)
             sums[1] = pool.num_pages
-            for t_pad in (one, self._chunk_pad):
-                if t_pad == one and n_pad != min(packed):
-                    continue      # decode rows alone: the least width
+            for t_pad in t_pads:
+                if rows * t_pad <= below:
+                    continue      # rows this short pack to a lesser width
                 for mp in mps:
+                    self._count_kernel_shape(
+                        n_pad, ("ragged_fused", b_pad, t_pad, mp, n_pad))
                     tok_d, gm, slots_d, rows_d, sums_d = _upload(
                         tok, z((b_pad, t_pad), i32), slots,
                         z((b_pad, mp + 2), i32), sums)

@@ -27,14 +27,21 @@ token per step (latency stays flat) while prefill saturates the chip;
 a 432-token prompt costs ceil(432/budget) steps instead of 432.
 
 Generation by diffusion over blocks (a model that declares
-``block_length``; models/sdar.py, docs/SERVING.md): a DECODE row feeds
-its open block's B ids every step — MASK at the positions not fixed yet —
-and gets back, a position, the best token and its probability, chosen on
-the device. A denoising pass commits nothing (the pool is rolled back)
-and fixes positions by the ``remasking`` rule; once no MASK is left one
-clean pass commits the block's K/V and its B tokens are delivered
-together. So a row receives B tokens every ``denoising_steps`` + 1
-passes at most (:meth:`BatchScheduler._step_block`).
+``block_length``; models/sdar.py, docs/SERVING.md): a DECODE row's state
+is its open block's B ids — MASK at the positions not fixed yet — and,
+behind it, the ids of the finished block whose K/V is not in the pages
+yet (none for a request's first block). Every step the row feeds its
+open block and gets back, a position, the best token and its
+probability, chosen on the device; the pass fixes positions by the
+``remasking`` rule and the open block's slots are rolled back. The pass
+that fixes a block's last position delivers its B tokens together; a
+request that ends there retires, and its last block's K/V is never
+written. Otherwise the block rides the NEXT block's first pass as one
+row of 2B tokens (the kernel's mask lets a later block of a row see an
+earlier one whole), which leaves its K/V in the pages: the one clean
+pass of the published generator, with no pass of its own. So a row
+receives B tokens every ``denoising_steps`` passes at most
+(:meth:`BatchScheduler._step_block`).
 
 Admission control: a request is admitted only while (a) the active
 batch is below ``max_batch_size`` and (b) the page pool would stay
@@ -310,15 +317,18 @@ class _Block:
     """A DECODE row's open block over a block-diffusion model: its B
     current ``ids`` (MASK where ``masked``), how many leading positions
     are the prompt's tail (never masked: the row's own state, a prompt or
-    chosen token may equal the MASK id), and the denoising passes made."""
+    chosen token may equal the MASK id), the denoising passes made, and
+    ``behind``: the ids of the finished block before it while its K/V is
+    not in the pages (the open block's first pass carries them)."""
 
-    __slots__ = ("ids", "masked", "n_prompt", "passes")
+    __slots__ = ("ids", "masked", "n_prompt", "passes", "behind")
 
-    def __init__(self, tail, size, mask_id):
+    def __init__(self, tail, size, mask_id, behind=()):
         self.n_prompt = len(tail)
         self.ids = list(tail) + [mask_id] * (size - len(tail))
         self.masked = [False] * len(tail) + [True] * (size - len(tail))
         self.passes = 0
+        self.behind = list(behind)
 
 
 REMASKING = ("low_confidence_dynamic", "low_confidence_static", "sequential")
@@ -675,7 +685,8 @@ class BatchScheduler:
         # a pass's share of the block: its ceil-split over the passes
         self._shares = [b // t + (s < b % t) for s in range(t)]
         self.block_stats = {"denoise_passes": 0, "commit_passes": 0,
-                            "tokens_unmasked": 0, "blocks_committed": 0}
+                            "commits_carried": 0, "tokens_unmasked": 0,
+                            "blocks_committed": 0}
 
     # -- set-up ------------------------------------------------------------
     def warm(self):
@@ -690,9 +701,12 @@ class BatchScheduler:
         if warm is None:
             return
         rows, chunk = self.max_batch_size, self.prefill_chunk_tokens
-        fed = rows * (self.block_length or 1)   # a decode row feeds a block
+        # a decode row feeds a token; over a block model its open block,
+        # and in that block's first pass the finished one behind it too
+        fed = rows * (self.block_length or 1)
+        most = 2 * fed if self.block_length else fed
         warm(rows, {bucket_packed_tokens(n, self.serving_buckets)
-                    for n in (fed, fed + chunk)}, chunk)
+                    for n in (fed, most, most + chunk)}, chunk)
 
     # -- pool accounting ---------------------------------------------------
     def _pool(self, model=None):
@@ -2507,17 +2521,19 @@ class BatchScheduler:
         n = len(req.prompt_ids)
         return n if req.max_new_tokens == 0 else n - n % self.block_length
 
-    def _open_block(self, req):
-        """The block at the row's committed length: the prompt's tail, if
-        the prompt ends inside it, and MASK behind."""
-        base = self.model.caches[0].seq_len(req.req_id)
+    def _open_block(self, req, behind=()):
+        """The block after what the row holds (the committed tokens and
+        ``behind``, the finished block not in the pages yet): the
+        prompt's tail, if the prompt ends inside it, and MASK behind."""
+        base = self.model.caches[0].seq_len(req.req_id) + len(behind)
         req._block = _Block(req.prompt_ids[base:], self.block_length,
-                            self.model.mask_token_id)
+                            self.model.mask_token_id, behind)
         req.state = RequestState.DECODE
 
     def _block_feeds(self, sids):
-        """Pack one block step: EVERY decode row's open block (B ids at
-        its committed length) plus up to ``prefill_chunk_tokens`` pending
+        """Pack one block step: EVERY decode row's open block at its
+        committed length (B ids; 2B in the pass that carries the finished
+        block behind it) plus up to ``prefill_chunk_tokens`` pending
         prompt tokens in whole blocks. Returns (rows, feeds, starts,
         prefill_tokens, indices of the decode rows)."""
         b = self.block_length
@@ -2543,7 +2559,7 @@ class BatchScheduler:
                 self._open_block(req)   # a prompt shorter than a block
             dec.append(len(rows))
             rows.append(s)
-            feeds.append(list(req._block.ids))
+            feeds.append(req._block.behind + req._block.ids)
             starts.append(self.model.caches[0].seq_len(s))
         return rows, feeds, starts, n_pre, dec
 
@@ -2571,16 +2587,20 @@ class BatchScheduler:
         return len(picks)
 
     def _roll_back(self, req, n):
-        """A denoising pass commits nothing: the row's pools go back to
-        its ``n`` committed tokens (the next pass writes the slots
-        again)."""
+        """The open block's slots hold a pass's K/V, not the block's: the
+        row's pools go back to ``n`` tokens, its committed length with the
+        finished block this pass carried (the next pass writes the open
+        block's slots again)."""
         for c in self.model.caches:
             c.truncate(req.req_id, n)
 
     def _deliver_block(self, req) -> tuple:
-        """A commit pass's outcome: the block's tokens reach the request
-        in position order (the prompt's tail as prompt tokens), up to the
-        token that ends it. Returns (tokens delivered, 1 if it retired)."""
+        """The outcome of the pass that fixed the block's last position:
+        the block's tokens reach the request in position order (the
+        prompt's tail as prompt tokens), up to the token that ends it: a
+        request that ends here retires and its last block's K/V is never
+        written. Otherwise the next block opens with this one behind it.
+        Returns (tokens delivered, 1 if it retired)."""
         blk, n = req._block, 0
         for t in blk.ids[:blk.n_prompt]:
             req._pos += 1
@@ -2595,18 +2615,19 @@ class BatchScheduler:
             if self._done(req, t):
                 self._retire(req)
                 return n, 1
-        self._open_block(req)
+        self._open_block(req, behind=blk.ids)
         return n, 0
 
     def _step_block(self, admitted, hit_tokens) -> dict:
         """The block-diffusion scheduler step (module docstring): one
         ragged ``prefill_chunk`` call feeds every decode row's open block
-        and the step's prompt chunks (whole blocks), and returns the
-        device's choice for every position of the decode rows. A row with
-        MASK left made a denoising pass: its slots are rolled back
-        (``truncate``) and this pass's positions are fixed; a row with
-        none made its commit pass: the pool keeps the block and its
-        tokens are delivered. ``sampler`` is not called."""
+        (behind the finished block whose K/V is still to be written, in
+        the open block's first pass) and the step's prompt chunks (whole
+        blocks), and returns the device's choice for every position of
+        the decode rows' open blocks. Every decode row made a denoising
+        pass: this pass's positions are fixed, the open block's slots are
+        rolled back (``truncate``; a carried block stays), and a block
+        with no MASK left is delivered. ``sampler`` is not called."""
         b = self.block_length
         with self._span("serving.pack") as sp:
             sids = sorted(self._active)
@@ -2620,7 +2641,7 @@ class BatchScheduler:
             else 0.0
         with self._span("serving.prefill_chunk", rows=len(rows),
                         packed=packed, pad_to=pad_to, prefill=n_pre,
-                        decode=len(dec) * b):
+                        decode=packed - n_pre):
             out = self.model.prefill_chunk(
                 feeds, rows, starts, pad_to=pad_to, choose_rows=dec)
         choice = self._pull(out).reshape(len(dec), b, 3)
@@ -2629,16 +2650,19 @@ class BatchScheduler:
                                   telemetry.clock() - t_exec)
             self._metrics.inc("exec.count.prefill_chunk")
 
-        finished = denoised = unmasked = delivered = 0
+        finished = carried = unmasked = blocks = delivered = 0
         with self._span("serving.block") as sp:
             for k, bi in enumerate(dec):
                 req = self._active[rows[bi]]
-                if any(req._block.masked):
-                    unmasked += self._unmask(req._block, choice[k])
-                    self._roll_back(req, starts[bi])
-                    denoised += 1
-                else:
+                blk = req._block
+                keep = starts[bi] + len(blk.behind)
+                carried += bool(blk.behind)
+                blk.behind = []
+                unmasked += self._unmask(blk, choice[k])
+                self._roll_back(req, keep)
+                if not any(blk.masked):
                     n, done = self._deliver_block(req)
+                    blocks += 1
                     delivered += n
                     finished += done
             for bi, s in enumerate(rows):
@@ -2647,15 +2671,18 @@ class BatchScheduler:
                     continue
                 finished += self._advance_block_prefill(req, feeds[bi])
             if sp is not None:
+                # a row's pass counts once, whatever it carried; no
+                # commit pass runs on its own
                 sp.attrs.update(
-                    rows=len(rows), denoise_rows=denoised,
-                    commit_rows=len(dec) - denoised, unmasked=unmasked,
+                    rows=len(rows), denoise_rows=len(dec), commit_rows=0,
+                    carried_rows=carried, unmasked=unmasked,
                     delivered=delivered)
 
-        for key, n in (("denoise_passes", denoised),
-                       ("commit_passes", len(dec) - denoised),
+        for key, n in (("denoise_passes", len(dec)),
+                       ("commit_passes", 0),
+                       ("commits_carried", carried),
                        ("tokens_unmasked", unmasked),
-                       ("blocks_committed", len(dec) - denoised)):
+                       ("blocks_committed", blocks)):
             self.block_stats[key] += n
             if self._metrics is not None:
                 self._metrics.inc("diffusion." + key, n)
@@ -2663,7 +2690,7 @@ class BatchScheduler:
         cs["steps"] += 1
         cs["chunk_calls"] += 1
         cs["prefill_tokens"] += n_pre
-        cs["decode_tokens"] += len(dec) * b
+        cs["decode_tokens"] += packed - n_pre
         cs["packed_tokens"] += packed
         cs["padded_tokens"] += pad_to - packed
         return {
@@ -2672,9 +2699,9 @@ class BatchScheduler:
             "finished": finished,
             "prefix_hit_tokens": hit_tokens,
             "prefill_tokens": n_pre,
-            # the tokens the decode rows FED (a block a row); what
+            # the tokens the decode rows FED (a block or two a row); what
             # reached the requests is delivered_tokens
-            "decode_tokens": len(dec) * b,
+            "decode_tokens": packed - n_pre,
             "delivered_tokens": delivered,
             "chunk_utilization": round(packed / pad_to, 4),
             "compile_count": getattr(self.model, "compile_count",

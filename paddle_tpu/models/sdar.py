@@ -23,9 +23,10 @@ Generation (``BatchScheduler`` over ``PagedLlamaAdapter``, which reads
 ``block_length`` / ``mask_token_id`` off this config): a block at a time,
 its ids start as ``mask_token_id`` (behind the prompt's tail, if the
 prompt ends inside it), up to ``denoising_steps`` passes over the block's
-B ids (nothing committed) unmask the best-scored positions, one more
-clean pass commits the block's K/V and its B tokens are delivered
-together. The parameter paths are Llama's (``model.layers.<i>.self_attn.
+B ids (nothing committed) unmask the best-scored positions, the pass
+that fixes the last one delivers the block's B tokens together, and one
+clean pass over its final tokens commits the block's K/V: the server
+(inference/serving.py) folds that pass into the next block's first. The parameter paths are Llama's (``model.layers.<i>.self_attn.
 q_proj.weight`` ...), with ``self_attn.q_norm.weight`` / ``k_norm.weight``
 and ``mlp.gate_weight`` / ``mlp.experts_{gate,up,down}`` beside them; the
 forward here is the full-sequence clean pass.

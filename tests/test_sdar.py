@@ -9,6 +9,7 @@ The seed's leaves are bfloat16 numbers; here they are handed to the
 program as float32, so program and float32 reference hold the same
 weights and agree to rounding: orders and argmaxes are then compared
 exactly wherever the reference's own margins are not rounding-thin."""
+import contextlib
 import importlib
 import json
 import os
@@ -222,6 +223,21 @@ def test_block_0_is_the_kernel_it_was_and_block_3_is_refused():
 
 
 # -- generation ----------------------------------------------------------------
+@contextlib.contextmanager
+def telemetry_mode(mode):
+    """``FLAGS_telemetry`` at ``mode`` over a fresh registry and ring."""
+    from paddle_tpu.framework import telemetry
+
+    prev = paddle.get_flags("FLAGS_telemetry")
+    paddle.set_flags({"FLAGS_telemetry": mode})
+    telemetry.reset()
+    try:
+        yield telemetry
+    finally:
+        paddle.set_flags(prev)
+        telemetry.reset()
+
+
 def generate(fam, prompts, max_new, sched_kw=None, model=None, cfg=None,
              eos=None):
     """Run the requests through BatchScheduler; returns (cfg, finished
@@ -317,8 +333,15 @@ def test_generation_is_the_references_replay(fam, rule, steps):
           "prefill_chunk_tokens": 8, "confidence_threshold": 0.03}
     cfg, done, sched, passes, calls, streams = generate(
         fam, prompts, max_new, kw)
-    assert sched.block_stats["blocks_committed"] >= sum(
-        -(-m // B) for m in max_new)
+    # a request's blocks: from the one its prompt ends in to its last
+    # token's; every one delivered, every one but the last carried by the
+    # next one's first pass, and no pass fed a finished block alone
+    n_blocks = sum(-(-(len(p) % B + m) // B) for p, m in zip(prompts,
+                                                             max_new))
+    st = sched.block_stats
+    assert (st["blocks_committed"], st["commits_carried"],
+            st["commit_passes"]) == (n_blocks, n_blocks - len(prompts), 0)
+    assert n_blocks <= st["denoise_passes"] <= steps * n_blocks
     for i, (p, m) in enumerate(zip(prompts, max_new)):
         req = done[f"r{i}"]
         assert len(req.generated_ids) == m
@@ -328,7 +351,8 @@ def test_generation_is_the_references_replay(fam, rule, steps):
         assert streams[f"r{i}"] == [(t, True) for t in p] + \
             [(t, False) for t in req.generated_ids]
     # every row of every call resumes on a block boundary and a chunk ends
-    # on one: the budget of 8 is two whole blocks
+    # on one: the budget of 8 is two whole blocks, and a decode row feeds
+    # its open block or, with the finished one before it, two
     for call in calls:
         for rid, start, n in call:
             assert start % B == 0 and n % B == 0 and n <= 8, call
@@ -336,13 +360,187 @@ def test_generation_is_the_references_replay(fam, rule, steps):
 
 def test_static_at_one_pass_fixes_the_whole_block_at_once(fam):
     rng = np.random.default_rng(8)
-    cfg, done, sched, passes, _, _ = generate(
+    cfg, done, sched, passes, calls, _ = generate(
         fam, [rng.integers(1, 255, 8).tolist()], [8],
         {"denoising_steps": 1, "remasking": "low_confidence_static"})
     assert [sum(b) - sum(a) for _, _, b, a, _ in passes["r0"]] == [4, 4]
     st = sched.block_stats
-    assert (st["denoise_passes"], st["commit_passes"],
-            st["tokens_unmasked"]) == (2, 2, 8)
+    assert (st["denoise_passes"], st["commit_passes"], st["commits_carried"],
+            st["tokens_unmasked"], st["blocks_committed"]) == (2, 0, 1, 8, 2)
+    # the prompt, the first block, and the second behind the first
+    assert calls == [[("r0", 0, 8)], [("r0", 8, 4)], [("r0", 8, 8)]]
+
+
+def _scheduler(fam, sched_kw=None):
+    cfg = tiny_config(scheduler=sched_kw)
+    sched = BatchScheduler(fam.serving(build(fam, cfg), cfg),
+                           **cfg["program"]["scheduler"])
+    return cfg, sched
+
+
+@pytest.mark.parametrize("prompt_len", (8, 10))
+def test_a_carried_pass_leaves_the_references_clean_kv_in_the_pages(
+        fam, prompt_len):
+    """After the pass that carries a finished block the pools stand at the
+    committed length + B, and what the pages hold up to there, the
+    finished block's K/V with it, is the reference's clean pass over the
+    row's tokens; the open block's slots were rolled back."""
+    cfg, sched = _scheduler(fam)
+    prompt = np.random.default_rng(12).integers(1, 255, prompt_len).tolist()
+    sched.submit(Request("a", prompt, max_new_tokens=12))
+    base = prompt_len - prompt_len % B         # the prompt's whole blocks
+    sched.step()                               # the prompt's whole blocks
+    while sched.block_stats["commits_carried"] < 1:
+        assert all(c.seq_len("a") == base for c in sched.model.caches)
+        sched.step()
+    req = sched._active["a"]
+    gen = req.generated_ids
+    assert len(gen) == base + B - prompt_len and req._block.behind == []
+    assert [c.seq_len("a") for c in sched.model.caches] == [base + B] * 2
+    seq = np.asarray(prompt + gen, np.int32)
+    tree = ref_leaves(fam, cfg)
+    with jax.default_matmul_precision("highest"):
+        x = tree["embed"][seq]
+        for lw, cache in zip(tree["layers"], sched.model.caches):
+            x, (k, v) = fam.layer(x, lw, jnp.arange(len(seq)), cfg, "f32")
+            tbl = np.asarray(cache._tables["a"])
+            for pages, want in ((cache.k_pages, k), (cache.v_pages, v)):
+                got = np.asarray(pages)[tbl].reshape(-1, *want.shape[1:])
+                np.testing.assert_allclose(got[:len(seq)], np.asarray(want),
+                                           atol=2e-5)
+    # the next step feeds the open block alone, at the new length
+    sched.step()
+    assert sched.block_stats["commits_carried"] == 1
+    assert all(c.seq_len("a") == base + B for c in sched.model.caches)
+
+
+@pytest.mark.parametrize("prompt_len,max_new,ends,blocks", [
+    (8, 8, False, 2),           # the answer ends on a block boundary
+    (8, 6, False, 2),           # inside a block
+    (10, 5, False, 2),          # a prompt's tail, an end inside a block
+    (8, 12, True, 2),           # an EOS inside the second block of three
+    (8, 3, False, 1),           # one block: nothing is ever carried
+])
+def test_a_requests_last_block_is_never_committed(fam, prompt_len, max_new,
+                                                  ends, blocks):
+    """The pass that fixes the last block's last position delivers it and
+    the request retires: no pass follows to write that block's K/V, and
+    the carried passes are the blocks but the last."""
+    prompt = np.random.default_rng(16).integers(1, 255, prompt_len).tolist()
+    eos, want = None, max_new
+    if ends:
+        _, done, _, _, _, _ = generate(fam, [prompt], [max_new])
+        gen = done["r0"].generated_ids
+        eos = next(t for t in gen[B:2 * B] if t not in gen[:B])
+        want = gen.index(eos) + 1
+    with telemetry_mode("metrics"):
+        _, done, sched, passes, calls, _ = generate(
+            fam, [prompt], [max_new], eos=eos)
+        snap = sched.metrics()["diffusion"]
+    assert len(done["r0"].generated_ids) == want
+    st = sched.block_stats
+    assert (st["blocks_committed"], st["commits_carried"],
+            st["commit_passes"]) == (blocks, blocks - 1, 0)
+    assert (snap["commits_carried"], snap["commit_passes"]) == (blocks - 1, 0)
+    # the last call is the last block's last denoising pass, and the rows
+    # of two blocks are the first passes of the blocks behind the first
+    assert len(calls) == 1 + len(passes["r0"])
+    assert sum(n == 2 * B for c in calls[1:] for _, _, n in c) == blocks - 1
+    assert sched.model.caches[0].num_free_pages == \
+        sched.model.caches[0].num_pages
+
+
+@pytest.mark.parametrize("steps,row_passes,carried", [(1, 3, 2), (4, 12, 2)])
+def test_every_pass_carries_at_one_step_and_one_in_four_at_four(
+        fam, steps, row_passes, carried):
+    """T = 1: a block a pass, every pass but a request's first a row of two
+    blocks. T = B: a token a pass, the first of four carries."""
+    prompt = np.random.default_rng(13).integers(1, 255, 8).tolist()
+    cfg, done, sched, passes, calls, _ = generate(
+        fam, [prompt], [12],
+        {"denoising_steps": steps, "remasking": "sequential"})
+    check_against_replay(fam, cfg, done["r0"], prompt, passes["r0"])
+    st = sched.block_stats
+    assert (st["denoise_passes"], st["commits_carried"], st["commit_passes"],
+            st["blocks_committed"]) == (row_passes, carried, 0, 3)
+    fed = [n for c in calls[1:] for _, _, n in c]
+    assert fed == [B] * steps + ([2 * B] + [B] * (steps - 1)) * 2
+
+
+def test_rows_in_different_passes_beside_a_prompt_chunk(fam):
+    """One step of a row of two blocks (its open block's first pass), a
+    row of one (a later pass) and a prompt chunk of 16: the multi-token
+    pad is a block where every row feeds one, two where some row feeds
+    two, the chunk's beyond; the packed width is the bucket of what was
+    fed; and the ``kernel.ragged`` span's counts are exact, a fed token
+    paired with every row up to its own block's end."""
+    rng = np.random.default_rng(14)
+    prompts = [rng.integers(1, 255, n).tolist() for n in (4, 20, 40)]
+    with telemetry_mode("trace") as telemetry:
+        cfg, done, sched, passes, calls, _ = generate(fam, prompts,
+                                                      [12, 12, 4])
+        spans = telemetry.tracer().spans()
+    ragged = [s.attrs for s in spans if s.name == "kernel.ragged"][::2]
+    packs = [s.attrs for s in spans if s.name == "serving.pack"]
+    assert len(ragged) == len(packs) == len(calls)
+    mixed = 0
+    for call, rg, pk in zip(calls, ragged, packs):
+        ns = [n for _, _, n in call]
+        top = max(ns)
+        assert rg["t"] == (B if top <= B else 2 * B if top <= 2 * B else 16)
+        assert (pk["packed"], pk["pad_to"]) == \
+            (sum(ns), 16 if sum(ns) <= 16 else 32)
+        pairs = sum((p // B + 1) * B for _, start, n in call
+                    for p in range(start, start + n))
+        assert (rg["fed"], rg["kv_rows"], rg["pairs"]) == \
+            (sum(ns), sum(st + n for _, st, n in call), pairs)
+        mixed += sorted(ns) == [B, 2 * B, 16]
+    assert mixed                          # the step the test is named for
+    for i, p in enumerate(prompts):
+        check_against_replay(fam, cfg, done[f"r{i}"], p, passes[f"r{i}"])
+
+
+def test_a_listed_row_is_one_block_or_two(fam):
+    cfg = tiny_config()
+    ad = fam.serving(build(fam, cfg), cfg)
+    ad.alloc("x")
+    for feed in ([1] * 12, [1, 2], [1] * 6):
+        with pytest.raises(ValueError, match="one or two blocks of "
+                                             "block_length=4"):
+            ad.prefill_chunk([feed], ["x"], [0], pad_to=16, choose_rows=[0])
+    assert ad.caches[0].seq_len("x") == 0          # refused before booking
+    out = ad.prefill_chunk([[1] * 8], ["x"], [0], pad_to=16, choose_rows=[0])
+    assert np.asarray(out).shape == (B, 3)         # the last block's choice
+
+
+def test_after_warm_a_generation_of_mixed_rows_builds_nothing(fam):
+    """``BatchScheduler.warm`` builds every (packed width, multi-token
+    pad, table width) that rows of one block, of two, and a prompt chunk
+    beside them can meet: a generation then adds no packed shape, no
+    kernel shape and no layer program."""
+    pa = importlib.import_module("paddle_tpu.ops.kernels.paged_attention")
+
+    cfg, sched = _scheduler(fam)
+    ad = sched.model
+    sched.warm()
+    assert float(jnp.abs(ad.caches[0].k_pages).max()) == 0.0
+    # widths 16 / 32 / 64 (4 rows x 4, x 8, + the chunk of 16), each with
+    # the pads rows that short can fill it with, at tables of 16 pages
+    assert {(n, t) for _, _, t, _, n in ad._kernel_shapes} == {
+        (16, 4), (16, 8), (32, 8), (16, 16), (32, 16), (64, 16)}
+    before = (ad.compile_count, ad.attend_program_count,
+              pa._jitted_layer_step.cache_info().misses)
+    rng = np.random.default_rng(15)
+    for i, (n, m) in enumerate(((4, 12), (20, 12), (40, 8), (9, 16),
+                                (16, 8), (30, 12))):
+        sched.submit(Request(f"r{i}", rng.integers(1, 255, n).tolist(),
+                             max_new_tokens=m))
+    done = sched.run_until_complete()
+    assert all(len(done[f"r{i}"].generated_ids) == m
+               for i, m in enumerate((12, 12, 8, 16, 8, 12)))
+    assert sched.block_stats["commits_carried"] > 0
+    assert (ad.compile_count, ad.attend_program_count,
+            pa._jitted_layer_step.cache_info().misses) == before
 
 
 def test_the_mask_id_is_never_chosen_and_a_prompts_mask_id_stays(fam):
@@ -448,33 +646,30 @@ def test_spans_and_counters_of_the_block_step(fam):
     its bytes, the ``diffusion.*`` counters."""
     from paddle_tpu.framework import telemetry
 
-    prev = paddle.get_flags("FLAGS_telemetry")
-    paddle.set_flags({"FLAGS_telemetry": "trace"})
-    try:
-        tr = telemetry.tracer()
-        tr.clear()
-        cfg = tiny_config()
-        sched = BatchScheduler(fam.serving(build(fam, cfg), cfg),
-                               **cfg["program"]["scheduler"])
+    with telemetry_mode("trace"):
+        cfg, sched = _scheduler(fam)
         sched.submit(Request("a", list(range(1, 13)), max_new_tokens=8))
         sched.run_until_complete()
-        spans = tr.spans()
+        spans = telemetry.tracer().spans()
         snap = sched.metrics()
-    finally:
-        paddle.set_flags(prev)
     blocks = [s.attrs for s in spans if s.name == "serving.block"]
     # 1 prompt step (12 tokens in one chunk of 16), then 2 blocks of
-    # 2 denoising passes + 1 commit
-    assert [(a["denoise_rows"], a["commit_rows"], a["unmasked"],
-             a["delivered"]) for a in blocks] == \
-        [(0, 0, 0, 0)] + [(1, 0, 2, 0), (1, 0, 2, 0), (0, 1, 0, 4)] * 2
+    # 2 denoising passes, the second block's first carrying the first
+    assert [(a["denoise_rows"], a["commit_rows"], a["carried_rows"],
+             a["unmasked"], a["delivered"]) for a in blocks] == \
+        [(0, 0, 0, 0, 0), (1, 0, 0, 2, 0), (1, 0, 0, 2, 4),
+         (1, 0, 1, 2, 0), (1, 0, 0, 2, 4)]
     ragged = [s.attrs for s in spans if s.name == "kernel.ragged"]
     assert all(a["block"] == B for a in ragged)
     first = ragged[0]                      # the prompt: 12 tokens, 3 blocks
     assert (first["fed"], first["kv_rows"], first["pairs"]) == \
         (12, 12, 4 * (4 + 8 + 12))
     dec = ragged[2]                        # a layer of the first decode pass
-    assert (dec["fed"], dec["kv_rows"], dec["pairs"]) == (4, 16, 64)
+    assert (dec["fed"], dec["kv_rows"], dec["pairs"], dec["t"]) == \
+        (4, 16, 64, B)
+    two = ragged[6]                        # the pass that carries a block
+    assert (two["fed"], two["kv_rows"], two["pairs"], two["t"]) == \
+        (8, 20, 4 * (16 + 20), 2 * B)
     pulls = [s.attrs for s in spans if s.name == "serving.logits_pull"]
     assert pulls and all(a["bytes"] == 0 or a["bytes"] == B * 3 * 4
                          for a in pulls)
@@ -482,12 +677,14 @@ def test_spans_and_counters_of_the_block_step(fam):
     assert counts and all(a["calls"] == 2 for a in counts)
     # a decode pass routes 4 tokens to 2 experts each in each of 2 layers
     assert counts[1]["assignments"] == 2 * 4 * 2
+    assert counts[3]["assignments"] == 2 * 8 * 2     # and 8 where it carries
     d = snap["diffusion"]
-    assert (d["denoise_passes"], d["commit_passes"], d["tokens_unmasked"],
-            d["blocks_committed"]) == (4, 2, 8, 2)
+    assert (d["denoise_passes"], d["commit_passes"], d["commits_carried"],
+            d["tokens_unmasked"], d["blocks_committed"]) == (4, 0, 1, 8, 2)
     assert {n for n, _, _ in telemetry.SURFACE} >= {
         "diffusion.denoise_passes", "diffusion.commit_passes",
-        "diffusion.tokens_unmasked", "diffusion.blocks_committed"}
+        "diffusion.commits_carried", "diffusion.tokens_unmasked",
+        "diffusion.blocks_committed"}
 
 
 def test_transfer_counts_and_choose_of_the_reference(fam):

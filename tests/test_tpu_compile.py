@@ -332,11 +332,15 @@ def test_eva_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
 
 # sdar-30b-a3b-serve.blocks-closed64's steps (SDAR-30B-A3B's widths:
 # hidden 2048, 32 query / 4 KV heads of 128, 128 experts of 768 top-8;
-# 5,120 pages; tables of 32 and 64 pages): 64 rows of one block of 4
-# tokens in the 256 bucket, and beside a 256-token prompt chunk in the 512
+# 5,120 pages; tables of 64 pages, before PR 37 of 32 as well): 64 rows of
+# one block of 4 tokens in the 256 bucket, and beside a 256-token prompt
+# chunk in the 512; since PR 37 rows of two blocks (a finished block
+# behind the open one) in the 256 and 512 buckets, and up to 512 such
+# tokens beside the chunk in the 1,024 the largest bucket rounds up to
 @pytest.mark.parametrize("n_pad,b_pad,t_pad,mp", [
     (256, 64, 4, 32), (256, 64, 4, 64), (256, 64, 256, 64),
-    (512, 64, 256, 64),
+    (512, 64, 256, 64), (256, 64, 8, 64), (512, 64, 8, 64),
+    (1024, 64, 256, 64),
 ])
 def test_sdar_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
     """The layer program with its three other switches on at SDAR's
