@@ -112,6 +112,7 @@ from __future__ import annotations
 
 import collections
 import contextvars
+import gc
 import itertools
 import json
 import math
@@ -144,6 +145,8 @@ __all__ = [
 # every timestamp this module (and, transitively, the serving stack)
 # records comes from here
 _clock = _time.perf_counter
+# the calling thread's CPU seconds, read at a live span's ends only
+_thread_time = _time.thread_time
 
 
 def clock() -> float:
@@ -963,10 +966,16 @@ class Span:
     actually doing the work owns the span, even when an executor
     handoff closes it somewhere else (the historical
     ``threading.get_ident()``-at-construction stamp silently
-    mis-attributed exactly that case)."""
+    mis-attributed exactly that case).
 
-    __slots__ = ("name", "cat", "t0", "dur", "tid", "depth", "path",
-                 "attrs", "span_id", "parent_id", "trace_id")
+    ``cpu`` is the opening thread's CPU seconds (``time.thread_time``)
+    between enter and exit, beside the wall ``dur``: near ``dur`` the
+    thread was running Python or native code, well below it the thread
+    waited (for the GIL, a transfer, a lock, a sleep). None on a range
+    recorded after the fact (:meth:`Tracer.add_complete`)."""
+
+    __slots__ = ("name", "cat", "t0", "dur", "cpu", "tid", "depth",
+                 "path", "attrs", "span_id", "parent_id", "trace_id")
 
     def __init__(self, name, cat="app", attrs=None):
         self.name = str(name)
@@ -974,6 +983,7 @@ class Span:
         self.attrs = attrs or {}
         self.t0 = 0.0
         self.dur = 0.0
+        self.cpu: Optional[float] = None
         self.tid = threading.get_ident()
         self.depth = 0
         self.path = self.name
@@ -1004,6 +1014,8 @@ class Span:
              "ts": self.t0, "dur": self.dur, "tid": self.tid,
              "depth": self.depth, "path": self.path,
              "args": dict(self.attrs)}
+        if self.cpu is not None:
+            d["cpu"] = self.cpu
         if self.span_id:
             d["id"] = self.span_id
         if self.parent_id is not None:
@@ -1103,12 +1115,14 @@ class _SpanCtx:
             s.path = sys.intern(parent.path + "/" + s.name)
         s._stamp_identity(parent)
         var.set(stack + (s,))
+        s.cpu = _thread_time()      # the start, until exit
         s.t0 = clock()
         return s
 
     def __exit__(self, *exc):
         s = self._span
         s.dur = clock() - s.t0
+        s.cpu = _thread_time() - s.cpu
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
@@ -1156,6 +1170,10 @@ class Tracer:
         # thread while another finishes a span must not hit "deque
         # mutated during iteration"
         self._lock = _concurrency.guarded("telemetry.tracer")
+        # ranges that may not take the lock (a collection can start on a
+        # thread that holds it): moved into the ring by the next commit
+        # or read; deque appends and pops are atomic
+        self._pending = collections.deque()
         self.dropped = 0  # spans evicted by ring rollover
         _csan = _concurrency.sanitizer()
         self._cv = None if _csan is None else _csan.shared(
@@ -1171,9 +1189,14 @@ class Tracer:
         with self._lock:
             if self._cv is not None:
                 self._cv.write()
-            if len(self._ring) == self._ring.maxlen:
-                self.dropped += 1
-            self._ring.append(span)
+            while self._pending:
+                self._append(self._pending.popleft())
+            self._append(span)
+
+    def _append(self, span: Span) -> None:
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+        self._ring.append(span)
 
     def span(self, name: str, cat: str = "app", **attrs) -> _SpanCtx:
         """``with tracer.span("serving.admit", admitted=2): ...`` —
@@ -1184,7 +1207,14 @@ class Tracer:
                      attrs=None) -> Span:
         """Record an already-timed range (t0 from :func:`clock`).
         Stamps the ambient trace context (if any), so bridged
-        profiler ranges stitch into the surrounding trace too."""
+        profiler ranges stitch into the surrounding trace too. A
+        ``compile`` range (``xla.*``) takes the ``key`` of the innermost
+        open span that declares one: what was built."""
+        s = self._ranged(name, t0, dur, cat, attrs)
+        self._commit(s)
+        return s
+
+    def _ranged(self, name, t0, dur, cat, attrs) -> Span:
         s = Span(name, cat, attrs)
         s.t0 = float(t0)
         s.dur = float(dur)
@@ -1192,8 +1222,12 @@ class Tracer:
         if stack:
             s.depth = len(stack)
             s.path = sys.intern(stack[-1].path + "/" + s.name)
+            if cat == "compile":
+                for open_span in reversed(stack):
+                    if "key" in open_span.attrs:
+                        s.attrs["key"] = open_span.attrs["key"]
+                        break
         s._stamp_identity(stack[-1] if stack else None)
-        self._commit(s)
         return s
 
     # -- readout -----------------------------------------------------------
@@ -1201,12 +1235,15 @@ class Tracer:
         with self._lock:
             if self._cv is not None:
                 self._cv.read()
+            while self._pending:
+                self._append(self._pending.popleft())
             return list(self._ring)
 
     def clear(self) -> None:
         with self._lock:
             if self._cv is not None:
                 self._cv.write()
+            self._pending.clear()
             self._ring.clear()
             self.dropped = 0
 
@@ -1309,7 +1346,35 @@ def tracer() -> Optional[Tracer]:
         with _STATE_LOCK:
             if _TRACER is None:
                 _TRACER = Tracer()
+                if _gc_callback not in gc.callbacks:
+                    gc.callbacks.append(_gc_callback)
     return _TRACER
+
+
+# the start of the collection running on each thread (py.gc)
+_GC_START = threading.local()
+
+
+def _gc_callback(phase, info) -> None:
+    """Python's collector as a ``py.gc`` range (``gen``, ``collected``,
+    ``uncollectable``) on the thread that triggered it, under whatever
+    span is open there; installed with the tracer singleton, a no-op
+    while no span is live. It takes no lock: a collection can start on
+    a thread that holds the ring's, so the range waits in the tracer's
+    pending queue until the next commit or read."""
+    if phase == "start":
+        _GC_START.t0 = clock() if tracing_on() else None
+        return
+    t0 = getattr(_GC_START, "t0", None)
+    tr = _TRACER
+    if t0 is None or tr is None:
+        return
+    _GC_START.t0 = None
+    dur = clock() - t0
+    attrs = {"gen": info["generation"], "collected": info["collected"],
+             "uncollectable": info["uncollectable"]}
+    _session_mark("py.gc", dur, attrs)
+    tr._pending.append(tr._ranged("py.gc", t0, dur, "gc", attrs))
 
 
 def request_traces() -> Optional[RequestTraceBook]:
@@ -1358,10 +1423,17 @@ def add_complete(name: str, t0: float, dur: float, cat: str = "event",
     tr = tracer()
     if tr is None:
         return None
+    _session_mark(name, dur, attrs)
+    return tr.add_complete(name, t0, dur, cat=cat, attrs=attrs)
+
+
+def _session_mark(name: str, dur: float, attrs: dict) -> None:
+    """Under a ``jax.profiler`` session, a zero-length mark ``name``
+    with the range's duration as ``dur_us``: the device profiler takes
+    no range after the fact."""
     if _ANNOTATION is not None and _SESSION_PROBE():
         with _ANNOTATION(name, dur_us=int(dur * 1e6), **attrs):
             pass
-    return tr.add_complete(name, t0, dur, cat=cat, attrs=attrs)
 
 
 def arm_tracer() -> Tracer:
@@ -1387,11 +1459,14 @@ def reset() -> None:
     (bench/test arm isolation). Handles cached by live
     schedulers/pools keep working against the detached objects. The
     performance ledger rides along: its singleton wraps the registry
-    being dropped, so the two must never skew."""
+    being dropped, so the two must never skew; the collector's
+    ``py.gc`` callback goes with the tracer."""
     global _REGISTRY, _TRACER, _TRACES, _ARMED
     with _STATE_LOCK:
         _REGISTRY = None
         _TRACER = None
+        if _gc_callback in gc.callbacks:
+            gc.callbacks.remove(_gc_callback)
         _TRACES = None
         _ARMED = 0
     from . import perf_ledger
@@ -1576,9 +1651,6 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("diffusion.denoise_passes", "counter",
      "row-passes of a block-diffusion step (BatchScheduler._step_block; "
      "the open block's slots rolled back), whatever they carried"),
-    ("diffusion.commit_passes", "counter",
-     "row-passes that fed a finished block alone to write its K/V: none, "
-     "a block's commit rides the next block's first pass"),
     ("diffusion.commits_carried", "counter",
      "row-passes that also wrote the K/V of the finished block behind "
      "the open one (a row of two blocks)"),
@@ -1712,13 +1784,6 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "fp wire bytes avoided by quantize-on-the-wire (fp payload "
      "minus quantized payload+sidecars; the live side of the "
      "planner's wire-savings assertion)"),
-    # eager kernel programs (ops/kernels.eager_call)
-    ("kernel.program_cache.hit.<kernel>", "counter",
-     "eager calls of a Pallas kernel with concrete arrays that ran "
-     "a cached jitted program (rms_norm, layer_norm_fused)"),
-    ("kernel.program_cache.miss.<kernel>", "counter",
-     "eager calls that built their program: a new shape, dtype, "
-     "eps, flag setting or mesh (a steady serving step reads 0)"),
     # async serving engine (inference/engine.py)
     ("engine.backpressure_state", "gauge",
      "ServingEngine admission-gate level: 0 open, 1 shed "
@@ -1807,7 +1872,11 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "session cancels forwarded to a replica engine that still "
      "knew the request"),
     # spans (trace mode)
-    ("span:serving.step", "span", "one scheduler iteration"),
+    ("span:serving.step", "span",
+     "one scheduler iteration (n attr: the scheduler's step count)"),
+    ("span:serving.warm", "span",
+     "BatchScheduler.warm: the steady steps' programs built before "
+     "the first request (key attr)"),
     ("span:serving.admit", "span", "admission pass of a step"),
     ("span:serving.prefill_chunk", "span",
      "the ragged model call (packed/pad_to/prefill/decode attrs)"),
@@ -1847,15 +1916,19 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("span:model.plan", "span",
      "host prep of prefill_chunk: lengths, positions, right-align "
      "plan, uploads (rows/packed/pad_to/bytes attrs)"),
-    ("span:model.embed", "span", "the embedding gather"),
+    ("span:model.embed", "span",
+     "the embedding gather (key attr on a programmed step)"),
     ("span:model.layer", "span",
      "one decoder layer (li attr; program = 1 where the layer ran as "
-     "one compiled program, 0 on the op-by-op body)"),
+     "one compiled program, 0 on the op-by-op body; key attr on a "
+     "programmed step: the shapes and switches of its program)"),
     ("span:model.norm", "span",
      "an eager rms_norm call of a layer (op-by-op body only)"),
     ("span:model.mlp", "span",
      "a layer's MLP and its residual add (op-by-op body only)"),
-    ("span:model.head", "span", "final norm, row gather, lm head"),
+    ("span:model.head", "span",
+     "final norm, row gather, lm head (key attr on a programmed "
+     "step)"),
     ("span:model.hc", "span",
      "an mHC site's read (coefficients, the one stream F sees) or "
      "write (X <- Hres X + Hpost^T y) as one program (li/site attrs)"),
@@ -1871,7 +1944,7 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
      "(rows/t/max_pages attrs; fused=1 with the page write)"),
     ("span:kernel.moe_gmm", "span",
      "the three grouped matmuls over the sorted assignments "
-     "(assignments attr)"),
+     "(assignments/key attrs)"),
     ("span:moe.counts", "span",
      "a step's expert counts as they reach the host with the logits "
      "pull (calls/assignments/experts_touched/expert_tokens_max/"
@@ -1900,8 +1973,21 @@ SURFACE: Tuple[Tuple[str, str, str], ...] = (
     ("span:xla.lower", "span",
      "jax.monitoring jaxpr_to_mlir_module_duration (fun attr)"),
     ("span:xla.build", "span",
-     "jax.monitoring backend_compile_duration; a persistent-cache "
-     "load lands here (fun attr)"),
+     "jax.monitoring backend_compile_duration: the persistent cache's "
+     "key hash, then a load (hit) or a compile and a cache write "
+     "(miss) (fun attr; cache = hit/miss/off; key = the key of the "
+     "innermost open span that declares one: what was built)"),
+    ("span:xla.cache_load", "span",
+     "jax.monitoring cache_retrieval_time_sec: an executable read "
+     "from the persistent cache, inside its xla.build (key attr as "
+     "the build's)"),
+    ("span:kernel.eager", "span",
+     "ops/kernels.eager_call: a Pallas kernel called with concrete "
+     "arrays through its cached program (kernel/key attrs)"),
+    ("span:py.gc", "span",
+     "a collection of Python's collector on the thread that "
+     "triggered it, under whatever span is open there (gen/collected/"
+     "uncollectable attrs)"),
 )
 
 

@@ -631,10 +631,15 @@ class PagedLlamaAdapter(PagedAdapterBase):
         plan, rope = (tok, gm), (self._cos, self._sin)
         few = self._few_row_rows(rows, gm.shape[1])
         sizes = []                 # a routed layer's per-expert counts
-        with span("model.embed"):
+        with span("model.embed") as sp:
+            if sp is not None:
+                sp.attrs["key"] = self._program_key("embed", rows, gm)
             x = embed(core.embed_tokens.weight._data, tok)      # (N, H)
         for li, layer in enumerate(core.layers):
-            with span("model.layer", li=li, program=1):
+            with span("model.layer", li=li, program=1) as sp:
+                if sp is not None:
+                    sp.attrs["key"] = self._program_key(
+                        "layer", rows, gm, layer)
                 self.chunk_stats["attend_calls"] += 1
                 self.chunk_stats["layer_programs"] += 1
                 self.chunk_stats["few_row_rows"] += few
@@ -645,7 +650,11 @@ class PagedLlamaAdapter(PagedAdapterBase):
                 if isinstance(x, tuple):
                     x, n = x
                     sizes.append(n)
-        with span("model.head"):
+        with span("model.head") as sp:
+            if sp is not None:
+                sp.attrs["key"] = self._program_key(
+                    "head" if choose_rows is None else "choose", rows, gm,
+                    last=last)
             head_w = (core.norm.weight._data,
                       (core.embed_tokens if self.model.lm_head is None
                        else self.model.lm_head).weight._data)
@@ -672,6 +681,24 @@ class PagedLlamaAdapter(PagedAdapterBase):
             # multi-row sampling epilogue: per-position logits for
             # the listed (verify) rows, concatenated in list order
             return logits, Tensor(head(x, verify[0], *head_w))
+
+    def _program_key(self, part, rows, gm, layer=None, last=None):
+        """What chooses the compiled program that a dispatch span of a
+        programmed step runs, as a short string: the span's ``key``,
+        which the ``xla.*`` ranges of a build under it copy. The embed
+        program is chosen by the packed width, the head's by it and the
+        rows it reads (``last``), a layer's by packed width, rows, row
+        length, table width and its static switches. Built only where
+        the span is live."""
+        if part == "embed":
+            return f"embed n{rows.pad_to}"
+        if layer is None:
+            return f"{part} n{rows.pad_to} r{last.shape[0]}"
+        b_pad, t_pad = gm.shape
+        switches = "".join(f" {k}" for k in self._layer_switches(layer))
+        window = f" w{self._window}" if self._window else ""
+        return (f"layer n{rows.pad_to} r{b_pad} t{t_pad} p{rows.mp_pad}"
+                f"{window}{switches}")
 
     def _note_block_counts(self, tables, rows):
         """What a block-diffusion step's kernel call reads and computes,

@@ -137,6 +137,24 @@ class PagedXing4Adapter(PagedAdapterBase):
     def _row_pad(longest):
         return 1 if longest == 1 else max(CHUNK_ROW_PAD, pow2(longest))
 
+    @staticmethod
+    def _program_key(part, rows, *shape):
+        """What chooses the compiled programs that a dispatch span of
+        the step runs, as a short string: the span's ``key``, which the
+        ``xla.*`` ranges of a build under it copy. The packed width;
+        then a layer's rows, row length, table width and feed-forward
+        (routed or dense), the head's rows, the grouped matmul's
+        assignments. Built only where the span is live."""
+        if part == "layer":
+            b_pad, t_pad, routed = shape
+            return (f"layer n{rows.pad_to} r{b_pad} t{t_pad} "
+                    f"p{rows.mp_pad} {'moe' if routed else 'dense'}")
+        if part == "moe_gmm":
+            return f"moe_gmm a{shape[0]}"
+        if part == "head":
+            return f"head n{rows.pad_to} r{shape[0]}"
+        return f"{part} n{rows.pad_to}"
+
     # -- set-up ------------------------------------------------------------
     def warm(self, rows, packed, chunk_tokens):
         """Build, before the first request, the programs of the steady
@@ -242,11 +260,16 @@ class PagedXing4Adapter(PagedAdapterBase):
         cos, sin = self._cos, self._sin
         core = self.model.model
         counts = []
-        with span("model.embed"):
+        with span("model.embed") as sp:
+            if sp is not None:
+                sp.attrs["key"] = self._program_key("embed", rows)
             xs = P["embed"](core.embed_tokens.weight._data, ids)
         for li, layer in enumerate(core.layers):
-            with span("model.layer", li=li):
+            with span("model.layer", li=li) as sp:
                 w = layer.arrays()
+                if sp is not None:
+                    sp.attrs["key"] = self._program_key(
+                        "layer", rows, b_pad, t_pad, "moe" in w)
                 with span("model.hc", li=li, site="attn"):
                     h, post, res = P["hc_pre"](xs, *w["attn_hc"], w["ln1"])
                 with span("model.mla", li=li):
@@ -268,7 +291,10 @@ class PagedXing4Adapter(PagedAdapterBase):
                         x_s, order, sizes, wt = P["moe_route"](
                             h, w_r, bias, valid)
                         with span("kernel.moe_gmm",
-                                  assignments=int(x_s.shape[0])):
+                                  assignments=int(x_s.shape[0])) as sp:
+                            if sp is not None:
+                                sp.attrs["key"] = self._program_key(
+                                    "moe_gmm", rows, x_s.shape[0])
                             ys = P["moe_gmm"](x_s, sizes, wg, wu, wd)
                         y = P["moe_combine"](ys, order, wt, h,
                                              *(w["shared"] or ()))
@@ -278,7 +304,9 @@ class PagedXing4Adapter(PagedAdapterBase):
                         y = P["mlp"](h, *w["mlp"])
                 with span("model.hc", li=li, site="ffn"):
                     xs = P["hc_post"](xs, y, post, res)
-        with span("model.head"):
+        with span("model.head") as sp:
+            if sp is not None:
+                sp.attrs["key"] = self._program_key("head", rows, b_pad)
             fh = core.final_hc
             head_w = (fh.phi._data, fh.b._data, fh.alpha._data,
                       core.norm.weight._data,

@@ -684,9 +684,8 @@ class BatchScheduler:
         self.confidence_threshold = float(confidence_threshold)
         # a pass's share of the block: its ceil-split over the passes
         self._shares = [b // t + (s < b % t) for s in range(t)]
-        self.block_stats = {"denoise_passes": 0, "commit_passes": 0,
-                            "commits_carried": 0, "tokens_unmasked": 0,
-                            "blocks_committed": 0}
+        self.block_stats = {"denoise_passes": 0, "commits_carried": 0,
+                            "tokens_unmasked": 0, "blocks_committed": 0}
 
     # -- set-up ------------------------------------------------------------
     def warm(self):
@@ -705,8 +704,13 @@ class BatchScheduler:
         # and in that block's first pass the finished one behind it too
         fed = rows * (self.block_length or 1)
         most = 2 * fed if self.block_length else fed
-        warm(rows, {bucket_packed_tokens(n, self.serving_buckets)
-                    for n in (fed, most, most + chunk)}, chunk)
+        packed = {bucket_packed_tokens(n, self.serving_buckets)
+                  for n in (fed, most, most + chunk)}
+        with self._span("serving.warm") as sp:
+            if sp is not None:
+                sp.attrs["key"] = \
+                    f"warm r{rows} n{sorted(packed)} c{chunk}"
+            warm(rows, packed, chunk)
 
     # -- pool accounting ---------------------------------------------------
     def _pool(self, model=None):
@@ -2058,8 +2062,10 @@ class BatchScheduler:
             self._step_epoch += 1
         self._in_step = True
         try:
-            with self._span("serving.step"):
+            with self._span("serving.step") as sp:
                 ev = self._step_impl()
+                if sp is not None:
+                    sp.attrs["n"] = self._fault_step
         finally:
             self._in_step = False
         if self._step_extras:
@@ -2671,15 +2677,13 @@ class BatchScheduler:
                     continue
                 finished += self._advance_block_prefill(req, feeds[bi])
             if sp is not None:
-                # a row's pass counts once, whatever it carried; no
-                # commit pass runs on its own
+                # a row's pass counts once, whatever it carried
                 sp.attrs.update(
-                    rows=len(rows), denoise_rows=len(dec), commit_rows=0,
+                    rows=len(rows), denoise_rows=len(dec),
                     carried_rows=carried, unmasked=unmasked,
                     delivered=delivered)
 
         for key, n in (("denoise_passes", len(dec)),
-                       ("commit_passes", 0),
                        ("commits_carried", carried),
                        ("tokens_unmasked", unmasked),
                        ("blocks_committed", blocks)):

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -49,28 +50,56 @@ def live_static_functions():
     return list(_LIVE_STATICS)
 
 
-# XLA's three build phases as ranges in the span ring, under whatever
-# span is open on the thread that built (an eager op that re-traces,
-# re-lowers and loads from the persistent cache shows up as three
-# ranges inside its model.* span; a cache hit lands in xla.build).
-# ONE listener for the process, registered at import: jax.monitoring
-# has no cheap way to take a listener off again, so it asks telemetry
-# first and is a no-op while no span is live.
+# XLA's build phases as ranges in the span ring, under whatever span is
+# open on the thread that built: xla.trace, xla.lower, xla.build (the
+# persistent cache's key hash, then a load or a compile and a cache
+# write) and, inside a build that the cache served, xla.cache_load.
+# Each xla.build says how the cache served it (cache = hit / miss /
+# off, from the note the cache's events leave on the building thread)
+# and carries the key of the innermost open span that declares one
+# (telemetry.Tracer.add_complete). ONE pair of listeners for the
+# process, registered at import: jax.monitoring has no cheap way to
+# take a listener off again, so they are no-ops while no span is live.
 _XLA_PHASES = {
     "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "xla.cache_load",
     "/jax/core/compile/backend_compile_duration": "xla.build",
 }
+# the cache's events inside a build: a request that uses the cache is a
+# miss until a hit says otherwise (JAX's own cache_misses event fires
+# only where the compiled program is written, not for every miss)
+_CACHE_NOTES = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_BUILD_NOTE = threading.local()
+
+
+def _xla_cache_listener(event, **kw):
+    note = _CACHE_NOTES.get(event)
+    if note is not None:
+        _BUILD_NOTE.cache = note
 
 
 def _xla_phase_listener(event, secs, **kw):
     name = _XLA_PHASES.get(event)
-    if name is not None and _telemetry.tracing_on():
+    if name is None:
+        return
+    fun = kw.get("fun_name")
+    attrs = {} if fun is None else {"fun": str(fun)}
+    if name == "xla.build":
+        # taken whether or not a span is live: a note must not outlive
+        # its build
+        attrs["cache"] = getattr(_BUILD_NOTE, "cache", "off")
+        _BUILD_NOTE.cache = "off"
+    if _telemetry.tracing_on():
         _telemetry.add_complete(
             name, _telemetry.clock() - secs, secs, cat="compile",
-            fun=str(kw.get("fun_name", "")))
+            **attrs)
 
 
+jax.monitoring.register_event_listener(_xla_cache_listener)
 jax.monitoring.register_event_duration_secs_listener(
     _xla_phase_listener)
 

@@ -86,8 +86,8 @@ def eager_call(kernel, fn, static, *arrays):
     so no cache of JAX's recognises it and it is traced, lowered
     through Mosaic and loaded again per call.
     Counted per call as ``<kernel>:program_hit|program_miss`` in
-    ``kernel_dispatch_stats()`` and, where the registry is on, as
-    ``kernel.program_cache.{hit,miss}.<kernel>``."""
+    ``kernel_dispatch_stats()``; under a live ``kernel.eager`` span
+    whose ``key`` names the program (what a build under it copies)."""
     from ...distributed.mesh import global_mesh
     from ...framework import telemetry
 
@@ -95,15 +95,21 @@ def eager_call(kernel, fn, static, *arrays):
                           use_pallas(), global_mesh())
     miss_key = f"{kernel}:program_miss"
     before = _DISPATCH[miss_key]
-    out = prog(*arrays)
-    reg = telemetry.registry()
+    with telemetry.span("kernel.eager", kernel=kernel) as sp:
+        if sp is not None:
+            sp.attrs["key"] = _eager_key(kernel, static, arrays)
+        out = prog(*arrays)
     if _DISPATCH[miss_key] == before:
         _DISPATCH[f"{kernel}:program_hit"] += 1
-        if reg is not None:
-            reg.inc("kernel.program_cache.hit." + kernel)
-    elif reg is not None:
-        reg.inc("kernel.program_cache.miss." + kernel)
     return out
+
+
+def _eager_key(kernel, static, arrays):
+    """An eager program's key as a short string: the kernel, its static
+    values, and each array's dtype and shape (``-`` for None)."""
+    shapes = " ".join("-" if a is None else
+                      f"{a.dtype}{list(a.shape)}" for a in arrays)
+    return f"{kernel} {static} {shapes}"
 
 
 from . import rms_norm as _rms_norm_mod

@@ -35,6 +35,34 @@ from sdar_tiny_config import tiny_config as sdar_tiny  # noqa: E402
 from xing4_tiny_config import tiny_config  # noqa: E402
 
 
+# the per-layer metrics ISSUE 38 appended to every serving cell
+HOST_METRICS = ("idle_in_cache_load_share.serve",
+                "idle_in_xla_trace_share.serve",
+                "programs_built_in_window.serve", "idle_in_gc_share.serve",
+                "idle_offcpu_share.serve")
+
+
+def test_the_cell_and_its_metrics_are_entered(monkeypatch):
+    """The EvaByte cell's entry as ``benchmarks/tests/
+    test_evabyte_family.py`` pins it (that file is the benchmark's and
+    stays as it is; its count of the cell's per-layer metrics predates
+    the five of ISSUE 38), and the five beside them."""
+    from benchmarks.lib import common
+    from benchmarks.tests import test_evabyte_family as eva
+
+    listed = common.metrics_for
+
+    def before(bench, cell, kind):
+        return [m for m in listed(bench, cell, kind)
+                if m["name"] not in HOST_METRICS]
+
+    monkeypatch.setattr(common, "metrics_for", before)
+    eva.test_the_cell_and_its_metrics_are_entered()
+    names = {m["name"] for m in
+             listed(common.load_benchmark(), eva.CELL, "per_layer")}
+    assert names >= set(HOST_METRICS)
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _interpret():
     paddle.set_flags({"FLAGS_pallas_interpret": True})

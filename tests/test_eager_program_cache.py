@@ -103,20 +103,32 @@ class TestEagerCallsHitOneProgram:
         fn(*args)
         assert _stats(kernel) == (1, 0, 1, 0)
 
-    def test_registry_counts_hits_and_misses_by_kernel(self, interp,
-                                                       kernel):
+    def test_stats_count_hits_and_misses_by_kernel(self, interp, kernel):
+        """Counted in ``kernel_dispatch_stats()`` alone, whatever the
+        registry's mode; under live spans each call is a ``kernel.eager``
+        span whose ``key`` names the program, and its build's ranges
+        carry that key."""
         fn, vecs, _ = KERNELS[kernel]
-        paddle.set_flags({"FLAGS_telemetry": "metrics"})
+        paddle.set_flags({"FLAGS_telemetry": "trace"})
         telemetry.reset()
         try:
             for _ in range(3):
                 fn(_x(), *vecs())
-            reg = telemetry.registry()
-            assert reg.counter("kernel.program_cache.miss." + kernel) == 1
-            assert reg.counter("kernel.program_cache.hit." + kernel) == 2
+            assert _stats(kernel) == (3, 0, 2, 1)
+            assert not any(k.startswith("program_cache") for k in
+                           telemetry.registry().snapshot().get("kernel", {}))
+            spans = telemetry.tracer().spans()
         finally:
             paddle.set_flags({"FLAGS_telemetry": "off"})
             telemetry.reset()
+        keys = [s.attrs["key"] for s in spans if s.name == "kernel.eager"]
+        eps = 1e-6 if kernel == "rms_norm" else 1e-5
+        want = f"{kernel} ({eps},) float32[4, {H}]" \
+            + f" float32[{H}]" * len(vecs())
+        assert keys == [want] * 3
+        built = {s.attrs.get("key") for s in spans if s.name == "xla.lower"
+                 and kernel in s.attrs["fun"]}
+        assert built == {keys[0]}
 
 
 @pytest.mark.parametrize("kernel", sorted(KERNELS))

@@ -339,8 +339,9 @@ def test_generation_is_the_references_replay(fam, rule, steps):
     n_blocks = sum(-(-(len(p) % B + m) // B) for p, m in zip(prompts,
                                                              max_new))
     st = sched.block_stats
-    assert (st["blocks_committed"], st["commits_carried"],
-            st["commit_passes"]) == (n_blocks, n_blocks - len(prompts), 0)
+    assert (st["blocks_committed"], st["commits_carried"]) == \
+        (n_blocks, n_blocks - len(prompts))
+    assert "commit_passes" not in st
     assert n_blocks <= st["denoise_passes"] <= steps * n_blocks
     for i, (p, m) in enumerate(zip(prompts, max_new)):
         req = done[f"r{i}"]
@@ -365,8 +366,8 @@ def test_static_at_one_pass_fixes_the_whole_block_at_once(fam):
         {"denoising_steps": 1, "remasking": "low_confidence_static"})
     assert [sum(b) - sum(a) for _, _, b, a, _ in passes["r0"]] == [4, 4]
     st = sched.block_stats
-    assert (st["denoise_passes"], st["commit_passes"], st["commits_carried"],
-            st["tokens_unmasked"], st["blocks_committed"]) == (2, 0, 1, 8, 2)
+    assert (st["denoise_passes"], st["commits_carried"],
+            st["tokens_unmasked"], st["blocks_committed"]) == (2, 1, 8, 2)
     # the prompt, the first block, and the second behind the first
     assert calls == [[("r0", 0, 8)], [("r0", 8, 4)], [("r0", 8, 8)]]
 
@@ -439,9 +440,10 @@ def test_a_requests_last_block_is_never_committed(fam, prompt_len, max_new,
         snap = sched.metrics()["diffusion"]
     assert len(done["r0"].generated_ids) == want
     st = sched.block_stats
-    assert (st["blocks_committed"], st["commits_carried"],
-            st["commit_passes"]) == (blocks, blocks - 1, 0)
-    assert (snap["commits_carried"], snap["commit_passes"]) == (blocks - 1, 0)
+    assert (st["blocks_committed"], st["commits_carried"]) == \
+        (blocks, blocks - 1)
+    assert snap["commits_carried"] == blocks - 1
+    assert "commit_passes" not in snap
     # the last call is the last block's last denoising pass, and the rows
     # of two blocks are the first passes of the blocks behind the first
     assert len(calls) == 1 + len(passes["r0"])
@@ -461,8 +463,8 @@ def test_every_pass_carries_at_one_step_and_one_in_four_at_four(
         {"denoising_steps": steps, "remasking": "sequential"})
     check_against_replay(fam, cfg, done["r0"], prompt, passes["r0"])
     st = sched.block_stats
-    assert (st["denoise_passes"], st["commits_carried"], st["commit_passes"],
-            st["blocks_committed"]) == (row_passes, carried, 0, 3)
+    assert (st["denoise_passes"], st["commits_carried"],
+            st["blocks_committed"]) == (row_passes, carried, 3)
     fed = [n for c in calls[1:] for _, _, n in c]
     assert fed == [B] * steps + ([2 * B] + [B] * (steps - 1)) * 2
 
@@ -655,12 +657,20 @@ def test_spans_and_counters_of_the_block_step(fam):
     blocks = [s.attrs for s in spans if s.name == "serving.block"]
     # 1 prompt step (12 tokens in one chunk of 16), then 2 blocks of
     # 2 denoising passes, the second block's first carrying the first
-    assert [(a["denoise_rows"], a["commit_rows"], a["carried_rows"],
-             a["unmasked"], a["delivered"]) for a in blocks] == \
-        [(0, 0, 0, 0, 0), (1, 0, 0, 2, 0), (1, 0, 0, 2, 4),
-         (1, 0, 1, 2, 0), (1, 0, 0, 2, 4)]
+    assert [(a["denoise_rows"], a["carried_rows"], a["unmasked"],
+             a["delivered"]) for a in blocks] == \
+        [(0, 0, 0, 0), (1, 0, 2, 0), (1, 0, 2, 4), (1, 1, 2, 0),
+         (1, 0, 2, 4)]
+    assert not any("commit_rows" in a for a in blocks)
     ragged = [s.attrs for s in spans if s.name == "kernel.ragged"]
     assert all(a["block"] == B for a in ragged)
+    # the layer program's key names its three switches of a block model
+    keys = [s.attrs["key"] for s in spans if s.name == "model.layer"]
+    assert keys and all(k.startswith("layer n")
+                        and k.endswith(" block qk_norm router") for k in keys)
+    heads = {s.attrs["key"].split()[0] for s in spans
+             if s.name == "model.head"}
+    assert heads <= {"choose", "head"} and "choose" in heads
     first = ragged[0]                      # the prompt: 12 tokens, 3 blocks
     assert (first["fed"], first["kv_rows"], first["pairs"]) == \
         (12, 12, 4 * (4 + 8 + 12))
@@ -679,12 +689,14 @@ def test_spans_and_counters_of_the_block_step(fam):
     assert counts[1]["assignments"] == 2 * 4 * 2
     assert counts[3]["assignments"] == 2 * 8 * 2     # and 8 where it carries
     d = snap["diffusion"]
-    assert (d["denoise_passes"], d["commit_passes"], d["commits_carried"],
-            d["tokens_unmasked"], d["blocks_committed"]) == (4, 0, 1, 8, 2)
-    assert {n for n, _, _ in telemetry.SURFACE} >= {
-        "diffusion.denoise_passes", "diffusion.commit_passes",
-        "diffusion.commits_carried", "diffusion.tokens_unmasked",
-        "diffusion.blocks_committed"}
+    assert (d["denoise_passes"], d["commits_carried"],
+            d["tokens_unmasked"], d["blocks_committed"]) == (4, 1, 8, 2)
+    assert "commit_passes" not in d
+    surface = {n for n, _, _ in telemetry.SURFACE}
+    assert surface >= {
+        "diffusion.denoise_passes", "diffusion.commits_carried",
+        "diffusion.tokens_unmasked", "diffusion.blocks_committed"}
+    assert "diffusion.commit_passes" not in surface
 
 
 def test_transfer_counts_and_choose_of_the_reference(fam):
