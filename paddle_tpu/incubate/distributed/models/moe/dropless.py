@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from .....framework.core import apply_op
 from .....nn import initializer as I
 from .....nn.layer.layers import Layer
+from .....ops import kernels
+from .....ops.kernels import grouped_matmul as gmm
 
 __all__ = ["DroplessMoE", "route", "sort_by_expert", "grouped_matmul",
            "experts_ffn", "combine", "swiglu", "dropless_moe"]
@@ -73,10 +75,16 @@ def sort_by_expert(idx, num_experts, valid=None):
 def grouped_matmul(xs, w, group_sizes):
     """xs [A, K] (rows sorted by group) x w [E, K, N] -> [A, N]: row a is
     multiplied by the matrix of its group; rows past the last group give
-    zeros. ``jax.lax.ragged_dot``: the group sizes are device data."""
-    return jax.lax.ragged_dot(xs, w, group_sizes,
-                              preferred_element_type=jnp.float32
-                              ).astype(xs.dtype)
+    zeros; the group sizes are device data. On the chip the Pallas
+    kernel ``grouped_matmul`` (interpreted under ``FLAGS_pallas_interpret``
+    off it), else ``jax.lax.ragged_dot``; counted as
+    ``grouped_matmul:pallas|xla_fallback`` in ``kernel_dispatch_stats()``."""
+    pallas = kernels.use_pallas() or kernels.interpret_mode()
+    kernels.record_dispatch("grouped_matmul", pallas)
+    if pallas:
+        return gmm.grouped_matmul_pallas(xs, w, group_sizes,
+                                         kernels.interpret_mode())
+    return gmm.grouped_matmul_reference(xs, w, group_sizes)
 
 
 def swiglu(x, w_gate, w_up, w_down):

@@ -383,6 +383,9 @@ def test_sdar_layer_program(one_chip, monkeypatch, n_pad, b_pad, t_pad, mp):
               if re.search(r"= \w+\[%d,(%d,%d|%d,%d)\]" % (ne, e, f, f, e),
                            ln) and " parameter(" not in ln]
     assert stacks == [], stacks[:3]
+    # the three grouped matmuls are the Pallas kernel, not XLA's
+    assert text.count("grouped_matmul/pallas_call") == 3
+    assert "ragged-dot" not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
 
@@ -451,6 +454,25 @@ def test_routed_experts_program(one_chip):
              ((c, f), BF16), ((c, f), BF16), ((f, c), BF16),
              ((n,), jnp.bool_)]
     _compile(run, one_chip, *specs)
+
+
+# the routed experts' stacks and assignments a call: SDAR's [128, 2048,
+# 768] and [128, 768, 2048] at 2,048 / 4,096 / 8,192 (the 256, 512 and
+# 1,024 packed widths x top-8), Xing4's [64, 3584, 1024] and [64, 1024,
+# 3584] at 256 (64 decode rows x top-4)
+@pytest.mark.parametrize("m,k,n,e", [
+    (m, k, n, 128) for m in (2048, 4096, 8192)
+    for k, n in ((2048, 768), (768, 2048))
+] + [(256, 3584, 1024, 64), (256, 1024, 3584, 64)])
+def test_grouped_matmul(one_chip, m, k, n, e):
+    """The kernel at the tiling the code chooses for the shapes: its
+    double-buffered tiles fit the default scoped VMEM, which interpret
+    mode never checks."""
+    from paddle_tpu.ops.kernels.grouped_matmul import gmm_pallas
+
+    text = _compile(gmm_pallas, one_chip, ((m, k), BF16), ((e, k, n), BF16),
+                    ((e,), jnp.int32))
+    assert "grouped_matmul" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("window", [0, 1024])
@@ -545,3 +567,39 @@ def test_kernels_under_mp4_mesh(topo, monkeypatch):
     finally:
         M.reset_mesh()
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_grouped_matmul_under_mp4_mesh(topo, monkeypatch):
+    """The routed experts' kernel under a 4-device mesh, forward and
+    backward (``ragged_dot``'s): every device runs the whole call on
+    replicated operands inside a shard_map, whatever the operands'
+    sharding."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import paddle_tpu.ops.kernels as K
+    from paddle_tpu.distributed import mesh as M
+    from paddle_tpu.incubate.distributed.models.moe.dropless import \
+        dropless_moe
+
+    monkeypatch.setattr(K, "on_tpu", lambda: True)
+    mesh = M.build_global_mesh(("dp", "mp"), (1, 4),
+                               devices=np.array(topo.devices))
+    try:
+        def loss(x, wr, wg, wu, wd):
+            y, _ = dropless_moe(x, wr, None, wg, wu, wd, None, 2,
+                                scoring="softmax")
+            return y.astype(jnp.float32).sum()
+
+        def spec(shape, *names):
+            return jax.ShapeDtypeStruct(
+                shape, BF16, sharding=NamedSharding(mesh, P(*names)))
+
+        c, f, e = 512, 256, 8
+        text = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+            spec((64, c), "mp"), spec((c, e)), spec((e, c, f)),
+            spec((e, c, f), None, None, "mp"), spec((e, f, c))
+        ).compile().as_text()
+    finally:
+        M.reset_mesh()
+    assert text.count("grouped_matmul/pallas_call") == 3
